@@ -8,6 +8,11 @@ and speculative decoding with a layer-skip draft.  Prompts/completions
 ride the object plane zero-copy (put_many/get_many).
 
 Run: python examples/serve_llm.py
+
+On a TPU host: CHIPS=1 python examples/serve_llm.py.  Each replica then
+reserves one chip with ``ray_actor_options={"num_tpus": 1}`` and decodes
+there; a replica that reserves no chip is a CPU worker.  ``stats()``
+reports the platform the engine is on.
 """
 import os
 import sys
@@ -27,9 +32,14 @@ if __name__ == "__main__":
     # One directory actor shares published KV pages across every
     # replica; bind args carry its handle into each LLMServer.
     directory = create_directory()
+    chips = int(os.environ.get("CHIPS", "0"))
+    # A replica that reserves chips can only be added while some are free.
+    max_replicas = 2 if not chips else max(1, min(2, int(
+        ray_tpu.cluster_resources().get("TPU", 0)) // chips))
     dep = serve.deployment(
         LLMServer, name="llm",
-        autoscaling_config={"min_replicas": 1, "max_replicas": 2,
+        ray_actor_options={"num_tpus": chips} if chips else None,
+        autoscaling_config={"min_replicas": 1, "max_replicas": max_replicas,
                             # Scale on engine load (active+queued work
                             # per decode slot), not router queue depth.
                             "metric_method": "autoscale_metric",
@@ -74,6 +84,7 @@ if __name__ == "__main__":
         print("chunk", n, "->", chunk)
 
     stats = ray_tpu.get(handle.method("stats").remote())
+    print("decoding on:", stats["platform"], stats["device_kind"])
     print("mid-batch admissions:", stats["admitted_mid_batch"],
           "avg occupancy:", round(stats["avg_batch_occupancy"], 2))
     print("prefix cache: hit pages", stats["prefix_hit_pages"],

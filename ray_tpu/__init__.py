@@ -41,19 +41,21 @@ def _default_num_cpus() -> float:
 
 
 def _detect_num_tpus() -> float:
+    """Chips on this host, counted from their device files: ``/dev/accel<N>``
+    (through v4) or one numbered vfio group per chip (v5e and later).
+
+    Initialising a JAX backend here would count them too, and would take
+    the chips away from the workers: libtpu hands a chip to one process.
+    A count that is wrong (a vfio group that is no TPU) is caught where
+    the chip is first used — ``mesh_group.rendezvous`` raises when a
+    worker granted chips comes up on the CPU."""
     env = os.environ.get("RAY_TPU_NUM_TPUS")
     if env:
         return float(env)
-    import sys
+    import glob
 
-    jax = sys.modules.get("jax")
-    if jax is not None:
-        try:
-            return float(len([d for d in jax.local_devices()
-                              if d.platform != "cpu"]))
-        except Exception:
-            return 0.0
-    return 0.0
+    return float(len(glob.glob("/dev/accel[0-9]*"))
+                 + len(glob.glob("/dev/vfio/[0-9]*")))
 
 
 def _boot_head(resources: Dict[str, float], labels=None,

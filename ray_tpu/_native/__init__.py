@@ -1,7 +1,8 @@
 """Native (C++) components, built on demand with g++ and bound via ctypes.
 
 Currently: the shared-memory arena store (shm_store.cpp) — the plasma-core
-equivalent.  Falls back gracefully (callers check `available()`)."""
+equivalent.  The binary is never committed: it is built from the source
+beside it the first time it is asked for, and a build that fails raises."""
 from __future__ import annotations
 
 import ctypes
@@ -17,39 +18,35 @@ _SRC = os.path.join(_HERE, "shm_store.cpp")
 
 _lib = None
 _build_lock = threading.Lock()
-_build_failed = False
 
 
-def _build() -> Optional[ctypes.CDLL]:
-    global _build_failed
+def _build() -> ctypes.CDLL:
     with _build_lock:
         if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
             try:
                 return ctypes.CDLL(_SO)
             except OSError:
-                # A stale binary built against a different glibc/toolchain
-                # (e.g. checked out on an older container) must not break
-                # the graceful fallback — rebuild from source below.
-                pass
-        if _build_failed:
-            return None
+                pass  # built against another toolchain: rebuild below
         try:
+            # Build beside the target and rename: another process must
+            # never load a half-written library.
             subprocess.run(
                 ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC,
-                 "-o", _SO, "-lrt"],
+                 "-o", _SO + ".tmp", "-lrt"],
                 check=True, capture_output=True, timeout=120)
-            return ctypes.CDLL(_SO)
-        except Exception:
-            _build_failed = True
-            return None
+        except (OSError, subprocess.SubprocessError) as e:
+            raise RuntimeError(
+                f"native store build failed: {e}\n"
+                f"{(getattr(e, 'stderr', None) or b'').decode(errors='replace')}"
+            ) from e
+        os.replace(_SO + ".tmp", _SO)
+        return ctypes.CDLL(_SO)
 
 
-def _get_lib() -> Optional[ctypes.CDLL]:
+def _get_lib() -> ctypes.CDLL:
     global _lib
     if _lib is None:
         lib = _build()
-        if lib is None:
-            return None
         lib.rtpu_store_create.restype = ctypes.c_void_p
         lib.rtpu_store_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
         lib.rtpu_store_destroy.argtypes = [ctypes.c_void_p]
@@ -80,7 +77,13 @@ def _get_lib() -> Optional[ctypes.CDLL]:
 
 
 def available() -> bool:
-    return _get_lib() is not None
+    """True when the library loads or builds here (for test skips; code
+    that was asked for the native store lets the build error surface)."""
+    try:
+        _get_lib()
+    except RuntimeError:
+        return False
+    return True
 
 
 class NativeArenaStore:
@@ -88,8 +91,6 @@ class NativeArenaStore:
 
     def __init__(self, name: str, capacity: int):
         lib = _get_lib()
-        if lib is None:
-            raise RuntimeError("native store unavailable (g++ build failed)")
         self._lib = lib
         self.name = name
         self.capacity = capacity

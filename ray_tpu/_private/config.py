@@ -115,9 +115,9 @@ CONFIG \
              "batched-notify object plane (put_many coalescing, pooled "
              "pre-faulted segments — the measured 7-8 GB/s path) and "
              "bypasses both; opt in only until it learns those "
-             "semantics.  (It was also silently disabled for several "
-             "rounds by a stale libshm_store.so built against a newer "
-             "glibc — the loader now rebuilds from source instead.)") \
+             "semantics.  The library is built from shm_store.cpp on "
+             "first use (the binary is not committed); asked for and "
+             "unbuildable, it raises.") \
     .declare("worker_idle_ttl_s", float, 300.0,
              "Idle pooled workers are reaped after this long.") \
     .declare("max_workers_per_node", int, 64,

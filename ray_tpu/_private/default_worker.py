@@ -42,6 +42,11 @@ def main():
         faulthandler.register(signal.SIGUSR1)
     except Exception:
         pass
+    # Before any task can import jax: the compile cache is placed through
+    # the environment, which jax reads once, at import.
+    from ray_tpu._private.jax_env import ensure_compile_cache
+
+    ensure_compile_cache()
     authkey = bytes.fromhex(os.environ["RAY_TPU_AUTHKEY"])
     node_id = NodeID.from_hex(os.environ["RAY_TPU_NODE_ID"])
     worker_id = WorkerID.from_hex(os.environ["RAY_TPU_WORKER_ID"])

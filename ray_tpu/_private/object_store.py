@@ -475,14 +475,12 @@ class SharedMemoryStore:
         from ray_tpu._private.config import CONFIG
 
         if use_native_arena and CONFIG.native_store:
-            try:
-                from ray_tpu import _native
+            # Asked for by configuration: a build failure raises here — the
+            # store does not quietly become the segment store.
+            from ray_tpu import _native
 
-                if _native.available():
-                    self.arena = _native.NativeArenaStore(
-                        "rtpu_arena_" + os.urandom(6).hex(), capacity_bytes)
-            except Exception:
-                self.arena = None
+            self.arena = _native.NativeArenaStore(
+                "rtpu_arena_" + os.urandom(6).hex(), capacity_bytes)
         # Segment pool: steady-state large puts reuse pre-faulted recycled
         # segments instead of paying shm_open + kernel page-zeroing per
         # object (see SegmentPool).  Free-list bytes are NOT charged to

@@ -190,9 +190,16 @@ class ClusterScheduler:
             if all(cap.get(k, 0.0) >= v
                    for k, v in spec.resources.items()):
                 return  # the autoscaler can launch a node for this
+        totals = self.total_resources()
+        if spec.resources.get("TPU") and not totals.get("TPU"):
+            raise Infeasible(
+                f"{spec.name or 'task'} asks for TPU={spec.resources['TPU']:g} "
+                f"but this cluster has no TPU resource: ray_tpu.init() found "
+                f"no TPU chip on its host and was given no num_tpus=; "
+                f"cluster totals {totals}")
         raise Infeasible(
             f"no node can ever satisfy {spec.resources}; "
-            f"cluster totals {dict(self.total_resources())}"
+            f"cluster totals {totals}"
         )
 
     def _pick_default(self, spec: TaskSpec, preferred: Optional[NodeID],

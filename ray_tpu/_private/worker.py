@@ -65,6 +65,26 @@ def _obs():
 # ---------------------------------------------------------------------------
 # Transports
 # ---------------------------------------------------------------------------
+_NO_REPLY = object()
+
+
+def _reply_or(fut: Future, timeout: Optional[float]):
+    """The reply a request future holds — its value returned, its exception
+    raised — or ``_NO_REPLY`` when none came within ``timeout``.
+
+    ``fut.result(timeout)`` cannot tell the two apart: from Python 3.11 on
+    ``concurrent.futures.TimeoutError`` IS the builtin ``TimeoutError``,
+    which ``GetTimeoutError`` subclasses, so "the future timed out" and
+    "the head replied that the get timed out" land in one except clause."""
+    try:
+        error = fut.exception(timeout=timeout)
+    except FuturesTimeoutError:
+        return _NO_REPLY
+    if error is not None:
+        raise error
+    return fut.result()
+
+
 class DirectTransport:
     """Driver-side transport: function calls straight into the Head."""
 
@@ -102,13 +122,13 @@ class DirectTransport:
 
         start = _time.monotonic()
         self.head.handle_request(op, payload, reply, self.worker_id)
-        try:
-            # timeout=None keeps blocking semantics (in-process calls
-            # cannot lose their reply); a given timeout is enforced.
-            return fut.result(timeout=timeout)
-        except FuturesTimeoutError:
+        # timeout=None keeps blocking semantics (in-process calls
+        # cannot lose their reply); a given timeout is enforced.
+        value = _reply_or(fut, timeout)
+        if value is _NO_REPLY:
             raise exc.RpcTimeoutError(
                 op=op, elapsed=_time.monotonic() - start, timeout=timeout)
+        return value
 
     def _request_faulted(self, sched, op: str, payload: dict,
                          timeout: Optional[float]):
@@ -152,11 +172,9 @@ class DirectTransport:
                     self.head.handle_request_keyed(op, payload, reply,
                                                    self.worker_id, key)
             attempts += 1
-            try:
-                return fut.result(
-                    timeout=max(0.001, deadline.bound(attempt_iv)))
-            except FuturesTimeoutError:
-                pass
+            value = _reply_or(fut, max(0.001, deadline.bound(attempt_iv)))
+            if value is not _NO_REPLY:
+                return value
             if deadline.expired():
                 retry_mod.note("timeouts")
                 raise exc.RpcTimeoutError(op=op, elapsed=deadline.elapsed(),
@@ -340,11 +358,10 @@ class ConnTransport:
                     pass  # conn breaking/being replaced: paced retry below
                 rec.attempts += 1
                 rec.last_send = _time.monotonic()
-                try:
-                    return fut.result(
-                        timeout=max(0.001, deadline.bound(attempt_wait)))
-                except FuturesTimeoutError:
-                    pass
+                value = _reply_or(
+                    fut, max(0.001, deadline.bound(attempt_wait)))
+                if value is not _NO_REPLY:
+                    return value
                 if self._closed:
                     raise exc.RayTpuError("connection closed")
                 if deadline.expired():

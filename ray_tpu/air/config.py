@@ -30,6 +30,15 @@ class ScalingConfig:
     mesh: Optional[MeshSpec] = None
     placement_strategy: str = "PACK"
 
+    def __post_init__(self):
+        if self.use_tpu and self.chips_per_worker < 1:
+            # A worker that reserves no chip is started on the CPU (the
+            # raylet keeps chips for the workers that asked): use_tpu with
+            # no chips would train there without saying so.
+            raise ValueError(
+                "ScalingConfig(use_tpu=True) needs chips_per_worker >= 1: "
+                "a worker only sees the chips it reserves")
+
     def worker_range(self) -> Tuple[int, int]:
         """(min, max) worker count — a fixed ``num_workers=n`` is the
         degenerate range (n, n)."""
@@ -59,7 +68,7 @@ class ScalingConfig:
     def worker_resources(self) -> Dict[str, float]:
         res = dict(self.resources_per_worker or {})
         res.setdefault("CPU", 1.0)
-        if self.use_tpu and self.chips_per_worker:
+        if self.use_tpu:
             res["TPU"] = float(self.chips_per_worker)
         return res
 

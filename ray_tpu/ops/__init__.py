@@ -1,7 +1,8 @@
-"""TPU compute ops: Pallas kernels with pure-XLA fallbacks.
+"""TPU compute ops: Pallas kernels beside their pure-XLA references.
 
-Kernel selection: pallas on real TPU, jnp reference elsewhere (CPU test
-meshes can't run Mosaic kernels).  Everything here is shape-static and
+Kernel selection is by platform and shape: pallas on real TPU, jnp
+reference elsewhere (CPU test meshes can't run Mosaic kernels).  A kernel
+that was selected and fails is an error, never a quiet switch of path.  Everything here is shape-static and
 jit/scan-friendly per XLA's compilation model.
 """
 from ray_tpu.ops.attention import (  # noqa: F401

@@ -21,22 +21,40 @@ NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
 
 def mha_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   causal: bool = True, sm_scale: Optional[float] = None,
-                  use_flash: Optional[bool] = None) -> jax.Array:
+                  use_flash: Optional[bool] = None, mesh=None) -> jax.Array:
     """Multi-head attention. q,k,v: [B, L, H, D] → [B, L, H, D].
 
     Dispatches to the Pallas flash kernel on real TPU backends for long
-    sequences, XLA reference otherwise.  The crossover is measured, not
-    assumed: on v5e (GPT-2 heads, d=64) with the tuned (256, 1024)
-    blocks the fused kernel's fwd+bwd beats XLA ~1.5x at 1k ctx, ~1.7x
-    at 4k, more beyond — below 1k the XLA path wins because attention is
-    a tiny FLOP fraction there and the d<128 lane padding around the
-    custom call costs more than the [L, L] materialization it avoids."""
+    sequences, XLA reference otherwise.  Below 1k ctx the XLA path is
+    chosen because attention is a tiny FLOP fraction there and the d<128
+    lane padding around the custom call costs more than the [L, L]
+    materialization it avoids (speeds on the current installation: not
+    measured).  A kernel that fails to trace or compile is the failure:
+    there is no fallback to the XLA path.
+
+    ``mesh``: pass it when the call sits under a plain ``jit`` whose arrays
+    are sharded over that mesh (``prepare_batch`` / ``prepare_train_state``).
+    The compiler will not split a Mosaic kernel by itself ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map" — seen on a four-chip v5e host), and at trace time the
+    inputs' shardings cannot be read, so the caller has to name the mesh:
+    the call is then shard_mapped by the repo's logical-axis rules, batch
+    over the data axes and heads over ``model``, every device running the
+    kernel on its own rows.  Inside a shard_map body arrays are already
+    per-device: leave it None there."""
+    if mesh is not None:
+        from ray_tpu.parallel.sharding import ShardingRules
+
+        spec = ShardingRules().spec_for(("batch", None, "heads", None), mesh)
+        local = functools.partial(mha_attention, causal=causal,
+                                  sm_scale=sm_scale, use_flash=use_flash)
+        return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
     b, lq, h, _ = q.shape
     lk = k.shape[1]
-    # [B, H, Lq, Lk] score-matrix footprint the XLA path materializes
-    # (also used by the fallback warning below for explicit use_flash).
-    score_bytes = b * h * lq * lk * q.dtype.itemsize
     if use_flash is None:
+        # [B, H, Lq, Lk] score-matrix footprint the XLA path materializes.
+        score_bytes = b * h * lq * lk * q.dtype.itemsize
         use_flash = (jax.default_backend() not in ("cpu",)
                      and lq % 128 == 0 and lk % 128 == 0
                      # Speed crossover is ~1k ctx with the tuned block
@@ -50,22 +68,7 @@ def mha_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      # auto-dispatch.
                      and (not causal or lq == lk))
     if use_flash:
-        try:
-            return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
-        except Exception as e:
-            if score_bytes > 512 * 1024 * 1024:
-                # Dispatch chose flash BECAUSE the XLA score matrix would
-                # likely OOM: falling back silently would surface as an
-                # opaque HBM OOM (or a silent 10x slowdown) instead of the
-                # real kernel failure — make the cause visible first.
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "flash attention kernel failed (%s: %s); falling back "
-                    "to the XLA path, which needs a ~%dMB score matrix and "
-                    "may OOM", type(e).__name__, e,
-                    score_bytes // (1024 * 1024))
-            # Fall back to the XLA path (e.g. interpreter platforms).
+        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
     return _xla_attention(q, k, v, causal, sm_scale)
 
 

@@ -137,15 +137,11 @@ def make_expert_parallel_moe(mesh, cfg: MoEConfig, num_tokens_per_shard: int,
     `axis_name` on dim 0 and the expert dim of w_in/w_out sharded over the
     same axis; w_router replicated."""
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # moved in newer jax
-        from jax.shard_map import shard_map  # type: ignore
 
     capacity = cfg.capacity(num_tokens_per_shard)
     body = functools.partial(moe_apply_expert_parallel, cfg=cfg,
                              capacity=capacity, axis_name=axis_name)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis_name, None), P(), P(axis_name, None, None),
                   P(axis_name, None, None)),

@@ -216,8 +216,6 @@ def _build_engine(spec: Dict[str, Any], params_host: Any, mesh,
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.rllib.utils.mesh import _shard_map
-
     world = int(np.prod(list(mesh.shape.values())))
     tx = spec["tx_factory"]()
     # Lane-granularity sharder: a FIXED lane count regardless of gang
@@ -233,9 +231,10 @@ def _build_engine(spec: Dict[str, Any], params_host: Any, mesh,
     step = build_elastic_step(spec["loss_fn"], tx, sharder,
                               slots=spec["slots"], world=world,
                               grad_clip=spec.get("grad_clip"))
-    stepj = jax.jit(_shard_map(step, mesh=mesh,
-                               in_specs=(P(), opt_specs, P(DATA_AXIS)),
-                               out_specs=(P(), opt_specs, P())))
+    stepj = jax.jit(jax.shard_map(step, mesh=mesh,
+                                  in_specs=(P(), opt_specs, P(DATA_AXIS)),
+                                  out_specs=(P(), opt_specs, P()),
+                                  check_vma=False))
     return {"tx": tx, "sharder": sharder, "opt_specs": opt_specs,
             "stepj": stepj, "world": world}
 

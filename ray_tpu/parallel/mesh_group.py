@@ -231,16 +231,13 @@ def bootstrap_jax_distributed(coordinator: str, world_size: int, rank: int,
     import jax
 
     if world_size > 1:
-        try:
-            from jax._src import xla_bridge
+        from jax._src import xla_bridge
 
-            if xla_bridge.backends_are_initialized():
-                raise RuntimeError(
-                    "mesh worker's jax backend was initialized before "
-                    "bootstrap (the worker ran jax code earlier); a "
-                    "multi-host MeshGroup requires fresh worker processes")
-        except ImportError:  # private API moved — proceed optimistically
-            pass
+        if xla_bridge.backends_are_initialized():
+            raise RuntimeError(
+                "mesh worker's jax backend was initialized before "
+                "bootstrap (the worker ran jax code earlier); a "
+                "multi-host MeshGroup requires fresh worker processes")
     if platform:
         try:
             jax.config.update("jax_platforms", platform)
@@ -271,10 +268,16 @@ def bootstrap_jax_distributed(coordinator: str, world_size: int, rank: int,
                 time.sleep(0.2 * (attempt + 1))
         if os.environ.get("RAY_TPU_GLOO_WARMUP", "1") != "0":
             _collective_warmup()
+    from ray_tpu._private.jax_env import CHIP_WORKER_ENV
+
+    dev = jax.local_devices()[0]
     return {"rank": rank,
             "process_index": jax.process_index(),
             "local_devices": jax.local_device_count(),
-            "global_devices": jax.device_count()}
+            "global_devices": jax.device_count(),
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "chips_granted": os.environ.get(CHIP_WORKER_ENV) == "1"}
 
 
 def _collective_warmup() -> None:
@@ -542,7 +545,32 @@ def rendezvous(workers: Sequence, platform: Optional[str] = None,
     # The rendezvous itself is a collective: a rank dying inside
     # jax.distributed.initialize would otherwise hang the peers (and the
     # driver) forever.
-    return gang_get(calls, timeout=timeout)
+    infos = gang_get(calls, timeout=timeout)
+    total = sum(i["local_devices"] for i in infos)
+    split = [i["rank"] for i in infos if i["global_devices"] != total]
+    if split:
+        # Seen on a four-chip v5e host with one chip per process: the raylet
+        # gives each process TPU_PROCESS_BOUNDS=1,1,1, so libtpu makes every
+        # process a slice of its own, and jax.distributed joins the
+        # coordinator without joining the devices.
+        raise RuntimeError(
+            f"the {world} ranks did not form one jax world: rank(s) {split} "
+            f"see {[i['global_devices'] for i in infos]} global devices, the "
+            f"gang holds {total}.  Several chip-owning processes on ONE host "
+            f"are separate TPU slices here; drive a host's chips from one "
+            f"worker (resources_per_host={{'TPU': <all of them>}}) and gang "
+            f"one worker per host.")
+    if platform != "cpu":
+        on_cpu = [i["rank"] for i in infos
+                  if i["chips_granted"] and i["platform"] == "cpu"]
+        if on_cpu:
+            raise RuntimeError(
+                f"rank(s) {on_cpu} were granted TPU chips but JAX came up on "
+                f"the cpu platform there: no TPU was found by libtpu in the "
+                f"worker process (chips held by another process, a TPU "
+                f"resource declared on a host without chips, or JAX_PLATFORMS "
+                f"excluding tpu).  Refusing to run a TPU job on the CPU.")
+    return infos
 
 
 def _restart_metrics():
@@ -905,9 +933,11 @@ class MeshGroup:
     # ---- gang lifecycle ----
     def _actor_opts(self) -> Dict[str, Any]:
         res = self._resources
-        opts: Dict[str, Any] = {"max_concurrency": self.pipeline_depth + 2}
-        if res.get("CPU"):
-            opts["num_cpus"] = res["CPU"]
+        # The actor asks for exactly what its bundle holds: left unset,
+        # num_cpus defaults to 1, which a {"TPU": n} bundle cannot give,
+        # and the gang would wait for placement until its timeout.
+        opts: Dict[str, Any] = {"max_concurrency": self.pipeline_depth + 2,
+                                "num_cpus": res.get("CPU", 0.0)}
         if res.get("TPU"):
             opts["num_tpus"] = res["TPU"]
         extra = {k: v for k, v in res.items() if k not in ("CPU", "TPU")}
@@ -945,8 +975,12 @@ class MeshGroup:
                                               self.local_device_count,
                                               timeout=self.bootstrap_timeout)
                 return
-            except exc.MeshGroupError as e:
+            except BaseException as e:
                 if attempt >= attempts - 1 or not is_transport_abort(e):
+                    # The caller gets no handle to a gang that failed its
+                    # rendezvous, so nobody else can free what it holds
+                    # (its chips, above all).
+                    self._teardown_workers()
                     raise
                 for w in self.workers:
                     try:

@@ -426,7 +426,6 @@ class StageCore:
         from jax.sharding import PartitionSpec as P
 
         from ray_tpu.parallel import zero as zero_mod
-        from ray_tpu.rllib.utils.mesh import _shard_map
 
         world = dict(self._mesh.shape).get("data", 1)
         zu = zero_mod.build_zero_update(
@@ -442,9 +441,10 @@ class StageCore:
             params, opt_block = zu.update(grads, opt_block, params)
             return params, opt_block
 
-        mapped = _shard_map(body, mesh=self._mesh,
-                            in_specs=(P(), zu.opt_specs, P(), P()),
-                            out_specs=(P(), zu.opt_specs))
+        mapped = jax.shard_map(body, mesh=self._mesh,
+                               in_specs=(P(), zu.opt_specs, P(), P()),
+                               out_specs=(P(), zu.opt_specs),
+                               check_vma=False)
         self._apply.append(jax.jit(
             mapped, donate_argnums=(0, 1, 2) if donate else ()))
         opt_sh = jax.tree_util.tree_map(

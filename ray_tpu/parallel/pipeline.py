@@ -78,7 +78,6 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     x_micro: [n_micro, mb, ...] microbatched input
     Returns [n_micro, mb, ...] outputs (replicated over `pipe`).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if axis not in mesh.axis_names:
@@ -99,9 +98,9 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     body = functools.partial(_pipeline_body, stage_fn=stage_fn,
                              axis_name=axis, n_stages=n_stages,
                              n_micro=n_micro, remat=remat)
-    return shard_map(body, mesh=mesh,
-                     in_specs=(param_spec, P()), out_specs=P(),
-                     check_rep=False)(stacked_params, x_micro)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(param_spec, P()), out_specs=P(),
+                         check_vma=False)(stacked_params, x_micro)
 
 
 def microbatch(x: jax.Array, n_micro: int) -> jax.Array:

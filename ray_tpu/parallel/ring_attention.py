@@ -69,7 +69,6 @@ def ring_attention(q, k, v, mesh, axis: str = "sequence",
     Usable standalone or composed inside a larger pjit program; the shard_map
     boundary keeps the ppermute schedule explicit while XLA still fuses the
     local blockwise math."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if axis not in mesh.axis_names:
@@ -83,8 +82,8 @@ def ring_attention(q, k, v, mesh, axis: str = "sequence",
     spec = P(bax if bax else None, axis, None, None)
     fn = functools.partial(_ring_fwd, axis_name=axis, axis_size=axis_size,
                            causal=causal, sm_scale=sm_scale)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _ulysses_fwd(q, k, v, *, axis_name: str, axis_size: int, causal: bool,
@@ -112,7 +111,6 @@ def ulysses_attention(q, k, v, mesh, axis: str = "sequence",
     """Ulysses-style sequence parallelism: all_to_all head/sequence swap.
 
     Requires num_heads % axis_size == 0."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if axis not in mesh.axis_names:
@@ -128,5 +126,5 @@ def ulysses_attention(q, k, v, mesh, axis: str = "sequence",
     spec = P(bax if bax else None, axis, None, None)
     fn = functools.partial(_ulysses_fwd, axis_name=axis, axis_size=axis_size,
                            causal=causal, sm_scale=sm_scale)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

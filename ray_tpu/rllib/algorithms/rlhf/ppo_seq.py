@@ -164,10 +164,10 @@ class SeqPPOLearner:
                            "response_mask": P(mesh_util.DATA_AXIS),
                            "behavior_logp": P(mesh_util.DATA_AXIS),
                            "rewards": P(mesh_util.DATA_AXIS)}
-            mapped = mesh_util._shard_map(
+            mapped = jax.shard_map(
                 train_step, mesh=mesh,
                 in_specs=(P(), opt_specs, P(), batch_specs),
-                out_specs=(P(), opt_specs, P(), P()))
+                out_specs=(P(), opt_specs, P(), P()), check_vma=False)
             self._step = jax.jit(mapped)
             init_sh = mesh_util.state_sharding(mesh, opt_specs)
             self._opt_state = jax.jit(

@@ -59,30 +59,13 @@ def state_sharding(mesh: Mesh, state_specs):
                         is_leaf=lambda s: isinstance(s, P))
 
 
-def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
-    """Version shim: jax >= 0.6 exposes top-level ``jax.shard_map`` with
-    ``check_vma``; older jax (this image ships 0.4.x) has
-    ``jax.experimental.shard_map.shard_map`` with the same knob under its
-    pre-rename name ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except AttributeError:
-            pass  # deprecation stub that raises on access (jax 0.4.3x)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def shard_train_step(step_fn, mesh: Mesh, state_specs, donate: bool = False):
     """jit(shard_map(...)) for a `state -> (state, metrics)` train step.
 
     `state_specs` is a pytree prefix of PartitionSpecs for the state;
     metrics are replicated (the step body must pmean/psum them)."""
-    mapped = _shard_map(step_fn, mesh=mesh, in_specs=(state_specs,),
-                        out_specs=(state_specs, P()))
+    mapped = jax.shard_map(step_fn, mesh=mesh, in_specs=(state_specs,),
+                           out_specs=(state_specs, P()), check_vma=False)
     return jax.jit(mapped, donate_argnums=(0,) if donate else ())
 
 

@@ -283,6 +283,8 @@ class LLMEngine:
                  self.kv_heads, self.head_dim)
         self._k_pages = jnp.zeros(shape, self.dtype)
         self._v_pages = jnp.zeros(shape, self.dtype)
+        # Where the page pool lives is where the engine decodes.
+        self._device = next(iter(self._k_pages.devices()))
 
         # ---- speculative decoding (draft + verify) ----
         self.spec_tokens = int(_cfg("serve_spec_tokens", spec_tokens,
@@ -646,6 +648,8 @@ class LLMEngine:
         cache_size = getattr(self._decode, "_cache_size", None)
         if callable(cache_size):
             out["decode_cache_size"] = cache_size()
+        out["platform"] = self._device.platform
+        out["device_kind"] = self._device.device_kind
         return out
 
     def close(self, timeout: float = 10.0):

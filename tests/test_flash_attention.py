@@ -112,6 +112,40 @@ def test_auto_dispatch_uses_xla_on_cpu():
     assert bool(jnp.all(jnp.isfinite(g)))
 
 
+def test_explicit_flash_propagates_the_kernel_error():
+    """use_flash=True is a request for the kernel: where it cannot compile
+    (here: the CPU backend, compiled mode) the error must reach the caller,
+    not be swallowed by a quiet switch to the XLA path."""
+    q, k, v = _rand_qkv(1, 256, 2, 32)
+    with pytest.raises(ValueError, match="interpret mode"):
+        mha_attention(q, k, v, causal=True, use_flash=True)
+
+
+def test_mesh_aware_attention_matches_unsharded():
+    """mha_attention(mesh=...) under a plain jit with batch- and head-
+    sharded inputs: the shard_map over the logical-axis rules gives every
+    device its own rows, and the result and gradient equal the unsharded
+    ones.  (On the chip this is what lets the Mosaic kernel run under a
+    sharded jit at all; here the XLA path runs inside the shard_map.)"""
+    from ray_tpu.parallel import MeshSpec, batch_sharding, make_mesh
+
+    mesh = make_mesh(MeshSpec({"data": 4, "model": 2}))
+    q, k, v = _rand_qkv(8, 128, 4, 32)
+    qs, ks, vs = (jax.device_put(x, batch_sharding(mesh, 4))
+                  for x in (q, k, v))
+    got = jax.jit(lambda q, k, v: mha_attention(q, k, v, mesh=mesh))(
+        qs, ks, vs)
+    assert got.sharding.spec[0] == "data" and got.sharding.spec[2] == "model"
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(mha_attention(q, k, v)),
+                               atol=1e-6, rtol=1e-6)
+    g = jax.jit(jax.grad(lambda q: jnp.sum(
+        mha_attention(q, ks, vs, mesh=mesh) ** 2)))(qs)
+    gw = jax.grad(lambda q: jnp.sum(mha_attention(q, k, v) ** 2))(q)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(gw),
+                               atol=1e-5, rtol=1e-5)
+
+
 def test_flash_vjp_composes_with_jit_and_vmap():
     """jit(grad(...)) and vmap over the custom VJP both work and match
     the XLA reference (the residual plumbing must survive both

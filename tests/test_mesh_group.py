@@ -40,6 +40,25 @@ def test_mesh_group_two_process_allreduce(shutdown_only):
         mg.shutdown()
 
 
+def test_rendezvous_refuses_chip_worker_that_came_up_on_cpu(shutdown_only):
+    """A worker that was granted TPU chips and finds none must fail the
+    rendezvous: a TPU job does not quietly run on the CPU.  (The TPU
+    resource is declared on a CPU node, so libtpu finds nothing.)"""
+    from ray_tpu.parallel import MeshGroup
+
+    ray_tpu.init(num_cpus=2, num_tpus=1, object_store_memory=256 * 1024**2)
+    with pytest.raises(RuntimeError, match="granted TPU chips"):
+        MeshGroup(num_hosts=1, resources_per_host={"TPU": 1})
+    # Asking for the CPU by name is a statement, not an accident.
+    mg = MeshGroup(num_hosts=1, resources_per_host={"TPU": 1},
+                   platform="cpu")
+    try:
+        (info,) = mg.device_info
+        assert info["platform"] == "cpu" and info["chips_granted"]
+    finally:
+        mg.shutdown()
+
+
 def test_distributed_learner_group_two_hosts(shutdown_only):
     from ray_tpu.rllib.core.learner import DistributedLearnerGroup
 

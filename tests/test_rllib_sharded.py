@@ -45,9 +45,10 @@ def test_normalize_global_matches_host():
     mesh = mesh_util.data_mesh(DEVICES)
     x = jnp.asarray(np.random.RandomState(0).randn(16, 24).astype(np.float32))
 
-    out = jax.jit(mesh_util._shard_map(
+    out = jax.jit(jax.shard_map(
         lambda v: mesh_util.normalize_global(v, True),
-        mesh=mesh, in_specs=(P("data"),), out_specs=P("data")))(x)
+        mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
+        check_vma=False))(x)
     expect = (x - x.mean()) / (jnp.sqrt(jnp.mean((x - x.mean()) ** 2)) + 1e-8)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-5, atol=2e-6)
@@ -102,9 +103,10 @@ def test_sharded_full_batch_update_matches_single_device():
             loc, loc, 1, 2, tx, sharded=True)
         return p, o
 
-    mapped = jax.jit(mesh_util._shard_map(
+    mapped = jax.jit(jax.shard_map(
         sharded, mesh=mesh,
-        in_specs=(P(), P(), P(), P("data")), out_specs=(P(), P())))
+        in_specs=(P(), P(), P(), P("data")), out_specs=(P(), P()),
+        check_vma=False))
     p8, _ = mapped(params, opt_state, rng, batch)
 
     for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p8)):

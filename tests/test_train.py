@@ -41,6 +41,15 @@ def test_scaling_config_elastic_range():
         ScalingConfig(num_workers=0).worker_range()
 
 
+def test_scaling_config_use_tpu_needs_chips():
+    """use_tpu with no chips reserved would start CPU workers and train
+    there without saying so."""
+    with pytest.raises(ValueError, match="chips_per_worker"):
+        ScalingConfig(use_tpu=True, chips_per_worker=0)
+    sc = ScalingConfig(use_tpu=True, chips_per_worker=2)
+    assert sc.worker_resources()["TPU"] == 2.0
+
+
 def test_backend_executor_elastic_range(ray_start_regular):
     """num_workers=(min, max): start() probes max->min and takes the
     largest gang the cluster can place now."""

@@ -99,9 +99,9 @@ def test_quantized_pmean_replica_identical_and_close():
         flat, _ = jax.flatten_util.ravel_pytree(out)
         return flat[None]
 
-    out = np.asarray(jax.jit(mesh_util._shard_map(
+    out = np.asarray(jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"),),
-        out_specs=P("data")))(per_dev))
+        out_specs=P("data"), check_vma=False))(per_dev))
     for i in range(1, w):
         np.testing.assert_array_equal(out[0], out[i])
     exact = np.asarray(per_dev).mean(0)
@@ -170,9 +170,9 @@ def _zero_run(params, x, world, mode, steps=3, clip=0.5, lr=1e-2,
     def step(p, opt, xloc):
         return zu.update(jax.grad(_toy_loss)(p, xloc), opt, p)
 
-    stepj = jax.jit(mesh_util._shard_map(
+    stepj = jax.jit(jax.shard_map(
         step, mesh=mesh, in_specs=(P(), zu.opt_specs, P("data")),
-        out_specs=(P(), zu.opt_specs)))
+        out_specs=(P(), zu.opt_specs), check_vma=False))
     shardings = jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), zu.opt_specs,
         is_leaf=lambda s: isinstance(s, P))
@@ -307,9 +307,10 @@ def test_zero_ppo_sgd_matches_replicated(world):
             loc, loc, 1, 2, None, sharded=True, update_fn=update_fn)
         return p
 
-    mapped = jax.jit(mesh_util._shard_map(
+    mapped = jax.jit(jax.shard_map(
         sharded, mesh=mesh,
-        in_specs=(P(), opt_specs, P(), P("data")), out_specs=P()))
+        in_specs=(P(), opt_specs, P(), P("data")), out_specs=P(),
+        check_vma=False))
     opt_sh = jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), opt_specs,
         is_leaf=lambda s: isinstance(s, P))
@@ -442,9 +443,9 @@ def test_opt_state_checkpoint_roundtrip_resharded():
         def step(p, opt, xloc):
             return zu2.update(jax.grad(_toy_loss)(p, xloc), opt, p)
 
-        stepj = jax.jit(mesh_util._shard_map(
+        stepj = jax.jit(jax.shard_map(
             step, mesh=mesh2, in_specs=(P(), zu2.opt_specs, P("data")),
-            out_specs=(P(), zu2.opt_specs)))
+            out_specs=(P(), zu2.opt_specs), check_vma=False))
         p2 = jax.device_get(p4)
         o2 = jax.tree_util.tree_map(jnp.asarray, o2)
         for _ in range(2):
