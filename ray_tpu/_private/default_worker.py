@@ -156,23 +156,18 @@ def main():
     # the head — a periodic flusher ships their spans on the node-stats
     # cadence so they still assemble (execute_task also flushes at task
     # start/end; this catches spans between tasks and long-running ones).
-    from ray_tpu.util.tracing import tracing_enabled
+    # An empty ring costs it one length check a period.
+    from ray_tpu import observability as obs
 
-    if tracing_enabled():
-        from ray_tpu import observability as obs
+    def span_flusher():
+        while not stop.wait(max(0.25, CONFIG.node_stats_period_s)):
+            try:
+                obs.flush(transport)
+            except Exception:
+                pass
 
-        def span_flusher():
-            import time as _time
-
-            while not stop.is_set():
-                _time.sleep(max(0.25, CONFIG.node_stats_period_s))
-                try:
-                    obs.flush(transport)
-                except Exception:
-                    pass
-
-        threading.Thread(target=span_flusher, name="rtpu-span-flush",
-                         daemon=True).start()
+    threading.Thread(target=span_flusher, name="rtpu-span-flush",
+                     daemon=True).start()
 
     def make_done(spec: TaskSpec):
         if server is not None and spec.task_id in server.cancelled:

@@ -136,6 +136,9 @@ class Head:
         self.trace_store = TraceStore(
             max_bytes=_CONFIG.trace_store_max_bytes,
             per_trace_bytes=_CONFIG.trace_max_bytes)
+        from ray_tpu import observability as _obs
+
+        _obs.set_session_store(self.trace_store)
         self._event_log: deque = deque(maxlen=512)
         # ---- multi-host plane ----
         # Host identity: object resolutions are host-aware — same host means
@@ -989,8 +992,6 @@ class Head:
         Workers and agents push theirs over the wire; in-process
         emitters (driver spans, head.<op> spans) are drained whenever
         the store is about to be read."""
-        if not self._tracing_on():
-            return
         from ray_tpu import observability as obs
 
         spans = obs.drain_spans()
@@ -1121,11 +1122,11 @@ class Head:
             from ray_tpu import observability as obs
 
             if obs.get_context() is not None:
-                t0 = time.time()
+                t0 = time.perf_counter()
                 try:
                     fn(payload, reply, caller)
                 finally:
-                    obs.record("head." + op, t0, time.time())
+                    obs.record("head." + op, t0, time.perf_counter())
                 return
         fn(payload, reply, caller)
 

@@ -465,14 +465,12 @@ class NodeAgent:
                              store=self.store, num_workers=n_workers)}
                 try:
                     from ray_tpu import observability as obs
-                    from ray_tpu.util.tracing import tracing_enabled
 
-                    if tracing_enabled():
-                        # Agent-side spans (transfer serving, pulls) ride
-                        # the stats cadence instead of their own frames.
-                        spans = obs.drain_spans()
-                        if spans:
-                            frame["spans"] = spans
+                    # Agent-side spans (transfer serving, pulls) ride
+                    # the stats cadence instead of their own frames.
+                    spans = obs.drain_spans()
+                    if spans:
+                        frame["spans"] = spans
                 except Exception:
                     pass
                 self.send(frame)

@@ -1,46 +1,27 @@
 """Chrome-trace timeline export (reference: ray.timeline() →
 chrome_tracing_dump, python/ray/_private/profiling.py:43 over core-worker
-profile events, src/ray/core_worker/profile_event.h) plus an in-process
-span recorder for driver-side hot-path instrumentation (pipeline dispatch
-and drain spans from ray_tpu.parallel.mesh_group.StepPipeline, device
-prefetch spans from ray_tpu.data.prefetch).
+profile events, src/ray/core_worker/profile_event.h) plus ``record_span``,
+the call that driver-side hot paths use (pipeline dispatch and drain spans
+from ray_tpu.parallel.mesh_group.StepPipeline, flow stages, checkpoints).
 
-The recorder is deliberately dumb and allocation-cheap: a bounded deque of
-dicts behind one lock, no I/O, no KV round trips — it must be safe to call
-once per training step without perturbing the thing it measures.  Readers
-(tools/perf_smoke.py, tests) pull spans with ``recorded_spans``; the chrome
-trace export merges them as one extra lane so overlap is visible in
-chrome://tracing next to the task timeline.
-
-When the tracing plane is on (ray_tpu.observability), every recorded
-span is ALSO stamped with the active (or explicitly passed) trace
-context and mirrored into the cluster span ring, so the
-``mpmd_stage_*`` / ``rollout_*`` / ``flow_*`` / ``replay_*`` families
-assemble into cross-process traces instead of staying anonymous.
-perf_counter timestamps are rebased to wall clock at record time so
-they merge with task events from other processes.
+``record_span`` is a caller of the one recorder, ray_tpu.observability:
+same ring, clock and rule for when a span is recorded (``on()``).  It
+stamps the active (or passed) trace context, so the ``mpmd_stage_*`` /
+``rollout_*`` / ``flow_*`` / ``replay_*`` families assemble into
+cross-process traces.  Readers (tools/perf_smoke.py, tests) turn tracing
+on and pull spans with ``recorded_spans``.
 """
 from __future__ import annotations
 
-import json
-import threading
-import time
-from collections import deque
 from typing import List, Optional
 
-# Bounded: a forgotten long-running pipeline must not grow driver memory.
-_MAX_RECORDED_SPANS = 8192
-_recorded: "deque" = deque(maxlen=_MAX_RECORDED_SPANS)
-_recorded_lock = threading.Lock()
+from ray_tpu import observability as obs
 
 
 def record_span(name: str, start: float, end: float,
                 _trace_ctx=None, _root=False, **args) -> None:
     """Record one completed span (timestamps from time.perf_counter()).
-
-    Used by the step pipeline ("pipeline_dispatch"/"pipeline_drain", with
-    step=<idx>) and the device prefetcher ("prefetch_h2d").  Thread-safe;
-    never raises.  ``_trace_ctx`` pins the span to an explicit
+    Never raises.  ``_trace_ctx`` pins the span to an explicit
     (trace_id, parent_span_id) pair for emitters that run off the
     submitting thread (flow stage workers); otherwise the thread's
     active context is stamped.  ``_root=True`` records the span AS the
@@ -48,70 +29,35 @@ def record_span(name: str, start: float, end: float,
     trace so children parented to the root id resolve to a real span
     and cross-process flow arrows have an anchor."""
     try:
-        with _recorded_lock:
-            _recorded.append({"name": name, "start": float(start),
-                              "end": float(end), "args": dict(args)})
-        from ray_tpu.util.tracing import tracing_enabled
-
-        if tracing_enabled():
-            from ray_tpu import observability as obs
-
-            # perf_counter → wall clock, rebased at record time.
-            offset = time.time() - time.perf_counter()
-            kw = {}
-            if _root and _trace_ctx is not None:
-                kw = {"span_id": _trace_ctx[1], "parent_id": None}
-            obs.record(name, float(start) + offset, float(end) + offset,
-                       ctx=_trace_ctx, **kw, **args)
+        sid = _trace_ctx[1] if _root and _trace_ctx is not None else None
+        obs.record(name, float(start), float(end), ctx=_trace_ctx,
+                   span_id=sid, **args)
     except Exception:
         pass
 
 
 def recorded_spans(name: Optional[str] = None,
                    clear: bool = False) -> List[dict]:
-    """Snapshot recorded spans (optionally filtered by name), oldest first."""
-    with _recorded_lock:
-        spans = list(_recorded)
-        if clear:
-            _recorded.clear()
+    """This process's recorded spans (optionally filtered by name),
+    oldest first; ``clear`` drains the ring."""
+    spans = obs.drain_spans() if clear else obs.ring().snapshot()
     if name is not None:
         spans = [s for s in spans if s["name"] == name]
     return spans
 
 
 def clear_recorded_spans() -> None:
-    with _recorded_lock:
-        _recorded.clear()
+    obs.drain_spans()
 
 
 def chrome_tracing_dump(task_events: List[dict],
                         filename: Optional[str] = None,
-                        include_recorded: bool = False,
                         spans: Optional[List[dict]] = None) -> List[dict]:
     """Convert the state API's task list into chrome://tracing events.
 
-    ``spans`` (TraceStore records) merge in with per-node pid lanes,
-    per-process tid lanes, and cross-process flow arrows — see
-    ray_tpu.observability.timeline.  ``include_recorded=True`` appends
-    the in-process span recorder's entries as a separate lane so
-    pipeline dispatch/drain overlap shows up against the task timeline."""
+    ``spans`` (TraceStore records, or ``recorded_spans()``: one format)
+    merge in with per-node pid lanes, per-process tid lanes, and
+    cross-process flow arrows — see ray_tpu.observability.timeline."""
     from ray_tpu.observability.timeline import build_chrome_trace
 
-    extra = None
-    if include_recorded:
-        extra = [{
-            "name": s["name"],
-            "cat": "SPAN",
-            "ph": "X",
-            "ts": s["start"] * 1e6,
-            "dur": (s["end"] - s["start"]) * 1e6,
-            "pid": "ray_tpu",
-            "tid": "spans",
-            "args": s["args"],
-        } for s in recorded_spans()]
-    events = build_chrome_trace(task_events, spans or [],
-                                extra_events=extra)
-    if filename:
-        with open(filename, "w") as f:
-            json.dump(events, f)
-    return events
+    return build_chrome_trace(task_events, spans or [], filename)

@@ -391,7 +391,7 @@ class ObjectTransferServer:
 
     def _serve_one(self, conn, oid: ObjectID, off=None, length=None,
                    tc=None):
-        t0 = time.time()
+        t0 = time.perf_counter()  # the span clock
         served0 = self.served_bytes
         try:
             # Cooperative path first: a range this process is still
@@ -443,7 +443,7 @@ class ObjectTransferServer:
                 try:
                     from ray_tpu import observability as obs
 
-                    obs.record("transfer.pull", t0, time.time(),
+                    obs.record("transfer.pull", t0, time.perf_counter(),
                                ctx=tuple(tc), oid=oid.hex(),
                                bytes=self.served_bytes - served0,
                                range=off is not None)

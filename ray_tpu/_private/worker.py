@@ -1739,7 +1739,7 @@ class CoreWorker:
             peer.register_partial(
                 oid, shm.buf if shm is not None else membuf, size, chunkb)
         view = shm.buf[:size] if shm is not None else memoryview(membuf)
-        t0 = _time.time()
+        t0 = _time.perf_counter()  # the span clock
         pulled = False
         try:
             meta, stats = transfer_mod.pull_striped(
@@ -1767,7 +1767,7 @@ class CoreWorker:
                 # many sources fed this pull and how many bytes striped.
                 try:
                     _obs().record(
-                        "transfer.pull", t0, _time.time(), ctx=tc,
+                        "transfer.pull", t0, _time.perf_counter(), ctx=tc,
                         oid=oid.hex(), striped_bytes=size,
                         sources=len(stats["bytes_from"]),
                         partial_ranges=stats["partial_ranges"])
@@ -2318,11 +2318,12 @@ class CoreWorker:
             if spec.task_type != TaskType.ACTOR_CREATION:
                 self.namespace, self.default_runtime_env = saved_job_defaults
             self.ctx.task_id = None
+            if self.mode == "worker":
+                # Goes by what the ring holds: spans recorded because a
+                # profile ran leave the worker too.
+                _obs().flush(self.transport)
             if tracing_on:
-                obs = _obs()
-                if self.mode == "worker":
-                    obs.flush(self.transport)
-                obs.set_context(saved_trace_ctx)
+                _obs().set_context(saved_trace_ctx)
         return {
             "type": "task_done",
             "task_id": spec.task_id.binary(),

@@ -29,13 +29,18 @@ Contract (unchanged from the hand-rolled version):
   even when the producer is blocked on a full queue.
 - Queue occupancy and batch counts export through ray_tpu.util.metrics
   (both the legacy ``data_prefetch_*`` names and the substrate's tagged
-  ``flow_*`` series; best-effort, skipped where no driver is connected)
-  and per-batch H2D spans land in the ray_tpu._private.profiling span
-  recorder as ``prefetch_h2d``.
+  ``flow_*`` series; best-effort, skipped where no driver is connected).
+- Spans (ray_tpu.observability; PERF.md lists their readers): on the
+  producer thread one ``ingest.produce`` per batch, around the block
+  fetch, the slicing and its ``ingest.h2d`` (the ``place`` call); on the
+  consumer ``ingest.wait`` around ``__next__``, with the queue ``depth``
+  it found.
 """
 from __future__ import annotations
 
 from typing import Any, Callable, Iterable, Iterator, Optional
+
+from ray_tpu import observability as obs
 
 
 def _make_place_fn(sharding, place_fn):
@@ -50,6 +55,45 @@ def _make_place_fn(sharding, place_fn):
         return jax.device_put(batch)
 
     return place
+
+
+class _Producer(Iterator[Any]):
+    """Source and transform of the prefetcher's stage in one object,
+    because one ``ingest.produce`` span covers both: it opens before a
+    host batch is fetched and closes once that batch is placed.  The
+    stage calls ``place`` right after each ``__next__``, on one thread."""
+
+    def __init__(self, host_batches: Iterable[Any], place):
+        self._it, self._place = iter(host_batches), place
+        self._span = obs.NO_SPAN
+
+    def __next__(self):
+        self._span = obs.span("ingest.produce").__enter__()
+        try:
+            return next(self._it)
+        except BaseException as e:
+            if isinstance(e, StopIteration):
+                self._span.cancel()  # the stream's end is no batch
+            self._span.__exit__(type(e), e, e.__traceback__)
+            raise
+
+    def place(self, batch):
+        import jax
+
+        leaves = jax.tree_util.tree_leaves(batch)
+        try:
+            with obs.span("ingest.h2d", bytes=sum(
+                    getattr(x, "nbytes", 0) for x in leaves)):
+                return self._place(batch)
+        finally:
+            self._span.set(rows=next(
+                (x.shape[0] for x in leaves if getattr(x, "shape", ())), 0))
+            self._span.__exit__(None, None, None)
+
+    def close(self):
+        close = getattr(self._it, "close", None)
+        if close is not None:
+            close()
 
 
 class DevicePrefetcher(Iterator[Any]):
@@ -69,11 +113,13 @@ class DevicePrefetcher(Iterator[Any]):
         from ray_tpu.parallel.flow import Stage  # lazy: parallel pulls jax
 
         self.prefetch = int(prefetch)
+        producer = _Producer(host_batches,
+                             _make_place_fn(sharding, place_fn))
         self._stage = Stage(
-            host_batches, _make_place_fn(sharding, place_fn),
+            producer, producer.place,
             depth=max(1, self.prefetch),
             workers=1 if self.prefetch > 0 else 0,
-            name=name, span="prefetch_h2d",
+            name=name, span="",
             # flow's throttled export is kept; the legacy gauge names are
             # exported once at end-of-stream/close below.
             export_metrics=True)
@@ -85,7 +131,8 @@ class DevicePrefetcher(Iterator[Any]):
 
     def __next__(self):
         try:
-            return next(self._stage)
+            with obs.span("ingest.wait", depth=self._stage.queue_depth):
+                return next(self._stage)
         except BaseException:
             self._export_metrics()
             raise

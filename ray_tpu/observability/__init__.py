@@ -8,29 +8,36 @@ OpenTelemetry-style context propagation.  Four pieces live here:
    hex strings, minted at driver API boundaries (``remote()``, ``put``,
    ``get``, ``generate_many``, pipeline step dispatch) and carried on
    every RPC frame, task spec, seal notify, and transfer pull.  The
-   active context is thread-local; ``util.tracing.span`` and
-   ``_private.profiling.record_span`` stamp it so a span recorded in a
-   worker three hops away still lands in the caller's trace.
-2. **SpanRing** — the shared bounded ring-buffer primitive: drop-oldest
-   with a dropped counter, zero allocation while tracing is off.  One
-   process-wide ring collects every completed span.
+   active context is thread-local; every span stamps it, so a span
+   recorded in a worker three hops away still lands in the caller's
+   trace.
+2. **The one span recorder** — :func:`span` and :func:`record` (for a
+   span whose start was stamped earlier), on the span clock
+   ``time.perf_counter()``; ``util.tracing.span`` and
+   ``_private.profiling.record_span`` are callers.  A span is recorded
+   when :func:`on` says so: the ``tracing_enabled`` flag, or a
+   ``jax.profiler`` trace running in this process, in which the span
+   is also a ``TraceAnnotation`` on a host line of the ``.xplane.pb``,
+   on the device's clock.  One process-wide :class:`SpanRing` collects
+   every completed span; off, :func:`span` is one shared no-op.
 3. **Flush path** — ``flush(transport)`` drains the ring into a
    ``span_batch`` one-way request to the head; workers flush at task
    start/end and on the node-stats cadence, node agents relay their
    ring inside ``node_stats`` frames, the head drains its own ring
-   in-process.  The head stores batches in a byte-budgeted TraceStore
-   (see :mod:`ray_tpu.observability.trace_store`).
+   in-process.  The head keeps batches in a byte-budgeted TraceStore,
+   which :func:`session_spans` still reads after ``ray_tpu.shutdown()``.
 4. **Flight recorder** — the same rings double as the crash black box:
    see :mod:`ray_tpu.observability.flight_recorder`.
 
 Everything here must be safe to import during bootstrap (no jax, no
-eager config reads at module scope) and free when tracing is off: the
-fast path out of every function is one cached-bool check.
+eager config reads at module scope) and free when nothing records: the
+fast path out of every function is two boolean checks.
 """
 from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -39,20 +46,40 @@ from typing import Any, Dict, List, Optional, Tuple
 TraceContext = Tuple[str, str]  # (trace_id, span_id) — both 16-char hex
 
 _tl = threading.local()
-_identity_lock = threading.Lock()
 _proc_label: Optional[str] = None
 _node_hex: Optional[str] = None
 
 
-def _enabled() -> bool:
+# The span clock is time.perf_counter(); a span's wall-clock position is
+# that plus this offset, taken once per process.
+_WALL_OFFSET = time.time() - time.perf_counter()
+
+
+def enabled() -> bool:
+    """True when the tracing plane is on (``tracing_enabled`` flag)."""
     from ray_tpu.util.tracing import tracing_enabled
 
     return tracing_enabled()
 
 
-def enabled() -> bool:
-    """True when the tracing plane is on (``tracing_enabled`` flag)."""
-    return _enabled()
+def _profile_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profile runs in this
+    process, else None.  jax is looked up, never imported: head, raylet
+    and node agent stay off it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        ta = jax.profiler.TraceAnnotation
+        return ta if ta.is_enabled() else None
+    except AttributeError:  # jax half-imported, or a build without it
+        return None
+
+
+def on() -> bool:
+    """The one rule for whether a span is recorded: the tracing plane is
+    on, or a ``jax.profiler`` trace is running in this process."""
+    return enabled() or _profile_annotation() is not None
 
 
 def new_id() -> str:
@@ -66,10 +93,9 @@ def set_identity(proc: str, node: Optional[str] = None) -> None:
     """Label this process's spans (e.g. ``worker:ab12cd34`` on node X).
     Called once from CoreWorker / node agent / head bootstrap."""
     global _proc_label, _node_hex
-    with _identity_lock:
-        _proc_label = proc
-        if node is not None:
-            _node_hex = node
+    _proc_label = proc
+    if node is not None:
+        _node_hex = node
 
 
 def identity() -> Tuple[str, Optional[str]]:
@@ -106,18 +132,16 @@ def mint_context() -> TraceContext:
 
 
 def clear_context() -> None:
-    """Drop this thread's active context.  Called at session boundaries
-    (``disable_tracing``, ``ray_tpu.shutdown``): an implicit context
-    installed by ``ensure_context`` must not outlive the session that
-    minted it, or every later operation on this thread silently joins
-    one stale, rootless trace."""
+    """Drop this thread's active context, at session boundaries: one
+    that ``ensure_context`` installed must not outlive its session, or
+    every later operation here joins one stale, rootless trace."""
     _tl.ctx = None
 
 
 def ensure_context() -> Optional[TraceContext]:
     """Driver API boundary helper: the active context, minting a new
     trace root if none is active.  None while tracing is off."""
-    if not _enabled():
+    if not enabled():
         return None
     ctx = get_context()
     if ctx is None:
@@ -126,20 +150,14 @@ def ensure_context() -> Optional[TraceContext]:
     return ctx
 
 
-def context_for_outbound() -> Optional[TraceContext]:
-    """Context to stamp on an outbound task spec / RPC frame."""
-    return ensure_context()
+context_for_outbound = ensure_context  # what an outbound spec / frame carries
 
 
 # ---------------------------------------------------------------------------
 # SpanRing: the shared bounded span buffer
 # ---------------------------------------------------------------------------
 class SpanRing:
-    """Bounded span buffer: drop-oldest with a dropped counter.
-
-    The primitive behind both the cluster flush path (the process ring
-    below) and ``util.tracing``'s local buffer — replaces the silent
-    10k-truncation list that predated the tracing plane."""
+    """Bounded span buffer: drop-oldest with a dropped counter."""
 
     def __init__(self, capacity: int = 4096):
         self.capacity = max(16, int(capacity))
@@ -187,27 +205,11 @@ def ring() -> SpanRing:
     return _ring
 
 
-def spans_dropped_total() -> int:
-    r = _ring
-    return r.dropped_total if r is not None else 0
-
-
 # ---------------------------------------------------------------------------
 # recording
 # ---------------------------------------------------------------------------
-def record(name: str, start: float, end: float,
-           ctx: Optional[TraceContext] = None,
-           parent_id: Optional[str] = None,
-           span_id: Optional[str] = None,
-           **args) -> Optional[str]:
-    """Record one completed span (wall-clock timestamps) into the
-    process ring.  ``ctx`` defaults to the active context; when a
-    context is live the span joins its trace with ``parent_id``
-    defaulting to the context's span id.  Free when tracing is off."""
-    if not _enabled():
-        return None
-    if ctx is None:
-        ctx = get_context()
+def _append(name, start, end, ctx, parent_id, span_id, args) -> str:
+    """One completed span (wall-clock seconds) into the process ring."""
     trace_id = ctx[0] if ctx else None
     if parent_id is None and ctx is not None:
         parent_id = ctx[1]
@@ -216,19 +218,120 @@ def record(name: str, start: float, end: float,
         parent_id = None  # a root span is not its own parent
     proc, node = identity()
     ring().append({
-        "name": name, "start": float(start), "end": float(end),
+        "name": name, "start": start, "end": end,
         "trace_id": trace_id, "span_id": sid, "parent_id": parent_id,
         "proc": proc, "node": node, "os_pid": os.getpid(),
-        "args": dict(args) if args else {},
+        "args": args,
     })
     return sid
+
+
+def record(name: str, start: float, end: float,
+           ctx: Optional[TraceContext] = None,
+           parent_id: Optional[str] = None,
+           span_id: Optional[str] = None,
+           **args) -> Optional[str]:
+    """Record one completed span whose ends were stamped on the span
+    clock, e.g. a request's queue wait.  ``ctx`` defaults to the active
+    context; ``parent_id`` to the context's span id, else to the
+    thread's open span.  A profile cannot take a span after the fact:
+    it gets a marker of that name where the span ends, with ``dur_us``."""
+    ann = _profile_annotation()
+    if ann is None and not enabled():
+        return None
+    if ann is not None:
+        with ann(name, dur_us=int((end - start) * 1e6), **args):
+            pass
+    if ctx is None:
+        ctx = get_context()
+    if ctx is None and parent_id is None:
+        parent_id = getattr(_tl, "open", None)
+    return _append(name, start + _WALL_OFFSET, end + _WALL_OFFSET, ctx,
+                   parent_id, span_id, args)
 
 
 def record_instant(name: str, **args) -> Optional[str]:
     """Zero-duration marker span (e.g. ``task.begin`` — flushed before
     execution so a SIGKILLed worker's last act is on record)."""
-    now = time.time()
+    now = time.perf_counter()
     return record(name, now, now, **args)
+
+
+class _NoSpan:
+    """What :func:`span` hands back while nothing records."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+    def cancel(self) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """An open span.  While open it is the parent of the thread's next
+    span and, inside a trace, of anything submitted from the thread."""
+    __slots__ = ("name", "args", "span_id", "_ctx", "_ann", "_saved", "_t0")
+
+    def __init__(self, name, args, ctx, ann):
+        self.name, self.args, self._ctx = name, args, ctx
+        self.span_id = new_id()
+        self._ann = ann(name, **args) if ann is not None else None
+
+    def set(self, **args) -> None:
+        """Arguments known only when the work is done.  They reach the
+        ring, not the profile (an annotation's are fixed as it opens)."""
+        self.args.update(args)
+
+    def cancel(self) -> None:
+        """Closing still restores the thread's state; nothing is kept."""
+        self.name = None
+
+    def __enter__(self):
+        active = getattr(_tl, "ctx", None)
+        self._saved = (active, getattr(_tl, "open", None))
+        if self._ctx is None:
+            self._ctx = active or (None, self._saved[1])
+        if self._ctx[0] is not None:
+            _tl.ctx = (self._ctx[0], self.span_id)
+        _tl.open = self.span_id
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _tl.ctx, _tl.open = self._saved
+        if self.name is None:
+            return False
+        start = _WALL_OFFSET + self._t0 * 1e-9
+        trace_id, parent_id = self._ctx
+        _append(self.name, start, start + dur * 1e-9,
+                (trace_id, parent_id) if trace_id is not None else None,
+                parent_id, self.span_id, self.args)
+        return False
+
+
+def span(name: str, _ctx: Optional[TraceContext] = None, **args):
+    """Context manager around one piece of work.  ``_ctx`` pins it to an
+    explicit ``(trace_id, parent_span_id)``; otherwise it joins the
+    thread's active context, the enclosing open span its parent."""
+    ann = _profile_annotation()
+    if ann is None and not enabled():
+        return NO_SPAN
+    return _Span(name, args, _ctx, ann)
 
 
 # ---------------------------------------------------------------------------
@@ -267,10 +370,10 @@ def _export_dropped(r: SpanRing) -> None:
 
 def flush(transport) -> int:
     """Drain the ring and ship the batch to the head as a one-way
-    ``span_batch`` request.  Returns the number of spans shipped."""
-    if not _enabled():
-        return 0
-    spans = drain_spans()
+    ``span_batch`` request; returns how many spans went.  Goes by what
+    the ring holds, not by a flag: spans recorded because a profile ran
+    leave the worker too."""
+    spans = drain_spans() if _ring is not None and len(_ring) else None
     if not spans:
         return 0
     try:
@@ -280,6 +383,28 @@ def flush(transport) -> int:
         # telemetry, never worth failing the caller for.
         return 0
     return len(spans)
+
+
+# The TraceStore of this process's newest head, put here by Head.__init__
+# and kept until the next: readable after ray_tpu.shutdown().
+_session_store = None
+
+
+def set_session_store(store) -> None:
+    global _session_store
+    _session_store = store
+
+
+def session_spans(name: Optional[str] = None) -> List[Dict[str, Any]]:
+    """The spans of the newest session that this process can see: its
+    head's store (readable until the next ``ray_tpu.init``) plus its own
+    ring, which is all there is where no runtime ran."""
+    spans = _session_store.spans() if _session_store is not None else []
+    if _ring is not None:
+        spans = spans + _ring.snapshot()
+    if name is not None:
+        spans = [s for s in spans if s["name"] == name]
+    return spans
 
 
 def flight_record(reason: str) -> None:

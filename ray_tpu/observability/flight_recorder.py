@@ -77,14 +77,10 @@ def write_bundle(reason: str, *,
                 "spans": len(spans)}
         if extra:
             meta.update(extra)
-        with open(os.path.join(path, "meta.json"), "w") as f:
-            json.dump(meta, f, indent=2)
-        with open(os.path.join(path, "spans.json"), "w") as f:
-            json.dump(spans, f, default=str)
-        with open(os.path.join(path, "tasks.json"), "w") as f:
-            json.dump(tasks or [], f, default=str)
-        with open(os.path.join(path, "events.json"), "w") as f:
-            json.dump(events or [], f, default=str)
+        for part, value in (("meta", meta), ("spans", spans),
+                            ("tasks", tasks or []), ("events", events or [])):
+            with open(os.path.join(path, part + ".json"), "w") as f:
+                json.dump(value, f, default=str)
         _prune(root)
         return path
     except Exception:

@@ -19,9 +19,7 @@ def _node_lane(node_hex: Optional[str]) -> str:
 
 
 def build_chrome_trace(tasks: List[dict], spans: List[dict],
-                       filename: Optional[str] = None,
-                       extra_events: Optional[List[dict]] = None
-                       ) -> List[dict]:
+                       filename: Optional[str] = None) -> List[dict]:
     """Merge state-API task rows and TraceStore spans into chrome
     events.  Returns the event list (and writes it when ``filename``)."""
     events: List[dict] = []
@@ -60,8 +58,6 @@ def build_chrome_trace(tasks: List[dict], spans: List[dict],
         })
     events.extend(_flow_edges(spans or [], by_id))
     events.extend(_lane_metadata(events))
-    if extra_events:
-        events.extend(extra_events)
     if filename:
         with open(filename, "w") as f:
             json.dump(events, f)

@@ -52,7 +52,6 @@ class TraceStore:
         self.spans_ingested = 0
         self.spans_dropped = 0
         self.traces_evicted = 0
-        self.ring_dropped = 0  # emitter-side ring drops, relayed in batches
 
     def ingest(self, spans: List[Dict[str, Any]]) -> None:
         if not spans:
@@ -83,11 +82,6 @@ class TraceStore:
                 _tid, victim = self._traces.popitem(last=False)
                 self.total_bytes -= victim.bytes
                 self.traces_evicted += 1
-
-    def note_ring_dropped(self, n: int) -> None:
-        if n > 0:
-            with self._lock:
-                self.ring_dropped += n
 
     def spans(self, trace_id: Optional[str] = None) -> List[Dict[str, Any]]:
         with self._lock:
@@ -139,5 +133,4 @@ class TraceStore:
                 "spans_ingested": self.spans_ingested,
                 "spans_dropped": self.spans_dropped,
                 "traces_evicted": self.traces_evicted,
-                "ring_dropped": self.ring_dropped,
             }

@@ -375,6 +375,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     if not with_lse:
         return res[0], None, (qf, kf, vf)
@@ -416,6 +417,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, sm_scale, block_q, block_k,
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse8, delta8)
 
     dk, dv = pl.pallas_call(
@@ -439,6 +441,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, sm_scale, block_q, block_k,
             jax.ShapeDtypeStruct((bh, lk, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(k, v, q, do, lse8, delta8)
     return dq, dk, dv
 

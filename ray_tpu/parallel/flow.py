@@ -302,7 +302,7 @@ def _stage_worker(core: _StageCore) -> None:
             with core.state_lock:
                 core.idle_s += t0 - t_wait0
                 core.busy_s += t1 - t0
-            if core.span is not None:
+            if core.span:
                 profiling.record_span(core.span, t0, t1, stage=core.name,
                                       seq=seq)
             if core.sink:
@@ -345,7 +345,9 @@ class Stage(Iterator[Any]):
     the request/response shape (e.g. the serve batcher), where callers
     wait on futures ``fn`` resolves rather than pulling an iterator.
     Iterate to consume (non-sink); ``close()`` (also via ``with`` or GC)
-    cancels, drains and joins every thread.
+    cancels, drains and joins every thread.  Each item's ``fn`` call is
+    recorded as a span named ``span`` (default ``flow_<name>``; ``""``
+    for a stage whose ``fn`` makes its own spans).
 
     The consumer side is single-threaded by contract (chained stages pull
     from each other under the downstream stage's source lock)."""
@@ -535,6 +537,11 @@ class Stage(Iterator[Any]):
     @property
     def peak_occupancy(self) -> int:
         return max(self._core.peak_queue, len(self._buffer))
+
+    @property
+    def queue_depth(self) -> int:
+        """Finished items waiting for the consumer."""
+        return self._core.out_q.qsize() + len(self._buffer)
 
     @property
     def items_delivered(self) -> int:

@@ -227,17 +227,19 @@ def make_anakin_ppo(config: AlgorithmConfig):
         rng_in = mesh_util.unwrap_rng(state.rng, sharded)
         carry = (state.params, state.env_states, state.obs, rng_in,
                  state.ep_return, jnp.zeros(()), jnp.zeros(()))
-        carry, traj = jax.lax.scan(rollout_step, carry, None, length=T)
+        with jax.named_scope("rollout"):
+            carry, traj = jax.lax.scan(rollout_step, carry, None, length=T)
         params, env_states, obs, rng, ep_ret, dsum_d, dcnt_d = carry
         obs_t, act_t, logp_t, val_t, rew_t, done_t = traj  # [T, N_loc, ...]
 
         dsum = state.done_return_sum + mesh_util.psum_if(dsum_d, sharded)
         dcnt = state.done_count + mesh_util.psum_if(dcnt_d, sharded)
 
-        _, last_value = module.apply(params, obs)
-        adv, vtarg = gae_jax(rew_t, val_t, done_t, last_value,
-                             config.gamma, config.lambda_)
-        adv = mesh_util.normalize_global(adv, sharded)
+        with jax.named_scope("gae"):
+            _, last_value = module.apply(params, obs)
+            adv, vtarg = gae_jax(rew_t, val_t, done_t, last_value,
+                                 config.gamma, config.lambda_)
+            adv = mesh_util.normalize_global(adv, sharded)
 
         flat = {
             "obs": (obs_t.reshape(batch_loc, *obs_shape)
@@ -249,12 +251,13 @@ def make_anakin_ppo(config: AlgorithmConfig):
             "value_targets": vtarg.reshape(batch_loc),
         }
 
-        (params, opt_state, rng), (losses, auxes) = run_ppo_sgd(
-            params, state.opt_state, rng,
-            lambda p, mb: loss_fn(p, module, mb),
-            lambda idx: {k_: v[idx] for k_, v in flat.items()},
-            batch_loc, mb_loc, num_mb, config.num_sgd_iter, None,
-            sharded=sharded, update_fn=update_fn)
+        with jax.named_scope("sgd"):
+            (params, opt_state, rng), (losses, auxes) = run_ppo_sgd(
+                params, state.opt_state, rng,
+                lambda p, mb: loss_fn(p, module, mb),
+                lambda idx: {k_: v[idx] for k_, v in flat.items()},
+                batch_loc, mb_loc, num_mb, config.num_sgd_iter, None,
+                sharded=sharded, update_fn=update_fn)
 
         new_state = AnakinState(params, opt_state, env_states, obs,
                                 mesh_util.wrap_rng(rng, sharded),

@@ -102,10 +102,16 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ray_tpu import observability as obs
 from ray_tpu.exceptions import EngineClosedError, KVPoolExhaustedError
 from ray_tpu.serve.sampling import GREEDY, SamplingParams
 
 _DEF = object()  # sentinel: constructor arg not given, consult CONFIG
+
+
+def _named(name: str, fn):
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def _cfg(name, given, fallback):
@@ -182,7 +188,13 @@ class _Request:
     max_new_tokens: int
     eos_id: Optional[int]
     sampling: SamplingParams = GREEDY
-    submitted: float = dataclasses.field(default_factory=time.monotonic)
+    # Span clock.  Where the wait in the queue starts: at submit, and
+    # anew when a preemption puts the request back.
+    submitted: float = dataclasses.field(default_factory=time.perf_counter)
+    pending_ahead: int = 0   # requests queued before it at submit
+    queue_wait: float = 0.0  # seconds, set as admission takes it off
+    first_token: float = 0.0  # span clock, at its first admission
+    preemptions: int = 0
     out: List[int] = dataclasses.field(default_factory=list)
     chunks: "queue.Queue" = dataclasses.field(default_factory=queue.Queue)
     done: threading.Event = dataclasses.field(
@@ -204,8 +216,8 @@ class _Request:
     out_logps: List[float] = dataclasses.field(default_factory=list)
     out_versions: List[int] = dataclasses.field(default_factory=list)
     # Distributed trace the request was submitted under (the caller's
-    # (trace_id, span_id) pair); engine step spans stamp it so a serve
-    # request's decode steps land in the client's timeline.
+    # (trace_id, span_id) pair): its request.queued and request.decode
+    # spans stamp it, so they land in the client's timeline.
     trace_ctx: Optional[tuple] = None
 
     def context(self) -> List[int]:
@@ -374,17 +386,22 @@ class LLMEngine:
         self._slot_pages: List[List[int]] = [[] for _ in range(self.max_slots)]
         self._slot_req: Dict[int, _Request] = {}
 
-        self._decode = jax.jit(self._make_decode_step(model),
-                               donate_argnums=(1, 2))
+        # A program is named after its function, and the profile shows it
+        # as jit_<name>: stable names, for whoever reads a trace.
+        self._decode = jax.jit(
+            _named("llm_decode", self._make_decode_step(model)),
+            donate_argnums=(1, 2))
         if self._spec:
             self._draft_decode = jax.jit(
-                self._make_decode_step(
-                    draft_model, window_pages=self._draft_window_pages),
+                _named("llm_draft_decode", self._make_decode_step(
+                    draft_model, window_pages=self._draft_window_pages)),
                 donate_argnums=(1, 2))
-            self._verify = jax.jit(self._make_verify_step(model),
-                                   donate_argnums=(1, 2))
-        self._adopt = jax.jit(self._make_adopt(self.dtype),
-                              donate_argnums=(0, 1))
+            self._verify = jax.jit(
+                _named("llm_verify", self._make_verify_step(model)),
+                donate_argnums=(1, 2))
+        self._adopt = jax.jit(
+            _named("llm_adopt", self._make_adopt(self.dtype)),
+            donate_argnums=(0, 1))
         self._adopt_buf_k = np.zeros(
             (self.num_layers, self.pages_per_slot, self.page_size,
              self.kv_heads, self.head_dim), np.float32)
@@ -423,7 +440,7 @@ class LLMEngine:
 
             self._stage = flow.Stage(
                 self._tick_source(), self._iteration, sink=True, workers=1,
-                name="llm_engine", export_metrics=False)
+                name="llm_engine", span="", export_metrics=False)
 
     # ------------------------------------------------------------------
     # public API (any thread)
@@ -447,21 +464,15 @@ class LLMEngine:
                 top_p=1.0 if top_p is None else float(top_p),
                 seed=0 if seed is None else int(seed))
         sampling.validate()
-        trace_ctx = None
-        try:
-            from ray_tpu import observability as obs
-
-            if obs.enabled():
-                trace_ctx = obs.get_context()
-        except Exception:
-            pass
+        trace_ctx = obs.get_context() if obs.enabled() else None
         with self._cond:
             if self._closed:
                 raise EngineClosedError("engine is closed")
             rid = self._next_id
             self._next_id += 1
             req = _Request(rid, prompt, max_new_tokens, eos_id,
-                           sampling=sampling, trace_ctx=trace_ctx)
+                           sampling=sampling, trace_ctx=trace_ctx,
+                           pending_ahead=len(self._pending))
             self._requests[rid] = req
             self._pending.append(req)
             self._cond.notify_all()
@@ -730,27 +741,33 @@ class LLMEngine:
                                  cfg.head_dim)
                 return view, lengths - start * ps
 
+        scope = self._jax.named_scope
+
         def step(params, k_pages, v_pages, table, lengths, tokens, active,
                  temps, top_ps, seeds):
-            k_cache, view_len = gather_view(k_pages, table, lengths)
-            v_cache, _ = gather_view(v_pages, table, lengths)
-            kv = [(k_cache[i], v_cache[i]) for i in range(L)]
-            logits, new_kvs = model.apply(
-                {"params": params}, tokens[:, None], lengths[:, None], kv,
-                view_len)
+            with scope("gather"):
+                k_cache, view_len = gather_view(k_pages, table, lengths)
+                v_cache, _ = gather_view(v_pages, table, lengths)
+                kv = [(k_cache[i], v_cache[i]) for i in range(L)]
+            with scope("attend"):
+                logits, new_kvs = model.apply(
+                    {"params": params}, tokens[:, None], lengths[:, None],
+                    kv, view_len)
             # The generated token sits at absolute position lengths + 1.
-            next_tok, next_logp = sample_tokens_with_logprobs(
-                logits[:, -1], lengths + 1, temps, top_ps, seeds)
-            newk = jnp.stack([nk[0][:, 0] for nk in new_kvs])
-            newv = jnp.stack([nk[1][:, 0] for nk in new_kvs])
-            slot_ix = jnp.arange(table.shape[0])
-            page_col = jnp.minimum(lengths // ps, pp - 1)
-            page_idx = jnp.where(active, table[slot_ix, page_col], 0)
-            off = lengths % ps
-            k_pages = k_pages.at[:, page_idx, off].set(
-                newk.astype(k_pages.dtype))
-            v_pages = v_pages.at[:, page_idx, off].set(
-                newv.astype(v_pages.dtype))
+            with scope("sample"):
+                next_tok, next_logp = sample_tokens_with_logprobs(
+                    logits[:, -1], lengths + 1, temps, top_ps, seeds)
+            with scope("scatter"):
+                newk = jnp.stack([nk[0][:, 0] for nk in new_kvs])
+                newv = jnp.stack([nk[1][:, 0] for nk in new_kvs])
+                slot_ix = jnp.arange(table.shape[0])
+                page_col = jnp.minimum(lengths // ps, pp - 1)
+                page_idx = jnp.where(active, table[slot_ix, page_col], 0)
+                off = lengths % ps
+                k_pages = k_pages.at[:, page_idx, off].set(
+                    newk.astype(k_pages.dtype))
+                v_pages = v_pages.at[:, page_idx, off].set(
+                    newv.astype(v_pages.dtype))
             return k_pages, v_pages, next_tok, next_logp
 
         return step
@@ -834,26 +851,30 @@ class LLMEngine:
             positions = jnp.arange(bucket)[None]
             empty = [(jnp.zeros((1, 0, self.kv_heads, self.head_dim),
                                 self.dtype),) * 2 for _ in range(L)]
-            logits, new_kvs = model.apply(
-                {"params": params}, ids, positions, empty,
-                jnp.zeros((1,), jnp.int32))
-            toks, logps = sample_tokens_with_logprobs(
-                logits[0, p - 1][None], jnp.reshape(p, (1,)),
-                jnp.reshape(temp, (1,)), jnp.reshape(top_p, (1,)),
-                jnp.reshape(seed, (1,)))
-            next_tok, next_logp = toks[0], logps[0]
-            t = jnp.arange(bucket)
-            page_idx = jnp.where(t < p, row[t // ps], 0)
-            off = t % ps
-            newk = jnp.stack([nk[0][0] for nk in new_kvs])  # [L,bkt,Hkv,D]
-            newv = jnp.stack([nk[1][0] for nk in new_kvs])
-            k_pages = k_pages.at[:, page_idx, off].set(
-                newk.astype(self.dtype))
-            v_pages = v_pages.at[:, page_idx, off].set(
-                newv.astype(self.dtype))
+            with jax.named_scope("attend"):
+                logits, new_kvs = model.apply(
+                    {"params": params}, ids, positions, empty,
+                    jnp.zeros((1,), jnp.int32))
+            with jax.named_scope("sample"):
+                toks, logps = sample_tokens_with_logprobs(
+                    logits[0, p - 1][None], jnp.reshape(p, (1,)),
+                    jnp.reshape(temp, (1,)), jnp.reshape(top_p, (1,)),
+                    jnp.reshape(seed, (1,)))
+                next_tok, next_logp = toks[0], logps[0]
+            with jax.named_scope("scatter"):
+                t = jnp.arange(bucket)
+                page_idx = jnp.where(t < p, row[t // ps], 0)
+                off = t % ps
+                newk = jnp.stack([nk[0][0] for nk in new_kvs])  # [L,bkt,Hkv,D]
+                newv = jnp.stack([nk[1][0] for nk in new_kvs])
+                k_pages = k_pages.at[:, page_idx, off].set(
+                    newk.astype(self.dtype))
+                v_pages = v_pages.at[:, page_idx, off].set(
+                    newv.astype(self.dtype))
             return k_pages, v_pages, next_tok, next_logp
 
-        fn = jax.jit(prefill, donate_argnums=(1, 2))
+        fn = jax.jit(_named(f"llm_prefill_{bucket}", prefill),
+                     donate_argnums=(1, 2))
         self._prefills[key] = fn
         return fn
 
@@ -878,33 +899,39 @@ class LLMEngine:
             """tokens: [bucket] tail ids (absolute positions start..p-1)
             padded past p-start; returns updated pages + the sampled
             next token at absolute position p and its behavior logprob."""
-            k_cache = gather(k_pages, row[None])  # [L, 1, max_ctx, Hkv, D]
-            v_cache = gather(v_pages, row[None])
-            kv = [(k_cache[i], v_cache[i]) for i in range(L)]
+            with jax.named_scope("gather"):
+                k_cache = gather(k_pages, row[None])  # [L,1,max_ctx,Hkv,D]
+                v_cache = gather(v_pages, row[None])
+                kv = [(k_cache[i], v_cache[i]) for i in range(L)]
             positions = (start + jnp.arange(bucket))[None]
-            logits, new_kvs = model.apply(
-                {"params": params}, tokens[None], positions, kv,
-                jnp.reshape(start, (1,)))
+            with jax.named_scope("attend"):
+                logits, new_kvs = model.apply(
+                    {"params": params}, tokens[None], positions, kv,
+                    jnp.reshape(start, (1,)))
             tail_len = p - start
-            toks, logps = sample_tokens_with_logprobs(
-                logits[0, tail_len - 1][None], jnp.reshape(p, (1,)),
-                jnp.reshape(temp, (1,)), jnp.reshape(top_p, (1,)),
-                jnp.reshape(seed, (1,)))
-            next_tok, next_logp = toks[0], logps[0]
-            t = jnp.arange(bucket)
-            abs_pos = start + t
-            page_idx = jnp.where(
-                t < tail_len, row[jnp.minimum(abs_pos // ps, pp - 1)], 0)
-            off = abs_pos % ps
-            newk = jnp.stack([nk[0][0] for nk in new_kvs])
-            newv = jnp.stack([nk[1][0] for nk in new_kvs])
-            k_pages = k_pages.at[:, page_idx, off].set(
-                newk.astype(self.dtype))
-            v_pages = v_pages.at[:, page_idx, off].set(
-                newv.astype(self.dtype))
+            with jax.named_scope("sample"):
+                toks, logps = sample_tokens_with_logprobs(
+                    logits[0, tail_len - 1][None], jnp.reshape(p, (1,)),
+                    jnp.reshape(temp, (1,)), jnp.reshape(top_p, (1,)),
+                    jnp.reshape(seed, (1,)))
+                next_tok, next_logp = toks[0], logps[0]
+            with jax.named_scope("scatter"):
+                t = jnp.arange(bucket)
+                abs_pos = start + t
+                page_idx = jnp.where(
+                    t < tail_len, row[jnp.minimum(abs_pos // ps, pp - 1)],
+                    0)
+                off = abs_pos % ps
+                newk = jnp.stack([nk[0][0] for nk in new_kvs])
+                newv = jnp.stack([nk[1][0] for nk in new_kvs])
+                k_pages = k_pages.at[:, page_idx, off].set(
+                    newk.astype(self.dtype))
+                v_pages = v_pages.at[:, page_idx, off].set(
+                    newv.astype(self.dtype))
             return k_pages, v_pages, next_tok, next_logp
 
-        fn = jax.jit(tail_prefill, donate_argnums=(1, 2))
+        fn = jax.jit(_named(f"llm_tail_prefill_{bucket}", tail_prefill),
+                     donate_argnums=(1, 2))
         self._prefills[key] = fn
         return fn
 
@@ -942,7 +969,8 @@ class LLMEngine:
                 newv.astype(v_pages.dtype))
             return k_pages, v_pages
 
-        fn = jax.jit(prefill, donate_argnums=(1, 2))
+        fn = jax.jit(_named(f"llm_draft_prefill_{bucket}", prefill),
+                     donate_argnums=(1, 2))
         self._prefills[key] = fn
         return fn
 
@@ -964,53 +992,57 @@ class LLMEngine:
                 return
             yield None
 
+    def _nothing_to_do(self) -> bool:
+        return not (self._closed or self._pending or self._awaiting
+                    or self._ready or self._pending_swaps
+                    or self._active.any())
+
     def _iteration(self, _tick):
+        """One pass of the loop thread.  Its spans (the names are a
+        contract, PERF.md lists them with their readers) say what the
+        host does between two device programs: ``engine.idle`` while
+        there is nothing to do, then ``engine.iteration`` around
+        ``engine.swap``, ``engine.admit`` (with one ``engine.prefill``
+        per local prefill), ``engine.decode.dispatch``,
+        ``engine.decode.fetch``, ``engine.emit`` and
+        ``engine.metrics_flush``."""
         with self._cond:
-            while (not self._closed and not self._pending
-                   and not self._awaiting and not self._ready
-                   and not self._pending_swaps
-                   and not self._active.any()):
-                self._cond.wait(0.2)
-                if self._stage is not None and self._stage.token.cancelled:
-                    return
+            if self._nothing_to_do():
+                with obs.span("engine.idle"):
+                    while self._nothing_to_do():
+                        self._cond.wait(0.2)
+                        if self._stage is not None and \
+                                self._stage.token.cancelled:
+                            return
             if self._closed:
                 return
-        t_work0 = time.perf_counter()
-        try:
-            self._apply_swaps()  # token boundary: between decode steps
-            self._poll_prefill()
-            self._admit()
-            self._grow()
-            if self._active.any():
-                if self._spec:
-                    self._decode_once_spec()
-                else:
-                    self._decode_once()
-                self._step_stamps.append(time.monotonic())
-        except BaseException as e:  # noqa: BLE001 — fail loudly per req
-            self._fail_all(e)
-            return
-        t_work1 = time.perf_counter()
-        self._work_s += t_work1 - t_work0
-        self._record_step_span(t_work0, t_work1)
-        self._flush_metrics()
-
-    def _record_step_span(self, t0: float, t1: float) -> None:
-        """Stamp the engine iteration onto an active request's trace so a
-        serve request's decode steps assemble into the client's timeline.
-        Free when no in-flight request carries a context."""
-        ctx = None
-        for req in self._slot_req.values():
-            if req.trace_ctx is not None:
-                ctx = tuple(req.trace_ctx)
-                break
-        if ctx is None:
-            return
-        from ray_tpu._private import profiling
-
-        profiling.record_span("serve_engine_step", t0, t1,
-                              active=int(self._active.sum()),
-                              _trace_ctx=ctx)
+        with obs.span("engine.iteration", active=len(self._slot_req),
+                      pending=len(self._pending)):
+            t_work0 = time.perf_counter()
+            try:
+                # token boundary: between decode steps
+                if self._pending_swaps:
+                    with obs.span("engine.swap") as sp:
+                        self._apply_swaps()
+                        sp.set(version=self._weight_version)
+                if self._pending or self._awaiting or self._ready:
+                    before = self._stats["admitted"]
+                    with obs.span("engine.admit") as sp:
+                        self._poll_prefill()
+                        self._admit()
+                        sp.set(admitted=self._stats["admitted"] - before)
+                self._grow()
+                if self._active.any():
+                    if self._spec:
+                        self._decode_once_spec()
+                    else:
+                        self._decode_once()
+                    self._step_stamps.append(time.monotonic())
+            except BaseException as e:  # noqa: BLE001 — fail loudly per req
+                self._fail_all(e)
+                return
+            self._work_s += time.perf_counter() - t_work0
+            self._flush_metrics()
 
     # ------------------------------------------------------------------
     # hot weight swap (loop thread only)
@@ -1142,6 +1174,7 @@ class LLMEngine:
                     with self._lock:
                         self._pending.popleft()
                         self._awaiting.append((req, job, start))
+                    self._left_queue(req)
                     self._stats["prefill_offloaded"] += 1
                     continue
             with self._lock:
@@ -1157,6 +1190,7 @@ class LLMEngine:
                 self._pending.popleft()
                 slot = free[0]
                 mid_batch = bool(self._active.any())
+            self._left_queue(req)
             self._slot_pages[slot] = pages
             row = np.zeros((self.pages_per_slot,), np.int32)
             row[:need] = pages
@@ -1168,8 +1202,19 @@ class LLMEngine:
                 self._adopt_pages(slot, 0, cached)
                 self._stats["prefill_tokens_saved"] += start
             nxt, lp = self._local_prefill(slot, req, ctx, start)
-            self._finish_admission(slot, req, p, int(nxt), float(lp),
-                                   mid_batch)
+            self._finish_admission(slot, req, p, nxt, lp, mid_batch)
+
+    def _left_queue(self, req: _Request):
+        """``_admit`` has just taken ``req`` off ``_pending``: the end of
+        its ``request.queued`` span, and of the wait that
+        ``_finish_admission`` hands the ``serve_queue_wait_s`` histogram
+        (not here: the histogram's round trip to the head would hold up
+        the prefill's dispatch, right after an emit woke every reader)."""
+        now = time.perf_counter()
+        req.queue_wait = now - req.submitted
+        obs.record("request.queued", req.submitted, now, ctx=req.trace_ctx,
+                   request_id=req.id, prompt_tokens=len(req.prompt),
+                   pending_ahead=req.pending_ahead)
 
     def _local_prefix_run(self, ctx: List[int]) -> int:
         """Length (tokens) of the leading full-page run present in the
@@ -1227,8 +1272,7 @@ class LLMEngine:
                         self._adopt_pages(slot, 0, cached)
                         self._stats["prefill_tokens_saved"] += hit
                     nxt, lp = self._local_prefill(slot, req, ctx, hit)
-                    self._finish_admission(slot, req, p, int(nxt),
-                                           float(lp), mid_batch)
+                    self._finish_admission(slot, req, p, nxt, lp, mid_batch)
                     continue
                 self._adopt_pages(slot, 0, cached)
                 self._stats["prefill_tokens_saved"] += start
@@ -1246,32 +1290,32 @@ class LLMEngine:
     def _local_prefill(self, slot: int, req: _Request, ctx: List[int],
                        start: int):
         """Run the (full or cache-aware tail) prefill into the slot's
-        pages; returns (sampled next token, its behavior logprob)."""
+        pages and wait for it; returns (sampled next token, its behavior
+        logprob)."""
         p = len(ctx)
         row = self._table[slot]
         s = req.sampling
         tail_len = p - start
         self._stats["prefill_tokens"] += tail_len
-        if start == 0:
-            bucket = self._bucket_for(p)
-            toks = np.zeros((bucket,), np.int32)
-            toks[:p] = ctx
-            fn = self._prefill_fn(bucket)
-            self._k_pages, self._v_pages, nxt, lp = fn(
-                self._params, self._k_pages, self._v_pages, row, toks,
-                np.int32(p), np.float32(s.temperature), np.float32(s.top_p),
-                np.int32(s.seed))
-        else:
-            bucket = self._bucket_for(tail_len)
+        bucket = self._bucket_for(tail_len)
+        with obs.span("engine.prefill", request_id=req.id, bucket=bucket,
+                      prompt_tokens=p, cached_tokens=start):
             toks = np.zeros((bucket,), np.int32)
             toks[:tail_len] = ctx[start:]
-            fn = self._tail_prefill_fn(bucket)
-            self._k_pages, self._v_pages, nxt, lp = fn(
-                self._params, self._k_pages, self._v_pages, row, toks,
-                np.int32(start), np.int32(p), np.float32(s.temperature),
-                np.float32(s.top_p), np.int32(s.seed))
-        self._publish_prefix(ctx, slot)
-        return nxt, lp
+            if start == 0:
+                fn = self._prefill_fn(bucket)
+                self._k_pages, self._v_pages, nxt, lp = fn(
+                    self._params, self._k_pages, self._v_pages, row, toks,
+                    np.int32(p), np.float32(s.temperature),
+                    np.float32(s.top_p), np.int32(s.seed))
+            else:
+                fn = self._tail_prefill_fn(bucket)
+                self._k_pages, self._v_pages, nxt, lp = fn(
+                    self._params, self._k_pages, self._v_pages, row, toks,
+                    np.int32(start), np.int32(p), np.float32(s.temperature),
+                    np.float32(s.top_p), np.int32(s.seed))
+            self._publish_prefix(ctx, slot)
+            return int(nxt), float(lp)
 
     def _finish_admission(self, slot: int, req: _Request, p: int,
                           next_tok: int, next_logp: float, mid_batch: bool):
@@ -1283,7 +1327,9 @@ class LLMEngine:
         self._stats["admitted"] += 1
         if mid_batch:
             self._stats["admitted_mid_batch"] += 1
-        self._observe_queue_wait(time.monotonic() - req.submitted)
+        self._observe_queue_wait(req.queue_wait)
+        if not req.first_token:
+            req.first_token = time.perf_counter()
         self._lengths[slot] = p
         self._last_tok[slot] = next_tok
         self._temps[slot] = s.temperature
@@ -1507,29 +1553,34 @@ class LLMEngine:
         self._table[slot] = 0
         self._lengths[slot] = 0
         self._stats["preemptions"] += 1
+        req.preemptions += 1
+        req.submitted = time.perf_counter()  # queued again, from now
         with self._lock:
             self._active[slot] = False
             self._pending.appendleft(req)  # readmitted first, from context()
 
     def _decode_once(self):
         n_active = int(self._active.sum())
-        self._k_pages, self._v_pages, nxt, lps = self._decode(
-            self._params, self._k_pages, self._v_pages, self._table,
-            self._lengths, self._last_tok, self._active, self._temps,
-            self._top_ps, self._seeds)
-        nxt = np.asarray(nxt)
-        lps = np.asarray(lps)
+        with obs.span("engine.decode.dispatch"):
+            self._k_pages, self._v_pages, nxt, lps = self._decode(
+                self._params, self._k_pages, self._v_pages, self._table,
+                self._lengths, self._last_tok, self._active, self._temps,
+                self._top_ps, self._seeds)
+        with obs.span("engine.decode.fetch"):  # the host waits here
+            nxt = np.asarray(nxt)
+            lps = np.asarray(lps)
         self._stats["steps"] += 1
         self._stats["tokens"] += n_active
         self._occupancy_sum += n_active / self.max_slots
-        for slot in range(self.max_slots):
-            if not self._active[slot]:
-                continue
-            self._lengths[slot] += 1  # the last token's K/V just landed
-            req = self._slot_req[slot]
-            tok = int(nxt[slot])
-            self._last_tok[slot] = tok
-            self._append_token(slot, req, tok, float(lps[slot]))
+        with obs.span("engine.emit", tokens=n_active):
+            for slot in range(self.max_slots):
+                if not self._active[slot]:
+                    continue
+                self._lengths[slot] += 1  # the last token's K/V just landed
+                req = self._slot_req[slot]
+                tok = int(nxt[slot])
+                self._last_tok[slot] = tok
+                self._append_token(slot, req, tok, float(lps[slot]))
 
     def _decode_once_spec(self):
         """Draft k-1 proposals per slot, verify the [slots, k] window in
@@ -1542,11 +1593,15 @@ class LLMEngine:
         proposals = np.zeros((self.max_slots, k - 1), np.int32)
         d_last = self._last_tok.copy()
         for j in range(k - 1):
-            self._dk_pages, self._dv_pages, nxt, _dlp = self._draft_decode(
-                self._draft_params, self._dk_pages, self._dv_pages,
-                self._table, self._lengths + j, d_last, self._active,
-                self._temps, self._top_ps, self._seeds)
-            d_last = np.asarray(nxt)
+            with obs.span("engine.decode.dispatch"):
+                self._dk_pages, self._dv_pages, nxt, _dlp = \
+                    self._draft_decode(
+                        self._draft_params, self._dk_pages, self._dv_pages,
+                        self._table, self._lengths + j, d_last,
+                        self._active, self._temps, self._top_ps,
+                        self._seeds)
+            with obs.span("engine.decode.fetch"):
+                d_last = np.asarray(nxt)
             proposals[:, j] = d_last
         # Catch-up step: write the LAST proposal's draft KV (position
         # len+k-1).  On full acceptance that position becomes part of
@@ -1554,41 +1609,46 @@ class LLMEngine:
         # draft would read a stale row and desync; on partial
         # acceptance the row sits beyond kv_lengths and is overwritten
         # before it is ever read.  The sampled output is discarded.
-        self._dk_pages, self._dv_pages, _, _ = self._draft_decode(
-            self._draft_params, self._dk_pages, self._dv_pages,
-            self._table, self._lengths + (k - 1), d_last, self._active,
-            self._temps, self._top_ps, self._seeds)
-        window = np.concatenate(
-            [self._last_tok[:, None], proposals], axis=1)
-        self._k_pages, self._v_pages, sampled, v_logps = self._verify(
-            self._params, self._k_pages, self._v_pages, self._table,
-            self._lengths, window, self._active, self._temps, self._top_ps,
-            self._seeds)
-        sampled = np.asarray(sampled)  # [slots, k]: tokens at len+1..len+k
-        v_logps = np.asarray(v_logps)
+        with obs.span("engine.decode.dispatch"):
+            self._dk_pages, self._dv_pages, _, _ = self._draft_decode(
+                self._draft_params, self._dk_pages, self._dv_pages,
+                self._table, self._lengths + (k - 1), d_last, self._active,
+                self._temps, self._top_ps, self._seeds)
+            window = np.concatenate(
+                [self._last_tok[:, None], proposals], axis=1)
+            self._k_pages, self._v_pages, sampled, v_logps = self._verify(
+                self._params, self._k_pages, self._v_pages, self._table,
+                self._lengths, window, self._active, self._temps,
+                self._top_ps, self._seeds)
+        with obs.span("engine.decode.fetch"):
+            sampled = np.asarray(sampled)  # [slots, k]: at len+1..len+k
+            v_logps = np.asarray(v_logps)
         self._stats["steps"] += 1
         self._stats["spec_steps"] += 1
         self._occupancy_sum += n_active / self.max_slots
-        for slot in range(self.max_slots):
-            if not self._active[slot]:
-                continue
-            req = self._slot_req[slot]
-            m = 0
-            while m < k - 1 and proposals[slot, m] == sampled[slot, m]:
-                m += 1
-            emit = m + 1  # matched proposals + the target's own token
-            self._stats["spec_proposed"] += k - 1
-            self._stats["spec_accepted"] += m
-            req.spec_proposed += k - 1
-            req.spec_accepted += m
-            self._stats["tokens"] += emit
-            self._lengths[slot] += emit
-            self._last_tok[slot] = int(sampled[slot, emit - 1])
-            for j in range(emit):
-                self._append_token(slot, req, int(sampled[slot, j]),
-                                   float(v_logps[slot, j]))
+        tokens0 = self._stats["tokens"]
+        with obs.span("engine.emit") as sp:
+            for slot in range(self.max_slots):
                 if not self._active[slot]:
-                    break  # retired mid-window (EOS / max_new_tokens)
+                    continue
+                req = self._slot_req[slot]
+                m = 0
+                while m < k - 1 and proposals[slot, m] == sampled[slot, m]:
+                    m += 1
+                emit = m + 1  # matched proposals + the target's own token
+                self._stats["spec_proposed"] += k - 1
+                self._stats["spec_accepted"] += m
+                req.spec_proposed += k - 1
+                req.spec_accepted += m
+                self._stats["tokens"] += emit
+                self._lengths[slot] += emit
+                self._last_tok[slot] = int(sampled[slot, emit - 1])
+                for j in range(emit):
+                    self._append_token(slot, req, int(sampled[slot, j]),
+                                       float(v_logps[slot, j]))
+                    if not self._active[slot]:
+                        break  # retired mid-window (EOS / max_new_tokens)
+            sp.set(tokens=self._stats["tokens"] - tokens0)
 
     def _append_token(self, slot: int, req: _Request, tok: int,
                       logp: float = float("nan")):
@@ -1614,6 +1674,11 @@ class LLMEngine:
             self._active[slot] = False
             self._evict_consumed_locked()
         self._stats["completed"] += 1
+        if req.first_token:
+            obs.record("request.decode", req.first_token,
+                       time.perf_counter(), ctx=req.trace_ctx,
+                       request_id=req.id, tokens=len(req.out),
+                       preemptions=req.preemptions)
         req.finish(error=error)
 
     def _evict_consumed_locked(self):
@@ -1678,27 +1743,28 @@ class LLMEngine:
             return
         self._metrics_flush = now
         try:
-            self._ensure_metrics()
-            m, st = self._metrics, self._stats
-            m["tokens"].mark(st["tokens"] - m["tokens"].total())
-            m["requests"].mark(st["completed"] - m["requests"].total())
-            m["prefix_hits"].mark(
-                st["prefix_hit_pages"] - m["prefix_hits"].total())
-            if st.get("spec_proposed", 0):
-                m["spec_accept"].set(
-                    st["spec_accepted"] / st["spec_proposed"])
-            with self._lock:
-                inflight = int(self._active.sum()) + len(self._pending)
-                occ = float(self._active.sum()) / self.max_slots
-            m["inflight"].set(inflight)
-            m["occupancy"].set(occ)
-            pool = self.pool.stats()
-            m["pages_in_use"].set(pool["in_use"])
-            m["pages_free"].set(pool["free"])
-            m["tokens_per_s"].set(st["tokens"] / max(1e-9,
-                                                     now - self._t0))
-            for meter in (m["tokens"], m["requests"], m["prefix_hits"]):
-                meter.flush()
+            with obs.span("engine.metrics_flush"):
+                self._ensure_metrics()
+                m, st = self._metrics, self._stats
+                m["tokens"].mark(st["tokens"] - m["tokens"].total())
+                m["requests"].mark(st["completed"] - m["requests"].total())
+                m["prefix_hits"].mark(
+                    st["prefix_hit_pages"] - m["prefix_hits"].total())
+                if st.get("spec_proposed", 0):
+                    m["spec_accept"].set(
+                        st["spec_accepted"] / st["spec_proposed"])
+                with self._lock:
+                    inflight = int(self._active.sum()) + len(self._pending)
+                    occ = float(self._active.sum()) / self.max_slots
+                m["inflight"].set(inflight)
+                m["occupancy"].set(occ)
+                pool = self.pool.stats()
+                m["pages_in_use"].set(pool["in_use"])
+                m["pages_free"].set(pool["free"])
+                m["tokens_per_s"].set(st["tokens"] / max(1e-9,
+                                                         now - self._t0))
+                for meter in (m["tokens"], m["requests"], m["prefix_hits"]):
+                    meter.flush()
         except Exception:
             pass
 

@@ -15,20 +15,20 @@ Surface:
   Each span joins the active distributed trace context
   (ray_tpu.observability) and becomes the active parent for anything
   submitted inside it, so cross-process timelines assemble.
-- Spans ALSO land in a process-local ring (``pop_local_spans``) so
-  `ray_tpu.timeline()`-style tooling sees them even with no SDK.  The
-  ring is the shared drop-oldest primitive (observability.SpanRing) —
-  overflow is counted, not silently truncated, and the counter is
-  exported as ``tracing_spans_dropped_total`` through util.metrics.
+- Spans ALSO land in the process's one span ring (ray_tpu.observability;
+  ``pop_local_spans`` drains it) so `ray_tpu.timeline()`-style tooling
+  sees them even with no SDK.  Overflow is counted, not silently
+  truncated, and the counter is exported as
+  ``tracing_spans_dropped_total`` through util.metrics.
 """
 from __future__ import annotations
 
 import contextlib
-import time
 from typing import Any, Dict, List, Optional
 
+from ray_tpu import observability as obs
+
 _enabled: Optional[bool] = None
-_local_ring = None  # observability.SpanRing, created on first span
 
 
 def enable_tracing():
@@ -43,9 +43,7 @@ def disable_tracing():
     # obs.ensure_context() installs one on this thread at API boundaries,
     # and a leftover would absorb the next session's spans into a stale
     # rootless trace.
-    from ray_tpu import observability as _obs
-
-    _obs.clear_context()
+    obs.clear_context()
 
 
 def tracing_enabled() -> bool:
@@ -66,76 +64,22 @@ def _tracer():
         return None
 
 
-def _ring():
-    global _local_ring
-    if _local_ring is None:
-        from ray_tpu import observability as obs
-
-        _local_ring = obs.SpanRing(10_000)
-    return _local_ring
-
-
-def spans_dropped_total() -> int:
-    """Local-buffer drops (the process ring counts its own separately)."""
-    return _local_ring.dropped_total if _local_ring is not None else 0
-
-
 @contextlib.contextmanager
 def span(name: str, **attributes):
-    """Instrumentation point: otel span (no-op without a provider) plus a
-    local record for timeline tooling.  Joins the active trace context
-    and is the active parent for nested work while open."""
-    if not tracing_enabled():
+    """Instrumentation point: otel span (no-op without a provider) plus
+    an ``observability.span`` for timeline tooling.  Joins the active
+    trace context, or roots a new trace, and is the active parent for
+    nested work while open."""
+    if not obs.on():
         yield
         return
-    from ray_tpu import observability as obs
-
-    t0 = time.time()
     tracer = _tracer()
     otel = (tracer.start_as_current_span(name, attributes=attributes)
             if tracer is not None else contextlib.nullcontext())
-    parent = obs.get_context()
-    trace_id = parent[0] if parent else obs.new_id()
-    parent_id = parent[1] if parent else None
-    sid = obs.new_id()
-    old = obs.set_context((trace_id, sid))
-    try:
-        with otel:
-            yield
-    finally:
-        obs.set_context(old)
-        end = time.time()
-        _ring().append({"name": name, "start": t0, "end": end,
-                        "trace_id": trace_id, "span_id": sid,
-                        "parent_id": parent_id, "attributes": attributes})
-        obs.record(name, t0, end, ctx=(trace_id, sid), parent_id=parent_id,
-                   span_id=sid, **attributes)
+    ctx = obs.get_context() or (obs.new_id(), None)
+    with obs.span(name, _ctx=ctx, **attributes), otel:
+        yield
 
 
 def pop_local_spans() -> List[Dict[str, Any]]:
-    r = _local_ring
-    if r is None:
-        return []
-    spans = r.drain()
-    _export_dropped(r)
-    return spans
-
-
-_dropped_exported = 0
-
-
-def _export_dropped(r) -> None:
-    """Ship the drop-counter delta into util.metrics, off the hot path
-    (drain cadence only) and best-effort (needs a live driver KV)."""
-    global _dropped_exported
-    delta = r.dropped_total - _dropped_exported
-    if delta <= 0:
-        return
-    try:
-        from ray_tpu.util.metrics import Counter
-
-        Counter("tracing_spans_dropped_total",
-                "spans dropped by full ring buffers").inc(delta)
-        _dropped_exported += delta
-    except Exception:
-        pass
+    return obs.drain_spans()

@@ -137,7 +137,7 @@ def test_tracing_spans_recorded(shutdown_only):
         spans = tracing.pop_local_spans()
         assert any(s["name"] == "custom.op" for s in spans)
         s = next(s for s in spans if s["name"] == "custom.op")
-        assert s["attributes"]["foo"] == "bar" and s["end"] >= s["start"]
+        assert s["args"]["foo"] == "bar" and s["end"] >= s["start"]
     finally:
         tracing.disable_tracing()
 

@@ -226,12 +226,17 @@ def test_external_cancel_unblocks_consumer():
 
 def test_stage_spans_recorded():
     from ray_tpu._private import profiling
+    from ray_tpu.util import tracing
 
-    profiling.clear_recorded_spans()
-    stage = Stage(iter(range(5)), lambda x: x, depth=2, name="spanstage",
-                  export_metrics=False)
-    assert list(stage) == list(range(5))
-    spans = profiling.recorded_spans("flow_spanstage")
+    tracing.enable_tracing()  # recorded_spans reads the one span ring
+    try:
+        profiling.clear_recorded_spans()
+        stage = Stage(iter(range(5)), lambda x: x, depth=2,
+                      name="spanstage", export_metrics=False)
+        assert list(stage) == list(range(5))
+    finally:
+        tracing.disable_tracing()
+    spans = profiling.recorded_spans("flow_spanstage", clear=True)
     assert len(spans) == 5
     assert {s["args"]["seq"] for s in spans} == set(range(5))
 

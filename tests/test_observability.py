@@ -328,7 +328,8 @@ def test_generate_many_assembles_one_trace(monkeypatch):
         wait_for_condition(assembled, timeout=30)
 
         names = {s["name"] for s in head.trace_store.spans(good[0])}
-        assert "serve_engine_step" in names
+        # the engine stamps a request's own spans with its context
+        assert {"request.queued", "request.decode"} <= names
         serve.shutdown()
     finally:
         tracing.disable_tracing()

@@ -1,0 +1,311 @@
+"""The one span recorder (ray_tpu.observability) and the spans the serve
+engine and the train ingest make with it (ISSUE 24).
+
+Structure, not time: which spans exist, what lies inside what, which
+counts agree with the engine's own, and that the same spans are in the
+JAX profiler's trace on a host line.  The names checked here are the
+contract PERF.md lists.
+"""
+import glob
+import subprocess
+import sys
+import time
+from collections import Counter
+
+import numpy as np
+import pytest
+
+from ray_tpu import observability as obs
+
+ENGINE_CHILDREN = ("engine.admit", "engine.decode.dispatch",
+                   "engine.decode.fetch", "engine.emit")
+
+
+@pytest.fixture(scope="module")
+def gpt2():
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import GPT2, GPT2Config
+
+    cfg = GPT2Config.tiny(dtype=jnp.float32)
+    model = GPT2(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    return model, params, cfg
+
+
+@pytest.fixture(scope="module")
+def traced_engine(gpt2, tmp_path_factory):
+    """A tiny engine serves three requests while a ``jax.profiler`` trace
+    runs on the CPU backend, the tracing flag off: what the ring holds,
+    the engine's counts over the same stretch, the queue waits its
+    histogram was given, and the profile."""
+    import jax
+
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    model, params, cfg = gpt2
+    eng = LLMEngine(model, params, max_slots=4, page_size=8, max_ctx=64)
+    rng = np.random.default_rng(0)
+    prompts = [list(map(int, rng.integers(0, cfg.vocab_size, size=n)))
+               for n in (5, 11, 19)]
+    try:
+        for r in [eng.submit(p, 3) for p in prompts]:  # compile
+            eng.result(r, timeout=300)
+        off = {"span": obs.span("engine.iteration", active=1),
+               "ring": len(obs.drain_spans())}
+        observed = []
+        eng._observe_queue_wait = observed.append
+        trace_dir = str(tmp_path_factory.mktemp("profile"))
+        before = eng.stats()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        try:
+            rids = [eng.submit(p, 6) for p in prompts]
+            for r in rids:
+                eng.result(r, timeout=300)
+            time.sleep(0.3)  # the last iteration closes inside the profile
+        finally:
+            jax.profiler.stop_trace()
+        after = eng.stats()
+        lowered = {
+            "decode": eng._decode.lower(
+                eng._params, eng._k_pages, eng._v_pages, eng._table,
+                eng._lengths, eng._last_tok, eng._active, eng._temps,
+                eng._top_ps, eng._seeds).as_text(debug_info=True),
+            "prefill": eng._prefill_fn(8).lower(
+                eng._params, eng._k_pages, eng._v_pages, eng._table[0],
+                np.zeros((8,), np.int32), np.int32(5), np.float32(0),
+                np.float32(1), np.int32(0)).as_text(debug_info=True)}
+    finally:
+        eng.close()
+    return {"spans": obs.drain_spans(), "rids": rids, "off": off,
+            "counts": {k: after[k] - before[k] for k in ("steps", "admitted")},
+            "observed": observed, "lowered": lowered,
+            "xplane": glob.glob(trace_dir + "/plugins/profile/*/*.xplane.pb")}
+
+
+def named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+# (a) ----------------------------------------------------------------------
+def test_off_is_one_shared_noop_and_an_empty_ring(traced_engine):
+    off = traced_engine["off"]
+    assert off["span"] is obs.NO_SPAN and off["ring"] == 0
+    assert obs.span("x", a=1) is obs.NO_SPAN and not obs.on()
+    with obs.span("x") as sp:
+        sp.set(b=2)
+    assert obs.record("x", 0.0, 1.0) is None
+    assert len(obs.ring()) == 0
+
+
+def test_off_imports_no_jax():
+    code = ("import sys; from ray_tpu import observability as obs; "
+            "assert obs.span('x', a=1) is obs.NO_SPAN; "
+            "assert obs._ring is None and 'jax' not in sys.modules")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+def test_spans_lie_on_a_host_line_of_the_profile(traced_engine):
+    from jax.profiler import ProfileData
+
+    assert traced_engine["xplane"], "the profiler wrote no .xplane.pb"
+    ring = Counter(s["name"] for s in traced_engine["spans"]
+                   if s["name"] != "engine.idle")
+    assert {"engine.iteration", "engine.prefill", "request.queued",
+            "request.decode", *ENGINE_CHILDREN} <= set(ring)
+    host = Counter()
+    for plane in ProfileData.from_file(traced_engine["xplane"][0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                host.update(e.name for e in line.events
+                            if e.name.startswith(("engine.", "request.")))
+    host.pop("engine.idle", None)  # open when the profile started or ended
+    assert host == ring
+
+
+# (b) ----------------------------------------------------------------------
+def test_children_lie_inside_their_iteration(traced_engine):
+    spans = traced_engine["spans"]
+    iterations = {s["span_id"]: s for s in named(spans, "engine.iteration")}
+    assert iterations
+    for name in ENGINE_CHILDREN:
+        for child in named(spans, name):
+            it = iterations[child["parent_id"]]
+            assert it["start"] <= child["start"] <= child["end"] <= it["end"]
+    admits = {s["span_id"]: s for s in named(spans, "engine.admit")}
+    for prefill in named(spans, "engine.prefill"):
+        a = admits[prefill["parent_id"]]
+        assert a["start"] <= prefill["start"] <= prefill["end"] <= a["end"]
+    assert all(s["parent_id"] is None for s in iterations.values())
+
+
+def test_counts_equal_the_engines_own(traced_engine):
+    spans, counts = traced_engine["spans"], traced_engine["counts"]
+    assert len(named(spans, "engine.decode.dispatch")) == counts["steps"]
+    assert len(named(spans, "engine.decode.fetch")) == counts["steps"]
+    assert len(named(spans, "engine.emit")) == counts["steps"]
+    assert len(named(spans, "engine.prefill")) == counts["admitted"] == 3
+    assert len(named(spans, "request.queued")) == len(traced_engine["rids"])
+    assert sum(s["args"]["admitted"]
+               for s in named(spans, "engine.admit")) == counts["admitted"]
+    assert sum(s["args"]["tokens"] for s in named(spans, "engine.emit")) \
+        == 3 * 5  # six tokens a request, the first from its prefill
+
+
+def test_a_requests_three_spans_share_its_id(traced_engine):
+    spans = traced_engine["spans"]
+    for name in ("request.queued", "engine.prefill", "request.decode"):
+        assert sorted(s["args"]["request_id"] for s in named(spans, name)) \
+            == sorted(traced_engine["rids"])
+    for s in named(spans, "request.decode"):
+        assert s["args"] == {"request_id": s["args"]["request_id"],
+                             "tokens": 6, "preemptions": 0}
+
+
+# (c) ----------------------------------------------------------------------
+def test_queue_wait_ends_where_prefill_starts(traced_engine):
+    spans = traced_engine["spans"]
+    prefill = {s["args"]["request_id"]: s
+               for s in named(spans, "engine.prefill")}
+    queued = named(spans, "request.queued")
+    for q in queued:
+        assert q["start"] <= q["end"] <= prefill[
+            q["args"]["request_id"]]["start"]
+    # serve_queue_wait_s is given that same interval, not submit to first
+    # token
+    assert traced_engine["observed"] == pytest.approx(
+        [q["end"] - q["start"] for q in queued], abs=1e-6)
+
+
+# (d) ----------------------------------------------------------------------
+def test_engine_programs_carry_scope_names(traced_engine, gpt2):
+    decode = traced_engine["lowered"]["decode"]
+    assert "llm_decode" in decode
+    for scope in ("gather", "attend", "sample", "scatter"):
+        assert f"llm_decode)/{scope}" in decode or f"/{scope}/" in decode
+    prefill = traced_engine["lowered"]["prefill"]
+    assert "llm_prefill_8" in prefill
+    for scope in ("attend", "sample", "scatter"):
+        assert f"/{scope}" in prefill
+
+
+def test_anakin_ppo_step_carries_scope_names():
+    from ray_tpu.rllib import PPOConfig
+
+    algo = (PPOConfig().environment("CartPole-v1")
+            .anakin(num_envs=8, unroll_length=4).debugging(seed=0).build())
+    text = algo._train_step.lower(algo._anakin_state).as_text(
+        debug_info=True)
+    for scope in ("rollout", "gae", "sgd"):
+        assert f"/{scope}/" in text
+
+
+def test_flash_kernels_carry_their_names():
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.attention import flash_attention
+
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).as_text(debug_info=True)
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert kernel in text
+
+
+# (e) ----------------------------------------------------------------------
+def test_prefetcher_spans():
+    from ray_tpu.data.prefetch import DevicePrefetcher
+    from ray_tpu.util import tracing
+
+    batches = [{"x": np.ones((4, 8), np.float32)} for _ in range(6)]
+    obs.drain_spans()
+    tracing.enable_tracing()
+    try:
+        assert len(list(DevicePrefetcher(batches))) == 6
+    finally:
+        tracing.disable_tracing()
+    spans = obs.drain_spans()
+    produce = {s["span_id"]: s for s in named(spans, "ingest.produce")}
+    h2d = named(spans, "ingest.h2d")
+    assert len(produce) == len(h2d) == 6
+    assert all(s["args"] == {"rows": 4} for s in produce.values())
+    for s in h2d:
+        p = produce[s["parent_id"]]
+        assert p["start"] <= s["start"] <= s["end"] <= p["end"]
+        assert s["args"] == {"bytes": 4 * 8 * 4}
+    waits = named(spans, "ingest.wait")
+    assert len(waits) >= 6
+    assert all(0 <= s["args"]["depth"] <= 2 for s in waits)
+
+
+# the recorder itself ---------------------------------------------------------
+def test_nesting_context_and_one_clock():
+    from ray_tpu._private import profiling
+    from ray_tpu.util import tracing
+
+    obs.drain_spans()
+    tracing.enable_tracing()
+    try:
+        with obs.span("outer", k=1) as outer:
+            with obs.span("inner"):
+                pass
+            outer.set(n=2)
+            t = time.perf_counter()
+            profiling.record_span("posthoc", t - 0.5, t, rid=3)
+        with tracing.span("root", a=1):
+            with obs.span("child"):
+                pass
+    finally:
+        tracing.disable_tracing()
+    got = {s["name"]: s for s in profiling.recorded_spans(clear=True)}
+    assert got["outer"]["args"] == {"k": 1, "n": 2}
+    assert got["outer"]["trace_id"] is None
+    assert got["inner"]["parent_id"] == got["outer"]["span_id"]
+    assert got["posthoc"]["parent_id"] == got["outer"]["span_id"]
+    assert got["posthoc"]["end"] - got["posthoc"]["start"] == \
+        pytest.approx(0.5)
+    assert got["outer"]["start"] <= got["posthoc"]["end"] <= \
+        got["outer"]["end"]  # perf_counter stamps land on the same clock
+    assert abs(got["outer"]["start"] - time.time()) < 5.0  # wall clock
+    assert got["root"]["trace_id"] and got["root"]["parent_id"] is None
+    assert got["child"]["trace_id"] == got["root"]["trace_id"]
+    assert got["child"]["parent_id"] == got["root"]["span_id"]
+    assert obs.get_context() is None
+
+
+def test_session_spans_outlive_shutdown(shutdown_only):
+    """The head's store stays readable after ``shutdown`` until the next
+    ``init``, and a worker's spans reach it though only the worker had
+    the flag on."""
+    import ray_tpu
+
+    ray_tpu.init(num_cpus=1)
+
+    @ray_tpu.remote
+    def work():
+        from ray_tpu import observability as o
+        from ray_tpu.util import tracing
+
+        tracing.enable_tracing()
+        try:
+            with o.span("worker.side", n=1):
+                pass
+        finally:
+            tracing.disable_tracing()
+        return True
+
+    assert ray_tpu.get(work.remote())
+    ray_tpu.shutdown()
+    assert [s["args"] for s in obs.session_spans("worker.side")] == [{"n": 1}]
+    ray_tpu.init(num_cpus=1)
+    assert obs.session_spans("worker.side") == []

@@ -45,6 +45,7 @@ def _jax_step(state, scale):
 def run_smoke(steps: int = STEPS, depth: int = DEPTH) -> dict:
     import ray_tpu
     from ray_tpu._private import profiling
+    from ray_tpu.util import tracing
     from ray_tpu.parallel import MeshGroup, mesh_group
 
     ray_tpu.init(num_cpus=4, object_store_memory=256 * 1024**2,
@@ -52,6 +53,7 @@ def run_smoke(steps: int = STEPS, depth: int = DEPTH) -> dict:
     mg = MeshGroup(num_hosts=1, platform="cpu", local_device_count=1,
                    pipeline_depth=depth)
     try:
+        tracing.enable_tracing()  # recorded_spans reads the span ring
         profiling.clear_recorded_spans()
         syncs_before = mesh_group.driver_sync_count()
         with mg.pipeline(depth=depth, metrics_interval=1) as pipe:
@@ -87,6 +89,7 @@ def run_smoke(steps: int = STEPS, depth: int = DEPTH) -> dict:
                          and syncs == 0)
         return out
     finally:
+        tracing.disable_tracing()
         mg.shutdown()
         ray_tpu.shutdown()
 
@@ -195,6 +198,7 @@ def run_checkpoint_smoke(steps: int = STEPS, depth: int = DEPTH) -> dict:
 
     import ray_tpu
     from ray_tpu._private import profiling
+    from ray_tpu.util import tracing
     from ray_tpu.checkpoint import latest_committed_step, restore_tree
     from ray_tpu.checkpoint.coordinator import AsyncCommitter
     from ray_tpu.parallel import MeshGroup, mesh_group
@@ -207,6 +211,7 @@ def run_checkpoint_smoke(steps: int = STEPS, depth: int = DEPTH) -> dict:
     committer = AsyncCommitter()
     save_at = steps // 2
     try:
+        tracing.enable_tracing()  # recorded_spans reads the span ring
         profiling.clear_recorded_spans()
         syncs_before = mesh_group.driver_sync_count()
         with mg.pipeline(depth=depth, metrics_interval=1) as pipe:
@@ -248,6 +253,7 @@ def run_checkpoint_smoke(steps: int = STEPS, depth: int = DEPTH) -> dict:
                          and syncs == 0 and out["restore_ok"])
         return out
     finally:
+        tracing.disable_tracing()
         mg.shutdown()
         ray_tpu.shutdown()
         shutil.rmtree(root, ignore_errors=True)
@@ -675,6 +681,7 @@ def run_zero_smoke(steps: int = STEPS, depth: int = DEPTH) -> dict:
     """
     import ray_tpu
     from ray_tpu._private import profiling
+    from ray_tpu.util import tracing
     from ray_tpu.parallel import MeshGroup, mesh_group
 
     ray_tpu.init(num_cpus=4, object_store_memory=256 * 1024**2,
@@ -682,6 +689,7 @@ def run_zero_smoke(steps: int = STEPS, depth: int = DEPTH) -> dict:
     mg = MeshGroup(num_hosts=1, platform="cpu", local_device_count=4,
                    pipeline_depth=depth)
     try:
+        tracing.enable_tracing()  # recorded_spans reads the span ring
         profiling.clear_recorded_spans()
         syncs_before = mesh_group.driver_sync_count()
         with mg.pipeline(depth=depth, metrics_interval=1) as pipe:
@@ -725,6 +733,7 @@ def run_zero_smoke(steps: int = STEPS, depth: int = DEPTH) -> dict:
                          and out["no_recompile"])
         return out
     finally:
+        tracing.disable_tracing()
         mg.shutdown()
         ray_tpu.shutdown()
 
@@ -1575,6 +1584,7 @@ def run_tracing_smoke(batch: int = 300, batches: int = 5) -> dict:
     ray_tpu.init(num_cpus=2, object_store_memory=256 * 1024**2,
                  ignore_reinit_error=True)
     try:
+        obs.drain_spans()  # what an earlier traced run left in the ring
         put_rates()  # warmup: pools, caches, first-touch pages
         baseline = statistics.median(put_rates())
         out["off_zero_spans"] = obs.drain_spans() == []
