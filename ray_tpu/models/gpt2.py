@@ -24,7 +24,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import cached_attention, mha_attention
+from ray_tpu.ops.attention import mha_attention
 from ray_tpu.ops.layers import gelu
 
 
@@ -101,11 +101,12 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, kv=None):
-        """kv = (k_cache, v_cache, lengths) switches the block to the
-        incremental-decode path: attention runs against the cached prefix
-        and the block ALSO returns this step's (k, v) projections so the
-        caller (serve/llm_engine.py) can write them into its page pool —
-        the cache layout is the engine's concern, not the model's."""
+        """kv = the caller's ``attend(q, k, v)`` for this layer switches
+        the block to the incremental-decode path: attention over the
+        cached prefix plus the new tokens is the caller's, and the block
+        ALSO returns this step's (k, v) projections so the caller
+        (serve/llm_engine.py) can write them into its page pool — the
+        cache layout is the engine's concern, not the model's."""
         c = self.config
         h = nn.LayerNorm(dtype=jnp.float32, name="ln_1")(x)
         qkv = nn.Dense(3 * c.hidden_size, dtype=c.dtype, name="attn_qkv")(h)
@@ -115,8 +116,7 @@ class Block(nn.Module):
         k = k.reshape(b, l, c.num_heads, c.head_dim)
         v = v.reshape(b, l, c.num_heads, c.head_dim)
         if kv is not None:
-            k_cache, v_cache, lengths = kv
-            attn = cached_attention(q, k, v, k_cache, v_cache, lengths)
+            attn = kv(q, k, v)
         elif self.attn_fn is not None:
             attn = self.attn_fn(q, k, v)
         else:
@@ -156,8 +156,7 @@ class GPT2(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids: jax.Array, positions: jax.Array = None,
-                 kv_caches=None, kv_lengths: jax.Array = None,
-                 return_hidden: bool = False):
+                 kv_caches=None, return_hidden: bool = False):
         """Training/full-context: input_ids [B, L] int32 → logits
         [B, L, vocab] (unchanged contract).  ``return_hidden=True``
         (full-context only) additionally returns the post-ln_f hidden
@@ -166,10 +165,13 @@ class GPT2(nn.Module):
 
         Incremental decode (``kv_caches`` given): ``positions`` [B, L]
         are the absolute positions of the new tokens, ``kv_caches`` is a
-        per-layer list of (k, v) each [B, S, H, D] of which the first
-        ``kv_lengths[b]`` rows are valid; returns (logits, new_kvs) where
-        new_kvs is the per-layer list of this call's (k, v) projections
-        [B, L, H, D] for the caller to append to its cache."""
+        per-layer list of ``attend(q, k, v) -> [B, L, H, D]`` callables,
+        each attending the new tokens to whatever its owner has cached
+        for that layer plus themselves (``ops.attention.cached_attention``
+        over a dense cache, ``ops.paged_attention`` over the engine's
+        page pool); returns (logits, new_kvs) where new_kvs is the
+        per-layer list of this call's (k, v) projections [B, L, H, D] for
+        the caller to append to its cache."""
         c = self.config
         b, l = input_ids.shape
         decode = kv_caches is not None
@@ -194,7 +196,7 @@ class GPT2(nn.Module):
             for i in range(c.num_layers):
                 if decode:
                     x, nkv = Block(c, self.attn_fn, name=f"h_{i}")(
-                        x, kv=(kv_caches[i][0], kv_caches[i][1], kv_lengths))
+                        x, kv=kv_caches[i])
                     new_kvs.append(nkv)
                 else:
                     x = Block(c, self.attn_fn, name=f"h_{i}")(x)

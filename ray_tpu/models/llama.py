@@ -23,7 +23,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import cached_attention, mha_attention
+from ray_tpu.ops.attention import mha_attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,11 +154,12 @@ class LlamaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, kv=None, positions=None):
-        """kv = (k_cache, v_cache, lengths) → incremental decode: rope is
-        applied at the tokens' absolute ``positions``, the cache stays at
-        num_kv_heads (the GQA memory win carries into the KV pages;
-        cached_attention expands heads after concat), and the layer also
-        returns this step's post-rope (k, v) for the caller's cache."""
+        """kv = the caller's ``attend(q, k, v)`` for this layer →
+        incremental decode: rope is applied at the tokens' absolute
+        ``positions``, k and v stay at num_kv_heads (the GQA memory win
+        carries into the KV pages; the caller's attention serves the
+        grouped heads), and the layer also returns this step's post-rope
+        (k, v) for the caller's cache."""
         c = self.config
         B, L, _ = x.shape
         hd = c.head_dim
@@ -175,8 +176,7 @@ class LlamaAttention(nn.Module):
                                    c.rope_theta)
             q = apply_rope(q, cos[positions], sin[positions])
             k = apply_rope(k, cos[positions], sin[positions])
-            k_cache, v_cache, lengths = kv
-            out = cached_attention(q, k, v, k_cache, v_cache, lengths)
+            out = kv(q, k, v)
             out = out.reshape(B, L, c.num_heads * hd)
             return dense(c.hidden_size, "o_proj")(out), (k, v)
         cos, sin = rope_tables(L, hd, c.rope_theta)
@@ -233,11 +233,12 @@ class Llama(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids: jax.Array, positions: jax.Array = None,
-                 kv_caches=None, kv_lengths: jax.Array = None):
+                 kv_caches=None):
         """Full-context: input_ids [B, L] → logits [B, L, vocab].  With
-        ``kv_caches`` (per-layer (k, v) at num_kv_heads, valid rows per
-        ``kv_lengths``) and absolute ``positions``: incremental decode,
-        returning (logits, new_kvs) — the same contract as GPT2."""
+        ``kv_caches`` (per-layer ``attend(q, k, v)`` callables over the
+        caller's cache, k and v at num_kv_heads) and absolute
+        ``positions``: incremental decode, returning (logits, new_kvs) —
+        the same contract as GPT2."""
         c = self.config
         emb = nn.Embed(c.vocab_size, c.hidden_size,
                        dtype=c.dtype, name="embed")
@@ -247,8 +248,7 @@ class Llama(nn.Module):
         for i in range(c.num_layers):
             if decode:
                 x, nkv = LlamaBlock(c, name=f"layer_{i}")(
-                    x, kv=(kv_caches[i][0], kv_caches[i][1], kv_lengths),
-                    positions=positions)
+                    x, kv=kv_caches[i], positions=positions)
                 new_kvs.append(nkv)
             else:
                 x = LlamaBlock(c, name=f"layer_{i}")(x)

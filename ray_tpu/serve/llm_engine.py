@@ -28,8 +28,10 @@ Load-bearing ideas:
    device-resident pool (`PagePool` — the SegmentPool free-list recycle
    design from `_private/object_store.py:163`, collapsed to one size
    class because pages are uniform).  A sequence owns `ceil(len/page)`
-   pages found through a per-slot page table; the decode step gathers
-   pages into the attention view and scatters the new token's K/V back.
+   pages found through a per-slot page table; the decode step's
+   attention reads them where they lie (``ops/paged_attention.py``: no
+   dense view of the cache is built) and the new token's K/V is
+   scattered back in place.
    Long and short sequences share the pool without fragmentation, pages
    recycle at retirement, and when the pool runs dry the engine preempts
    the youngest request (its pages free; it restarts later from
@@ -94,6 +96,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import math
 import queue
 import threading
@@ -112,6 +115,54 @@ _DEF = object()  # sentinel: constructor arg not given, consult CONFIG
 def _named(name: str, fn):
     fn.__name__ = fn.__qualname__ = name
     return fn
+
+
+def _attend_uncached(q, k, v):
+    """The models' per-layer cache hook when nothing is cached (a full
+    prefill): causal self-attention, ``cached_attention``'s S == 0 case."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.attention import cached_attention
+
+    empty = jnp.zeros((k.shape[0], 0) + k.shape[2:], k.dtype)
+    return cached_attention(q, k, v, empty, empty,
+                            jnp.zeros((k.shape[0],), jnp.int32))
+
+
+def _paged_attend(n_layers, k_pages, v_pages, table, lengths, active,
+                  first_page=None):
+    """The models' per-layer cache hooks of the decode programs: attention
+    that reads each slot's live pages from the pool in place
+    (``ops/paged_attention.py``).  A free lane reads nothing."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.paged_attention import paged_attention
+
+    cached = jnp.where(active, lengths, 0)
+    return [functools.partial(
+        paged_attention, k_pool=k_pages, v_pool=v_pages, layer=i,
+        table=table, lengths=cached, first_page=first_page)
+        for i in range(n_layers)]
+
+
+def _write_rows(pages, rows, page_idx, off):
+    """Row ``n`` of every layer of ``rows`` (``[L, n..., Hkv, D]`` or
+    ``[L, n..., Hkv*D]``) goes to ``pages[:, page_idx[n], off[n]]``; the
+    updated pool is the donated one, written in place.  The pool is
+    addressed as the ``[L*P*ps, width]`` rows it is made of: scattering
+    into ``[L, P, ps, ...]`` with the layers as a window makes the
+    compiler re-lay the whole pool out, there and back, around the
+    scatter (9.7 ms a step on the v5e for GPT-2 medium's 2 x 806 MB)."""
+    import jax.numpy as jnp
+
+    n_layers, n_pages, ps, width = pages.shape
+    row = (jnp.arange(n_layers)[:, None] * n_pages
+           + page_idx.reshape(1, -1)) * ps + off.reshape(1, -1)
+    rows = rows.reshape(row.size, -1).astype(pages.dtype)
+    if rows.shape[1] < width:  # a toy model's row, see pool_width
+        rows = jnp.pad(rows, ((0, 0), (0, width - rows.shape[1])))
+    flat = pages.reshape(-1, width).at[row.reshape(-1)].set(rows)
+    return flat.reshape(pages.shape)
 
 
 def _cfg(name, given, fallback):
@@ -291,8 +342,13 @@ class LLMEngine:
         self.pool = PagePool(num_pages)
         self.chunk_tokens = chunk_tokens
 
+        # A page is one lane-dense [page_size, kv_heads * head_dim] tile:
+        # what the paged-attention kernel copies whole, and no head of 64
+        # padded out to a 128-lane register.
+        from ray_tpu.ops.paged_attention import pool_width
+
         shape = (self.num_layers, num_pages, self.page_size,
-                 self.kv_heads, self.head_dim)
+                 pool_width(self.kv_heads, self.head_dim))
         self._k_pages = jnp.zeros(shape, self.dtype)
         self._v_pages = jnp.zeros(shape, self.dtype)
         # Where the page pool lives is where the engine decodes.
@@ -319,12 +375,12 @@ class LLMEngine:
                     f"positions {dc.max_position_embeddings} vs "
                     f"{self.max_ctx})")
             dshape = (dc.num_layers, num_pages, self.page_size,
-                      getattr(dc, "num_kv_heads", dc.num_heads), dc.head_dim)
+                      pool_width(getattr(dc, "num_kv_heads", dc.num_heads),
+                                 dc.head_dim))
             self._dk_pages = jnp.zeros(dshape, dc.dtype)
             self._dv_pages = jnp.zeros(dshape, dc.dtype)
-        # Sliding-window draft attention: the draft's page gather — the
-        # dominant per-step cost at long context — shrinks from
-        # pages_per_slot to ceil(draft_window / page_size) pages.
+        # Sliding-window draft attention: the draft reads only the newest
+        # ceil(draft_window / page_size) pages of a slot.
         self._draft_window_pages = None
         if draft_window is not None:
             if not self._spec:
@@ -403,8 +459,8 @@ class LLMEngine:
             _named("llm_adopt", self._make_adopt(self.dtype)),
             donate_argnums=(0, 1))
         self._adopt_buf_k = np.zeros(
-            (self.num_layers, self.pages_per_slot, self.page_size,
-             self.kv_heads, self.head_dim), np.float32)
+            (self.num_layers, self.pages_per_slot) + self._k_pages.shape[2:],
+            np.float32)
         self._adopt_buf_v = np.zeros_like(self._adopt_buf_k)
         self._prefills: Dict[Any, Any] = {}
 
@@ -683,115 +739,76 @@ class LLMEngine:
     # ------------------------------------------------------------------
     # compiled programs
     # ------------------------------------------------------------------
-    def _gather_for(self, cfg):
-        """Pages + [slots, pp] table → per-slot contiguous
-        [L, slots, max_ctx, Hkv, D] attention view (rows past each
-        slot's length are garbage — masked by cached_attention)."""
-        L = cfg.num_layers
-        hkv = getattr(cfg, "num_kv_heads", cfg.num_heads)
-        d, mc = cfg.head_dim, self.max_ctx
-
-        def gather(pages, table):
-            g = pages[:, table]  # [L, slots, pp, ps, Hkv, D]
-            return g.reshape(L, table.shape[0], mc, hkv, d)
-
-        return gather
-
     def _make_decode_step(self, model, window_pages: Optional[int] = None):
         """One token for every slot (fixed shapes — compiled once).
         Inactive lanes compute garbage routed to the scratch page.
         Shared shape for the target and the draft model (each gets its
-        own jit over its own page arrays).
+        own jit over its own page arrays).  Attention reads each slot's
+        live pages from the pool in place (``ops/paged_attention.py``).
 
-        ``window_pages`` (draft only) switches the attention view to a
-        sliding window of the LAST n pages: the page gather — the
-        step's dominant cost at long context — shrinks from
-        pages_per_slot to n.  Positional information is baked into the
-        cached K/V at write time (learned embeddings at embed, rope at
-        projection), so a windowed view plus window-relative valid
-        lengths is exact windowed attention, no re-indexing.  The
-        target never does this (it must attend to everything); the
-        draft is a guesser, and the verify step catches what the
-        shortened horizon loses."""
+        ``window_pages`` (draft only) starts that read at the first of
+        the LAST n pages: a sliding window.  Positional information is
+        baked into the cached K/V at write time (learned embeddings at
+        embed, rope at projection), so a later first page is exact
+        windowed attention, no re-indexing.  The target never does this
+        (it must attend to everything); the draft is a guesser, and the
+        verify step catches what the shortened horizon loses."""
         jnp = self._jnp
-        cfg = model.config
-        L, ps, pp = cfg.num_layers, self.page_size, self.pages_per_slot
+        L, ps, pp = model.config.num_layers, self.page_size, \
+            self.pages_per_slot
         from ray_tpu.serve.sampling import sample_tokens_with_logprobs
-
-        hkv = getattr(cfg, "num_kv_heads", cfg.num_heads)
-        if window_pages is None or window_pages >= pp:
-            gather = self._gather_for(cfg)
-
-            def gather_view(pages, table, lengths):
-                return gather(pages, table), lengths
-        else:
-            wp = int(window_pages)
-
-            def gather_view(pages, table, lengths):
-                # Pages [(len-1)//ps - wp + 1 .. (len-1)//ps], clamped:
-                # the newest wp pages.  Valid rows within the view are
-                # lengths - start*ps (window-relative).
-                last_page = jnp.maximum(lengths - 1, 0) // ps
-                start = jnp.maximum(last_page - (wp - 1), 0)
-                cols = start[:, None] + jnp.arange(wp)[None]
-                idx = jnp.take_along_axis(
-                    table, jnp.minimum(cols, pp - 1), axis=1)
-                g = pages[:, idx]  # [L, slots, wp, ps, Hkv, D]
-                view = g.reshape(L, table.shape[0], wp * ps, hkv,
-                                 cfg.head_dim)
-                return view, lengths - start * ps
 
         scope = self._jax.named_scope
 
         def step(params, k_pages, v_pages, table, lengths, tokens, active,
                  temps, top_ps, seeds):
-            with scope("gather"):
-                k_cache, view_len = gather_view(k_pages, table, lengths)
-                v_cache, _ = gather_view(v_pages, table, lengths)
-                kv = [(k_cache[i], v_cache[i]) for i in range(L)]
             with scope("attend"):
+                first = None
+                if window_pages is not None and window_pages < pp:
+                    # Pages [(len-1)//ps - wp + 1 .. (len-1)//ps],
+                    # clamped: the newest wp pages.
+                    last_page = jnp.maximum(lengths - 1, 0) // ps
+                    first = jnp.maximum(last_page - (window_pages - 1), 0)
                 logits, new_kvs = model.apply(
                     {"params": params}, tokens[:, None], lengths[:, None],
-                    kv, view_len)
+                    _paged_attend(L, k_pages, v_pages, table, lengths,
+                                  active, first))
             # The generated token sits at absolute position lengths + 1.
             with scope("sample"):
                 next_tok, next_logp = sample_tokens_with_logprobs(
                     logits[:, -1], lengths + 1, temps, top_ps, seeds)
             with scope("scatter"):
-                newk = jnp.stack([nk[0][:, 0] for nk in new_kvs])
-                newv = jnp.stack([nk[1][:, 0] for nk in new_kvs])
+                # [L, slots, 1, Hkv, D]
+                newk = jnp.stack([nk[0] for nk in new_kvs])
+                newv = jnp.stack([nk[1] for nk in new_kvs])
                 slot_ix = jnp.arange(table.shape[0])
                 page_col = jnp.minimum(lengths // ps, pp - 1)
                 page_idx = jnp.where(active, table[slot_ix, page_col], 0)
                 off = lengths % ps
-                k_pages = k_pages.at[:, page_idx, off].set(
-                    newk.astype(k_pages.dtype))
-                v_pages = v_pages.at[:, page_idx, off].set(
-                    newv.astype(v_pages.dtype))
+                k_pages = _write_rows(k_pages, newk, page_idx, off)
+                v_pages = _write_rows(v_pages, newv, page_idx, off)
             return k_pages, v_pages, next_tok, next_logp
 
         return step
 
     def _make_verify_step(self, model):
         """Target-model verification of a [slots, k] speculative window:
-        one forward over the window, KV scattered for every position,
-        and the target's sampled token at every position — the host
-        applies accept-longest-prefix to the result."""
+        one forward over the window (the decode step's attention with k
+        query rows a slot), KV scattered for every position, and the
+        target's sampled token at every position — the host applies
+        accept-longest-prefix to the result."""
         jnp = self._jnp
-        cfg = model.config
-        L, ps, pp = cfg.num_layers, self.page_size, self.pages_per_slot
+        L, ps, pp = model.config.num_layers, self.page_size, \
+            self.pages_per_slot
         k_win = self.spec_tokens
-        gather = self._gather_for(cfg)
         from ray_tpu.serve.sampling import sample_tokens_with_logprobs
 
         def verify(params, k_pages, v_pages, table, lengths, window, active,
                    temps, top_ps, seeds):
-            k_cache = gather(k_pages, table)
-            v_cache = gather(v_pages, table)
-            kv = [(k_cache[i], v_cache[i]) for i in range(L)]
             positions = lengths[:, None] + jnp.arange(k_win)[None]
             logits, new_kvs = model.apply(
-                {"params": params}, window, positions, kv, lengths)
+                {"params": params}, window, positions,
+                _paged_attend(L, k_pages, v_pages, table, lengths, active))
             newk = jnp.stack([nk[0] for nk in new_kvs])  # [L,slots,k,Hkv,D]
             newv = jnp.stack([nk[1] for nk in new_kvs])
             page_col = jnp.minimum(positions // ps, pp - 1)
@@ -799,10 +816,8 @@ class LLMEngine:
                                  jnp.take_along_axis(table, page_col, axis=1),
                                  0)
             off = positions % ps
-            k_pages = k_pages.at[:, page_idx, off].set(
-                newk.astype(k_pages.dtype))
-            v_pages = v_pages.at[:, page_idx, off].set(
-                newv.astype(v_pages.dtype))
+            k_pages = _write_rows(k_pages, newk, page_idx, off)
+            v_pages = _write_rows(v_pages, newv, page_idx, off)
             n = table.shape[0]
             flat = logits.reshape(n * k_win, -1)
             rep = lambda a: jnp.repeat(a, k_win)
@@ -819,12 +834,14 @@ class LLMEngine:
         prefill payloads) into the device page arrays.  Fixed
         [pages_per_slot] shape — compiled once; unused rows are routed
         to the scratch page by the host-masked ids."""
+        jnp = self._jnp
 
         def adopt(k_pages, v_pages, page_ids, k_new, v_new):
-            k_pages = k_pages.at[:, page_ids].set(
-                k_new.astype(k_pages.dtype))
-            v_pages = v_pages.at[:, page_ids].set(
-                v_new.astype(v_pages.dtype))
+            _, n, ps, _ = k_new.shape  # [L, pages, ps, Hkv*D]
+            page_idx = jnp.repeat(page_ids, ps)
+            off = jnp.tile(jnp.arange(ps), n)
+            k_pages = _write_rows(k_pages, k_new, page_idx, off)
+            v_pages = _write_rows(v_pages, v_new, page_idx, off)
             return k_pages, v_pages
 
         return adopt
@@ -849,12 +866,10 @@ class LLMEngine:
             behavior logprob."""
             ids = tokens[None]
             positions = jnp.arange(bucket)[None]
-            empty = [(jnp.zeros((1, 0, self.kv_heads, self.head_dim),
-                                self.dtype),) * 2 for _ in range(L)]
             with jax.named_scope("attend"):
                 logits, new_kvs = model.apply(
-                    {"params": params}, ids, positions, empty,
-                    jnp.zeros((1,), jnp.int32))
+                    {"params": params}, ids, positions,
+                    [_attend_uncached] * L)
             with jax.named_scope("sample"):
                 toks, logps = sample_tokens_with_logprobs(
                     logits[0, p - 1][None], jnp.reshape(p, (1,)),
@@ -867,10 +882,8 @@ class LLMEngine:
                 off = t % ps
                 newk = jnp.stack([nk[0][0] for nk in new_kvs])  # [L,bkt,Hkv,D]
                 newv = jnp.stack([nk[1][0] for nk in new_kvs])
-                k_pages = k_pages.at[:, page_idx, off].set(
-                    newk.astype(self.dtype))
-                v_pages = v_pages.at[:, page_idx, off].set(
-                    newv.astype(self.dtype))
+                k_pages = _write_rows(k_pages, newk, page_idx, off)
+                v_pages = _write_rows(v_pages, newv, page_idx, off)
             return k_pages, v_pages, next_tok, next_logp
 
         fn = jax.jit(_named(f"llm_prefill_{bucket}", prefill),
@@ -882,8 +895,11 @@ class LLMEngine:
         """Cache-aware tail prefill: the first ``start`` tokens' KV is
         already in the slot's pages (adopted from the prefix cache), so
         only the tail runs through the model — the tail tokens attend to
-        the gathered cache prefix plus themselves.  One program per pow2
-        tail bucket."""
+        the cache prefix plus themselves.  One program per pow2 tail
+        bucket.  The one place that still gathers a dense view, of this
+        one slot's row (``max_ctx`` rows, not the pool): a bucket of
+        queries against one row is prefill-shaped work for
+        ``cached_attention``, not the decode kernel's."""
         key = ("tail", bucket)
         fn = self._prefills.get(key)
         if fn is not None:
@@ -891,8 +907,13 @@ class LLMEngine:
         jax, jnp = self._jax, self._jnp
         model = self._model
         L, ps, pp = self.num_layers, self.page_size, self.pages_per_slot
-        gather = self._gather_for(model.config)
+        from ray_tpu.ops.attention import cached_attention
         from ray_tpu.serve.sampling import sample_tokens_with_logprobs
+
+        def gather(pages, row):  # → [L, 1, max_ctx, Hkv, D]
+            rows = pages[:, row, :, :self.kv_heads * self.head_dim]
+            return rows.reshape(L, 1, self.max_ctx, self.kv_heads,
+                                self.head_dim)
 
         def tail_prefill(params, k_pages, v_pages, row, tokens, start, p,
                          temp, top_p, seed):
@@ -900,14 +921,16 @@ class LLMEngine:
             padded past p-start; returns updated pages + the sampled
             next token at absolute position p and its behavior logprob."""
             with jax.named_scope("gather"):
-                k_cache = gather(k_pages, row[None])  # [L,1,max_ctx,Hkv,D]
-                v_cache = gather(v_pages, row[None])
-                kv = [(k_cache[i], v_cache[i]) for i in range(L)]
+                k_cache = gather(k_pages, row)
+                v_cache = gather(v_pages, row)
+                attend = [functools.partial(
+                    cached_attention, k_cache=k_cache[i], v_cache=v_cache[i],
+                    cache_lengths=jnp.reshape(start, (1,)))
+                    for i in range(L)]
             positions = (start + jnp.arange(bucket))[None]
             with jax.named_scope("attend"):
                 logits, new_kvs = model.apply(
-                    {"params": params}, tokens[None], positions, kv,
-                    jnp.reshape(start, (1,)))
+                    {"params": params}, tokens[None], positions, attend)
             tail_len = p - start
             with jax.named_scope("sample"):
                 toks, logps = sample_tokens_with_logprobs(
@@ -924,10 +947,8 @@ class LLMEngine:
                 off = abs_pos % ps
                 newk = jnp.stack([nk[0][0] for nk in new_kvs])
                 newv = jnp.stack([nk[1][0] for nk in new_kvs])
-                k_pages = k_pages.at[:, page_idx, off].set(
-                    newk.astype(self.dtype))
-                v_pages = v_pages.at[:, page_idx, off].set(
-                    newv.astype(self.dtype))
+                k_pages = _write_rows(k_pages, newk, page_idx, off)
+                v_pages = _write_rows(v_pages, newv, page_idx, off)
             return k_pages, v_pages, next_tok, next_logp
 
         fn = jax.jit(_named(f"llm_tail_prefill_{bucket}", tail_prefill),
@@ -948,25 +969,19 @@ class LLMEngine:
         model = self._draft_model
         dc = model.config
         L, ps = dc.num_layers, self.page_size
-        hkv = getattr(dc, "num_kv_heads", dc.num_heads)
 
         def prefill(params, k_pages, v_pages, row, tokens, p):
             ids = tokens[None]
             positions = jnp.arange(bucket)[None]
-            empty = [(jnp.zeros((1, 0, hkv, dc.head_dim), dc.dtype),) * 2
-                     for _ in range(L)]
             _, new_kvs = model.apply(
-                {"params": params}, ids, positions, empty,
-                jnp.zeros((1,), jnp.int32))
+                {"params": params}, ids, positions, [_attend_uncached] * L)
             t = jnp.arange(bucket)
             page_idx = jnp.where(t < p, row[t // ps], 0)
             off = t % ps
             newk = jnp.stack([nk[0][0] for nk in new_kvs])
             newv = jnp.stack([nk[1][0] for nk in new_kvs])
-            k_pages = k_pages.at[:, page_idx, off].set(
-                newk.astype(k_pages.dtype))
-            v_pages = v_pages.at[:, page_idx, off].set(
-                newv.astype(v_pages.dtype))
+            k_pages = _write_rows(k_pages, newk, page_idx, off)
+            v_pages = _write_rows(v_pages, newv, page_idx, off)
             return k_pages, v_pages
 
         fn = jax.jit(_named(f"llm_draft_prefill_{bucket}", prefill),
@@ -1423,9 +1438,10 @@ class LLMEngine:
         ids = np.zeros((self.pages_per_slot,), np.int32)
         ids[:n] = self._table[slot, first_page:first_page + n]
         bk, bv = self._adopt_buf_k, self._adopt_buf_v
-        for j, (k_np, v_np) in enumerate(pages):
-            bk[:, j] = k_np
-            bv[:, j] = v_np
+        hd = self.kv_heads * self.head_dim
+        for j, (k_np, v_np) in enumerate(pages):  # [L, ps, Hkv, D] each
+            bk[:, j, :, :hd] = k_np.reshape(bk.shape[0], self.page_size, hd)
+            bv[:, j, :, :hd] = v_np.reshape(bv.shape[0], self.page_size, hd)
         bk[:, n:] = 0
         bv[:, n:] = 0
         self._k_pages, self._v_pages = self._adopt(
@@ -1451,8 +1467,12 @@ class LLMEngine:
             if self._prefix.contains(key):
                 continue
             page_id = int(self._table[slot, i])
-            k_np = np.asarray(self._k_pages[:, page_id])
-            v_np = np.asarray(self._v_pages[:, page_id])
+            # The cache and the wire keep a page as [L, ps, Hkv, D].
+            page = (self.num_layers, self.page_size, self.kv_heads,
+                    self.head_dim)
+            hd = self.kv_heads * self.head_dim
+            k_np = np.asarray(self._k_pages[:, page_id, :, :hd]).reshape(page)
+            v_np = np.asarray(self._v_pages[:, page_id, :, :hd]).reshape(page)
             self._prefix.put(key, k_np, v_np)
             self._stats["prefix_published_pages"] += 1
             to_publish.append((key, k_np, v_np))
@@ -1561,7 +1581,10 @@ class LLMEngine:
 
     def _decode_once(self):
         n_active = int(self._active.sum())
-        with obs.span("engine.decode.dispatch"):
+        # kv_tokens: the cached rows this step's attention reads, which is
+        # what the benchmark's paged_attn_roofline counts the bytes of.
+        with obs.span("engine.decode.dispatch",
+                      kv_tokens=int(self._lengths[self._active].sum())):
             self._k_pages, self._v_pages, nxt, lps = self._decode(
                 self._params, self._k_pages, self._v_pages, self._table,
                 self._lengths, self._last_tok, self._active, self._temps,

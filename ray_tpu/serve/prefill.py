@@ -141,18 +141,16 @@ class PrefillWorker:
         import jax
         import jax.numpy as jnp
 
+        from ray_tpu.serve.llm_engine import _attend_uncached
         from ray_tpu.serve.sampling import sample_tokens_with_logprobs
 
         model, L = self._model, self.num_layers
-        hkv, d, dt = self.kv_heads, self.head_dim, self.dtype
 
         def prefill(params, tokens, p, temp, top_p, seed):
             ids = tokens[None]
             positions = jnp.arange(bucket)[None]
-            empty = [(jnp.zeros((1, 0, hkv, d), dt),) * 2 for _ in range(L)]
             logits, new_kvs = model.apply(
-                {"params": params}, ids, positions, empty,
-                jnp.zeros((1,), jnp.int32))
+                {"params": params}, ids, positions, [_attend_uncached] * L)
             toks, logps = sample_tokens_with_logprobs(
                 logits[0, p - 1][None], jnp.reshape(p, (1,)),
                 jnp.reshape(temp, (1,)), jnp.reshape(top_p, (1,)),

@@ -186,8 +186,10 @@ def test_queue_wait_ends_where_prefill_starts(traced_engine):
 def test_engine_programs_carry_scope_names(traced_engine, gpt2):
     decode = traced_engine["lowered"]["decode"]
     assert "llm_decode" in decode
-    for scope in ("gather", "attend", "sample", "scatter"):
+    for scope in ("attend", "sample", "scatter"):
         assert f"llm_decode)/{scope}" in decode or f"/{scope}/" in decode
+    # attention reads the pool in place: the kernel, and no gather before it
+    assert "paged_attn" in decode and "/gather/" not in decode
     prefill = traced_engine["lowered"]["prefill"]
     assert "llm_prefill_8" in prefill
     for scope in ("attend", "sample", "scatter"):
