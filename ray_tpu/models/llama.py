@@ -13,6 +13,14 @@ conventions:
   GQA head expansion,
 - the same parameter-name → logical-axis table as GPT-2, so
   ShardingRules runs it 1-chip, DP, FSDP or DP×TP unchanged.
+
+Layer options carry other families through the same decoder, set from the
+keys of their published ``config.json``: ``qk_norm`` (an RMSNorm over the
+whole projected width of q and of k, before the split into heads and
+before rope) and ``num_experts`` / ``num_experts_per_tok`` / ``expert_size``
+/ ``norm_topk_prob`` (a dropless top-k SwiGLU expert FFN, ``ops/moe.py``,
+in place of the dense MLP).  OLMoE-1B-7B is this decoder with both set.
+``param_dtype`` is the dtype the leaves are made in.
 """
 from __future__ import annotations
 
@@ -24,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import mha_attention
+from ray_tpu.ops.moe import experts_dropless, route_topk
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,6 +48,12 @@ class LlamaConfig:
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     use_flash: Optional[bool] = None
+    param_dtype: Any = jnp.float32   # the dtype init makes the leaves in
+    qk_norm: bool = False            # whole-width RMSNorm on q and k
+    num_experts: int = 0             # > 0 → expert FFN in every block
+    num_experts_per_tok: int = 0
+    expert_size: Optional[int] = None  # one expert's width (default mlp_dim)
+    norm_topk_prob: bool = False     # rescale the chosen weights to sum 1
 
     @classmethod
     def tiny(cls, **kw):  # test-sized
@@ -88,10 +103,16 @@ class LlamaConfig:
     @property
     def block_params(self) -> int:
         """Parameters per decoder block: q/o at h^2, GQA k/v at
-        h^2 * kv/heads, three SwiGLU mats at h*mlp (+2 RMSNorm scales)."""
+        h^2 * kv/heads, three SwiGLU mats at h*mlp (+2 RMSNorm scales);
+        with experts, E times the three mats at h*expert width plus the
+        router; with qk_norm, its two scales."""
         h, m = self.hidden_size, self.mlp_dim
         kv = self.num_kv_heads / self.num_heads
-        return int(h * h * (2 + 2 * kv) + 3 * h * m + 2 * h)
+        ffn = 3 * h * m
+        if self.num_experts:
+            ffn = self.num_experts * (3 * h * self.expert_dim + h)
+        qk = int(h + h * kv) if self.qk_norm else 0
+        return int(h * h * (2 + 2 * kv) + ffn + 2 * h + qk)
 
     @property
     def n_params(self) -> int:
@@ -113,10 +134,15 @@ class LlamaConfig:
         raw = int(self.hidden_size * 8 / 3)
         return ((raw + 31) // 32) * 32
 
+    @property
+    def expert_dim(self) -> int:
+        return self.expert_size or self.mlp_dim
+
 
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
@@ -124,8 +150,18 @@ class RMSNorm(nn.Module):
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
                        keepdims=True)
         norm = x * jax.lax.rsqrt(var + self.eps).astype(x.dtype)
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           self.param_dtype)
         return norm * scale.astype(x.dtype)
+
+
+def _norm(c: "LlamaConfig", name: str) -> RMSNorm:
+    return RMSNorm(c.rms_eps, c.dtype, c.param_dtype, name=name)
+
+
+def _dense(c: "LlamaConfig", feats: int, name: str) -> nn.Dense:
+    return nn.Dense(feats, use_bias=False, dtype=c.dtype,
+                    param_dtype=c.param_dtype, name=name)
 
 
 def rope_tables(length: int, head_dim: int, theta: float):
@@ -163,13 +199,15 @@ class LlamaAttention(nn.Module):
         c = self.config
         B, L, _ = x.shape
         hd = c.head_dim
-        dense = lambda feats, name: nn.Dense(  # noqa: E731
-            feats, use_bias=False, dtype=c.dtype, name=name)
-        q = dense(c.num_heads * hd, "q_proj")(x).reshape(
-            B, L, c.num_heads, hd)
-        k = dense(c.num_kv_heads * hd, "k_proj")(x).reshape(
-            B, L, c.num_kv_heads, hd)
-        v = dense(c.num_kv_heads * hd, "v_proj")(x).reshape(
+        q = _dense(c, c.num_heads * hd, "q_proj")(x)
+        k = _dense(c, c.num_kv_heads * hd, "k_proj")(x)
+        if c.qk_norm:
+            # Over all heads together: one variance a token, not one a head.
+            q = _norm(c, "q_norm")(q)
+            k = _norm(c, "k_norm")(k)
+        q = q.reshape(B, L, c.num_heads, hd)
+        k = k.reshape(B, L, c.num_kv_heads, hd)
+        v = _dense(c, c.num_kv_heads * hd, "v_proj")(x).reshape(
             B, L, c.num_kv_heads, hd)
         if kv is not None:
             cos, sin = rope_tables(c.max_position_embeddings, hd,
@@ -178,7 +216,7 @@ class LlamaAttention(nn.Module):
             k = apply_rope(k, cos[positions], sin[positions])
             out = kv(q, k, v)
             out = out.reshape(B, L, c.num_heads * hd)
-            return dense(c.hidden_size, "o_proj")(out), (k, v)
+            return _dense(c, c.hidden_size, "o_proj")(out), (k, v)
         cos, sin = rope_tables(L, hd, c.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -191,7 +229,7 @@ class LlamaAttention(nn.Module):
             v = jnp.repeat(v, rep, axis=2)
         out = mha_attention(q, k, v, causal=True, use_flash=c.use_flash)
         out = out.reshape(B, L, c.num_heads * hd)
-        return dense(c.hidden_size, "o_proj")(out)
+        return _dense(c, c.hidden_size, "o_proj")(out)
 
 
 class LlamaMLP(nn.Module):
@@ -200,11 +238,47 @@ class LlamaMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.config
-        dense = lambda feats, name: nn.Dense(  # noqa: E731
-            feats, use_bias=False, dtype=c.dtype, name=name)
-        gate = dense(c.mlp_dim, "gate_proj")(x)
-        up = dense(c.mlp_dim, "up_proj")(x)
-        return dense(c.hidden_size, "down_proj")(nn.silu(gate) * up)
+        gate = _dense(c, c.mlp_dim, "gate_proj")(x)
+        up = _dense(c, c.mlp_dim, "up_proj")(x)
+        return _dense(c, c.hidden_size, "down_proj")(nn.silu(gate) * up)
+
+
+# One expert's matrices see fan-in d (or f), whatever the stack's size.
+_expert_init = nn.initializers.variance_scaling(
+    1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
+    batch_axis=(0,))
+
+
+class LlamaMoE(nn.Module):
+    """The block's FFN as ``num_experts`` SwiGLU experts, each token
+    through its ``num_experts_per_tok`` best, none dropped
+    (``ops/moe.py``).  Three stacked leaves a layer and the router.  The
+    chosen experts of every token are sown into the ``moe`` collection
+    (``expert_idx``, [B, L, k]) for a caller that asks for it
+    (``mutable=["moe"]``): the serve engine counts the experts a decode
+    step touched from them; any other caller pays nothing."""
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.config
+        d, e, f = c.hidden_size, c.num_experts, c.expert_dim
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (d, e), c.param_dtype)
+        w_gate = self.param("w_gate", _expert_init, (e, d, f), c.param_dtype)
+        w_up = self.param("w_up", _expert_init, (e, d, f), c.param_dtype)
+        w_down = self.param("w_down", _expert_init, (e, f, d), c.param_dtype)
+        B, L, _ = x.shape
+        rows = x.reshape(B * L, d).astype(c.dtype)
+        with jax.named_scope("route"):
+            weights, experts = route_topk(
+                rows, router, c.num_experts_per_tok, c.norm_topk_prob)
+        self.sow("moe", "expert_idx",
+                 experts.reshape(B, L, c.num_experts_per_tok))
+        with jax.named_scope("experts"):
+            out = experts_dropless(rows, weights, experts, w_gate, w_up,
+                                   w_down)
+        return out.reshape(B, L, d)
 
 
 class LlamaBlock(nn.Module):
@@ -213,19 +287,16 @@ class LlamaBlock(nn.Module):
     @nn.compact
     def __call__(self, x, kv=None, positions=None):
         c = self.config
+        attn = LlamaAttention(c, name="attn")(
+            _norm(c, "attn_norm")(x), kv=kv, positions=positions)
+        new_kv = None
         if kv is not None:
-            attn, new_kv = LlamaAttention(c, name="attn")(
-                RMSNorm(c.rms_eps, c.dtype, name="attn_norm")(x),
-                kv=kv, positions=positions)
-            x = x + attn
-            x = x + LlamaMLP(c, name="mlp")(
-                RMSNorm(c.rms_eps, c.dtype, name="mlp_norm")(x))
-            return x, new_kv
-        x = x + LlamaAttention(c, name="attn")(
-            RMSNorm(c.rms_eps, c.dtype, name="attn_norm")(x))
-        x = x + LlamaMLP(c, name="mlp")(
-            RMSNorm(c.rms_eps, c.dtype, name="mlp_norm")(x))
-        return x
+            attn, new_kv = attn
+        x = x + attn
+        ffn = LlamaMoE(c, name="moe") if c.num_experts else \
+            LlamaMLP(c, name="mlp")
+        x = x + ffn(_norm(c, "mlp_norm")(x))
+        return x if kv is None else (x, new_kv)
 
 
 class Llama(nn.Module):
@@ -240,8 +311,8 @@ class Llama(nn.Module):
         ``positions``: incremental decode, returning (logits, new_kvs) —
         the same contract as GPT2."""
         c = self.config
-        emb = nn.Embed(c.vocab_size, c.hidden_size,
-                       dtype=c.dtype, name="embed")
+        emb = nn.Embed(c.vocab_size, c.hidden_size, dtype=c.dtype,
+                       param_dtype=c.param_dtype, name="embed")
         x = emb(input_ids)
         decode = kv_caches is not None
         new_kvs = []
@@ -252,9 +323,10 @@ class Llama(nn.Module):
                 new_kvs.append(nkv)
             else:
                 x = LlamaBlock(c, name=f"layer_{i}")(x)
-        x = RMSNorm(c.rms_eps, c.dtype, name="final_norm")(x)
+        x = _norm(c, "final_norm")(x)
         # Untied LM head (llama convention), fp32 logits for the softmax.
         logits = nn.Dense(c.vocab_size, use_bias=False, dtype=jnp.float32,
+                          param_dtype=c.param_dtype,
                           name="lm_head")(x.astype(jnp.float32))
         if decode:
             return logits, new_kvs
@@ -280,15 +352,16 @@ class LlamaStage(nn.Module):
         c = self.config
         if self.first:
             x = nn.Embed(c.vocab_size, c.hidden_size, dtype=c.dtype,
-                         name="embed")(x)
+                         param_dtype=c.param_dtype, name="embed")(x)
         else:
             x = x.astype(c.dtype)
         for i in range(*self.blocks):
             x = LlamaBlock(c, name=f"layer_{i}")(x)
         if self.last:
-            x = RMSNorm(c.rms_eps, c.dtype, name="final_norm")(x)
+            x = _norm(c, "final_norm")(x)
             logits = nn.Dense(c.vocab_size, use_bias=False,
-                              dtype=jnp.float32, name="lm_head")(
+                              dtype=jnp.float32, param_dtype=c.param_dtype,
+                              name="lm_head")(
                 x.astype(jnp.float32))
             return logits
         return x

@@ -1,24 +1,31 @@
-"""Mixture-of-Experts: top-k routing + expert-parallel dispatch/combine.
+"""Mixture-of-Experts: top-k routing, and two ways to run the experts.
 
 Net-new TPU scope (SURVEY §2.4 EP row — the reference has no MoE or expert
-parallelism; its substrate is just placement groups + collectives).  Two
-interchangeable formulations of the same math:
+parallelism; its substrate is just placement groups + collectives).
 
-- ``moe_apply`` — dense dispatch/combine einsums (GShard/Switch style with
-  static capacity).  Pure jnp: runs anywhere under jit, and under pjit the
-  one-hot dispatch einsums partition cleanly when the expert dim of the
-  weights is sharded over the ``expert`` mesh axis (XLA inserts the
-  all_to_all itself — the GSPMD-idiomatic path).
-- ``moe_apply_expert_parallel`` — explicit shard_map version: tokens are
-  sharded over the ``expert`` axis, dispatch runs locally, and
-  ``lax.all_to_all`` exchanges token groups so each device computes only
-  its resident experts.  Byte-equivalent to running ``moe_apply`` on each
-  token shard (tests/test_moe.py asserts this on an 8-device CPU mesh).
+**Dropless** (``moe_dropless`` = ``route_topk`` + ``experts_dropless``):
+every token reaches every expert it chose, whatever the load; SwiGLU
+experts; the softmax over all experts, the chosen weights renormalised only
+on request.  What a published sparse model computes, so what serving runs:
+``models/llama.py`` with ``num_experts`` set (OLMoE through ``LLMServer``).
+Two exact forms, chosen from the static row count alone: few rows compute
+every expert on every row and mask (a decode step is bound by the weights
+it streams, and the masked einsum is one pass over them); many rows are
+sorted by expert and multiplied group by group (``lax.ragged_dot``, which
+the TPU compiler turns into a grouped-matmul kernel of its own).
 
-Routing is top-k with probabilities renormalized over the selected experts
-and a static per-expert capacity ``C = ceil(k * N * capacity_factor / E)``;
-overflowing tokens drop (standard Switch semantics — the residual stream
-carries them unchanged).
+**Capacity-factor** (``moe_apply`` and its expert-parallel twin
+``moe_apply_expert_parallel``): GShard/Switch dense dispatch/combine
+einsums with a static per-expert capacity ``C = ceil(k * N *
+capacity_factor / E)``; overflowing tokens DROP (the residual stream
+carries them unchanged), weights renormalised over the chosen, two-matrix
+GELU experts.  Used by ``GPT2Config.moe_tiny`` (``models/gpt2.py``), the
+multichip dry run and ``tests/test_moe.py``.  Under pjit the one-hot
+einsums partition cleanly when the expert dim of the weights is sharded
+over the ``expert`` mesh axis; the ``shard_map`` twin makes the
+``all_to_all`` explicit and is byte-equivalent on each token shard.  It
+goes when a training cell takes the dropless op over the ``expert`` axis
+(ROADMAP D3).
 """
 from __future__ import annotations
 
@@ -159,3 +166,82 @@ def init_moe_params(key, d_model: int, d_ff: int, cfg: MoEConfig):
         "w_out": jax.random.normal(k3, (cfg.num_experts, d_ff, d_model),
                                    jnp.float32) * scale,
     }
+
+
+# ---------------------------------------------------------------------------
+# Dropless MoE (serving: logits must equal the reference's, so no capacity)
+# ---------------------------------------------------------------------------
+
+# Rows up to which every expert is computed on every row.  The masked form
+# does E/k times the needed arithmetic but reads each weight once with no
+# sort; the grouped kernel works in tiles of 512 rows an expert, so for few
+# rows it multiplies more padding than the masked form multiplies zeros.
+# One OLMoE layer (64 experts of 2048 x 1024, top-8) on the v5e, ms: masked
+# 1.25 up to 256 rows (the weights' stream), 2.33 at 512, 4.53 at 1024;
+# grouped 2.6-2.8 up to 256, 3.10 at 512, 3.74 at 1024 (PERF.md, PR 27).
+DENSE_MAX_ROWS = 512
+
+
+def route_topk(x: jax.Array, w_router: jax.Array, top_k: int,
+               norm_topk_prob: bool = False):
+    """x [N, d], w_router [d, E] → (weights [N, k] fp32, experts [N, k]
+    int32): softmax in fp32 over ALL experts, then the k largest.  The
+    weights are the softmax's own values (they sum to under 1) unless
+    ``norm_topk_prob`` rescales them to sum to 1."""
+    top_p, top_i = lax.top_k(router_probs(x, w_router), top_k)
+    if norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_i.astype(jnp.int32)
+
+
+def expert_rows(experts: jax.Array, num_experts: int) -> jax.Array:
+    """experts [..., k] → rows assigned to each expert, [E] int32."""
+    return jnp.zeros((num_experts,), jnp.int32).at[
+        experts.reshape(-1)].add(1)
+
+
+def experts_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
+                     w_gate: jax.Array, w_up: jax.Array,
+                     w_down: jax.Array) -> jax.Array:
+    """sum_j weights[n, j] * down_e(silu(gate_e(x_n)) * up_e(x_n)) with
+    e = experts[n, j]: x [N, d], w_gate / w_up [E, d, f], w_down [E, f, d]
+    → [N, d] in x's dtype.  Products in x's dtype, sums in fp32.  N (a
+    static shape) picks the form; both are exact, no token is dropped."""
+    n, d = x.shape
+    e, k = w_gate.shape[0], experts.shape[1]
+    f32 = jnp.float32
+    w_gate, w_up, w_down = (w.astype(x.dtype) for w in (w_gate, w_up, w_down))
+    if n <= DENSE_MAX_ROWS:
+        # [N, E] combine weights, zero where an expert was not chosen.
+        combine = jnp.zeros((n, e), f32).at[
+            jnp.arange(n)[:, None], experts].add(weights)
+        g = jnp.einsum("nd,edf->enf", x, w_gate, preferred_element_type=f32)
+        u = jnp.einsum("nd,edf->enf", x, w_up, preferred_element_type=f32)
+        h = (jax.nn.silu(g) * u * combine.T[:, :, None]).astype(x.dtype)
+        out = jnp.einsum("enf,efd->nd", h, w_down,
+                         preferred_element_type=f32)
+        return out.astype(x.dtype)
+    # Sort the N*k (row, expert) assignments by expert; each expert then
+    # owns one contiguous group of rows, of any size.
+    order = jnp.argsort(experts.reshape(-1), stable=True)
+    sizes = expert_rows(experts, e)
+    xs = x[order // k]
+    g = lax.ragged_dot(xs, w_gate, sizes, preferred_element_type=f32)
+    u = lax.ragged_dot(xs, w_up, sizes, preferred_element_type=f32)
+    y = lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype), w_down, sizes,
+                       preferred_element_type=f32)
+    # Back to (row, choice) order by a gather, then the weighted sum.
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(n * k, dtype=order.dtype))
+    y = y[back].reshape(n, k, d) * weights[:, :, None]
+    return jnp.sum(y, axis=1).astype(x.dtype)
+
+
+def moe_dropless(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
+                 w_up: jax.Array, w_down: jax.Array, top_k: int,
+                 norm_topk_prob: bool = False):
+    """Dropless top-k MoE on a flat token batch: x [N, d] → ([N, d], rows
+    per expert [E] int32)."""
+    weights, experts = route_topk(x, w_router, top_k, norm_topk_prob)
+    out = experts_dropless(x, weights, experts, w_gate, w_up, w_down)
+    return out, expert_rows(experts, w_router.shape[1])

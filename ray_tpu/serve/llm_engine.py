@@ -165,6 +165,30 @@ def _write_rows(pages, rows, page_idx, off):
     return flat.reshape(pages.shape)
 
 
+def _routes(model) -> bool:
+    """Whether the model's FFN is a routed expert layer (it then sows each
+    token's chosen experts, ``models/llama.py::LlamaMoE``)."""
+    return bool(getattr(model.config, "num_experts", 0))
+
+
+def _experts_touched(sown, active, num_experts):
+    """From what a decode step's expert layers sowed ([slots, 1, k] chosen
+    experts a layer): int32 [2], summed over layers, of the experts that
+    got at least one row of an active slot, and of the busiest expert's
+    rows.  A free lane's garbage row counts for nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    hit = busiest = 0
+    for idx in jax.tree_util.tree_leaves(sown):
+        rows = jnp.sum(jax.nn.one_hot(idx[:, 0], num_experts,
+                                      dtype=jnp.int32)
+                       * active[:, None, None], axis=(0, 1))
+        hit += jnp.sum(rows > 0)
+        busiest += jnp.max(rows)
+    return jnp.stack([hit, busiest]).astype(jnp.int32)
+
+
 def _cfg(name, given, fallback):
     if given is not _DEF and given is not None:
         return given
@@ -473,6 +497,12 @@ class LLMEngine:
         self._closed = False
         self._stats = collections.Counter()
         self._occupancy_sum = 0.0
+        # Routed models: choices a row makes in one step (layers x top-k)
+        # and experts a step can touch, for the two moe_* shares of stats().
+        self._moe_choices = c.num_layers * getattr(
+            c, "num_experts_per_tok", 0)
+        self._moe_experts = c.num_layers * getattr(c, "num_experts", 0)
+        self._moe_busiest_share_sum = 0.0
         self._t0 = time.monotonic()
         # Hot weight swap: queued (params_or_ref, version, event) applied
         # by the loop thread at the next token boundary.
@@ -710,6 +740,15 @@ class LLMEngine:
                                    if s.get("swaps", 0) else 0.0),
             "work_seconds": self._work_s,
         }
+        if self._moe_experts:
+            # Of the experts a step could touch, the share it did; and the
+            # busiest expert's share of a step's assignments (1/E when
+            # routing is even): means over the decode steps so far.
+            out["moe_experts_hit_share"] = (
+                s.get("moe_experts_hit", 0) / (steps * self._moe_experts)
+                if steps else 0.0)
+            out["moe_max_expert_share"] = (
+                self._moe_busiest_share_sum / steps if steps else 0.0)
         if self._prefix is not None:
             out["prefix_cache"] = self._prefix.stats()
         cache_size = getattr(self._decode, "_cache_size", None)
@@ -759,6 +798,7 @@ class LLMEngine:
         from ray_tpu.serve.sampling import sample_tokens_with_logprobs
 
         scope = self._jax.named_scope
+        routes = _routes(model)
 
         def step(params, k_pages, v_pages, table, lengths, tokens, active,
                  temps, top_ps, seeds):
@@ -769,10 +809,12 @@ class LLMEngine:
                     # clamped: the newest wp pages.
                     last_page = jnp.maximum(lengths - 1, 0) // ps
                     first = jnp.maximum(last_page - (window_pages - 1), 0)
-                logits, new_kvs = model.apply(
+                out = model.apply(
                     {"params": params}, tokens[:, None], lengths[:, None],
                     _paged_attend(L, k_pages, v_pages, table, lengths,
-                                  active, first))
+                                  active, first),
+                    mutable=["moe"] if routes else False)
+                (logits, new_kvs), sown = out if routes else (out, None)
             # The generated token sits at absolute position lengths + 1.
             with scope("sample"):
                 next_tok, next_logp = sample_tokens_with_logprobs(
@@ -787,6 +829,10 @@ class LLMEngine:
                 off = lengths % ps
                 k_pages = _write_rows(k_pages, newk, page_idx, off)
                 v_pages = _write_rows(v_pages, newv, page_idx, off)
+            if routes:
+                return (k_pages, v_pages, next_tok, next_logp,
+                        _experts_touched(sown, active,
+                                         model.config.num_experts))
             return k_pages, v_pages, next_tok, next_logp
 
         return step
@@ -1585,13 +1631,19 @@ class LLMEngine:
         # what the benchmark's paged_attn_roofline counts the bytes of.
         with obs.span("engine.decode.dispatch",
                       kv_tokens=int(self._lengths[self._active].sum())):
-            self._k_pages, self._v_pages, nxt, lps = self._decode(
+            self._k_pages, self._v_pages, nxt, lps, *touched = self._decode(
                 self._params, self._k_pages, self._v_pages, self._table,
                 self._lengths, self._last_tok, self._active, self._temps,
                 self._top_ps, self._seeds)
-        with obs.span("engine.decode.fetch"):  # the host waits here
+        with obs.span("engine.decode.fetch") as sp:  # the host waits here
             nxt = np.asarray(nxt)
             lps = np.asarray(lps)
+            if touched:  # a routed model: see _experts_touched
+                hit, busiest = (int(v) for v in np.asarray(touched[0]))
+                sp.set(experts_hit=hit)
+                self._stats["moe_experts_hit"] += hit
+                self._moe_busiest_share_sum += busiest / (
+                    n_active * self._moe_choices)
         self._stats["steps"] += 1
         self._stats["tokens"] += n_active
         self._occupancy_sum += n_active / self.max_slots
@@ -1617,7 +1669,7 @@ class LLMEngine:
         d_last = self._last_tok.copy()
         for j in range(k - 1):
             with obs.span("engine.decode.dispatch"):
-                self._dk_pages, self._dv_pages, nxt, _dlp = \
+                self._dk_pages, self._dv_pages, nxt, *_ = \
                     self._draft_decode(
                         self._draft_params, self._dk_pages, self._dv_pages,
                         self._table, self._lengths + j, d_last,
@@ -1633,7 +1685,7 @@ class LLMEngine:
         # acceptance the row sits beyond kv_lengths and is overwritten
         # before it is ever read.  The sampled output is discarded.
         with obs.span("engine.decode.dispatch"):
-            self._dk_pages, self._dv_pages, _, _ = self._draft_decode(
+            self._dk_pages, self._dv_pages, *_ = self._draft_decode(
                 self._draft_params, self._dk_pages, self._dv_pages,
                 self._table, self._lengths + (k - 1), d_last, self._active,
                 self._temps, self._top_ps, self._seeds)
@@ -1849,7 +1901,15 @@ def build_model(model_kind: str, config_kw: Optional[dict] = None,
                 seed: int = 0):
     """(model, params) for a serving replica.  Seeded init: every replica
     of a deployment materializes identical weights without shipping
-    params through init args."""
+    params through init args.
+
+    ``config_kw`` are the model's config fields; ``tiny`` (default True:
+    the tests' and examples' toy presets) starts from ``<Config>.tiny()``,
+    so a configuration at published widths passes ``"tiny": False`` and
+    every size.  The leaves are made one by one in the config's
+    ``param_dtype`` (``LlamaConfig``; float32 by default and for GPT-2):
+    a model served in bfloat16 passes ``"param_dtype": "bfloat16"`` and is
+    never held whole in float32."""
     import jax
     import jax.numpy as jnp
 
