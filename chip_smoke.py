@@ -50,8 +50,7 @@ LEG_CAP_S = {"train": 600.0, "serve": 400.0, "rl": 400.0, "gang": 300.0}
 
 SIZES = {
     False: {
-        # The two shapes BENCH_r05 recorded: steps are counted after one
-        # warm-up step each.
+        # Steps are counted after one warm-up step each.
         "train_fits": [{"batch": 16, "seq": 1024, "steps": 5},
                        {"batch": 4, "seq": 4096, "steps": 2}],
         "vocab": 50257,

@@ -113,23 +113,12 @@ CONFIG \
              "Use the C++ shared-memory arena for driver puts.  Off by "
              "default: the arena path predates the segment-pool + "
              "batched-notify object plane (put_many coalescing, pooled "
-             "pre-faulted segments — the measured 7-8 GB/s path) and "
-             "bypasses both; opt in only until it learns those "
-             "semantics.  The library is built from shm_store.cpp on "
-             "first use (the binary is not committed); asked for and "
-             "unbuildable, it raises.") \
-    .declare("worker_idle_ttl_s", float, 300.0,
-             "Idle pooled workers are reaped after this long.") \
-    .declare("max_workers_per_node", int, 64,
-             "Worker-process cap per node.") \
+             "pre-faulted segments) and bypasses both; opt in only "
+             "until it learns those semantics.  The library is built "
+             "from shm_store.cpp on first use (the binary is not "
+             "committed); asked for and unbuildable, it raises.") \
     .declare("health_check_period_s", float, 0.5,
              "Worker liveness poll interval in the head monitor.") \
-    .declare("spawn_failure_limit", int, 3,
-             "Consecutive worker spawn failures before queued work fails.") \
-    .declare("object_store_memory", int, 2 * 1024**3,
-             "Default per-node store capacity in bytes.") \
-    .declare("inline_object_threshold", int, 100 * 1024,
-             "Objects <= this many bytes inline in replies/directory.") \
     .declare("transfer_chunk_bytes", int, 4 * 1024 * 1024,
              "Cross-host object transfer chunk size.") \
     .declare("transfer_pipeline_depth", int, 2,
@@ -143,13 +132,6 @@ CONFIG \
              "pull path; smaller ones keep the single-stream pull.") \
     .declare("transfer_stripe_sources", int, 4,
              "Max concurrent source streams per striped pull.") \
-    .declare("transfer_coop_broadcast", bool, True,
-             "Receivers advertise partially-pulled objects as chunk-"
-             "range sources (dissemination tree for one-to-N broadcast) "
-             "and coalesce concurrent same-object pulls.") \
-    .declare("segment_pool", bool, True,
-             "Recycle shm segments across puts through size-class free "
-             "lists instead of create/unlink per object.") \
     .declare("segment_pool_bytes", int, 0,
              "Free-list byte cap of the segment pool (0 = the store's "
              "capacity).") \
@@ -163,10 +145,6 @@ CONFIG \
     .declare("parallel_copy_min_bytes", int, 8 * 1024 * 1024,
              "Buffers at least this large are copied by the parallel "
              "memcpy pool.") \
-    .declare("spill_enabled", bool, True,
-             "Spill referenced objects to disk under memory pressure.") \
-    .declare("collective_timeout_s", float, 300.0,
-             "Actor-collective rendezvous timeout.") \
     .declare("serve_control_interval_s", float, 1.0,
              "Serve controller reconcile period.") \
     .declare("serve_max_slots", int, 8,
@@ -184,12 +162,6 @@ CONFIG \
              "Per-replica host LRU budget for prefix-cache KV pages.") \
     .declare("tcp_host", str, "127.0.0.1",
              "Head TCP bind host (0.0.0.0 to accept remote nodes).") \
-    .declare("chaos_delay_us", int, 0,
-             "Chaos: max random delay injected at instrumented points.") \
-    .declare("scheduler_spread_threshold", float, 0.5,
-             "Hybrid policy: node load ratio above which tasks spread.") \
-    .declare("task_event_buffer_size", int, 10000,
-             "Max task events retained for the state API.") \
     .declare("gcs_snapshot_period_s", float, 0.0,
              "Persist GCS tables every N seconds (0 = disabled).") \
     .declare("tracing_enabled", bool, False,
@@ -245,15 +217,6 @@ CONFIG \
              "Per-attempt reply wait before a pending request frame is "
              "resent (idempotency keys + the head reply cache make the "
              "resend exactly-once).") \
-    .declare("rpc_retry_base_s", float, 0.05,
-             "Base backoff between RPC retry attempts (exponential, "
-             "jittered, capped at rpc_retry_cap_s).") \
-    .declare("rpc_retry_cap_s", float, 2.0,
-             "Backoff cap between RPC retry attempts.") \
-    .declare("rpc_acked_ops", bool, False,
-             "Route one-way notifies/submits through acked, idempotency-"
-             "keyed requests so dropped frames are retried (auto-enabled "
-             "while RAY_TPU_TESTING_NET_SCHEDULE is set).") \
     .declare("rpc_reply_cache_size", int, 1024,
              "Head-side idempotency reply-cache entries (exactly-once "
              "dedup window for retried/duplicated frames).") \
@@ -279,7 +242,7 @@ CONFIG \
              "rebuild.") \
     .declare("object_durability_min_bytes", int, 0,
              "Only puts at least this large enter the durability plane "
-             "(inline puts below inline_object_threshold are head-"
+             "(inline puts, object_store.INLINE_OBJECT_THRESHOLD, are head-"
              "resident and already survive node loss).") \
     .declare("node_lease_timeout_s", float, 15.0,
              "A remote node agent whose heartbeat is silent this long is "
@@ -290,29 +253,7 @@ CONFIG \
     .declare("node_heartbeat_period_s", float, 1.0,
              "Node-agent liveness heartbeat period (any agent message "
              "also refreshes the lease).") \
-    .declare("zero_sharding", str, "off",
-             "ZeRO-style data-parallel update sharding for the Train JAX "
-             "loops: 'off' | 'opt' (optimizer state sharded 1/N, grads "
-             "all-reduced) | 'opt+grads' (grads reduce-scattered too).  "
-             "Consumed as the default by the bench GPT-2 loop and "
-             "train.jax.compile_zero_step callers; RLlib uses "
-             "AlgorithmConfig.resources(zero_sharding=...).") \
-    .declare("quantized_collectives", str, "off",
-             "Gradient-reduction wire format for the sharded train "
-             "steps: 'off' (fp32 psum) | 'int8' (block-scaled int8, "
-             "~4x fewer bytes, loss-parity gated).") \
-    .declare("locality_scheduling", bool, True,
-             "Arg-locality-aware placement: tasks with ObjectRef args "
-             "wait for their args to exist, then prefer nodes on the "
-             "host already holding the most arg bytes (reference: "
-             "locality_aware_lease_policy.h).  'off' restores pure "
-             "utilization packing (bench baseline / regression triage).") \
     .declare("locality_min_bytes", int, 1024 * 1024,
              "Resident arg bytes a host must hold before locality "
              "outranks the hybrid utilization score (tiny args are not "
-             "worth unbalancing the cluster for).") \
-    .declare("locality_prefetch", bool, True,
-             "When a task is placed on a node whose host is missing "
-             "some of its args, start pulling them into that node's "
-             "store while the task is still queued (dispatch overlaps "
-             "the wire instead of serializing behind it).")
+             "worth unbalancing the cluster for).")

@@ -211,9 +211,6 @@ class Head:
         # missing from the chosen host are prefetched into its store
         # while the task is queued (initialized BEFORE snapshot restore —
         # restored creation specs go through _schedule below).
-        self._locality_on: bool = CONFIG.locality_scheduling
-        self._locality_prefetch: bool = (self._locality_on
-                                         and CONFIG.locality_prefetch)
         self._dep_parked: Dict[ObjectID, List[TaskSpec]] = defaultdict(list)
         self._prefetch_inflight: set = set()          # {(oid, node_id)}
         self._prefetch_recs: Dict[tuple, dict] = {}   # in-flight records
@@ -1132,7 +1129,7 @@ class Head:
 
     def req_notify_msg(self, payload, reply, caller):
         """Acked notify: a one-way message routed through the keyed
-        request path (chaos / rpc_acked_ops), so a dropped seal or
+        request path (senders use it under a net-fault schedule), so a dropped seal or
         task_done is retried by its sender and a duplicated frame is
         deduplicated by the reply cache instead of double-applying."""
         msg = payload["msg"]
@@ -1668,8 +1665,6 @@ class Head:
         error value so the task still dispatches and fails loudly.
         Returns True when the task was parked (re-scheduled from
         _notify_object when the first missing arg becomes available)."""
-        if not self._locality_on:
-            return False
         for oid in self._iter_arg_refs(spec, direct_only=True):
             entry = self.gcs.object_lookup(oid)
             if entry is not None and entry.lost:
@@ -1693,8 +1688,6 @@ class Head:
         is host-level); ``arg_bytes`` lists (oid, size, hosts, entry)
         per sized directory arg, reused for hit/miss metrics and
         prefetch targeting after placement."""
-        if not self._locality_on:
-            return None, []
         arg_bytes = []
         host_bytes: Dict[str, float] = {}
         for oid in self._iter_arg_refs(spec):
@@ -1719,7 +1712,7 @@ class Head:
         """Post-placement accounting + prefetch kick: count how many arg
         bytes the chosen host already holds, and start pulling the rest
         into the chosen node's store while the task is still queued."""
-        if not self._locality_on or not arg_bytes:
+        if not arg_bytes:
             return
         chosen_host = self.node_host.get(node_id, self.host_key)
         local = remote = 0.0
@@ -1752,10 +1745,9 @@ class Head:
         if tot_l + tot_r > 0:
             self._loc_gauge_set("sched_locality_local_bytes_fraction",
                                 tot_l / (tot_l + tot_r))
-        if missing and self._locality_prefetch:
-            for oid, size, entry in missing:
-                self._start_prefetch(spec, oid, size, entry, node_id,
-                                     chosen_host)
+        for oid, size, entry in missing:
+            self._start_prefetch(spec, oid, size, entry, node_id,
+                                 chosen_host)
 
     def _loc_counter_add(self, name: str, delta: float) -> None:
         """Bump a sched_locality_* counter; write-through to the GCS KV

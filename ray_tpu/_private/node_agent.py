@@ -261,10 +261,7 @@ class NodeAgent:
         size = int(msg.get("size") or 0)
         if size < int(CONFIG.transfer_stripe_min_bytes):
             return None
-        coop = bool(CONFIG.transfer_coop_broadcast)
         addrs = [tuple(a) for a in (msg.get("addrs") or [msg["addr"]])]
-        if not (coop or len(addrs) > 1 or msg.get("sources")):
-            return None
         from ray_tpu._private import transfer as transfer_mod
 
         chunkb = int(msg.get("chunk") or CONFIG.transfer_chunk_bytes) \
@@ -279,7 +276,7 @@ class NodeAgent:
             return None
         buf = bytearray(size)
         key = None
-        if coop and self.node_id is not None:
+        if self.node_id is not None:
             key = b"na:" + self.node_id.binary()
             self.xfer.register_partial(oid, buf, size, chunkb)
 

@@ -485,13 +485,10 @@ class SharedMemoryStore:
         # segments instead of paying shm_open + kernel page-zeroing per
         # object (see SegmentPool).  Free-list bytes are NOT charged to
         # `used` — like plasma's arena, pooled memory is store overhead.
-        self.pool: Optional[SegmentPool] = None
-        if CONFIG.segment_pool:
-            pool_cap = CONFIG.segment_pool_bytes or capacity_bytes
-            self.pool = SegmentPool(pool_cap)
-            spec = CONFIG.segment_pool_prewarm
-            if spec:
-                self.pool.prewarm(spec)
+        self.pool = SegmentPool(CONFIG.segment_pool_bytes or capacity_bytes)
+        spec = CONFIG.segment_pool_prewarm
+        if spec:
+            self.pool.prewarm(spec)
         # Monotone create counter: "no new segments appeared here" checks
         # (e.g. the cooperative-broadcast smoke asserting the owner's
         # store stayed untouched) can't be fooled by a create+delete pair
@@ -534,8 +531,7 @@ class SharedMemoryStore:
                     self.used, data_size, self.capacity)
             pool_class = None
             shm = None
-            if segment is None and self.pool is not None \
-                    and data_size >= SegmentPool.MIN_CLASS:
+            if segment is None and data_size >= SegmentPool.MIN_CLASS:
                 acq = self.pool.acquire(data_size)
                 if acq is not None:
                     shm, pool_class = acq
@@ -689,7 +685,6 @@ class SharedMemoryStore:
                     # tolerant path below rather than yank it mid-send.
                     view_clean = obj.release_view()
                 if (obj.pool_class is not None and view_clean
-                        and self.pool is not None
                         and self.pool.release(obj.shm, obj.pool_class)):
                     # Recycled: the mapped, faulted segment goes back to
                     # its size-class free list for the next put.  Pinned
@@ -841,8 +836,7 @@ class SharedMemoryStore:
             if self.arena is not None:
                 self.arena.close()
                 self.arena = None
-            if self.pool is not None:
-                self.pool.close()
+            self.pool.close()
 
     def stats(self) -> Dict[str, int]:
         with self._lock:
@@ -853,8 +847,7 @@ class SharedMemoryStore:
                 "num_pinned": len(self._pinned),
                 "segments_created_total": self.segments_created_total,
             }
-            if self.pool is not None:
-                out.update(self.pool.stats())
+            out.update(self.pool.stats())
             return out
 
 

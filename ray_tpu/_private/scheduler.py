@@ -97,12 +97,11 @@ class ClusterScheduler:
         # Set by StandardAutoscaler; empty means no autoscaler.  Instance
         # state: two heads in one process must not share capacity.
         self.external_capacity: list = []
-        # Arg-locality policy knobs (reference: the locality-aware lease
+        # Arg-locality policy knob (reference: the locality-aware lease
         # policy, locality_aware_lease_policy.h): resident arg bytes
         # outrank utilization once a host holds at least min_bytes.
         from ray_tpu._private.config import CONFIG
 
-        self.locality_enabled: bool = CONFIG.locality_scheduling
         self.locality_min_bytes: int = CONFIG.locality_min_bytes
 
     # ----- membership -----
@@ -217,8 +216,6 @@ class ClusterScheduler:
             if n is not None and n.fits(spec.resources) and n.utilization() < 0.5:
                 n.allocate(spec.resources)
                 return n.node_id
-        if not (self.locality_enabled and locality):
-            locality = None
         best, best_score = None, None
         for n in self.nodes.values():
             if not n.fits(spec.resources):

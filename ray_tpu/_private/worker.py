@@ -266,8 +266,8 @@ class ConnTransport:
     key.  A blocking request waits ``rpc_attempt_timeout`` for its reply
     and then resends the same frame (exponentially paced), bounded by the
     caller's timeout (or RAY_TPU_RPC_TIMEOUT when set) — on expiry it
-    raises :class:`RpcTimeoutError` instead of blocking forever.  Under
-    ``rpc_acked_ops`` (auto-on while a net-fault schedule is active),
+    raises :class:`RpcTimeoutError` instead of blocking forever.  While
+    a net-fault schedule is active (RAY_TPU_TESTING_NET_SCHEDULE),
     one-way ops (submits, seal/put notifies, task_done) also ride keyed
     request frames; a keeper thread resends the unacked ones, and the
     head's reply cache makes any resend/duplicate exactly-once.  On head
@@ -305,11 +305,7 @@ class ConnTransport:
     def _acked_ops(self) -> bool:
         from ray_tpu._private.chaos import net_schedule
 
-        if net_schedule() is not None:
-            return True
-        from ray_tpu._private.config import CONFIG
-
-        return bool(CONFIG.rpc_acked_ops)
+        return net_schedule() is not None
 
     def pending_rpcs(self) -> List[_Rpc]:
         with self._futures_lock:
@@ -419,7 +415,7 @@ class ConnTransport:
     def request_oneway(self, op: str, payload: dict):
         """Fire-and-forget request: one send, no reply frame, no round
         trip.  Used for acked-only ops on the submission hot path.  In
-        acked mode (chaos / rpc_acked_ops) the frame is keyed and
+        acked mode (a net-fault schedule is active) the frame is keyed and
         keeper-retried instead, so a dropped submit cannot strand its
         caller."""
         if self._acked_ops():
@@ -780,7 +776,7 @@ class CoreWorker:
         self._pulls_lock = threading.Lock()
         # Cooperative-broadcast peer server: serves ranges of objects
         # THIS process is still pulling (lazily started on first striped
-        # pull with transfer_coop_broadcast on).
+        # pull).
         self._peer_srv = None
         self._func_cache: Dict[bytes, Callable] = {}
         self._func_blobs: Dict[bytes, bytes] = {}
@@ -1647,10 +1643,7 @@ class CoreWorker:
         size = int(msg.get("size") or 0)
         if size < int(CONFIG.transfer_stripe_min_bytes):
             return False, None
-        coop = bool(CONFIG.transfer_coop_broadcast)
         addrs = [tuple(a) for a in (msg.get("addrs") or [msg["addr"]])]
-        if not (coop or len(addrs) > 1 or msg.get("sources")):
-            return False, None
         shm = membuf = None
         try:
             from multiprocessing import shared_memory
@@ -1660,7 +1653,7 @@ class CoreWorker:
             store_mod.untrack(shm)
             store_mod.track_for_exit(shm)
         except FileExistsError:
-            if msg.get("local_partial") and coop:
+            if msg.get("local_partial"):
                 # A same-host striped pull owns the canonical segment:
                 # wait for its seal instead of pulling the bytes twice
                 # (_pull_once's local_partial path).
@@ -1680,13 +1673,12 @@ class CoreWorker:
                     for a, c in (msg.get("sources") or [])] \
             or [(a, None) for a in addrs]
         peer = own_addr = None
-        if coop:
-            try:
-                peer = self._peer_server()
-                own_addr = tuple(peer.address)
-                src_list = [s for s in src_list if s[0] != own_addr]
-            except Exception:
-                peer = None
+        try:
+            peer = self._peer_server()
+            own_addr = tuple(peer.address)
+            src_list = [s for s in src_list if s[0] != own_addr]
+        except Exception:
+            peer = None
         key = self.worker_id.binary()
 
         def progress(off, ln):
@@ -1745,7 +1737,7 @@ class CoreWorker:
             meta, stats = transfer_mod.pull_striped(
                 self._transfer_client(), oid, size, src_list, view,
                 meta_hint=msg.get("meta"), chunk=chunkb, tc=tc,
-                refresh=refresh if coop else None, progress=progress)
+                refresh=refresh, progress=progress)
             if meta is None:
                 raise OSError(f"striped pull of {oid}: no source knew "
                               "the serialization meta")
@@ -1832,12 +1824,9 @@ class CoreWorker:
             # know nothing about — or long dead, leaking the name).
             shm = None
             if local_partial:
-                from ray_tpu._private.config import CONFIG
-
-                if CONFIG.transfer_coop_broadcast:
-                    got = self._await_local_seal(oid)
-                    if got is not None:
-                        return got
+                got = self._await_local_seal(oid)
+                if got is not None:
+                    return got
         except Exception:
             shm = None
         try:

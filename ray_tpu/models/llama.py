@@ -69,7 +69,7 @@ class LlamaConfig:
     def llama_1b(cls, **kw):
         """~1.1B-param GQA config (TinyLlama-1.1B shape: 22 layers,
         2048 hidden, 32 q heads over 4 kv heads, 5632 SwiGLU) — the 3D
-        pipeline x SPMD x ZeRO scale target (bench.py bench_llama_3d)."""
+        pipeline x SPMD x ZeRO scale target (tests/test_mpmd_3d.py)."""
         kw.setdefault("vocab_size", 32000)
         kw.setdefault("max_position_embeddings", 2048)
         kw.setdefault("num_layers", 22)

@@ -486,7 +486,7 @@ class WorkerSet:
         (and replacements are re-seeded from ``_weights_ref``).
 
         The N concurrent resolutions of the one ref ride the transfer
-        plane's cooperative broadcast (transfer_coop_broadcast): each
+        plane's cooperative broadcast: each
         receiver advertises its landed chunk ranges and serves them to
         the others, so the owner uploads ~one copy instead of N and
         aggregate bandwidth scales with the worker count."""

@@ -1851,8 +1851,7 @@ class NaiveLM:
     """Per-request serving baseline: batch-1, no KV cache — every token
     re-runs the full-context forward pass at a fixed padded width (one
     compile; padding is exact under the causal mask).  This is the
-    reference the engine must be token-identical to, and the denominator
-    of the continuous-batching speedup in bench.py.  ``sampling`` makes
+    reference the engine must be token-identical to.  ``sampling`` makes
     it the seeded-sampling reference too: it draws with the same
     ``fold_in(PRNGKey(seed), position)`` keys over full-context logits,
     so engine sampling must reproduce it bitwise."""

@@ -96,8 +96,8 @@ def test_compile_cache_placed_from_outside(tmp_path):
 
 def test_unknown_device_kind_has_no_peak():
     sys.path.insert(0, REPO)
-    import bench
+    from benchmark.common import peak_for
 
-    assert bench.peak_flops_for("TPU v5 lite") == 197e12
+    assert peak_for("TPU v5 lite")["bf16_flops"] == 197e12
     with pytest.raises(ValueError, match="mystery"):
-        bench.peak_flops_for("mystery")
+        peak_for("mystery")

@@ -1,6 +1,9 @@
 """Config-flag registry (reference: RAY_CONFIG x-macro table,
 src/ray/common/ray_config_def.h:17-22 — typed defaults, RAY_<name> env
 overrides, _system_config overrides)."""
+import pathlib
+import re
+
 import pytest
 
 from ray_tpu._private.config import CONFIG
@@ -17,23 +20,23 @@ def test_defaults_and_attr_access():
     # native_store defaults OFF: the arena path bypasses the segment-pool
     # + batched-notify object plane (see the registry declaration).
     assert CONFIG.native_store is False
-    assert CONFIG.max_workers_per_node == 64
+    assert CONFIG.serve_max_slots == 8
     assert CONFIG.get("transfer_chunk_bytes") == 4 * 1024 * 1024
 
 
 def test_env_override(monkeypatch):
-    monkeypatch.setenv("RAY_TPU_MAX_WORKERS_PER_NODE", "7")
-    monkeypatch.setenv("RAY_TPU_SPILL_ENABLED", "false")
+    monkeypatch.setenv("RAY_TPU_SERVE_MAX_SLOTS", "7")
+    monkeypatch.setenv("RAY_TPU_DIRECT_TRANSPORT", "false")
     CONFIG.reset()
-    assert CONFIG.max_workers_per_node == 7
-    assert CONFIG.spill_enabled is False
+    assert CONFIG.serve_max_slots == 7
+    assert CONFIG.direct_transport is False
 
 
 def test_system_config_override_beats_env(monkeypatch):
-    monkeypatch.setenv("RAY_TPU_WORKER_IDLE_TTL_S", "11")
+    monkeypatch.setenv("RAY_TPU_LEASE_IDLE_S", "11")
     CONFIG.reset()
-    CONFIG.apply_system_config({"worker_idle_ttl_s": 42.0})
-    assert CONFIG.worker_idle_ttl_s == 42.0
+    CONFIG.apply_system_config({"lease_idle_s": 42.0})
+    assert CONFIG.lease_idle_s == 42.0
 
 
 def test_undeclared_flag_rejected():
@@ -47,6 +50,21 @@ def test_dump_lists_every_flag():
     d = CONFIG.dump()
     assert "native_store" in d and "gcs_snapshot_period_s" in d
     assert len(d) >= 15
+
+
+def test_every_declared_flag_is_read():
+    """A flag that is parsed and then read by nothing accepts a setting and
+    ignores it.  A reader is ``CONFIG.<name>``, ``CONFIG.get("<name>")`` or
+    the serve engine's ``_cfg("<name>", ...)`` in a file under ``ray_tpu/``
+    other than the registry itself."""
+    root = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu"
+    registry = root / "_private" / "config.py"
+    source = "\n".join(p.read_text() for p in sorted(root.rglob("*.py"))
+                       if p != registry)
+    reader = r"""CONFIG\.%s\b|(?:CONFIG\.get|_cfg)\(\s*["']%s["']"""
+    unread = [name for name in CONFIG.dump()
+              if not re.search(reader % (name, name), source)]
+    assert not unread, f"declared in config.py and read nowhere: {unread}"
 
 
 def test_system_config_string_bool_goes_through_parser():

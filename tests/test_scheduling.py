@@ -207,17 +207,6 @@ def test_tiny_args_never_unbalance_packing():
     assert got == busy
 
 
-def test_locality_off_restores_pure_packing():
-    s = _sched()
-    s.locality_enabled = False
-    busy, holder = _node_id(), _node_id()
-    s.add_node(busy, {"CPU": 4})
-    s.add_node(holder, {"CPU": 4})
-    s.nodes[busy].allocate({"CPU": 2})
-    got = s.pick_node(_spec(), locality={holder: 1 << 30})
-    assert got == busy
-
-
 def test_soft_node_affinity_honors_locality():
     """A soft affinity to a dead node falls back to the default policy —
     WITH the locality signal, not blind packing."""

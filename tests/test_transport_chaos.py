@@ -55,7 +55,6 @@ def no_hang(seconds: float):
 def fast_rpc():
     """Short attempt timeouts so retries happen at test speed."""
     CONFIG.apply_system_config({"rpc_attempt_timeout": 0.25,
-                                "rpc_retry_base_s": 0.02,
                                 "rpc_watchdog_interval_s": 0.1})
     yield
     CONFIG.reset()
