@@ -32,9 +32,25 @@ def _has_ref_args(spec: TaskSpec) -> bool:
                for a in list(spec.args) + list(spec.kwargs.values()))
 
 
+def _leave_now():
+    """End this process without interpreter finalisation.  ``sys.exit``
+    runs atexit hooks and joins what it can while daemon threads (an
+    engine's loop thread still dispatching to the device) keep running,
+    and that can hang for good; with the head gone nobody is left to kill
+    the hung process."""
+    for stream in (sys.stdout, sys.stderr):
+        try:
+            stream.flush()
+        except Exception:
+            pass
+    os._exit(0)
+
+
 def main():
     import faulthandler
     import signal
+
+    started_by = os.getppid()
 
     # SIGUSR1 dumps all thread stacks to stderr (lands in the worker's
     # captured log) — the debugging hook for stuck workers.
@@ -134,6 +150,11 @@ def main():
             try:
                 msg = transport.conn.recv()
             except (EOFError, OSError):
+                if not head_addr:
+                    # A local worker dies with the head process — at
+                    # once, from this thread: the main thread may sit in
+                    # a task that never returns.
+                    _leave_now()
                 if not reconnect():
                     stop.set()
                     task_queue.put(None)
@@ -150,6 +171,21 @@ def main():
                 return
 
     threading.Thread(target=reader, name="rtpu-reader", daemon=True).start()
+
+    def parent_watch():
+        """A local worker leads a session of its own (raylet.spawn_worker),
+        so nothing signals it when the head process is killed; it notices
+        by its parent changing.  (Not PR_SET_PDEATHSIG: that fires when
+        the *thread* that forked ends, and workers are spawned from the
+        head's connection threads.)"""
+        while os.getppid() == started_by:
+            if stop.wait(0.25):
+                return
+        _leave_now()
+
+    if not head_addr:
+        threading.Thread(target=parent_watch, name="rtpu-parent-watch",
+                         daemon=True).start()
     register()
 
     # Tracing plane: direct-path tasks reply to their caller, bypassing

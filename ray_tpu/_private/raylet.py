@@ -9,7 +9,9 @@ with ray.cluster_utils.Cluster (python/ray/cluster_utils.py:99).
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import signal
 import subprocess
 import sys
 import threading
@@ -27,11 +29,23 @@ IDLE_WORKER_TTL_S = 300.0
 
 
 def _reap(proc) -> None:
-    """Kill a worker process and wait until it is gone.  libtpu hands a chip
-    to one process at a time, and the chip stays taken until its owner has
-    exited — not merely been signalled."""
-    proc.kill()
-    proc.wait(timeout=30.0)
+    """Kill a worker process, wait until it is gone, then kill what is left
+    of its process group.  libtpu hands a chip to one process at a time, and
+    the chip stays taken until its owner has exited — not merely been
+    signalled.  A local worker leads a session of its own (``spawn_worker``),
+    so its group is the worker and whatever it started: a resource tracker
+    or a helper process does not outlive it.  Harmless on a process that
+    has already exited."""
+    try:
+        proc.kill()
+        proc.wait(timeout=30.0)
+    finally:
+        pid = getattr(proc, "pid", None)  # a _RemoteProc has none
+        if pid is not None:
+            try:
+                os.killpg(pid, signal.SIGKILL)
+            except (ProcessLookupError, PermissionError):
+                pass  # nothing left in the group
 
 
 class WorkerHandle:
@@ -84,6 +98,9 @@ class Raylet:
         # (TPU_VISIBLE_CHIPS) or the second hangs/fails at backend init.
         self.tpu_chips_total = int(tpu_chips)
         self._free_chips = list(range(self.tpu_chips_total))
+        # Every process this raylet started and has not yet waited for,
+        # whatever became of its handle: what shutdown() answers for.
+        self._procs: list = []
 
     # ---- worker pool ----
     @staticmethod
@@ -225,10 +242,16 @@ class Raylet:
                 env=env,
                 stdout=out_f,
                 stderr=err_f,
+                # Its own session, so its own process group: _reap can
+                # take the worker's children with it.  The worker watches
+                # for its parent's death itself (default_worker.py).
+                start_new_session=True,
             )
         finally:
             out_f.close()
             err_f.close()
+        self._procs = [p for p in self._procs if p.returncode is None]
+        self._procs.append(proc)
         h = WorkerHandle(worker_id, proc, self.node_id)
         h.tpu_visible = tpu_visible
         h.tpu_chips = tuple(tpu_chips)
@@ -257,13 +280,16 @@ class Raylet:
             self.idle.remove(worker_id)
         except ValueError:
             pass
+        # This runs when the control connection closes (a killed actor, a
+        # crash, an exit that hangs), which a dying process does before it
+        # is gone and a hung one without ever going: nothing will name the
+        # handle again, so its process ends here.
+        with contextlib.suppress(Exception):
+            _reap(h.proc)  # on a fault it stays in _procs, for shutdown()
         if h.tpu_chips:
-            # Return the chip partition to the free pool — once its owner
-            # is gone.  This runs when the control connection closes (a
-            # killed actor, a crash), which a dying process does before it
-            # lets go of its devices; the next owner is spawned from the
-            # free pool and must find the chips released.
-            _reap(h.proc)
+            # Return the chip partition to the free pool — now that its
+            # owner is gone; the next owner is spawned from the free pool
+            # and must find the chips released.
             self._free_chips.extend(h.tpu_chips)
             self._free_chips.sort()
             h.tpu_chips = ()
@@ -324,30 +350,33 @@ class Raylet:
 
     def shutdown(self, keep_spilled: bool = False):
         self.dead = True
+        # Registered workers are asked to leave and given a grace period:
+        # they may hold something to flush.  One that is still starting
+        # never registered, was sent nothing and holds nothing — waiting
+        # the grace out for it is 2 s for nothing.
+        asked = set()
         for h in list(self.workers.values()):
             try:
                 if h.conn is not None:
                     h.conn.send({"type": "shutdown"})
+                    asked.add(h.proc)
             except Exception:
                 pass
         deadline = time.monotonic() + 2.0
         try:
-            for h in list(self.workers.values()):
-                if h.proc is None:
-                    continue
-                if h.conn is None:
-                    # Still starting: it never registered, so it was sent
-                    # no shutdown and holds nothing to flush — waiting out
-                    # the grace period for it is 2 s for nothing.
-                    _reap(h.proc)
-                    continue
-                try:
-                    h.proc.wait(
-                        timeout=max(0.05, deadline - time.monotonic()))
-                except subprocess.TimeoutExpired:
-                    # The chip must be free when ray_tpu.shutdown()
-                    # returns: the caller's next step may be its next owner.
-                    _reap(h.proc)
+            # Every process ever started and not yet waited for, not only
+            # the handles still in self.workers.  The chip must be free
+            # when ray_tpu.shutdown() returns (the caller's next step may
+            # be its next owner), and no process may outlive it.
+            for proc in self._procs:
+                # One process's fault does not spare the others.
+                with contextlib.suppress(Exception):
+                    if proc in asked:
+                        with contextlib.suppress(subprocess.TimeoutExpired):
+                            proc.wait(timeout=max(
+                                0.05, deadline - time.monotonic()))
+                    _reap(proc)
+            self._procs = [p for p in self._procs if p.returncode is None]
         finally:
             self.store.shutdown(keep_spilled=keep_spilled)
 

@@ -714,6 +714,8 @@ class LLMEngine:
             "page_pool": pool,
             "prefill_buckets": len(self._prefills),
             # sampling / speculative decoding
+            "greedy_steps": s.get("greedy_steps", 0),
+            "sampled_steps": s.get("sampled_steps", 0),
             "spec_steps": s.get("spec_steps", 0),
             "spec_proposed": s.get("spec_proposed", 0),
             "spec_accepted": s.get("spec_accepted", 0),
@@ -1618,6 +1620,7 @@ class LLMEngine:
         self._slot_pages[slot] = []
         self._table[slot] = 0
         self._lengths[slot] = 0
+        self._temps[slot], self._top_ps[slot] = 0.0, 1.0  # as in _retire
         self._stats["preemptions"] += 1
         req.preemptions += 1
         req.submitted = time.perf_counter()  # queued again, from now
@@ -1625,12 +1628,22 @@ class LLMEngine:
             self._active[slot] = False
             self._pending.appendleft(req)  # readmitted first, from context()
 
+    def _count_sampling_rows(self) -> int:
+        """Slots whose row asks this step's sampler for a draw (freed
+        slots are reset to greedy, so these are active ones): 0 means the
+        step's programs take the argmax and nothing else."""
+        n = int(np.count_nonzero(self._temps > 0.0))
+        self._stats["sampled_steps" if n else "greedy_steps"] += 1
+        return n
+
     def _decode_once(self):
         n_active = int(self._active.sum())
+        sampling_rows = self._count_sampling_rows()
         # kv_tokens: the cached rows this step's attention reads, which is
         # what the benchmark's paged_attn_roofline counts the bytes of.
         with obs.span("engine.decode.dispatch",
-                      kv_tokens=int(self._lengths[self._active].sum())):
+                      kv_tokens=int(self._lengths[self._active].sum()),
+                      sampling_rows=sampling_rows):
             self._k_pages, self._v_pages, nxt, lps, *touched = self._decode(
                 self._params, self._k_pages, self._v_pages, self._table,
                 self._lengths, self._last_tok, self._active, self._temps,
@@ -1665,6 +1678,7 @@ class LLMEngine:
         non-speculative stream — the draft only sets the tokens/step."""
         k = self.spec_tokens
         n_active = int(self._active.sum())
+        self._count_sampling_rows()
         proposals = np.zeros((self.max_slots, k - 1), np.int32)
         d_last = self._last_tok.copy()
         for j in range(k - 1):
@@ -1744,6 +1758,10 @@ class LLMEngine:
         self._slot_pages[slot] = []
         self._table[slot] = 0
         self._lengths[slot] = 0
+        # The sampler does what the step's rows ask for, all rows: a freed
+        # slot asks for nothing, or one retired sampled request would hold
+        # the nucleus pass open for the greedy ones after it.
+        self._temps[slot], self._top_ps[slot] = 0.0, 1.0
         self._slot_req.pop(slot, None)
         with self._lock:
             self._active[slot] = False

@@ -64,20 +64,43 @@ def top_p_mask(logits, top_p):
 
     The highest-probability token is always kept (its cumulative mass
     *before* itself is 0 < top_p), so the mask can never be empty.
-    Ties are broken by sort order, which jnp.argsort makes stable —
-    the numpy reference in tests mirrors it exactly.
+    Ties are broken by sort order, which is stable — the numpy reference
+    in tests mirrors it exactly.
     """
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    order = jnp.argsort(-probs, axis=-1)  # descending, stable
-    sorted_probs = jnp.take_along_axis(probs, order, axis=-1)
+    axis = probs.ndim - 1
+    iota = lax.broadcasted_iota(jnp.int32, probs.shape, axis)
+    # One sort gives the descending probabilities and their order; a
+    # second, keyed on that order (a permutation), carries the decision
+    # back to vocabulary order.  No gather over [.., V].
+    neg_sorted, order = lax.sort((-probs, iota), dimension=axis,
+                                 is_stable=True, num_keys=1)
+    sorted_probs = -neg_sorted
     csum = jnp.cumsum(sorted_probs, axis=-1)
     # Keep a token while the mass accumulated BEFORE it is < top_p.
     keep_sorted = (csum - sorted_probs) < top_p[..., None]
-    inv = jnp.argsort(order, axis=-1)
-    return jnp.take_along_axis(keep_sorted, inv, axis=-1)
+    _, keep = lax.sort((order, keep_sorted.astype(jnp.int32)),
+                       dimension=axis, is_stable=False, num_keys=1)
+    return keep.astype(bool)
+
+
+def _draw(row_logits, positions, seeds):
+    """One seeded categorical draw a row: ``fold_in(PRNGKey(seed), pos)``.
+    Every sampling branch draws through this, on the same ``logits /
+    temperature`` expression, so which branch a step took never shows in
+    a row's token."""
+    import jax
+    import jax.numpy as jnp
+
+    def draw(logits, pos, seed):
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
+        return jax.random.categorical(key, logits).astype(jnp.int32)
+
+    return jax.vmap(draw)(row_logits, positions, seeds)
 
 
 def sample_tokens_with_logprobs(logits, positions, temperature, top_p,
@@ -90,6 +113,15 @@ def sample_tokens_with_logprobs(logits, positions, temperature, top_p,
     Rows with ``temperature <= 0`` take the argmax instead (greedy and
     sampled requests share one compiled step).
 
+    The work follows what the step's rows ask for, decided on the device
+    from ``temperature`` and ``top_p`` (one ``lax.switch``; no host read,
+    no second program): no row samples — the argmax and nothing else;
+    some row samples and none of those truncates — the seeded draw, no
+    nucleus pass; otherwise the nucleus pass too.  A row's token is the
+    same in every branch: greedy rows take one argmax computed outside
+    the switch, a ``top_p >= 1`` row is never masked, and a truncating
+    row only ever runs the third branch.
+
     Returns ``(tokens [N] int32, logps [N] f32)``.  The logprob is the
     RAW log-softmax of the model's logits at the chosen token —
     ``log pi(token | context)`` at temperature 1 with no nucleus
@@ -99,24 +131,40 @@ def sample_tokens_with_logprobs(logits, positions, temperature, top_p,
     the definition of the captured logprob, so greedy and sampled
     requests stamp comparable values.
     """
-    import jax
     import jax.numpy as jnp
+    from jax import lax
 
     logits = logits.astype(jnp.float32)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    temp = jnp.maximum(temperature, 1e-6)[..., None]
-    scaled = logits / temp
-    masked = jnp.where(top_p_mask(scaled, top_p), scaled, -jnp.inf)
+    samples = temperature > 0.0
 
-    def draw(row_logits, pos, seed):
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
-        return jax.random.categorical(key, row_logits).astype(jnp.int32)
+    def scaled():
+        return logits / jnp.maximum(temperature, 1e-6)[..., None]
 
-    sampled = jax.vmap(draw)(masked, positions, seeds)
-    tokens = jnp.where(temperature <= 0.0, greedy, sampled)
-    logp_all = jax.nn.log_softmax(logits, axis=-1)
-    logps = jnp.take_along_axis(logp_all, tokens[..., None],
-                                axis=-1)[..., 0]
+    def argmax_only():
+        return greedy
+
+    def plain():
+        return jnp.where(samples, _draw(scaled(), positions, seeds), greedy)
+
+    def nucleus():
+        s = scaled()
+        # (csum - p) < 1.0 can cut a tail token once the float32 running
+        # sum passes 1.0; a row that asked for no truncation gets none,
+        # so it draws what ``plain`` draws.
+        keep = top_p_mask(s, top_p) | (top_p >= 1.0)[..., None]
+        masked = jnp.where(keep, s, -jnp.inf)
+        return jnp.where(samples, _draw(masked, positions, seeds), greedy)
+
+    branch = (jnp.any(samples).astype(jnp.int32)
+              + jnp.any(samples & (top_p < 1.0)).astype(jnp.int32))
+    tokens = lax.switch(branch, (argmax_only, plain, nucleus))
+    # log_softmax at one token a row, in jax.nn.log_softmax's own order
+    # of operations (same value), without the [N, V] table and its gather.
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    lse = jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1))
+    at_token = jnp.take_along_axis(logits, tokens[..., None], axis=-1)
+    logps = (at_token - m)[..., 0] - lse
     return tokens, logps
 
 

@@ -5,7 +5,9 @@ JAX tests run on a virtual 8-device CPU mesh: the env vars MUST be set before
 jax is imported anywhere in the process (fake-accelerator mode, the JAX
 equivalent of the reference's _fake_gpus)."""
 import os
+import signal
 import sys
+import threading
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -26,6 +28,32 @@ from ray_tpu._private.jax_env import ensure_compile_cache  # noqa: E402
 # recompile identical programs on every spawn/rebuild, which dominates
 # suite wall-clock.  Must run before anything imports jax.
 ensure_compile_cache()
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    """``@pytest.mark.timeout(seconds)``: a limit of the test's own (the
+    installation has no pytest-timeout).  A test that hangs fails after
+    its limit instead of running the whole suite into the driver's clock
+    (PR 29 ended in exit code 124).  SIGALRM interrupts the main thread,
+    where pytest and every xdist worker run their tests."""
+    marker = item.get_closest_marker("timeout")
+    if marker is None \
+            or threading.current_thread() is not threading.main_thread():
+        yield
+        return
+    seconds = float(marker.args[0])
+
+    def on_alarm(signum, frame):
+        raise TimeoutError(f"{item.nodeid} passed its limit of {seconds} s")
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
 
 
 @pytest.fixture
