@@ -22,7 +22,11 @@ Load-bearing ideas:
    for ALL in-flight requests, then admits pending requests into free
    slots *between* steps (one prefill each) — a new request joins the
    running batch at the next token boundary instead of waiting for the
-   batch to drain (Orca's iteration-level scheduling).
+   batch to drain (Orca's iteration-level scheduling).  The plain decode
+   loop keeps one step in flight: step n+1 is dispatched from step n's
+   tokens where they are, on the device, and the host reads and emits
+   step n, admits and grows pages while a program runs
+   (``_decode_once``).
 
 3. **Paged KV cache.**  K/V live in fixed-size pages allocated from a
    device-resident pool (`PagePool` — the SegmentPool free-list recycle
@@ -310,6 +314,17 @@ class _Request:
         self.done.set()
 
 
+@dataclasses.dataclass
+class _Step:
+    """A dispatched decode step the host has not read yet: its outputs,
+    still on the device with their copies to the host under way, and
+    the (slot, request) rows it computed a token for."""
+    tokens: Any
+    logps: Any
+    touched: Any  # a routed model's _experts_touched, else None
+    rows: List[tuple]
+
+
 class LLMEngine:
     """Replica-resident continuous-batching decode engine.
 
@@ -463,6 +478,20 @@ class LLMEngine:
         self._temps = np.zeros((self.max_slots,), np.float32)
         self._top_ps = np.ones((self.max_slots,), np.float32)
         self._seeds = np.zeros((self.max_slots,), np.int32)
+        # The plain decode loop runs one step ahead of the host
+        # (_decode_once).  _budget: the decode tokens a slot may still
+        # have dispatched (max_new_tokens less those emitted and in
+        # flight), so a slot is out of the first step it does not need;
+        # _fresh: slots admitted since the last dispatch, whose input
+        # token is the prefill's, on the host, where a continuing slot's
+        # is the last step's, on the device (_prev_tok); _resident: the
+        # device's copy of each array of slot state with the host value
+        # it holds, sent again only when the host's differs.
+        self._budget = np.zeros((self.max_slots,), np.int32)
+        self._fresh = np.zeros((self.max_slots,), bool)
+        self._prev_tok = jnp.zeros((self.max_slots,), jnp.int32)
+        self._resident: Dict[str, tuple] = {}
+        self._inflight: Optional[_Step] = None
         self._slot_pages: List[List[int]] = [[] for _ in range(self.max_slots)]
         self._slot_req: Dict[int, _Request] = {}
 
@@ -706,6 +735,11 @@ class LLMEngine:
             "completed": s.get("completed", 0),
             "preemptions": s.get("preemptions", 0),
             "steps": steps,
+            # the plain loop's step ahead: dispatched under a running
+            # step / with none in flight; rows an eos_id made useless
+            "lookahead_steps": s.get("lookahead_steps", 0),
+            "drained_steps": s.get("drained_steps", 0),
+            "late_eos_rows": s.get("late_eos_rows", 0),
             "tokens_generated": s.get("tokens", 0),
             "avg_batch_occupancy": (self._occupancy_sum / steps
                                     if steps else 0.0),
@@ -772,6 +806,9 @@ class LLMEngine:
             applied.set()  # wake blocked swappers; version stays put
         if self._stage is not None:
             self._stage.close()
+        ahead, self._inflight = self._inflight, None
+        if ahead is not None:  # nothing runs under a closed engine
+            self._jax.block_until_ready(ahead.tokens)
         err = EngineClosedError("engine closed with requests in flight")
         for req in list(self._requests.values()):
             if not req.done.is_set():
@@ -793,7 +830,16 @@ class LLMEngine:
         embed, rope at projection), so a later first page is exact
         windowed attention, no re-indexing.  The target never does this
         (it must attend to everything); the draft is a guesser, and the
-        verify step catches what the shortened horizon loses."""
+        verify step catches what the shortened horizon loses.
+
+        ``prev_tokens`` and ``fresh`` (the plain loop, which runs a step
+        ahead of the host): a row takes its input token from
+        ``prev_tokens``, the step before's output still on the device,
+        unless ``fresh`` marks it as admitted since, with its prefill's
+        token in ``tokens``.  One program decides per row.  Without them
+        every row takes ``tokens`` (the draft, whose proposals the host
+        compares).  The step also returns the lengths it leaves behind,
+        so they too stay on the device."""
         jnp = self._jnp
         L, ps, pp = model.config.num_layers, self.page_size, \
             self.pages_per_slot
@@ -803,7 +849,9 @@ class LLMEngine:
         routes = _routes(model)
 
         def step(params, k_pages, v_pages, table, lengths, tokens, active,
-                 temps, top_ps, seeds):
+                 temps, top_ps, seeds, prev_tokens=None, fresh=None):
+            if fresh is not None:
+                tokens = jnp.where(fresh, tokens, prev_tokens)
             with scope("attend"):
                 first = None
                 if window_pages is not None and window_pages < pp:
@@ -831,11 +879,12 @@ class LLMEngine:
                 off = lengths % ps
                 k_pages = _write_rows(k_pages, newk, page_idx, off)
                 v_pages = _write_rows(v_pages, newv, page_idx, off)
+            out = (k_pages, v_pages, next_tok, next_logp,
+                   lengths + active.astype(lengths.dtype))
             if routes:
-                return (k_pages, v_pages, next_tok, next_logp,
-                        _experts_touched(sown, active,
-                                         model.config.num_experts))
-            return k_pages, v_pages, next_tok, next_logp
+                out += (_experts_touched(sown, active,
+                                         model.config.num_experts),)
+            return out
 
         return step
 
@@ -1058,7 +1107,7 @@ class LLMEngine:
     def _nothing_to_do(self) -> bool:
         return not (self._closed or self._pending or self._awaiting
                     or self._ready or self._pending_swaps
-                    or self._active.any())
+                    or self._active.any() or self._inflight is not None)
 
     def _iteration(self, _tick):
         """One pass of the loop thread.  Its spans (the names are a
@@ -1068,7 +1117,9 @@ class LLMEngine:
         ``engine.swap``, ``engine.admit`` (with one ``engine.prefill``
         per local prefill), ``engine.decode.dispatch``,
         ``engine.decode.fetch``, ``engine.emit`` and
-        ``engine.metrics_flush``."""
+        ``engine.metrics_flush``.  In the plain loop the dispatch is
+        step n+1's and the fetch and emit are step n's
+        (``_decode_once``)."""
         with self._cond:
             if self._nothing_to_do():
                 with obs.span("engine.idle"):
@@ -1085,6 +1136,8 @@ class LLMEngine:
             try:
                 # token boundary: between decode steps
                 if self._pending_swaps:
+                    # A token keeps the version that computed it.
+                    self._drain()
                     with obs.span("engine.swap") as sp:
                         self._apply_swaps()
                         sp.set(version=self._weight_version)
@@ -1095,12 +1148,12 @@ class LLMEngine:
                         self._admit()
                         sp.set(admitted=self._stats["admitted"] - before)
                 self._grow()
-                if self._active.any():
-                    if self._spec:
+                if self._spec:
+                    if self._active.any():
                         self._decode_once_spec()
-                    else:
-                        self._decode_once()
-                    self._step_stamps.append(time.monotonic())
+                        self._step_stamps.append(time.monotonic())
+                elif self._active.any() or self._inflight is not None:
+                    self._decode_once()
             except BaseException as e:  # noqa: BLE001 — fail loudly per req
                 self._fail_all(e)
                 return
@@ -1185,6 +1238,7 @@ class LLMEngine:
             self._ready.clear()
             swaps = list(self._pending_swaps)
             self._pending_swaps.clear()
+        self._inflight = None
         for _, _, applied in swaps:
             applied.set()
         for req in list(self._requests.values()):
@@ -1404,6 +1458,8 @@ class LLMEngine:
             self._active[slot] = True
         self._slot_req[slot] = req
         self._append_token(slot, req, next_tok, next_logp)
+        self._budget[slot] = req.max_new_tokens - len(req.out)
+        self._fresh[slot] = True
 
     def _warm_draft(self, slot: int, ctx: List[int]):
         """Spec mode: full draft prefill of the context into the draft
@@ -1575,23 +1631,31 @@ class LLMEngine:
     # decode steps
     # ------------------------------------------------------------------
     def _grow(self):
-        """Allocate pages for every active slot whose write horizon
-        crosses a page boundary; preempt the youngest other request when
-        the pool is dry (vLLM-style recompute preemption).  The horizon
-        is one token, or ``spec_tokens`` positions in spec mode (the
-        verify step scatters the whole window)."""
+        """Allocate pages for every slot in the next step whose write
+        horizon crosses a page boundary; preempt the youngest other
+        request when the pool is dry (vLLM-style recompute preemption).
+        The horizon is one token past ``_lengths`` (which the plain loop
+        advances as it dispatches, so this looks one step further than
+        the tokens the host has seen), or ``spec_tokens`` positions in
+        spec mode (the verify step scatters the whole window)."""
         horizon = self.spec_tokens if self._spec else 1
         for slot in range(self.max_slots):
-            if not self._active[slot]:
-                continue
-            pos = int(self._lengths[slot])
-            page_needed = min(pos + horizon - 1,
-                              self.max_ctx - 1) // self.page_size
-            while page_needed >= len(self._slot_pages[slot]):
+            while self._active[slot] and self._budget[slot] > 0:
+                pos = int(self._lengths[slot])
+                page_needed = min(pos + horizon - 1,
+                                  self.max_ctx - 1) // self.page_size
+                if page_needed < len(self._slot_pages[slot]):
+                    break
                 got = self.pool.alloc(1)
                 if got is not None:
                     self._table[slot, len(self._slot_pages[slot])] = got[0]
                     self._slot_pages[slot].append(got[0])
+                    continue
+                if self._inflight is not None:
+                    # A dry pool: be in step with the device before any
+                    # request is put back, and the step in flight may
+                    # itself retire one and free its pages.
+                    self._drain()
                     continue
                 victim = self._pick_victim(exclude=slot)
                 if victim is None:
@@ -1637,38 +1701,101 @@ class LLMEngine:
         return n
 
     def _decode_once(self):
-        n_active = int(self._active.sum())
+        """One turn of the plain decode loop, which keeps one step in
+        flight: step n+1 is dispatched before step n is read, so the
+        read (a wait for copies started at dispatch), the emit and
+        whatever the loop does before it comes back here (metrics,
+        admission, page growth) run under a running program.  Nothing in
+        step n+1's inputs needs step n on the host: its tokens are on the
+        device, its lengths the host advances itself, and
+        ``max_new_tokens`` ends a request before the dispatch.  Only an
+        ``eos_id`` is seen a step late (``_collect`` drops that row)."""
+        ahead = self._dispatch_step()
+        self._drain()
+        self._inflight = ahead
+
+    def _drain(self):
+        """Be in step with the device: read and emit the step in flight,
+        if any.  Before a weight swap, and before a dry pool preempts."""
+        done, self._inflight = self._inflight, None
+        if done is not None:
+            self._collect(done)
+
+    def _on_device(self, name: str, host: np.ndarray):
+        """The device's copy of an array of slot state.  It stays there
+        between steps and is sent again only when the host's value is no
+        longer the one it holds (admission, retirement, page growth)."""
+        held = self._resident.get(name)
+        if held is None or not np.array_equal(held[1], host):
+            value = host.copy()  # the host's array changes in place
+            held = self._resident[name] = (self._jax.device_put(value),
+                                           value)
+        return held[0]
+
+    def _dispatch_step(self) -> Optional[_Step]:
+        """Launch one decode step for the slots that still need a token;
+        None when no slot does."""
+        rows = self._active & (self._budget > 0)
+        if not rows.any():
+            return None
+        in_flight = int(self._inflight is not None)
+        self._stats["lookahead_steps" if in_flight else "drained_steps"] += 1
         sampling_rows = self._count_sampling_rows()
+        dev = self._on_device
         # kv_tokens: the cached rows this step's attention reads, which is
         # what the benchmark's paged_attn_roofline counts the bytes of.
         with obs.span("engine.decode.dispatch",
-                      kv_tokens=int(self._lengths[self._active].sum()),
-                      sampling_rows=sampling_rows):
-            self._k_pages, self._v_pages, nxt, lps, *touched = self._decode(
-                self._params, self._k_pages, self._v_pages, self._table,
-                self._lengths, self._last_tok, self._active, self._temps,
-                self._top_ps, self._seeds)
+                      kv_tokens=int(self._lengths[rows].sum()),
+                      sampling_rows=sampling_rows, in_flight=in_flight):
+            (self._k_pages, self._v_pages, nxt, lps, lengths,
+             *touched) = self._decode(
+                self._params, self._k_pages, self._v_pages,
+                dev("table", self._table), dev("lengths", self._lengths),
+                dev("last_tok", self._last_tok), dev("active", rows),
+                dev("temps", self._temps), dev("top_ps", self._top_ps),
+                dev("seeds", self._seeds), self._prev_tok,
+                dev("fresh", self._fresh))
+            for out in (nxt, lps, *touched):
+                out.copy_to_host_async()
+        self._lengths[rows] += 1  # as the program does: that K/V lands
+        self._resident["lengths"] = (lengths, self._lengths.copy())
+        self._budget[rows] -= 1
+        self._fresh[:] = False
+        self._prev_tok = nxt
+        return _Step(nxt, lps, touched[0] if touched else None,
+                     [(s, self._slot_req[s])
+                      for s in np.flatnonzero(rows).tolist()])
+
+    def _collect(self, step: _Step):
+        """Wait for a dispatched step's results and emit them."""
+        n_rows = len(step.rows)
         with obs.span("engine.decode.fetch") as sp:  # the host waits here
-            nxt = np.asarray(nxt)
-            lps = np.asarray(lps)
-            if touched:  # a routed model: see _experts_touched
-                hit, busiest = (int(v) for v in np.asarray(touched[0]))
+            nxt = np.asarray(step.tokens)
+            lps = np.asarray(step.logps)
+            if step.touched is not None:  # see _experts_touched
+                hit, busiest = (int(v) for v in np.asarray(step.touched))
                 sp.set(experts_hit=hit)
                 self._stats["moe_experts_hit"] += hit
                 self._moe_busiest_share_sum += busiest / (
-                    n_active * self._moe_choices)
+                    n_rows * self._moe_choices)
         self._stats["steps"] += 1
-        self._stats["tokens"] += n_active
-        self._occupancy_sum += n_active / self.max_slots
-        with obs.span("engine.emit", tokens=n_active):
-            for slot in range(self.max_slots):
-                if not self._active[slot]:
+        self._occupancy_sum += n_rows / self.max_slots
+        emitted = 0
+        with obs.span("engine.emit") as sp:
+            for slot, req in step.rows:
+                if self._slot_req.get(slot) is not req:
+                    # Its eos_id came in the step before, read after this
+                    # one was dispatched.  The row's K/V went to a page the
+                    # slot held then; whoever gets that page writes behind
+                    # it on the device's one stream.
+                    self._stats["late_eos_rows"] += 1
                     continue
-                self._lengths[slot] += 1  # the last token's K/V just landed
-                req = self._slot_req[slot]
-                tok = int(nxt[slot])
-                self._last_tok[slot] = tok
-                self._append_token(slot, req, tok, float(lps[slot]))
+                emitted += 1
+                self._append_token(slot, req, int(nxt[slot]),
+                                   float(lps[slot]))
+            sp.set(tokens=emitted)
+        self._stats["tokens"] += emitted
+        self._step_stamps.append(time.monotonic())
 
     def _decode_once_spec(self):
         """Draft k-1 proposals per slot, verify the [slots, k] window in

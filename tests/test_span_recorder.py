@@ -83,7 +83,8 @@ def traced_engine(gpt2, tmp_path_factory):
     finally:
         eng.close()
     return {"spans": obs.drain_spans(), "rids": rids, "off": off,
-            "counts": {k: after[k] - before[k] for k in ("steps", "admitted")},
+            "counts": {k: after[k] - before[k] for k in (
+                "steps", "admitted", "lookahead_steps", "drained_steps")},
             "observed": observed, "lowered": lowered,
             "xplane": glob.glob(trace_dir + "/plugins/profile/*/*.xplane.pb")}
 
@@ -155,6 +156,18 @@ def test_counts_equal_the_engines_own(traced_engine):
                for s in named(spans, "engine.admit")) == counts["admitted"]
     assert sum(s["args"]["tokens"] for s in named(spans, "engine.emit")) \
         == 3 * 5  # six tokens a request, the first from its prefill
+
+
+def test_the_loop_runs_a_step_ahead_and_the_span_says_so(traced_engine):
+    """``in_flight`` on ``engine.decode.dispatch``: 1 where the step was
+    dispatched while the one before it was still unread (ISSUE 34)."""
+    counts = traced_engine["counts"]
+    flights = [s["args"]["in_flight"] for s in named(
+        traced_engine["spans"], "engine.decode.dispatch")]
+    assert set(flights) == {0, 1}  # every burst starts drained
+    assert sum(flights) == counts["lookahead_steps"] > 0
+    assert flights.count(0) == counts["drained_steps"] > 0
+    assert len(flights) == counts["steps"]
 
 
 def test_a_requests_three_spans_share_its_id(traced_engine):
