@@ -12,6 +12,7 @@ import importlib
 import json
 import os
 import shutil
+import sys
 import time
 
 WINDOW_SPAN = "bench:window"  # trace_reduce takes the window from it
@@ -198,13 +199,22 @@ def stop_trace(path: str) -> dict:
 
     from benchmark import trace_reduce
 
+    t0 = time.perf_counter()
     jax.profiler.stop_trace()
     files = glob.glob(os.path.join(path, "plugins", "profile", "*",
                                    "*.xplane.pb"))
     if not files:
         return {}
-    events = trace_reduce.events_from_xplane(files[0])
+    t1 = time.perf_counter()
+    events = trace_reduce.events_from_xplane(
+        files[0], host_names=SPAN_NAMES | {WINDOW_SPAN})
+    t2 = time.perf_counter()
     out = trace_reduce.reduce(
         events, span_names=sorted(SPAN_NAMES - {WINDOW_SPAN}))
+    # a traced run has 360 s in all: say where the reading's share went
+    print(f"[bench] trace: stop {t1 - t0:.1f} s, read {t2 - t1:.1f} s "
+          f"({len(events)} events, {os.path.getsize(files[0])} bytes), "
+          f"reduce {time.perf_counter() - t2:.1f} s", file=sys.stderr,
+          flush=True)
     shutil.rmtree(path, ignore_errors=True)
     return out

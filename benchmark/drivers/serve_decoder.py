@@ -7,11 +7,9 @@ binds ``config["serve"]["model_kind"]`` with ``config["serve"]["model_kw"]``,
 in which a value ``"$key"`` stands for the configuration's published
 ``key``: the program's arguments are built from the published numbers, and a
 rehearsal that shrinks a published number shrinks the model.  Everything else
-is ``serve_lm``'s: its ``Client``, its ``warm_prompts``, its server's
-counters and profiler, the same window and the same arithmetic on the
-stamps.  The body of ``run`` is that file's, copied, because a PR that adds a
-cell may edit no file the benchmark has; PERF.md section 7 lists the two for
-folding into one.
+is ``serve_lm``'s: ``deployed``, ``play`` (the client processes, the window
+and the arithmetic on the stamps) and ``record``, its ``warm_prompts``, its
+server's counters and profiler.
 
 What differs besides the binding: the comparison with the plain reference
 runs the reference as it is (it jits layer by layer, so that it fits beside
@@ -21,19 +19,18 @@ experts it chose, reports ``router_agreement``; it is made a second time
 where the traffic file has a ``reference.long`` (a short prompt reaches only
 the smallest prefill program, a few pages of context and, in a routed model,
 only the few-rows form of the expert FFN; the long one has a limit of its
-own); the record carries the engine's ``moe_*`` shares; and
-``counters.live_tokens_at_trace`` is gone (PERF.md section 7: readers take
-``kv_tokens`` from the engine's spans).  A
+own); and the record carries the engine's ``moe_*`` shares.  A
 program that cannot build the model (the parent of the PR that brought a
 configuration) raises in the replica's constructor; ``serve.run`` hands that
 error on and the run ends non-zero within seconds.
 """
 from __future__ import annotations
 
-import time
+import contextlib
 
-from benchmark import common, loadgen
-from benchmark.drivers.serve_lm import BenchLLMServer, Client, warm_prompts
+from benchmark import common
+from benchmark.drivers import serve_lm
+from benchmark.drivers.serve_lm import BenchLLMServer, warm_prompts
 
 
 def model_kw(config: dict) -> dict:
@@ -132,118 +129,34 @@ class BenchDecoderServer(BenchLLMServer):
                        program_experts(eng._model, eng._params, prompt, got))
 
 
-def run(cell, config, traffic, seed, seconds, trace, allow_cpu=False):
-    import ray_tpu
-    from ray_tpu import serve
-
+@contextlib.contextmanager
+def session(cell, config, traffic, seed, allow_cpu=False):
+    """``serve_lm.session`` for this driver's binding and comparisons."""
     s = config["serve"]
-    ray_tpu.init(**({"num_tpus": 1} if allow_cpu else {}))
-    try:
-        handle = serve.run(serve.deployment(
-            BenchDecoderServer, name="llm", num_replicas=1,
-            ray_actor_options={"num_tpus": 1,
-                               "max_concurrency": s["max_concurrency"]},
-        ).bind(s["model_kind"], model_kw(config),
-               seed=common.jax_seed(seed), allow_cpu=allow_cpu,
-               max_slots=s["max_slots"], page_size=s["page_size"],
-               max_ctx=s["max_ctx"], chunk_tokens=s["chunk_tokens"]))
-
-        def call(method, *args):
-            return ray_tpu.get(handle.method(method).remote(*args),
-                               timeout=1100.0)
-
+    with serve_lm.deployed(BenchDecoderServer,
+                           (s["model_kind"], model_kw(config)), config, seed,
+                           allow_cpu) as (handle, call):
         vocab = config["vocab_size"]
         call("warm", warm_prompts(traffic, vocab), 2)
         refs = comparisons(traffic["reference"])
         found = [call("reference_check", cell["config"], config,
                       reference_prompt(r["prompt_tokens"], seed, vocab),
                       r["new_tokens"]) for r in refs]
+        first = dict(found[0])
+        if len(found) > 1:
+            first["long"] = {**refs[1], **found[1]}
+        sound = all(within(c, r) for c, r in zip(found, refs))
 
-        preroll = float(traffic["preroll_s"])
-        schedule = loadgen.build_schedule(traffic, seed, vocab,
-                                          preroll + seconds)
-        client = Client(handle, schedule)
-        gen = loadgen.OpenLoop(schedule, client.send)
-        t0 = time.perf_counter() + 0.05
-        w0, w1 = t0 + preroll, t0 + preroll + seconds
-        gen.start(t0)
-        time.sleep(max(0.0, w0 - time.perf_counter()))
-        call("arm")
-        window_start = time.time() - (time.perf_counter() - w0)
-        traced = None
-        if trace:
-            time.sleep(max(0.0, w0 + traffic["trace_offset_s"]
-                           - time.perf_counter()))
-            call("trace_start")
-            time.sleep(traffic["trace_s"])
-            traced = call("trace_stop")
-        time.sleep(max(0.0, w1 - time.perf_counter()))
-        gen.stop()
-        compiles = call("disarm")
-        stats, stamps = call("stats"), call("step_stamps")
-        device = call("facts")
-        client.close()
-    finally:
-        serve.shutdown()
-        ray_tpu.shutdown()
+        def window(traffic, seconds, trace):
+            played = serve_lm.play(
+                handle, call, traffic, seed, vocab, seconds, trace,
+                engine_keys=("moe_experts_hit_share", "moe_max_expert_share"))
+            return serve_lm.record(played, call("facts"), first, refs[0],
+                                   sound)
 
-    gaps, ttft, in_window_tokens = [], [], 0
-    attempted = failed = 0
-    waiting = [0, 0]  # due, but no first token yet, at the window's edges
-    for i, req in enumerate(schedule):
-        if gen.sent_at[i] is None:
-            continue
-        st, due = client.stamps[i], t0 + req["due_s"]
-        attempted += 1
-        done = client.done_at[i]
-        if i in client.errors or len(st) > req["max_new_tokens"] or (
-                done is not None and len(st) != req["max_new_tokens"]):
-            failed += 1
-        gaps += [g * 1e3 for g in loadgen.gaps_in_window(st, w0, w1)]
-        in_window_tokens += sum(1 for t in st if w0 <= t <= w1)
-        if w0 <= due <= w1 and st:
-            ttft.append((st[0] - due) * 1e3)
-        for k, edge in enumerate((w0, w1)):
-            if due <= edge and (not st or st[0] > edge):
-                waiting[k] += 1
+        yield window
 
-    recent = [t for t in stamps if t >= stamps[-1] - seconds] if stamps else []
-    step_ms = [(b - a) * 1e3 for a, b in zip(recent, recent[1:])]
-    checks = {**found[0], **traffic["reference"],
-              "compiles_in_window": compiles,
-              "decode_programs": stats.get("decode_cache_size"),
-              "errors": sorted(client.errors.values())[:3]}
-    if len(found) > 1:
-        checks["long"] = {**refs[1], **found[1]}
-    correct = (all(within(c, r) for c, r in zip(found, refs))
-               and compiles == 0 and failed == 0 and bool(gaps)
-               and device["platform"] == "tpu")
-    end_to_end = {}
-    if gaps:
-        end_to_end = {"token_gap_p50_ms": loadgen.percentile(gaps, 50),
-                      "token_gap_p95_ms": loadgen.percentile(gaps, 95)}
-    return {
-        "device": device, "correct": bool(correct), "checks": checks,
-        "attempted": attempted, "failed": failed,
-        "window_start": window_start, "window_s": float(seconds),
-        "end_to_end": end_to_end,
-        "counters": {
-            "gaps": len(gaps), "tokens_in_window": in_window_tokens,
-            "tokens_per_s": in_window_tokens / seconds,
-            "requests_due_in_window": len(ttft),
-            "waiting_at_window_start": waiting[0],
-            "waiting_at_window_end": waiting[1],
-            "rate_per_s": traffic["arrivals"]["rate_per_s"],
-            "preroll_s": preroll,
-            "param_count": device.get("param_count"),
-            "engine": {k: stats[k] for k in (
-                "steps", "tokens_generated", "avg_batch_occupancy",
-                "admitted", "completed", "pending", "active", "preemptions",
-                "prefill_tokens", "prefill_buckets", "pages_in_use",
-                "moe_experts_hit_share", "moe_max_expert_share")
-                if k in stats}},
-        "samples": {"gap_ms": gaps, "ttft_ms": ttft,
-                    "lateness_ms": gen.lateness_ms(w0, w1),
-                    "engine_step_ms": step_ms},
-        "trace": traced,
-    }
+
+def run(cell, config, traffic, seed, seconds, trace, allow_cpu=False):
+    with session(cell, config, traffic, seed, allow_cpu) as window:
+        return window(traffic, seconds, trace)

@@ -3,9 +3,12 @@ replica, deployed through the normal ``serve`` API.
 
 The parent never touches jax: the replica's worker owns the chip.  Every
 request goes ``submit_stream`` -> ``next_chunk`` ... through the serve handle,
-one token to a chunk, and the client stamps each chunk as its reply arrives.
-The generator starts during set-up and plays a pre-roll at the cell's rate,
+one token to a chunk, from one of the traffic file's ``clients`` processes
+(``benchmark/clients.py``), which stamps each chunk as its reply arrives.
+The generators start during set-up and play a pre-roll at the cell's rate,
 so the measured window opens on an engine at its steady occupancy.
+``deployed``, ``play`` and ``record`` are what ``serve_decoder`` shares with
+this driver; ``session`` is what ``sweep.py`` shares with ``run``.
 
 ``BenchLLMServer`` adds to ``LLMServer`` only what a measurement needs inside
 the process that holds the chip: the device's facts, the comparison with the
@@ -14,12 +17,11 @@ engine's two dispatches.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
-import queue
-import threading
 import time
 
-from benchmark import common, loadgen
+from benchmark import clients, common, loadgen
 from ray_tpu.serve.llm_engine import LLMServer
 
 
@@ -118,58 +120,6 @@ class BenchLLMServer(LLMServer):
         return self.engine.recent_step_stamps()
 
 
-class Client:
-    """Submits through the handle and stamps every chunk.  Replies land on a
-    queue from whichever thread completes the call, with the time taken
-    there; one collector thread does the book-keeping and asks for the next
-    chunk."""
-
-    def __init__(self, handle, schedule):
-        self._handle, self.schedule = handle, schedule
-        n = len(schedule)
-        self.stamps = [[] for _ in range(n)]
-        self.done_at = [None] * n
-        self.errors = {}
-        self._rid = [None] * n
-        self._events = queue.SimpleQueue()
-        self._thread = threading.Thread(target=self._collect, name="collect",
-                                        daemon=True)
-        self._thread.start()
-
-    def _call(self, kind, i, method, *args):
-        fut = self._handle.method(method).remote(*args).future()
-        fut.add_done_callback(
-            lambda f: self._events.put((kind, i, time.perf_counter(), f)))
-
-    def send(self, i, req):
-        self._call("rid", i, "submit_stream", req["prompt"],
-                   req["max_new_tokens"])
-
-    def _collect(self):
-        while True:
-            ev = self._events.get()
-            if ev is None:
-                return
-            kind, i, t, fut = ev
-            try:
-                val = fut.result()
-            except Exception as e:  # noqa: BLE001 — counted as failed
-                self.errors[i] = repr(e)
-                continue
-            if kind == "rid":
-                self._rid[i] = val
-            elif val is None:
-                self.done_at[i] = t
-                continue
-            else:
-                self.stamps[i].extend([t] * len(val))
-            self._call("chunk", i, "next_chunk", self._rid[i], 300.0)
-
-    def close(self):
-        self._events.put(None)
-        self._thread.join()
-
-
 def warm_prompts(traffic: dict, vocab: int) -> list:
     """One prompt at every power of two between the shortest and the longest
     prompt, and at both ends: every prefill program this traffic can reach."""
@@ -179,7 +129,11 @@ def warm_prompts(traffic: dict, vocab: int) -> list:
     return [[(7 * j + n) % vocab for j in range(n)] for n in sorted(lens)]
 
 
-def run(cell, config, traffic, seed, seconds, trace, allow_cpu=False):
+@contextlib.contextmanager
+def deployed(server, bind_args, config, seed, allow_cpu):
+    """One replica of ``server`` on the chip behind the serve handle, for the
+    length of the block: the handle, and a blocking call of one of the
+    replica's methods."""
     import ray_tpu
     from ray_tpu import serve
 
@@ -187,23 +141,158 @@ def run(cell, config, traffic, seed, seconds, trace, allow_cpu=False):
     ray_tpu.init(**({"num_tpus": 1} if allow_cpu else {}))
     try:
         handle = serve.run(serve.deployment(
-            BenchLLMServer, name="llm", num_replicas=1,
+            server, name="llm", num_replicas=1,
             ray_actor_options={"num_tpus": 1,
                                "max_concurrency": s["max_concurrency"]},
-        ).bind("gpt2", {
-            "tiny": False, "vocab_size": config["vocab_size"],
-            "max_position_embeddings": config["n_positions"],
-            "num_layers": config["n_layer"], "num_heads": config["n_head"],
-            "hidden_size": config["n_embd"], "dtype": s["dtype"],
-            "scan_layers_threshold": s["scan_layers_threshold"],
-        }, seed=common.jax_seed(seed), allow_cpu=allow_cpu,
-            max_slots=s["max_slots"], page_size=s["page_size"],
-            max_ctx=s["max_ctx"], chunk_tokens=s["chunk_tokens"]))
+        ).bind(*bind_args, seed=common.jax_seed(seed), allow_cpu=allow_cpu,
+               max_slots=s["max_slots"], page_size=s["page_size"],
+               max_ctx=s["max_ctx"], chunk_tokens=s["chunk_tokens"]))
 
         def call(method, *args):
             return ray_tpu.get(handle.method(method).remote(*args),
                                timeout=1100.0)
 
+        yield handle, call
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+
+
+def play(handle, call, traffic, seed, vocab, seconds, trace,
+         engine_keys=()) -> dict:
+    """One pre-roll and one window of the traffic through ``clients``
+    processes (``benchmark/clients.py``), and the arithmetic on their stamps:
+    the record's parts that do not depend on the model.  Times are seconds
+    since ``t0``; the window is [preroll, preroll + seconds]."""
+    preroll = float(traffic["preroll_s"])
+    schedule = loadgen.build_schedule(traffic, seed, vocab, preroll + seconds)
+    fleet = clients.Fleet(handle, schedule, int(traffic["clients"]))
+    while True:  # an engine that holds nothing: so set-up leaves it
+        held = call("stats")
+        if not held["active"] + held["pending"]:
+            break
+        time.sleep(0.2)
+    t0 = time.time() + 0.25
+    w0, w1 = preroll, preroll + seconds
+
+    def sleep_until(t):
+        time.sleep(max(0.0, t0 + t - time.time()))
+
+    fleet.start(t0)
+    sleep_until(w0)
+    call("arm")
+    at_start = call("stats")
+    traced = None
+    if trace:
+        sleep_until(w0 + traffic["trace_offset_s"])
+        call("trace_start")
+        time.sleep(traffic["trace_s"])
+        traced = call("trace_stop")
+    sleep_until(w1)
+    played = fleet.stop()
+    compiles = call("disarm")
+    stats, stamps = call("stats"), call("step_stamps")
+
+    gaps, ttft, late, in_window_tokens = [], [], [], 0
+    attempted = failed = 0
+    live_s = 0.0  # stream-seconds inside the window, first token to last
+    offered = 0  # tokens of the answers due in the window
+    waiting = [0, 0]  # due, but no first token yet, at the window's edges
+    errors = []
+    for req, got in zip(schedule, played):
+        if got["sent_at"] is None:
+            continue
+        st, due = got["stamps"], req["due_s"]
+        attempted += 1
+        if got["error"] is not None:
+            errors.append(got["error"])
+        if got["error"] is not None or len(st) > req["max_new_tokens"] or (
+                got["done_at"] is not None
+                and len(st) != req["max_new_tokens"]):
+            failed += 1
+        gaps += [g * 1e3 for g in loadgen.gaps_in_window(st, w0, w1)]
+        in_window_tokens += sum(1 for t in st if w0 <= t <= w1)
+        if st:
+            live_s += max(0.0, min(st[-1], w1) - max(st[0], w0))
+        if w0 <= due <= w1:
+            offered += req["max_new_tokens"]
+            late.append((got["sent_at"] - due) * 1e3)
+            if st:
+                ttft.append((st[0] - due) * 1e3)
+        for k, edge in enumerate((w0, w1)):
+            if due <= edge and (not st or st[0] > edge):
+                waiting[k] += 1
+
+    recent = [t for t in stamps if t >= stamps[-1] - seconds] if stamps else []
+    step_ms = [(b - a) * 1e3 for a, b in zip(recent, recent[1:])]
+    steps = stats["steps"] - at_start["steps"]
+    end_to_end = {}
+    if gaps:
+        end_to_end = {"token_gap_p50_ms": loadgen.percentile(gaps, 50),
+                      "serve_tokens_per_s": in_window_tokens / seconds}
+    return {
+        "attempted": attempted, "failed": failed,
+        "window_start": t0 + w0, "window_s": float(seconds),
+        "end_to_end": end_to_end,
+        "checks": {"compiles_in_window": compiles,
+                   "decode_programs": stats.get("decode_cache_size"),
+                   "errors": sorted(errors)[:3]},
+        "counters": {
+            "gaps": len(gaps), "tokens_in_window": in_window_tokens,
+            "tokens_per_s": in_window_tokens / seconds,
+            "offered_tokens_per_s": offered / seconds,
+            "requests_due_in_window": len(late),
+            "waiting_at_window_start": waiting[0],
+            "waiting_at_window_end": waiting[1],
+            "live_streams_mean": live_s / seconds,
+            "lateness_p95_ms": loadgen.percentile(late, 95) if late else None,
+            "rate_per_s": traffic["arrivals"]["rate_per_s"],
+            "clients": int(traffic["clients"]),
+            "preroll_s": preroll,
+            # the window's own share of the slots that were decoding, from
+            # the engine's running mean at the window's two ends
+            "window_occupancy": (
+                (stats["avg_batch_occupancy"] * stats["steps"]
+                 - at_start["avg_batch_occupancy"] * at_start["steps"])
+                / steps if steps else None),
+            "engine": {k: stats[k] for k in (
+                "steps", "tokens_generated", "avg_batch_occupancy",
+                "admitted", "completed", "pending", "active", "preemptions",
+                "prefill_tokens", "prefill_buckets", "pages_in_use",
+                *engine_keys) if k in stats}},
+        "samples": {"gap_ms": gaps, "ttft_ms": ttft, "lateness_ms": late,
+                    "engine_step_ms": step_ms},
+        "trace": traced,
+    }
+
+
+def record(played, device, found, limits, sound) -> dict:
+    """``play``'s parts with the device's facts and the comparison with the
+    reference: ``correct`` where that comparison held (``sound``), nothing
+    compiled in the window, no request failed and there were gaps to read."""
+    checks = {**limits, **found, **played.pop("checks")}
+    correct = (sound and checks["compiles_in_window"] == 0
+               and played["failed"] == 0 and bool(played["samples"]["gap_ms"])
+               and device["platform"] == "tpu")
+    played["counters"]["param_count"] = device.get("param_count")
+    return {"device": device, "correct": bool(correct), "checks": checks,
+            **played}
+
+
+@contextlib.contextmanager
+def session(cell, config, traffic, seed, allow_cpu=False):
+    """The replica deployed, warmed and compared with the reference, for the
+    length of the block: yields ``window(traffic, seconds, trace)``, which
+    plays one pre-roll and window and returns the run's record (``run``
+    plays one; ``sweep.py`` several, at other rates)."""
+    model = {
+        "tiny": False, "vocab_size": config["vocab_size"],
+        "max_position_embeddings": config["n_positions"],
+        "num_layers": config["n_layer"], "num_heads": config["n_head"],
+        "hidden_size": config["n_embd"], "dtype": config["serve"]["dtype"],
+        "scan_layers_threshold": config["serve"]["scan_layers_threshold"]}
+    with deployed(BenchLLMServer, ("gpt2", model), config, seed,
+                  allow_cpu) as (handle, call):
         vocab = config["vocab_size"]
         call("warm", warm_prompts(traffic, vocab), 2)
         ref = traffic["reference"]
@@ -211,97 +300,17 @@ def run(cell, config, traffic, seed, seconds, trace, allow_cpu=False):
                      [(3 * j + seed) % vocab
                       for j in range(ref["prompt_tokens"])],
                      ref["new_tokens"])
+        sound = (check["tokens"] == ref["new_tokens"]
+                 and check["logprob_max_err"] <= ref["logprob_tolerance"]
+                 and check["argmax_margin_max"] <= ref["logprob_tolerance"])
 
-        preroll = float(traffic["preroll_s"])
-        schedule = loadgen.build_schedule(traffic, seed, vocab,
-                                          preroll + seconds)
-        client = Client(handle, schedule)
-        gen = loadgen.OpenLoop(schedule, client.send)
-        t0 = time.perf_counter() + 0.05
-        w0, w1 = t0 + preroll, t0 + preroll + seconds
-        gen.start(t0)
-        time.sleep(max(0.0, w0 - time.perf_counter()))
-        call("arm")
-        window_start = time.time() - (time.perf_counter() - w0)
-        traced, t_mid = None, None
-        if trace:
-            time.sleep(max(0.0, w0 + traffic["trace_offset_s"]
-                           - time.perf_counter()))
-            ta = time.perf_counter()
-            call("trace_start")
-            time.sleep(traffic["trace_s"])
-            traced = call("trace_stop")
-            t_mid = (ta + time.perf_counter()) / 2
-        time.sleep(max(0.0, w1 - time.perf_counter()))
-        gen.stop()
-        compiles = call("disarm")
-        stats, stamps = call("stats"), call("step_stamps")
-        device = call("facts")
-        client.close()
-    finally:
-        serve.shutdown()
-        ray_tpu.shutdown()
+        def window(traffic, seconds, trace):
+            played = play(handle, call, traffic, seed, vocab, seconds, trace)
+            return record(played, call("facts"), check, ref, sound)
 
-    gaps, ttft, in_window_tokens = [], [], 0
-    attempted = failed = live_tokens = 0
-    waiting = [0, 0]  # due, but no first token yet, at the window's edges
-    for i, req in enumerate(schedule):
-        if gen.sent_at[i] is None:
-            continue
-        st, due = client.stamps[i], t0 + req["due_s"]
-        attempted += 1
-        done = client.done_at[i]
-        if i in client.errors or len(st) > req["max_new_tokens"] or (
-                done is not None and len(st) != req["max_new_tokens"]):
-            failed += 1
-        gaps += [g * 1e3 for g in loadgen.gaps_in_window(st, w0, w1)]
-        in_window_tokens += sum(1 for t in st if w0 <= t <= w1)
-        if w0 <= due <= w1 and st:
-            ttft.append((st[0] - due) * 1e3)
-        for k, edge in enumerate((w0, w1)):
-            if due <= edge and (not st or st[0] > edge):
-                waiting[k] += 1
-        if t_mid is not None and st and st[0] <= t_mid and (
-                done is None or done > t_mid):
-            live_tokens += len(req["prompt"]) + sum(
-                1 for t in st if t <= t_mid)
+        yield window
 
-    recent = [t for t in stamps if t >= stamps[-1] - seconds] if stamps else []
-    step_ms = [(b - a) * 1e3 for a, b in zip(recent, recent[1:])]
-    checks = {**check, **traffic["reference"],
-              "compiles_in_window": compiles,
-              "decode_programs": stats.get("decode_cache_size"),
-              "errors": sorted(client.errors.values())[:3]}
-    correct = (check["tokens"] == ref["new_tokens"]
-               and check["logprob_max_err"] <= ref["logprob_tolerance"]
-               and check["argmax_margin_max"] <= ref["logprob_tolerance"]
-               and compiles == 0 and failed == 0 and bool(gaps)
-               and device["platform"] == "tpu")
-    end_to_end = {}
-    if gaps:
-        end_to_end = {"token_gap_p50_ms": loadgen.percentile(gaps, 50),
-                      "token_gap_p95_ms": loadgen.percentile(gaps, 95)}
-    return {
-        "device": device, "correct": bool(correct), "checks": checks,
-        "attempted": attempted, "failed": failed,
-        "window_start": window_start, "window_s": float(seconds),
-        "end_to_end": end_to_end,
-        "counters": {
-            "gaps": len(gaps), "tokens_in_window": in_window_tokens,
-            "tokens_per_s": in_window_tokens / seconds,
-            "requests_due_in_window": len(ttft),
-            "waiting_at_window_start": waiting[0],
-            "waiting_at_window_end": waiting[1],
-            "rate_per_s": traffic["arrivals"]["rate_per_s"],
-            "preroll_s": preroll, "live_tokens_at_trace": live_tokens,
-            "param_count": device.get("param_count"),
-            "engine": {k: stats[k] for k in (
-                "steps", "tokens_generated", "avg_batch_occupancy",
-                "admitted", "completed", "pending", "active", "preemptions",
-                "prefill_tokens", "prefill_buckets", "pages_in_use")
-                if k in stats}},
-        "samples": {"gap_ms": gaps, "ttft_ms": ttft,
-                    "lateness_ms": gen.lateness_ms(w0, w1),
-                    "engine_step_ms": step_ms},
-        "trace": traced,
-    }
+
+def run(cell, config, traffic, seed, seconds, trace, allow_cpu=False):
+    with session(cell, config, traffic, seed, allow_cpu) as window:
+        return window(traffic, seconds, trace)

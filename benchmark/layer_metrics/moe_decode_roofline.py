@@ -17,17 +17,12 @@ import statistics
 from benchmark import costs_moe, program_spans
 
 
-def _args(name, key):
-    return [a[key] for a in ((s.get("args") or {})
-                             for s in program_spans.spans(name)) if key in a]
-
-
 def read(record, ctx):
     programs = (record.get("trace") or {}).get("program_s") or {}
     runs = [s for name, v in programs.items()
             if name.endswith("llm_decode") for s in v]
-    hit = _args("engine.decode.fetch", "experts_hit")
-    kv = _args("engine.decode.dispatch", "kv_tokens")
+    hit = program_spans.arg_values("engine.decode.fetch", "experts_hit")
+    kv = program_spans.arg_values("engine.decode.dispatch", "kv_tokens")
     params = record.get("counters", {}).get("param_count")
     if not (runs and hit and kv and params) or "peak" not in ctx:
         return None

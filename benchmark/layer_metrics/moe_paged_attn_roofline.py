@@ -31,8 +31,7 @@ def read(record, ctx):
                 and name.count(",") == 2)
     steps = sum(len(v) for name, v in (t.get("program_s") or {}).items()
                 if name.endswith("llm_decode"))
-    rows = [a["kv_tokens"] for a in ((s.get("args") or {}) for s in
-            program_spans.spans("engine.decode.dispatch")) if "kv_tokens" in a]
+    rows = program_spans.arg_values("engine.decode.dispatch", "kv_tokens")
     if spent <= 0 or not steps or not rows or "peak" not in ctx:
         return None
     size = 2 if cfg["serve"]["dtype"] == "bfloat16" else 4

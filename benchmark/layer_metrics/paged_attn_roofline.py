@@ -8,13 +8,7 @@ slots' lengths): live tokens, not whole pages, so the count cannot carry the
 share past 100%.  The trace shows a Pallas kernel as a custom call with the
 target ``tpu_custom_call``; in the serve cell that is the paged kernel and
 nothing else (prefill attends through XLA).  A program without the kernel
-has neither the operation nor the span's argument: nothing to read.
-
-Not ``counters.live_tokens_at_trace``: the driver takes that at the middle
-between the profile's start and the return of its reduction, which with four
-times as many steps to reduce lies seconds after the traced window, at a
-moment when the replica, busy parsing the profile, has let requests pile up
-(5,104 tokens against some 1,200 a step inside the window: 238%)."""
+has neither the operation nor the span's argument: nothing to read."""
 from benchmark import costs, program_spans
 
 KERNEL = "tpu_custom_call"
@@ -26,9 +20,7 @@ def read(record, ctx):
                 if name.startswith(KERNEL))
     if spent <= 0 or "peak" not in ctx:
         return None
-    rows = [(s.get("args") or {}).get("kv_tokens")
-            for s in program_spans.spans("engine.decode.dispatch")]
-    rows = [r for r in rows if r is not None]
+    rows = program_spans.arg_values("engine.decode.dispatch", "kv_tokens")
     if not rows:
         return None
     served = 2 if ctx["config"]["serve"]["dtype"] == "bfloat16" else 4

@@ -1,11 +1,10 @@
 """The load generator and the arithmetic on its stamps.
 
-A traffic file fixes the shape of the load once and for all: the arrival
-instants and the multiset of (prompt length, output length) pairs are drawn
-from the file's own ``schedule_seed``, so every run offers the same amount of
-work at the same instants.  ``--seed`` decides which request gets which pair
-(a permutation) and every token id.  Different seeds are then the same load in
-another order, and the schedule is a function of (traffic file, seed) alone.
+A traffic file fixes the load once and for all: the arrival instants and the
+(prompt length, output length) pair of every request are drawn from the
+file's own ``schedule_seed``, so every run meets the same lengths at the same
+instants in the same order.  ``--seed`` draws only the token ids (and the
+weights): the schedule's shape is a function of the traffic file alone.
 
 What a traffic file may say, all of it data: ``arrivals.process`` names a file
 of ``benchmark/arrivals/`` (its other keys are that process's parameters);
@@ -71,13 +70,12 @@ def build_schedule(traffic: dict, seed: int, vocab_size: int,
                              o["min"], o["max"], n)
     prompt = np.minimum(prompt, traffic["max_total_tokens"] - out)
     keep = int(np.searchsorted(due, horizon_s))
-    order = np.random.default_rng(int(seed)).permutation(keep)
     ids = np.random.default_rng([int(seed), 1])
     schedule = [{"due_s": float(due[i]),
                  "prompt": ids.integers(0, vocab_size,
-                                        int(prompt[j])).tolist(),
-                 "max_new_tokens": int(out[j])}
-                for i, j in enumerate(order)]
+                                        int(prompt[i])).tolist(),
+                 "max_new_tokens": int(out[i])}
+                for i in range(keep)]
     share = traffic.get("shared_prefix")
     if share:
         # Request i begins with prefix i mod pool, as far as its length goes.

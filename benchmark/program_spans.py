@@ -15,6 +15,12 @@ def spans(name=None) -> list:
     return read(name) if read else []
 
 
+def arg_values(name: str, key: str) -> list:
+    """The argument ``key`` of every span of that name that carries it."""
+    return [a[key] for a in ((s.get("args") or {}) for s in spans(name))
+            if a.get(key) is not None]
+
+
 def ms(span: dict) -> float:
     return (span["end"] - span["start"]) * 1e3
 

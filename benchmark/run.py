@@ -32,8 +32,34 @@ def log(*a):
     print("[bench]", *a, file=sys.stderr, flush=True)
 
 
+def compared(checks):
+    """The numbers a driver compared and their limits, without the prose that
+    a traffic file keeps beside them."""
+    if isinstance(checks, dict):
+        return {k: compared(v) for k, v in checks.items()
+                if not isinstance(v, str)}
+    return checks
+
+
 def applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
+
+
+def load_cell(workload, overrides=None):
+    """The manifest, the cell's entry, its configuration and its traffic, by
+    the names in ``BENCHMARK.json``; ``overrides`` are the rehearsal's toy
+    sizes and the sweep's rates, never a measurement's."""
+    from benchmark import common
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    cell = next(w for w in manifest["workloads"] if w["name"] == workload)
+    entry = next(c for c in manifest["configs"] if c["name"] == cell["config"])
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        config = json.load(f)
+    traffic = common.load_traffic(cell["traffic"])
+    common.merge({"config": config, "traffic": traffic}, overrides or {})
+    return manifest, cell, config, traffic
 
 
 def measure(workload, seed, seconds, trace, allow_cpu=False, overrides=None):
@@ -42,16 +68,7 @@ def measure(workload, seed, seconds, trace, allow_cpu=False, overrides=None):
     from ray_tpu._private.jax_env import ensure_compile_cache
 
     log("compile cache:", ensure_compile_cache())
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    cell = next(w for w in manifest["workloads"] if w["name"] == workload)
-    entry = next(c for c in manifest["configs"] if c["name"] == cell["config"])
-    with open(os.path.join(ROOT, entry["file"])) as f:
-        config = json.load(f)
-    traffic = common.load_traffic(cell["traffic"])
-    # overrides: the rehearsal's toy sizes and the sweep's rates, never a
-    # measurement's
-    common.merge({"config": config, "traffic": traffic}, overrides or {})
+    manifest, cell, config, traffic = load_cell(workload, overrides)
     driver = common.load_module("drivers", traffic["driver"])
     record = driver.run(cell, config, traffic, seed, seconds, trace,
                         allow_cpu=allow_cpu)
@@ -82,6 +99,7 @@ def measure(workload, seed, seconds, trace, allow_cpu=False, overrides=None):
         result["device"]["window_s"] = t["window_s"]
         result["breakdown"] = {"device_ops": t["device_ops"],
                                "idle_gaps": t["idle_gaps"]}
+    result["checks"] = compared(record["checks"])  # last in the line
     return result, record
 
 
@@ -100,7 +118,9 @@ def main(argv=None) -> int:
     if result["device"]["platform"] != "tpu":
         log("no TPU: no result")
         return 1
-    print(json.dumps(result), flush=True)
+    print(json.dumps(result, default=str), flush=True)
+    log("correct:", result["correct"], "compared:",
+        json.dumps(result["checks"], default=str))
     return 0
 
 

