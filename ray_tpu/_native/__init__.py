@@ -27,19 +27,22 @@ def _build() -> ctypes.CDLL:
                 return ctypes.CDLL(_SO)
             except OSError:
                 pass  # built against another toolchain: rebuild below
+        # Build beside the target and rename: another process must never
+        # load a half-written library.  A name of this process's own: six
+        # test workers on a fresh checkout all build at once, and with one
+        # shared name the second rename found its file gone.
+        tmp = f"{_SO}.{os.getpid()}.tmp"
         try:
-            # Build beside the target and rename: another process must
-            # never load a half-written library.
             subprocess.run(
                 ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC,
-                 "-o", _SO + ".tmp", "-lrt"],
+                 "-o", tmp, "-lrt"],
                 check=True, capture_output=True, timeout=120)
         except (OSError, subprocess.SubprocessError) as e:
             raise RuntimeError(
                 f"native store build failed: {e}\n"
                 f"{(getattr(e, 'stderr', None) or b'').decode(errors='replace')}"
             ) from e
-        os.replace(_SO + ".tmp", _SO)
+        os.replace(tmp, _SO)
         return ctypes.CDLL(_SO)
 
 

@@ -92,6 +92,20 @@ Load-bearing ideas:
    serves RLHF rollouts yields the exact PPO-ratio denominator with no
    second forward pass (``rollout()`` / ``generate_rollouts``).
 
+9. **Per-slot recurrent state** beside the pages.  A model whose layers
+   carry a state of fixed size from token to token (a state-space mixer:
+   ``models/falcon_h1.py``) names it in ``slot_state``; the engine then
+   keeps one array a layer a kind, ``[max_slots, ...]``, on the pools'
+   device and donates them through the decode and prefill programs.  A
+   prefill writes its slot's state as it stands after the prompt's last
+   real row (the bucket's padding advances nothing), so admission is the
+   reset and recompute-preemption rebuilds it; a decode step advances
+   the rows of active slots only and stays one step ahead of the host,
+   so the state never visits the host.  Its prefill takes the head at
+   the sampled row only.  What hands a request over as pages of K/V and
+   nothing else (the prefix cache, a draft model, remote prefill, the
+   tail prefill) is refused for such a model at construction.
+
 Request/response payloads ride the object plane zero-copy: see
 ``generate_many`` (client: ``put_many`` prompts → replica:
 ``get_many`` → decode → ``put_many`` outputs → client: ``get_many``).
@@ -393,6 +407,29 @@ class LLMEngine:
         # Where the page pool lives is where the engine decodes.
         self._device = next(iter(self._k_pages.devices()))
 
+        # ---- per-slot recurrent state ----
+        # What the model says a slot holds besides pages of K/V
+        # (``slot_state``: name -> (shape, dtype), one set a layer; GPT-2
+        # and Llama say nothing): one array a layer a kind, so that each
+        # is updated in place (idea 9 of the module's docstring).
+        self._state = None
+        spec = getattr(model, "slot_state", None)
+        if spec:
+            for name, given in (("prefix_cache", prefix_cache),
+                                ("prefix_directory", prefix_directory),
+                                ("draft_model", draft_model),
+                                ("prefill", prefill)):
+                if given is not None and given is not False:
+                    raise ValueError(
+                        f"{name}= cannot serve a model with per-slot "
+                        "recurrent state: a cached prefix, a draft's "
+                        "window and a remote prefill all hand over pages "
+                        "of K/V and no state snapshot")
+            self._state = [
+                {k: jnp.zeros((self.max_slots,) + tuple(shape), dtype)
+                 for k, (shape, dtype) in spec.items()}
+                for _ in range(self.num_layers)]
+
         # ---- speculative decoding (draft + verify) ----
         self.spec_tokens = int(_cfg("serve_spec_tokens", spec_tokens,
                                     4 if draft_model is not None else 0))
@@ -499,7 +536,8 @@ class LLMEngine:
         # as jit_<name>: stable names, for whoever reads a trace.
         self._decode = jax.jit(
             _named("llm_decode", self._make_decode_step(model)),
-            donate_argnums=(1, 2))
+            # pools, and the state (``step``'s thirteenth argument)
+            donate_argnums=(1, 2) if self._state is None else (1, 2, 12))
         if self._spec:
             self._draft_decode = jax.jit(
                 _named("llm_draft_decode", self._make_decode_step(
@@ -746,6 +784,11 @@ class LLMEngine:
             "pages_in_use": pool["in_use"],
             "pages_free": pool["free"],
             "page_pool": pool,
+            # per-slot recurrent state beside the pools (0: the model
+            # carries none)
+            "state_pool_bytes": sum(
+                a.nbytes for layer in self._state or ()
+                for a in layer.values()),
             "prefill_buckets": len(self._prefills),
             # sampling / speculative decoding
             "greedy_steps": s.get("greedy_steps", 0),
@@ -849,9 +892,13 @@ class LLMEngine:
         routes = _routes(model)
 
         def step(params, k_pages, v_pages, table, lengths, tokens, active,
-                 temps, top_ps, seeds, prev_tokens=None, fresh=None):
+                 temps, top_ps, seeds, prev_tokens=None, fresh=None,
+                 state=None):
             if fresh is not None:
                 tokens = jnp.where(fresh, tokens, prev_tokens)
+            # recurrent state: advanced where ``active``
+            carried = {} if state is None else {"state": state,
+                                                "active": active}
             with scope("attend"):
                 first = None
                 if window_pages is not None and window_pages < pp:
@@ -863,8 +910,9 @@ class LLMEngine:
                     {"params": params}, tokens[:, None], lengths[:, None],
                     _paged_attend(L, k_pages, v_pages, table, lengths,
                                   active, first),
-                    mutable=["moe"] if routes else False)
-                (logits, new_kvs), sown = out if routes else (out, None)
+                    mutable=["moe"] if routes else False, **carried)
+                (logits, new_kvs, *state), sown = out if routes else (
+                    out, None)
             # The generated token sits at absolute position lengths + 1.
             with scope("sample"):
                 next_tok, next_logp = sample_tokens_with_logprobs(
@@ -884,7 +932,7 @@ class LLMEngine:
             if routes:
                 out += (_experts_touched(sown, active,
                                          model.config.num_experts),)
-            return out
+            return out + tuple(state)  # the state, last, where there is one
 
         return step
 
@@ -956,20 +1004,35 @@ class LLMEngine:
         from ray_tpu.serve.sampling import sample_tokens_with_logprobs
 
         def prefill(params, k_pages, v_pages, row, tokens, p, temp, top_p,
-                    seed):
+                    seed, slot=None, state=None):
             """tokens: [bucket] ids padded past p; row: [pp] page table
             row.  Returns updated pages + the sampled next token (the
             token at absolute position p, key fold_in(seed, p)) and its
-            behavior logprob."""
+            behavior logprob.  With ``state`` (a model that carries
+            recurrent state): also the state, ``slot``'s set to what the
+            prompt leaves behind (the padding past p advances nothing),
+            and the head taken at row p - 1 only."""
             ids = tokens[None]
             positions = jnp.arange(bucket)[None]
             with jax.named_scope("attend"):
-                logits, new_kvs = model.apply(
-                    {"params": params}, ids, positions,
-                    [_attend_uncached] * L)
+                if state is None:
+                    logits, new_kvs = model.apply(
+                        {"params": params}, ids, positions,
+                        [_attend_uncached] * L)
+                    last = logits[0, p - 1][None]
+                else:
+                    logits, new_kvs, left = model.apply(
+                        {"params": params}, ids, positions,
+                        [_attend_uncached] * L,
+                        lengths=jnp.reshape(p, (1,)),
+                        logits_at=jnp.reshape(p - 1, (1,)))
+                    last = logits[0]
+                    state = [{k: held[k].at[slot].set(
+                        new[k][0].astype(held[k].dtype)) for k in held}
+                        for held, new in zip(state, left)]
             with jax.named_scope("sample"):
                 toks, logps = sample_tokens_with_logprobs(
-                    logits[0, p - 1][None], jnp.reshape(p, (1,)),
+                    last, jnp.reshape(p, (1,)),
                     jnp.reshape(temp, (1,)), jnp.reshape(top_p, (1,)),
                     jnp.reshape(seed, (1,)))
                 next_tok, next_logp = toks[0], logps[0]
@@ -981,10 +1044,13 @@ class LLMEngine:
                 newv = jnp.stack([nk[1][0] for nk in new_kvs])
                 k_pages = _write_rows(k_pages, newk, page_idx, off)
                 v_pages = _write_rows(v_pages, newv, page_idx, off)
-            return k_pages, v_pages, next_tok, next_logp
+            out = (k_pages, v_pages, next_tok, next_logp)
+            return out if state is None else out + (state,)
 
         fn = jax.jit(_named(f"llm_prefill_{bucket}", prefill),
-                     donate_argnums=(1, 2))
+                     # pools, and the state (the eleventh argument)
+                     donate_argnums=(1, 2) if self._state is None
+                     else (1, 2, 10))
         self._prefills[key] = fn
         return fn
 
@@ -997,6 +1063,11 @@ class LLMEngine:
         one slot's row (``max_ctx`` rows, not the pool): a bucket of
         queries against one row is prefill-shaped work for
         ``cached_attention``, not the decode kernel's."""
+        if self._state is not None:
+            raise ValueError(
+                "a tail prefill cannot serve a model with per-slot "
+                "recurrent state: the cached prefix holds K/V and no "
+                "state snapshot to start the tail from")
         key = ("tail", bucket)
         fn = self._prefills.get(key)
         if fn is not None:
@@ -1415,16 +1486,24 @@ class LLMEngine:
         tail_len = p - start
         self._stats["prefill_tokens"] += tail_len
         bucket = self._bucket_for(tail_len)
+        # scanned_rows / padded_rows: what a recurrent scan ran over, the
+        # prompt's real rows and the bucket's padding that advanced nothing
+        scanned = {} if self._state is None else {
+            "scanned_rows": tail_len, "padded_rows": bucket - tail_len}
         with obs.span("engine.prefill", request_id=req.id, bucket=bucket,
-                      prompt_tokens=p, cached_tokens=start):
+                      prompt_tokens=p, cached_tokens=start, **scanned):
             toks = np.zeros((bucket,), np.int32)
             toks[:tail_len] = ctx[start:]
             if start == 0:
                 fn = self._prefill_fn(bucket)
-                self._k_pages, self._v_pages, nxt, lp = fn(
-                    self._params, self._k_pages, self._v_pages, row, toks,
-                    np.int32(p), np.float32(s.temperature),
-                    np.float32(s.top_p), np.int32(s.seed))
+                args = (self._params, self._k_pages, self._v_pages, row,
+                        toks, np.int32(p), np.float32(s.temperature),
+                        np.float32(s.top_p), np.int32(s.seed))
+                if self._state is None:
+                    self._k_pages, self._v_pages, nxt, lp = fn(*args)
+                else:
+                    (self._k_pages, self._v_pages, nxt, lp,
+                     self._state) = fn(*args, np.int32(slot), self._state)
             else:
                 fn = self._tail_prefill_fn(bucket)
                 self._k_pages, self._v_pages, nxt, lp = fn(
@@ -1744,9 +1823,13 @@ class LLMEngine:
         dev = self._on_device
         # kv_tokens: the cached rows this step's attention reads, which is
         # what the benchmark's paged_attn_roofline counts the bytes of.
+        # state_slots: the slots whose recurrent state the step advances.
+        stateful = () if self._state is None else (self._state,)
         with obs.span("engine.decode.dispatch",
                       kv_tokens=int(self._lengths[rows].sum()),
-                      sampling_rows=sampling_rows, in_flight=in_flight):
+                      sampling_rows=sampling_rows, in_flight=in_flight,
+                      **({"state_slots": int(rows.sum())} if stateful
+                         else {})):
             (self._k_pages, self._v_pages, nxt, lps, lengths,
              *touched) = self._decode(
                 self._params, self._k_pages, self._v_pages,
@@ -1754,7 +1837,9 @@ class LLMEngine:
                 dev("last_tok", self._last_tok), dev("active", rows),
                 dev("temps", self._temps), dev("top_ps", self._top_ps),
                 dev("seeds", self._seeds), self._prev_tok,
-                dev("fresh", self._fresh))
+                dev("fresh", self._fresh), *stateful)
+            if stateful:
+                self._state = touched.pop()
             for out in (nxt, lps, *touched):
                 out.copy_to_host_async()
         self._lengths[rows] += 1  # as the program does: that K/V lands
@@ -2068,6 +2153,11 @@ def build_model(model_kind: str, config_kw: Optional[dict] = None,
 
         model = Llama(LlamaConfig.tiny(**config_kw) if config_kw.pop(
             "tiny", True) else LlamaConfig(**config_kw))
+    elif model_kind == "falcon_h1":
+        from ray_tpu.models import FalconH1, FalconH1Config
+
+        model = FalconH1(FalconH1Config.tiny(**config_kw) if config_kw.pop(
+            "tiny", True) else FalconH1Config(**config_kw))
     else:
         raise ValueError(f"unknown model_kind {model_kind!r}")
     ids = jnp.zeros((1, 8), jnp.int32)
@@ -2119,6 +2209,11 @@ class LLMServer:
     cache, ``prefix_directory=`` (a ``prefix_cache.create_directory()``
     handle) shares it cluster-wide; ``prefill=`` (a PrefillWorker
     deployment handle) disaggregates prefill.
+
+    ``model_kind`` is what ``build_model`` binds: ``"gpt2"``, ``"llama"``
+    (with its layer options, OLMoE's decoder) or ``"falcon_h1"`` (a
+    Mamba-2 mixer beside attention in every block; the engine then holds
+    per-slot recurrent state and refuses the four options above).
     """
 
     def __init__(self, model_kind: str = "gpt2",
