@@ -11,6 +11,7 @@ bound and memory-bound.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -25,12 +26,14 @@ def mha_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Multi-head attention. q,k,v: [B, L, H, D] → [B, L, H, D].
 
     Dispatches to the Pallas flash kernel on real TPU backends for long
-    sequences, XLA reference otherwise.  Below 1k ctx the XLA path is
-    chosen because attention is a tiny FLOP fraction there and the d<128
-    lane padding around the custom call costs more than the [L, L]
-    materialization it avoids (speeds on the current installation: not
-    measured).  A kernel that fails to trace or compile is the failure:
-    there is no fallback to the XLA path.
+    sequences, XLA reference otherwise.  On a v5e, forward and backward
+    of [8, 1024, 16, 64] bf16 causal: the kernels 1.36 ms, this XLA path
+    4.44 (PR 39's chip runs; PERF.md section 6).  Below 1k ctx the XLA
+    path is still chosen, as it was when the kernels were 2.2 times slower
+    than now; at [16, 512, 16, 64] they read 1.17 ms against XLA's 2.06,
+    so the crossover lies lower than this rule puts it, and nobody has
+    looked for it.  A kernel that fails to trace or compile is the
+    failure: there is no fallback to the XLA path.
 
     ``mesh``: pass it when the call sits under a plain ``jit`` whose arrays
     are sharded over that mesh (``prepare_batch`` / ``prepare_train_state``).
@@ -57,8 +60,8 @@ def mha_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         score_bytes = b * h * lq * lk * q.dtype.itemsize
         use_flash = (jax.default_backend() not in ("cpu",)
                      and lq % 128 == 0 and lk % 128 == 0
-                     # Speed crossover is ~1k ctx with the tuned block
-                     # sizes; memory can force flash even earlier:
+                     # From 1k ctx on (the docstring has the times);
+                     # memory can force flash even earlier:
                      # per-layer score matrices past ~512MB OOM real
                      # training steps on a 16G chip.
                      and (lq >= 1024 or score_bytes > 512 * 1024 * 1024)
@@ -170,42 +173,131 @@ def finalize_blockwise(o, l):
 # keeps training MXU-bound instead of HBM-bound (and is why the XLA
 # reference path OOMs at batch 32 / 1024 ctx on a 16G chip while this
 # doesn't).
+#
+# The causal schedule.  The [lq, lk] square is cut into (block_q, block_k)
+# tiles.  A tile whose every column lies past its every row is skipped, one
+# whose every column lies at or before its every row runs with no mask, and
+# only the tiles the diagonal crosses pay for the iota/compare/select.  The
+# two helpers below give those bounds from either side (a q tile's range of
+# k tiles, a k tile's range of q tiles); the kernels' loops and
+# ``causal_tile_schedule`` (the number a test and PERF.md quote) both read
+# them, so what is counted is what runs.
 # ---------------------------------------------------------------------------
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref, causal,
-                      sm_scale, block_k, seq_len_k):
+_LSE_SUBLANES = 8  # minimum sublane tiling for an f32 operand
+
+
+def _clamp(x, hi):
+    """min(x, hi) for a Python int (a static schedule) or a traced value."""
+    return min(x, hi) if isinstance(x, int) else jnp.minimum(x, hi)
+
+
+def _k_tile_bounds(q_off, block_q, block_k, num_k_blocks):
+    """For the q tile starting at row ``q_off``: k tiles ``[0, full)`` run
+    unmasked, ``[full, end)`` masked, the rest are skipped.  Clamped to
+    ``num_k_blocks``: with lq > lk the tail query rows sit entirely past
+    the last K block and an unclamped bound would read past K/V.  Works on
+    Python ints and on traced values."""
+    full = _clamp(q_off // block_k, num_k_blocks)
+    end = _clamp((q_off + block_q + block_k - 1) // block_k, num_k_blocks)
+    return full, end
+
+
+def _q_tile_bounds(k_off, block_k, block_q, num_q_blocks):
+    """For the k tile starting at column ``k_off``: q tiles ``[0, first)``
+    are skipped (they lie above the diagonal), ``[first, full)`` run
+    masked, ``[full, num_q_blocks)`` unmasked."""
+    first = _clamp(k_off // block_q, num_q_blocks)
+    full = _clamp((k_off + block_k + block_q - 1) // block_q, num_q_blocks)
+    return first, full
+
+
+def causal_tile_schedule(lq: int, lk: int, block_q: int, block_k: int
+                         ) -> dict:
+    """What the causal kernels visit at these tile sizes: tiles ``visited``
+    (``masked`` of them under the iota/select), ``skipped``, ``total``, and
+    ``visited_share`` of the lq x lk square.  The mask itself needs
+    1/2 + 1/(2 * lq) of it when lq == lk."""
+    nq, nk = lq // block_q, lk // block_k
+    visited = masked = 0
+    for i in range(nq):
+        full, end = _k_tile_bounds(i * block_q, block_q, block_k, nk)
+        visited += end
+        masked += end - full
+    return {"total": nq * nk, "visited": visited, "masked": masked,
+            "skipped": nq * nk - visited,
+            "visited_share": visited / (nq * nk)}
+
+
+def _dot_nt(a, b):
+    """a @ b.T with float32 accumulation (the MXU takes the transposed
+    right operand as it is)."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _fold_scale(q, sm_scale):
+    """(q', s_scale): where the softmax scale is a power of two (d = 64:
+    1/8) it goes onto the [block, d] query tile, which is exact in every
+    binary float format, and the [block_q, block_k] score tile is left
+    alone (s_scale None); any other scale stays a float32 multiply on the
+    scores."""
+    if math.frexp(sm_scale)[0] == 0.5:
+        return q * jnp.asarray(sm_scale, q.dtype), None
+    return q, sm_scale
+
+
+def _loop(lo, hi, body, carry):
+    """``fori_loop``, unrolled in Python where both bounds are static (the
+    whole-head kernels; a non-causal call's tiles): the compiler then
+    schedules one tile's VPU work under the next one's matmuls, which a
+    rolled loop with a traced trip count forbids."""
+    if isinstance(lo, int) and isinstance(hi, int):
+        for i in range(lo, hi):
+            carry = body(i, carry)
+        return carry
+    return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _tile_rel(rows: int, cols: int, transposed: bool = False):
+    """row - col of a [rows, cols] tile at the origin (col - row of the
+    transposed tile): the tile at (q_off, k_off) shows the entries with
+    rel >= k_off - q_off."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    return c - r if transposed else r - c
+
+
+def _fwd_q_tile(q, q_off, k_ref, v_ref, causal, sm_scale, block_k):
+    """One q tile against its k tiles: (o [block_q, d] float32, normalised;
+    lse [block_q]).  ``q_off`` is a Python int in the unrolled kernel and
+    a traced value where the grid walks the q tiles."""
     import jax.experimental.pallas as pl
 
     # Inputs stay in their storage dtype (bf16 on the training path): the
     # MXU multiplies natively and accumulates f32 via
     # preferred_element_type — casting blocks to f32 up front would force
     # full-precision MXU passes and halve throughput.
-    q = q_ref[...]  # [block_q, d] (batch*heads block squeezed)
-    block_q = q.shape[0]
-    q_off = pl.program_id(1) * block_q
-
-    m = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l = jnp.zeros((block_q,), jnp.float32)
-    o = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-
-    num_k_blocks = seq_len_k // block_k
+    q, s_scale = _fold_scale(q, sm_scale)
+    block_q, d = q.shape
+    num_k_blocks = k_ref.shape[0] // block_k
+    rel = _tile_rel(block_q, block_k) if causal else None
 
     def make_body(masked):
         def body(kb, carry):
             m, l, o = carry
             k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
             v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-            s = jnp.dot(q, k_blk.T,
-                        preferred_element_type=jnp.float32) * sm_scale
+            s = _dot_nt(q, k_blk)
+            if s_scale is not None:
+                s = s * s_scale
             if masked:
-                rows = q_off + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                cols = kb * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1)
-                s = jnp.where(rows >= cols, s, NEG_INF)
+                s = jnp.where(rel >= kb * block_k - q_off, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             corr = jnp.exp(m - m_new)
+            # No second select on p: rows >= cols shows column 0 to every
+            # row and the loop starts there, so m_new is finite from a
+            # row's first tile on and exp(NEG_INF - m_new) is exactly 0.
             p = jnp.exp(s - m_new[:, None])
-            if masked:
-                p = jnp.where(s <= NEG_INF / 2, 0.0, p)
             l_new = l * corr + jnp.sum(p, axis=-1)
             o_new = o * corr[:, None] + jnp.dot(
                 p.astype(v_blk.dtype), v_blk,
@@ -213,133 +305,194 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref, causal,
             return m_new, l_new, o_new
         return body
 
-    if causal:
-        # Interior blocks (strictly below the diagonal band) skip the mask
-        # entirely — the iota/select pair is pure VPU overhead there; only
-        # the diagonal-crossing tail blocks mask.  Clamp to num_k_blocks:
-        # with lq > lk the tail query rows sit entirely past the last K
-        # block and an unclamped bound would read past K/V.
-        num_full = jnp.minimum(q_off // block_k, num_k_blocks)
-        last = (q_off + block_q + block_k - 1) // block_k
-        num_iter = jnp.minimum(last, num_k_blocks)
-        m, l, o = jax.lax.fori_loop(0, num_full, make_body(False), (m, l, o))
-        m, l, o = jax.lax.fori_loop(num_full, num_iter, make_body(True),
-                                    (m, l, o))
-    else:
-        m, l, o = jax.lax.fori_loop(0, num_k_blocks, make_body(False),
-                                    (m, l, o))
-
+    carry = (jnp.full((block_q,), NEG_INF, jnp.float32),
+             jnp.zeros((block_q,), jnp.float32),
+             jnp.zeros((block_q, d), jnp.float32))
+    num_full, num_iter = (
+        _k_tile_bounds(q_off, block_q, block_k, num_k_blocks) if causal
+        else (num_k_blocks, num_k_blocks))
+    carry = _loop(0, num_full, make_body(False), carry)
+    m, l, o = _loop(num_full, num_iter, make_body(True), carry)
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[...] = (o / l_safe[:, None]).astype(o_ref.dtype)
-    if maybe_lse_ref:  # omitted on the inference path — nothing reads it
-        # lse is broadcast across an 8-sublane dim: TPU block shapes need
-        # the last two dims (sublane, lane)-tiled; a lane dim of 1 would
-        # pad 128x in HBM, blowing up the residuals kept for the backward.
-        lse_ref = maybe_lse_ref[0]
-        lse_ref[...] = jnp.broadcast_to((m + jnp.log(l_safe))[None, :],
-                                        lse_ref.shape)
+    return o / l_safe[:, None], m + jnp.log(l_safe)
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref, causal,
+                      sm_scale, block_q, block_k, unrolled):
+    """``unrolled``: one grid step is a whole head, its q tiles walked
+    here with static offsets; otherwise the grid's second axis walks them
+    and ``q_ref`` is the tile."""
+    import jax.experimental.pallas as pl
+
+    if unrolled:
+        offs = [i * block_q for i in range(q_ref.shape[0] // block_q)]
+    else:
+        offs = [pl.program_id(1) * block_q]
+    for q_off in offs:
+        rows = pl.ds(q_off, block_q) if unrolled else slice(None)
+        o, lse = _fwd_q_tile(q_ref[rows, :], q_off, k_ref, v_ref, causal,
+                             sm_scale, block_k)
+        o_ref[rows, :] = o.astype(o_ref.dtype)
+        if maybe_lse_ref:  # omitted on the inference path: nothing reads it
+            # lse is broadcast across an 8-sublane dim: TPU block shapes
+            # need the last two dims (sublane, lane)-tiled; a lane dim of 1
+            # would pad 128x in HBM, blowing up the residuals kept for the
+            # backward.
+            maybe_lse_ref[0][:, rows] = jnp.broadcast_to(
+                lse[None, :], (_LSE_SUBLANES, block_q))
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, *, causal, sm_scale, block_k, seq_len_k):
+                     dq_ref, *, causal, sm_scale, block_k):
+    """The two-kernel backward's dq half: one q tile against its k tiles."""
     import jax.experimental.pallas as pl
 
-    q = q_ref[...]                     # [block_q, d]
+    q, s_scale = _fold_scale(q_ref[...], sm_scale)  # [block_q, d]
     do = do_ref[...]                   # [block_q, d]
     lse = lse_ref[0, :]                # [block_q] (sublane 0 of 8)
     delta = delta_ref[0, :]            # [block_q]
     block_q = q.shape[0]
     q_off = pl.program_id(1) * block_q
-    num_k_blocks = seq_len_k // block_k
+    num_k_blocks = k_ref.shape[0] // block_k
+    rel = _tile_rel(block_q, block_k) if causal else None
 
     def make_body(masked):
         def body(kb, dq):
             k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
             v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-            s = jnp.dot(q, k_blk.T,
-                        preferred_element_type=jnp.float32) * sm_scale
+            s = _dot_nt(q, k_blk)
+            if s_scale is not None:
+                s = s * s_scale
             if masked:
-                rows = q_off + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                cols = kb * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1)
-                s = jnp.where(rows >= cols, s, NEG_INF)
+                s = jnp.where(rel >= kb * block_k - q_off, s, NEG_INF)
+            # lse is finite (every row sees column 0), so a masked entry's
+            # exp(NEG_INF - lse) is exactly 0 with no second select.
             p = jnp.exp(s - lse[:, None])
-            if masked:
-                p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-            dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta[:, None]) * sm_scale).astype(k_blk.dtype)
+            dp = _dot_nt(do, v_blk)
+            ds = (p * (dp - delta[:, None])).astype(k_blk.dtype)
             return dq + jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
         return body
 
     dq = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-    if causal:
-        # Same lq > lk clamp as the forward (see _flash_fwd_kernel).
-        num_full = jnp.minimum(q_off // block_k, num_k_blocks)
-        last = (q_off + block_q + block_k - 1) // block_k
-        num_iter = jnp.minimum(last, num_k_blocks)
-        dq = jax.lax.fori_loop(0, num_full, make_body(False), dq)
-        dq = jax.lax.fori_loop(num_full, num_iter, make_body(True), dq)
-    else:
-        dq = jax.lax.fori_loop(0, num_k_blocks, make_body(False), dq)
-    dq_ref[...] = dq.astype(dq_ref.dtype)
+    num_full, num_iter = (
+        _k_tile_bounds(q_off, block_q, block_k, num_k_blocks) if causal
+        else (num_k_blocks, num_k_blocks))
+    dq = _loop(0, num_full, make_body(False), dq)
+    dq = _loop(num_full, num_iter, make_body(True), dq)
+    # ds = p * (dp - delta) * scale: the scale goes once onto the
+    # [block_q, d] result, not onto every [block_q, block_k] tile.
+    dq_ref[...] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
-def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, *, causal, sm_scale, block_q,
-                      seq_len_q):
+def _bwd_k_tile(k_blk, v_blk, k_off, q_ref, do_ref, lse_ref, delta_ref,
+                dq, causal, sm_scale, block_q):
+    """One k tile against the q tiles at or under its diagonal: (dk, dv)
+    float32, unscaled.  The tiles are [block_k, block_q], the transposed
+    orientation: lse and delta then broadcast along sublanes as they are
+    stored, and dk, dv need no transpose.  With ``dq`` (the unrolled
+    kernel's list of float32 [block_q, d] sums, one a q tile) every tile
+    also adds its dq there, so s, p, dp and ds are computed once a tile: 5
+    matmuls where the two-kernel form runs 7."""
     import jax.experimental.pallas as pl
 
-    k_blk = k_ref[...]                 # [block_k, d]
-    v_blk = v_ref[...]                 # [block_k, d]
     block_k = k_blk.shape[0]
-    k_off = pl.program_id(1) * block_k
-    num_q_blocks = seq_len_q // block_q
+    num_q_blocks = q_ref.shape[0] // block_q
+    k_s, s_scale = _fold_scale(k_blk, sm_scale)
+    rel = _tile_rel(block_k, block_q, transposed=True) if causal else None
 
     def make_body(masked):
         def body(qb, carry):
             dk, dv = carry
-            q_blk = q_ref[pl.ds(qb * block_q, block_q), :]
-            do_blk = do_ref[pl.ds(qb * block_q, block_q), :]
-            lse = lse_ref[0, pl.ds(qb * block_q, block_q)]
-            delta = delta_ref[0, pl.ds(qb * block_q, block_q)]
-            s = jnp.dot(q_blk, k_blk.T,
-                        preferred_element_type=jnp.float32) * sm_scale
+            rows = pl.ds(qb * block_q, block_q)
+            q_blk = q_ref[rows, :]
+            do_blk = do_ref[rows, :]
+            st = _dot_nt(k_s, q_blk)       # [block_k, block_q]
+            if s_scale is not None:
+                st = st * s_scale
             if masked:
-                rows = qb * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 0)
-                cols = k_off + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                s = jnp.where(rows >= cols, s, NEG_INF)
-            p = jnp.exp(s - lse[:, None])
-            if masked:
-                p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-            dv = dv + jnp.dot(p.astype(do_blk.dtype).T, do_blk,
+                st = jnp.where(rel >= k_off - qb * block_q, st, NEG_INF)
+            pt = jnp.exp(st - lse_ref[0:1, rows])
+            dv = dv + jnp.dot(pt.astype(do_blk.dtype), do_blk,
                               preferred_element_type=jnp.float32)
-            dp = jnp.dot(do_blk, v_blk.T, preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta[:, None]) * sm_scale).astype(q_blk.dtype)
-            dk = dk + jnp.dot(ds.T, q_blk, preferred_element_type=jnp.float32)
+            dpt = _dot_nt(v_blk, do_blk)
+            dst = (pt * (dpt - delta_ref[0:1, rows])).astype(q_blk.dtype)
+            dk = dk + jnp.dot(dst, q_blk, preferred_element_type=jnp.float32)
+            if dq is not None:
+                dq[qb] = dq[qb] + jax.lax.dot_general(
+                    dst, k_blk, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
             return dk, dv
         return body
 
-    dk = jnp.zeros(k_blk.shape, jnp.float32)
-    dv = jnp.zeros(v_blk.shape, jnp.float32)
-    if causal:
-        # Only q blocks at or past this k block's diagonal contribute;
-        # blocks fully below the diagonal band skip the mask.
-        first = k_off // block_q
-        first_full = (k_off + block_k + block_q - 1) // block_q
-        first_full = jnp.minimum(first_full, num_q_blocks)
-        dk, dv = jax.lax.fori_loop(first, first_full, make_body(True),
-                                   (dk, dv))
-        dk, dv = jax.lax.fori_loop(first_full, num_q_blocks,
-                                   make_body(False), (dk, dv))
-    else:
-        dk, dv = jax.lax.fori_loop(0, num_q_blocks, make_body(False),
-                                   (dk, dv))
-    dk_ref[...] = dk.astype(dk_ref.dtype)
+    carry = (jnp.zeros(k_blk.shape, jnp.float32),
+             jnp.zeros(v_blk.shape, jnp.float32))
+    first, first_full = (
+        _q_tile_bounds(k_off, block_k, block_q, num_q_blocks) if causal
+        else (0, 0))
+    carry = _loop(first, first_full, make_body(True), carry)
+    return _loop(first_full, num_q_blocks, make_body(False), carry)
+
+
+def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                      dk_ref, dv_ref, *, causal, sm_scale, block_q):
+    """The two-kernel backward's dk/dv half: the grid walks the k tiles."""
+    import jax.experimental.pallas as pl
+
+    k_off = pl.program_id(1) * k_ref.shape[0]
+    dk, dv = _bwd_k_tile(k_ref[...], v_ref[...], k_off, q_ref, do_ref,
+                         lse_ref, delta_ref, None, causal, sm_scale, block_q)
+    # ds = p * (dp - delta) * scale: the scale goes once onto the
+    # [block_k, d] result, not onto every tile of ds.
+    dk_ref[...] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
-_LSE_SUBLANES = 8  # minimum sublane tiling for an f32 operand
+def _flash_bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                      dk_ref, dv_ref, dq_ref, *, causal, sm_scale, block_q,
+                      block_k):
+    """The fused backward: one grid step is a whole head, its k tiles
+    walked here with static offsets, dq summed a q tile beside dk and dv."""
+    import jax.experimental.pallas as pl
+
+    d = q_ref.shape[1]
+    dq = [jnp.zeros((block_q, d), jnp.float32)
+          for _ in range(q_ref.shape[0] // block_q)]
+    for k_off in range(0, k_ref.shape[0], block_k):
+        cols = pl.ds(k_off, block_k)
+        dk, dv = _bwd_k_tile(k_ref[cols, :], v_ref[cols, :], k_off, q_ref,
+                             do_ref, lse_ref, delta_ref, dq, causal,
+                             sm_scale, block_q)
+        dk_ref[cols, :] = (dk * sm_scale).astype(dk_ref.dtype)
+        dv_ref[cols, :] = dv.astype(dv_ref.dtype)
+    for i, dq_i in enumerate(dq):
+        dq_ref[pl.ds(i * block_q, block_q), :] = (dq_i * sm_scale).astype(
+            dq_ref.dtype)
+
+
+# What a kernel may take in VMEM without asking for more (Mosaic's scoped
+# default on v5e), and how many tile bodies the unrolled kernels may hold:
+# the compile time grows with them (10 tiles 1-3 s a kernel, 136 tiles 14 s,
+# compiled here for a described v5e) and is part of a train cell's set-up.
+_VMEM_BUDGET = 16 * 1024 * 1024
+_MAX_UNROLLED_TILES = 16
+
+
+def _whole_head_fits(lq: int, lk: int, d: int, itemsize: int, block_q: int,
+                     block_k: int, causal: bool) -> bool:
+    """Whether the kernels take a whole head a grid step, their tile loops
+    unrolled (the forward) and dq accumulated beside dk and dv (the fused
+    backward).  The backward's residency decides: Q, K, V, dO in and dQ,
+    dK, dV out whole, the lane dimension padded to 128 and every pipelined
+    block held twice, lse and delta on 8 sublanes; half the budget is left
+    to the [block_k, block_q] float32 tiles (s, p, dp, ds and their casts),
+    the float32 sums and the compiler's own scratch."""
+    tiles = (causal_tile_schedule(lq, lk, block_q, block_k)["visited"]
+             if causal else (lq // block_q) * (lk // block_k))
+    lanes = -(-d // 128) * 128
+    blocks = 2 * (3 * lq + 4 * lk) * lanes * itemsize
+    rows = 2 * 2 * _LSE_SUBLANES * lq * 4
+    return (tiles <= _MAX_UNROLLED_TILES
+            and blocks + rows <= _VMEM_BUDGET // 2)
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
@@ -354,24 +507,33 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
 
+    unrolled = _whole_head_fits(lq, lk, d, q.dtype.itemsize, block_q,
+                                block_k, causal)
     kernel = functools.partial(_flash_fwd_kernel, causal=causal,
-                               sm_scale=scale, block_k=block_k,
-                               seq_len_k=lk)
-    out_specs = [pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0))]
+                               sm_scale=scale, block_q=block_q,
+                               block_k=block_k, unrolled=unrolled)
+    # The grid is (heads,) with whole blocks when unrolled, (heads, q
+    # tiles) otherwise; ``whole``'s index map takes either.
+    whole = lambda *block: pl.BlockSpec((None,) + block,
+                                        lambda i, *_: (i, 0, 0))
+    if unrolled:
+        grid, q_spec = (b * h,), whole(lq, d)
+        lse_spec = whole(_LSE_SUBLANES, lq)
+    else:
+        grid = (b * h, lq // block_q)
+        q_spec = pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0))
+        lse_spec = pl.BlockSpec((None, _LSE_SUBLANES, block_q),
+                                lambda i, j: (i, 0, j))
+    out_specs = [q_spec]
     out_shape = [jax.ShapeDtypeStruct((b * h, lq, d), q.dtype)]
     if with_lse:
-        out_specs.append(pl.BlockSpec((None, _LSE_SUBLANES, block_q),
-                                      lambda i, j: (i, 0, j)))
+        out_specs.append(lse_spec)
         out_shape.append(jax.ShapeDtypeStruct(
             (b * h, _LSE_SUBLANES, lq), jnp.float32))
     res = pl.pallas_call(
         kernel,
-        grid=(b * h, lq // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, lk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, lk, d), lambda i, j: (i, 0, 0)),
-        ],
+        grid=grid,
+        in_specs=[q_spec, whole(lk, d), whole(lk, d)],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
@@ -386,7 +548,10 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
 
 
 def _flash_bwd(q, k, v, out, lse, do, causal, sm_scale, block_q, block_k,
-               interpret):
+               fused, interpret):
+    """``fused``: ``flash_bwd``, one kernel, a whole head a grid step;
+    otherwise ``flash_dkv`` and ``flash_dq``, each walking its tiles on
+    the grid with K, V (or Q, dO) whole beside them."""
     import jax.experimental.pallas as pl
 
     bh, lq, d = q.shape
@@ -400,49 +565,50 @@ def _flash_bwd(q, k, v, out, lse, do, causal, sm_scale, block_q, block_k,
     lse8 = jnp.broadcast_to(lse[:, None, :], (bh, _LSE_SUBLANES, lq))
     delta8 = jnp.broadcast_to(delta2[:, None, :], (bh, _LSE_SUBLANES, lq))
 
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, causal=causal, sm_scale=scale,
-                          block_k=block_k, seq_len_k=lk),
-        grid=(bh, lq // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, lk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, lk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, _LSE_SUBLANES, block_q),
-                         lambda i, j: (i, 0, j)),
-            pl.BlockSpec((None, _LSE_SUBLANES, block_q),
-                         lambda i, j: (i, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
-        interpret=interpret,
-        name="flash_dq",
-    )(q, k, v, do, lse8, delta8)
+    whole = lambda *block: pl.BlockSpec((None,) + block,
+                                        lambda i, *_: (i, 0, 0))
+    q_whole, k_whole = whole(lq, d), whole(lk, d)
+    row_whole = whole(_LSE_SUBLANES, lq)
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    if fused:
+        dk, dv, dq = pl.pallas_call(
+            functools.partial(_flash_bwd_kernel, causal=causal,
+                              sm_scale=scale, block_q=block_q,
+                              block_k=block_k),
+            grid=(bh,),
+            in_specs=[k_whole, k_whole, q_whole, q_whole, row_whole,
+                      row_whole],
+            out_specs=[k_whole, k_whole, q_whole],
+            out_shape=[like(k), like(v), like(q)],
+            interpret=interpret,
+            name="flash_bwd",
+        )(k, v, q, do, lse8, delta8)
+        return dq, dk, dv
 
+    k_tile = pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, causal=causal, sm_scale=scale,
-                          block_q=block_q, seq_len_q=lq),
+                          block_q=block_q),
         grid=(bh, lk // block_k),
-        in_specs=[
-            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, lq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, lq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, _LSE_SUBLANES, lq), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, _LSE_SUBLANES, lq), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, lk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, lk, d), v.dtype),
-        ],
+        in_specs=[k_tile, k_tile, q_whole, q_whole, row_whole, row_whole],
+        out_specs=[k_tile, k_tile],
+        out_shape=[like(k), like(v)],
         interpret=interpret,
         name="flash_dkv",
     )(k, v, q, do, lse8, delta8)
+    q_tile = pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0))
+    row_tile = pl.BlockSpec((None, _LSE_SUBLANES, block_q),
+                            lambda i, j: (i, 0, j))
+    dq = pl.pallas_call(
+        functools.partial(_flash_dq_kernel, causal=causal, sm_scale=scale,
+                          block_k=block_k),
+        grid=(bh, lq // block_q),
+        in_specs=[q_tile, k_whole, k_whole, q_tile, row_tile, row_tile],
+        out_specs=q_tile,
+        out_shape=like(q),
+        interpret=interpret,
+        name="flash_dq",
+    )(q, k, v, do, lse8, delta8)
     return dq, dk, dv
 
 
@@ -471,9 +637,11 @@ def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret,
     h = bh // g.shape[0]
     b = g.shape[0]
     gf = g.transpose(0, 2, 1, 3).reshape(bh, lq, d)
-    dq, dk, dv = _flash_bwd(qf, kf, vf, out, lse, gf, causal, sm_scale,
-                            block_q, block_k, interpret)
     lk = kf.shape[1]
+    fused = _whole_head_fits(lq, lk, d, qf.dtype.itemsize, block_q, block_k,
+                             causal)
+    dq, dk, dv = _flash_bwd(qf, kf, vf, out, lse, gf, causal, sm_scale,
+                            block_q, block_k, fused, interpret)
 
     def unfold(x, l):
         return x.reshape(b, h, l, d).transpose(0, 2, 1, 3)
@@ -484,20 +652,44 @@ def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret,
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def _auto_blocks(lq: int, lk: int) -> Tuple[int, int]:
-    """Measured on v5e (GPT-2 heads, d=64, 4k ctx): (256, 1024) runs the
-    fwd+bwd 2.1x faster than (128, 128) — bigger K tiles amortize the
-    per-block loop/bookkeeping and keep the MXU fed; past ~(512, 2048)
-    the f32 score/probability tiles blow the 16M VMEM scoped budget."""
+def _auto_blocks(lq: int, lk: int, d: int, causal: bool) -> Tuple[int, int]:
+    """(block_q, block_k) by shape.  Causal calls from 1,024 keys on take
+    (512, 512): no tile lies wholly above the diagonal at any length (at
+    1,024 three of four tiles are visited, two of them masked), and up to
+    2,048 a head's tiles are few enough for the unrolled forward and the
+    fused backward (``_whole_head_fits``).  Measured on a v5e, bf16 causal,
+    batch x heads x length = 131,072 rows (d = 64) or 65,536 (d = 128), ms
+    a call, forward + backward (PR 39's chip runs; the parent's tiles are
+    (256, 1024); ``*``: the form the code takes there is the rolled,
+    two-kernel one):
+
+        L, d       parent      256x256     256x512     512x512     512x1024
+        1024,  64  0.89+2.21   0.48+0.95   0.49+0.97   0.49+0.96   0.58+1.17
+        1024, 128  0.50+1.11   0.29+0.47   0.29+0.48   0.29+0.47   0.34+0.59
+        2048,  64  0.62+1.61   0.83+1.59*  0.57+1.39*  0.40+0.69   0.42+0.79
+        2048, 128  0.60+1.60   0.83+1.61*  0.58+1.40*  0.37+0.69   0.42+0.79
+        4096,  64  0.85+2.49   1.36+2.77*  0.84+2.26*  0.85+1.89*  0.87+2.00*
+        4096, 128  0.87+2.49   1.36+2.82*  0.85+2.31*  0.86+1.91*  0.90+2.01*
+
+    At 4,096 the parent's (256, 1024) already skipped (62.5% of the square
+    visited) and is level in the forward (0.83 with this code); the
+    backward is where (512, 512) gains.  Rolled, small tiles lose to the
+    loop's own cost (256 x 256 at 1,024: 1.04 + 2.02 against 0.49 + 0.95
+    unrolled).  Non-causal calls keep (256, 1024): (512, 512) read the
+    same there (1,024: 0.58 + 1.17 against 0.57 + 1.19).  Under 1,024 keys
+    the choice is the one the code always made, (128, 128): not measured
+    beyond [16, 512, 16, 64] (``mha_attention``)."""
     def pick(l, target):
         b = target
         while b > 128 and l % b:
             b //= 2
         return b if l % b == 0 else 128
 
-    if lk >= 1024:
-        return pick(lq, 256), pick(lk, 1024)
-    return pick(lq, 128), pick(lk, 128)
+    if lk < 1024:
+        return pick(lq, 128), pick(lk, 128)
+    if causal:
+        return pick(lq, 512), pick(lk, 512)
+    return pick(lq, 256), pick(lk, 1024)
 
 
 def flash_attention(q, k, v, causal: bool = True,
@@ -513,7 +705,7 @@ def flash_attention(q, k, v, causal: bool = True,
     pass them explicitly to override."""
     b, lq, h, d = q.shape
     lk = k.shape[1]
-    auto_q, auto_k = _auto_blocks(lq, lk)
+    auto_q, auto_k = _auto_blocks(lq, lk, d, causal)
     block_q = auto_q if block_q is None else block_q
     block_k = auto_k if block_k is None else block_k
     if lq % block_q or lk % block_k:

@@ -170,3 +170,193 @@ def test_flash_vjp_composes_with_jit_and_vmap():
                          _xla_attention(q * 0.5, k, v, True, None)])
     np.testing.assert_allclose(np.asarray(vm), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The causal schedule and the two forms of the backward pass (PR 39).
+# ---------------------------------------------------------------------------
+def _grads(fn, q, k, v):
+    return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(
+        fn(q, k, v).astype(jnp.float32))), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((1, 1024, 2, 64), jnp.bfloat16),   # the train cells' tile geometry
+    ((1, 1024, 2, 64), jnp.float32),
+    ((1, 1024, 1, 128), jnp.bfloat16),  # Llama-family heads
+    ((1, 1024, 1, 128), jnp.float32),
+])
+def test_flash_auto_blocks_at_1k_match_xla(shape, dtype):
+    """Forward and all three gradients with the auto tile choice at the
+    length the train cells run: several tiles a head, skipped, unmasked
+    and masked ones among them."""
+    from ray_tpu.ops.attention import _auto_blocks, causal_tile_schedule
+
+    sched = causal_tile_schedule(
+        shape[1], shape[1], *_auto_blocks(shape[1], shape[1], shape[3],
+                                          True))
+    assert sched["skipped"] and sched["masked"] < sched["visited"]
+    q, k, v = (x.astype(dtype) for x in _rand_qkv(*shape))
+    ref = tuple(x.astype(jnp.float32) for x in (q, k, v))
+    with jax.default_matmul_precision("float32"):
+        out_f = flash_attention(q, k, v, causal=True, interpret=True)
+        out_x = _xla_attention(*ref, True, None)
+        gf = _grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=True), q, k, v)
+        gx = _grads(lambda q, k, v: _xla_attention(q, k, v, True, None),
+                    *ref)
+    # bf16: p and ds are rounded to 8 bits before their matmuls, as the
+    # kernels always did; the bound is PR 21's, 2^-6 of the largest value.
+    for a, b, name in zip((out_f,) + gf, (out_x,) + gx,
+                          ("out", "dq", "dk", "dv")):
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        bound = 2e-4 if dtype == jnp.float32 else 2 ** -6 * np.abs(b).max()
+        assert np.abs(a - b).max() <= bound, (name, np.abs(a - b).max())
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blocks", [(128, 128), (128, 256), (256, 128)])
+def test_fused_backward_equals_the_two_kernel_form(blocks, causal):
+    """One kernel that computes a tile's s, p, dp, ds once, and the dq and
+    dk/dv kernels that each compute them: the same gradients."""
+    from ray_tpu.ops.attention import _flash_bwd, _flash_fwd
+
+    bq, bk = blocks
+    q, k, v = _rand_qkv(1, 512, 2, 32)
+    do = _rand_qkv(1, 512, 2, 32, seed=3)[0]
+    with jax.default_matmul_precision("float32"):
+        out, lse, (qf, kf, vf) = _flash_fwd(q, k, v, causal, None, bq, bk,
+                                            True)
+        dof = do.transpose(0, 2, 1, 3).reshape(qf.shape)
+        one = _flash_bwd(qf, kf, vf, out, lse, dof, causal, None, bq, bk,
+                         True, True)
+        two = _flash_bwd(qf, kf, vf, out, lse, dof, causal, None, bq, bk,
+                         False, True)
+    for a, b, name in zip(one, two, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5,
+                                   rtol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_both_backward_forms_match_xla(fused, monkeypatch):
+    """The custom VJP takes whichever form ``_fused_bwd_fits`` names; both
+    are held to the XLA reference here."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_whole_head_fits", lambda *a: fused)
+    q, k, v = _rand_qkv(1, 512, 2, 32)
+    with jax.default_matmul_precision("float32"):
+        gf = _grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128,
+            interpret=True), q, k, v)
+        gx = _grads(lambda q, k, v: _xla_attention(q, k, v, True, None),
+                    q, k, v)
+    for a, b, name in zip(gf, gx, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   rtol=1e-3, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("blocks", [(128, 256), (256, 128), (512, 128),
+                                    (128, 512)])
+def test_explicit_blocks_are_honoured_in_the_gradients(blocks):
+    """Unequal explicit tiles: the skipped / masked / unmasked bounds are
+    taken from both sides (a q tile's k range in fwd and dq, a k tile's q
+    range in dk/dv) and have to agree."""
+    bq, bk = blocks
+    q, k, v = _rand_qkv(1, 512, 1, 32)
+    with jax.default_matmul_precision("float32"):
+        gf = _grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=bq, block_k=bk, interpret=True),
+            q, k, v)
+        gx = _grads(lambda q, k, v: _xla_attention(q, k, v, True, None),
+                    q, k, v)
+    for a, b, name in zip(gf, gx, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   rtol=1e-3, err_msg=f"d{name}")
+
+
+def test_non_causal_keeps_its_schedule():
+    """Non-causal calls visit the whole rectangle with the tiles they
+    always had."""
+    from ray_tpu.ops.attention import _auto_blocks
+
+    for d in (64, 128):
+        assert _auto_blocks(1024, 1024, d, False) == (256, 1024)
+        assert _auto_blocks(4096, 4096, d, False) == (256, 1024)
+        assert _auto_blocks(512, 512, d, False) == (128, 128)
+        assert _auto_blocks(384, 384, d, False) == (128, 128)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("length", [256, 384, 512, 640, 1024, 1152, 1536,
+                                    2048, 3072, 4096, 8192])
+def test_auto_schedule_skips_what_the_mask_removes(length, d):
+    """The auto tile choice never visits a tile wholly above the diagonal,
+    and visits at most the causal half plus one diagonal band of tiles.
+    (Until PR 39 the choice at 1,024 was one k tile as long as the
+    sequence: visited share 1.0, all of it masked.)"""
+    from ray_tpu.ops.attention import (_auto_blocks, _q_tile_bounds,
+                                       causal_tile_schedule)
+
+    bq, bk = _auto_blocks(length, length, d, True)
+    assert length % bq == 0 and length % bk == 0
+    nq, nk = length // bq, length // bk
+    sched = causal_tile_schedule(length, length, bq, bk)
+    # Every visited tile holds an entry on or under the diagonal...
+    kinds = {}
+    for i in range(nq):
+        for j in range(nk):
+            above = j * bk > i * bq + bq - 1     # first col past last row
+            under = j * bk + bk - 1 <= i * bq    # last col at or before row
+            kinds[i, j] = "skip" if above else ("full" if under else "mask")
+    assert sched["visited"] == sum(k != "skip" for k in kinds.values())
+    assert sched["masked"] == sum(k == "mask" for k in kinds.values())
+    assert sched["skipped"] == sum(k == "skip" for k in kinds.values())
+    # ... the k-side bounds (dk/dv, the fused backward) say the same ...
+    for j in range(nk):
+        first, full = (int(x) for x in _q_tile_bounds(j * bk, bk, bq, nq))
+        assert [kinds[i, j] for i in range(nq)] == (
+            ["skip"] * first + ["mask"] * (full - first)
+            + ["full"] * (nq - full))
+    # ... and the visited area is the causal half plus at most one band.
+    band = max(bq, bk) * length
+    assert sched["visited_share"] * length * length <= (
+        length * length / 2 + band)
+    if length >= 1024:
+        assert sched["visited_share"] <= 0.75
+
+
+def test_schedule_counter_on_the_parent_choice():
+    """What PR 39 found: (256, 1024) at 1,024 visits the whole square, all
+    of it under the mask; at 4,096 the same tiles do skip."""
+    from ray_tpu.ops.attention import causal_tile_schedule
+
+    assert causal_tile_schedule(1024, 1024, 256, 1024) == {
+        "total": 4, "visited": 4, "masked": 4, "skipped": 0,
+        "visited_share": 1.0}
+    assert causal_tile_schedule(4096, 4096, 256, 1024)["visited_share"] \
+        == 0.625
+    assert causal_tile_schedule(1024, 1024, 256, 256) == {
+        "total": 16, "visited": 10, "masked": 4, "skipped": 6,
+        "visited_share": 0.625}
+
+
+@pytest.mark.parametrize("length,d,itemsize,whole", [
+    (1024, 64, 2, True),     # the train cells: one head's Q, K, V, dO fit
+    (2048, 128, 2, True),
+    (1024, 64, 4, True),
+    (2048, 64, 4, False),    # float32 doubles the residency
+    (3072, 64, 2, False),    # 21 tiles: past what the kernels unroll
+    (4096, 64, 2, False),
+    (8192, 128, 2, False),
+])
+def test_whole_head_form_follows_the_shape(length, d, itemsize, whole):
+    """The unrolled forward and the fused backward are taken where one
+    head's blocks fit the kernel's share of VMEM and the tiles are few;
+    the grid-walking kernels stay for the rest.  Chosen from the length,
+    the head size and the item size, by no option."""
+    from ray_tpu.ops.attention import _auto_blocks, _whole_head_fits
+
+    bq, bk = _auto_blocks(length, length, d, True)
+    assert _whole_head_fits(length, length, d, itemsize, bq, bk,
+                            True) is whole

@@ -1,0 +1,65 @@
+"""The flash kernels compiled for a described TPU v5e at the widths the
+train cells and the Llama-family models run, without a chip: what Mosaic
+refuses (a block that does not fit VMEM, a slice off the tiling) fails here
+and costs no chip time.  Nothing runs, so this gives no result and no time.
+
+The topology is described inside a fixture, never at import: only the
+worker that is handed this file loads the TPU's library, and every worker
+collects the same tests (on-chip-measurement guide, section 2)."""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.ops.attention import (_auto_blocks, _whole_head_fits,
+                                   flash_attention)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here, or its lock
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # An executable compiled for a described chip cannot be read back from
+    # the persistent cache without one: keep these compiles out of it.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.timeout(120)
+@pytest.mark.parametrize("shape,dtype,kernels", [
+    # the train cells: [8, 1024, 16, 64] bf16 on every chip
+    ((8, 1024, 16, 64), jnp.bfloat16, ("flash_fwd", "flash_bwd")),
+    # Llama-family heads, the longest length the fused backward holds
+    ((1, 2048, 8, 128), jnp.bfloat16, ("flash_fwd", "flash_bwd")),
+    # past its residency: the two-kernel form, K and V whole beside a tile
+    ((1, 4096, 12, 64), jnp.bfloat16, ("flash_fwd", "flash_dq",
+                                       "flash_dkv")),
+    ((1, 8192, 4, 128), jnp.bfloat16, ("flash_fwd", "flash_dq",
+                                       "flash_dkv")),
+    ((2, 1024, 4, 64), jnp.float32, ("flash_fwd", "flash_bwd")),
+])
+def test_flash_kernels_compile_for_v5e(one_chip, shape, dtype, kernels):
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    grad = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True)
+                                .astype(jnp.float32)), argnums=(0, 1, 2)))
+    text = grad.lower(x, x, x).compile().as_text()
+    assert "tpu_custom_call" in text
+    for name in ("flash_fwd", "flash_bwd", "flash_dq", "flash_dkv"):
+        assert (name in text) == (name in kernels), name
+    _, length, _, d = shape
+    whole = _whole_head_fits(length, length, d, jnp.dtype(dtype).itemsize,
+                             *_auto_blocks(length, length, d, True), True)
+    assert whole == ("flash_bwd" in kernels)

@@ -220,11 +220,19 @@ def test_anakin_ppo_step_carries_scope_names():
         assert f"/{scope}/" in text
 
 
-def test_flash_kernels_carry_their_names():
+@pytest.mark.parametrize("whole_head,kernels", [
+    (True, ("flash_fwd", "flash_bwd")),            # one backward kernel
+    (False, ("flash_fwd", "flash_dq", "flash_dkv")),  # past its residency
+])
+def test_flash_kernels_carry_their_names(whole_head, kernels, monkeypatch):
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.ops import attention
     from ray_tpu.ops.attention import flash_attention
+
+    monkeypatch.setattr(attention, "_whole_head_fits",
+                        lambda *a: whole_head)
 
     q = jnp.ones((1, 128, 2, 64), jnp.float32)
 
@@ -233,8 +241,8 @@ def test_flash_kernels_carry_their_names():
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, q, q).as_text(debug_info=True)
-    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
-        assert kernel in text
+    for kernel in ("flash_fwd", "flash_bwd", "flash_dq", "flash_dkv"):
+        assert (kernel in text) == (kernel in kernels), kernel
 
 
 # (e) ----------------------------------------------------------------------
