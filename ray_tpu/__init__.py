@@ -128,6 +128,20 @@ def init(num_cpus: Optional[float] = None, num_tpus: Optional[float] = None,
     with ``address="host:port"`` — join an existing remote head over TCP.
 
     Reference: ray.init (python/ray/_private/worker.py:1043)."""
+    from ray_tpu import observability as _obs
+
+    mode = ("local_mode" if kwargs.get("local_mode")
+            else "head" if address is None else "remote")
+    with _obs.span("runtime.init", _lifecycle=True, mode=mode) as sp:
+        worker = _init(num_cpus, num_tpus, resources, object_store_memory,
+                       labels, ignore_reinit_error, address, _authkey, kwargs)
+        if worker is None:  # already up, ignore_reinit_error: no start
+            sp.cancel()
+        return worker
+
+
+def _init(num_cpus, num_tpus, resources, object_store_memory, labels,
+          ignore_reinit_error, address, _authkey, kwargs):
     global _head, _remote_driver
     with _head_lock:
         if is_initialized():

@@ -52,7 +52,7 @@ class WorkerHandle:
     __slots__ = ("worker_id", "proc", "conn", "busy", "actor_id", "node_id",
                  "current_task", "idle_since", "tpu_visible", "tpu_chips",
                  "task_started_at", "direct_addr", "leased_to", "lease_spec",
-                 "blocked")
+                 "blocked", "asked_at", "asked_for")
 
     def __init__(self, worker_id: WorkerID, proc, node_id: NodeID):
         self.worker_id = worker_id
@@ -70,6 +70,11 @@ class WorkerHandle:
         self.leased_to = None    # caller worker id holding a lease on us
         self.lease_spec = None   # synthetic spec whose resources the lease holds
         self.blocked = False     # blocked in get(): resources released
+        # the ``runtime.worker_start`` lifecycle span: when the process
+        # was asked for (span clock), and the task or actor class it was
+        # asked for (None: the pool's own spare)
+        self.asked_at = time.perf_counter()
+        self.asked_for: Optional[str] = None
 
 
 class Raylet:
@@ -160,13 +165,15 @@ class Raylet:
                         return
                 else:
                     chips = ()  # shared mode: all chips visible, none owned
-                self.spawn_worker(tpu_visible=True, tpu_chips=chips)
+                wid = self.spawn_worker(tpu_visible=True, tpu_chips=chips)
+                self.workers[wid].asked_for = spec.name
             return
         if self.idle or self.num_starting > 0:
             return
         if len(self.workers) + self.num_starting >= self.max_workers:
             return
-        self.spawn_worker()
+        wid = self.spawn_worker()
+        self.workers[wid].asked_for = spec.name if spec is not None else None
 
     def _allocate_chips(self, n: int) -> Optional[tuple]:
         """Reserve n chip indices for a new TPU worker (None if unavailable).
@@ -266,6 +273,11 @@ class Raylet:
             return None
         h.conn = conn
         h.direct_addr = direct_addr
+        from ray_tpu import observability as obs
+
+        obs.record("runtime.worker_start", h.asked_at, time.perf_counter(),
+                   _lifecycle=True, worker_id=worker_id.hex(),
+                   **{"for": h.asked_for})
         self.num_starting = max(0, self.num_starting - 1)
         self.consecutive_start_failures = 0
         self.idle.append(worker_id)

@@ -14,12 +14,16 @@ OpenTelemetry-style context propagation.  Four pieces live here:
 2. **The one span recorder** — :func:`span` and :func:`record` (for a
    span whose start was stamped earlier), on the span clock
    ``time.perf_counter()``; ``util.tracing.span`` and
-   ``_private.profiling.record_span`` are callers.  A span is recorded
-   when :func:`on` says so: the ``tracing_enabled`` flag, or a
-   ``jax.profiler`` trace running in this process, in which the span
-   is also a ``TraceAnnotation`` on a host line of the ``.xplane.pb``,
-   on the device's clock.  One process-wide :class:`SpanRing` collects
-   every completed span; off, :func:`span` is one shared no-op.
+   ``_private.profiling.record_span`` are callers.  A per-call,
+   per-step or per-request span is recorded when :func:`on` says so:
+   the ``tracing_enabled`` flag, or a ``jax.profiler`` trace running in
+   this process, in which the span is also a ``TraceAnnotation`` on a
+   host line of the ``.xplane.pb``, on the device's clock.  A lifecycle
+   span (``_lifecycle=True``: a site that runs at most a few dozen
+   times in a process's life, such as ``runtime.init`` or a program's
+   first compile) is recorded always.  One process-wide
+   :class:`SpanRing` collects every completed span; off, :func:`span`
+   is one shared no-op.
 3. **Flush path** — ``flush(transport)`` drains the ring into a
    ``span_batch`` one-way request to the head; workers flush at task
    start/end and on the node-stats cadence, node agents relay their
@@ -77,8 +81,9 @@ def _profile_annotation():
 
 
 def on() -> bool:
-    """The one rule for whether a span is recorded: the tracing plane is
-    on, or a ``jax.profiler`` trace is running in this process."""
+    """The rule for whether a per-call, per-step or per-request span is
+    recorded: the tracing plane is on, or a ``jax.profiler`` trace is
+    running in this process.  Lifecycle spans do not ask."""
     return enabled() or _profile_annotation() is not None
 
 
@@ -230,14 +235,16 @@ def record(name: str, start: float, end: float,
            ctx: Optional[TraceContext] = None,
            parent_id: Optional[str] = None,
            span_id: Optional[str] = None,
+           _lifecycle: bool = False,
            **args) -> Optional[str]:
     """Record one completed span whose ends were stamped on the span
     clock, e.g. a request's queue wait.  ``ctx`` defaults to the active
     context; ``parent_id`` to the context's span id, else to the
     thread's open span.  A profile cannot take a span after the fact:
-    it gets a marker of that name where the span ends, with ``dur_us``."""
+    it gets a marker of that name where the span ends, with ``dur_us``.
+    ``_lifecycle``: as for :func:`span`."""
     ann = _profile_annotation()
-    if ann is None and not enabled():
+    if ann is None and not _lifecycle and not enabled():
         return None
     if ann is not None:
         with ann(name, dur_us=int((end - start) * 1e6), **args):
@@ -324,12 +331,17 @@ class _Span:
         return False
 
 
-def span(name: str, _ctx: Optional[TraceContext] = None, **args):
+def span(name: str, _ctx: Optional[TraceContext] = None,
+         _lifecycle: bool = False, **args):
     """Context manager around one piece of work.  ``_ctx`` pins it to an
     explicit ``(trace_id, parent_span_id)``; otherwise it joins the
-    thread's active context, the enclosing open span its parent."""
+    thread's active context, the enclosing open span its parent.
+    ``_lifecycle`` marks a span of a process's set-up, recorded whatever
+    :func:`on` says: only for a site that runs at most a few dozen times
+    in a process's life (the table of names in docs/OBSERVABILITY.md),
+    never one a call, a step, a request, a task or an object."""
     ann = _profile_annotation()
-    if ann is None and not enabled():
+    if ann is None and not _lifecycle and not enabled():
         return NO_SPAN
     return _Span(name, args, _ctx, ann)
 
@@ -405,6 +417,15 @@ def session_spans(name: Optional[str] = None) -> List[Dict[str, Any]]:
     if name is not None:
         spans = [s for s in spans if s["name"] == name]
     return spans
+
+
+def session_spans_dropped() -> int:
+    """How many spans the session :func:`session_spans` reads from lost
+    before they could be read: what its head's store refused and what
+    this process's ring pushed out.  A reader that adds spans up (the
+    phases of set-up) has holes where this is not 0."""
+    lost = _session_store.spans_dropped if _session_store is not None else 0
+    return lost + (_ring.dropped_total if _ring is not None else 0)
 
 
 def flight_record(reason: str) -> None:

@@ -230,6 +230,9 @@ def bootstrap_jax_distributed(coordinator: str, world_size: int, rank: int,
 
     import jax
 
+    from ray_tpu._private.jax_env import ensure_compile_listener
+
+    ensure_compile_listener()
     if world_size > 1:
         from jax._src import xla_bridge
 
@@ -519,6 +522,16 @@ def rendezvous(workers: Sequence, platform: Optional[str] = None,
     Workers must expose node_info/setup_env and either bootstrap() (native
     MeshWorker) or execute() (Train's TrainWorker) — this is the piece
     BackendExecutor delegates to.  Returns per-rank device info."""
+    from ray_tpu import observability as obs
+
+    with obs.span("train.rendezvous", _lifecycle=True, world=len(workers),
+                  platform=platform) as sp:
+        infos = _rendezvous(workers, platform, local_device_count, timeout)
+        sp.set(platform=infos[0].get("platform", platform))
+    return infos
+
+
+def _rendezvous(workers, platform, local_device_count, timeout):
     world = len(workers)
     infos = ray_tpu.get([w.node_info.remote() for w in workers],
                         timeout=timeout)

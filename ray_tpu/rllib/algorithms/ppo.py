@@ -155,8 +155,10 @@ def make_anakin_ppo(config: AlgorithmConfig):
     device rolls out N/D envs and runs the minibatch scan on its shard,
     with gradients/moments pmean'd across the axis — the only cross-chip
     traffic is the grad all-reduce riding ICI."""
+    from ray_tpu._private.jax_env import ensure_compile_listener
     from ray_tpu.rllib.utils import mesh as mesh_util
 
+    ensure_compile_listener()
     env = make_jax_env(config.env) if isinstance(config.env, str) \
         else config.env
     obs_shape = getattr(env, "obs_shape", None)

@@ -7,6 +7,7 @@ import threading
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu import observability as obs
 
 _deployments: Dict[str, "Deployment"] = {}
 _proxy = None
@@ -18,9 +19,11 @@ class _Replica:
     serve/_private/replica.py:260).  A replica can hold a pjit-compiled
     inference mesh — the callable owns whatever devices its worker sees."""
 
-    def __init__(self, cls_or_fn, init_args, init_kwargs):
+    def __init__(self, cls_or_fn, init_args, init_kwargs, deployment=None):
         if isinstance(cls_or_fn, type):
-            self._callable = cls_or_fn(*init_args, **init_kwargs)
+            with obs.span("serve.replica_init", _lifecycle=True,
+                          deployment=deployment):
+                self._callable = cls_or_fn(*init_args, **init_kwargs)
         else:
             self._callable = cls_or_fn
         self._queued = 0
@@ -265,7 +268,7 @@ class Deployment:
         opts = dict(self.ray_actor_options)
         opts.setdefault("max_concurrency", 8)
         r = _Replica.options(**opts).remote(self._func, self._init_args,
-                                            self._init_kwargs)
+                                            self._init_kwargs, self.name)
         if self.user_config is not None:
             ray_tpu.get(r.reconfigure.remote(self.user_config))
         self._replicas.append(r)
@@ -278,7 +281,12 @@ class Deployment:
             start = max(int(self.autoscaling_config.get("min_replicas", 1)),
                         min(start, int(self.autoscaling_config.get(
                             "max_replicas", start))))
-        replicas = [self._make_replica() for _ in range(start)]
+        with obs.span("serve.deploy", _lifecycle=True, deployment=self.name,
+                      replicas=start):
+            replicas = [self._make_replica() for _ in range(start)]
+            # every replica answering: its constructor has run, or raises
+            # here and not in the first request
+            ray_tpu.get([r.queue_len.remote() for r in replicas])
         self.handle = DeploymentHandle(self.name, replicas)
         if self.autoscaling_config:
             from ray_tpu.serve.controller import get_controller

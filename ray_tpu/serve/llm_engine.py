@@ -115,6 +115,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import logging
 import math
 import queue
 import threading
@@ -124,15 +125,35 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from ray_tpu import observability as obs
+from ray_tpu._private import jax_env
 from ray_tpu.exceptions import EngineClosedError, KVPoolExhaustedError
 from ray_tpu.serve.sampling import GREEDY, SamplingParams
 
 _DEF = object()  # sentinel: constructor arg not given, consult CONFIG
+logger = logging.getLogger(__name__)
 
 
 def _named(name: str, fn):
     fn.__name__ = fn.__qualname__ = name
     return fn
+
+
+def _engine_init_span(init):
+    """``LLMEngine.__init__`` inside the ``engine.init`` lifecycle span:
+    pools and per-slot state allocated, the ``jit`` objects made."""
+    @functools.wraps(init)
+    def traced(self, *args, **kw):
+        jax_env.ensure_compile_listener()
+        with obs.span("engine.init", _lifecycle=True) as sp:
+            init(self, *args, **kw)
+            pools = [self._k_pages, self._v_pages]
+            if self._spec:
+                pools += [self._dk_pages, self._dv_pages]
+            sp.set(slots=self.max_slots,
+                   pool_bytes=sum(a.nbytes for a in pools),
+                   state_pool_bytes=self._state_pool_bytes())
+
+    return traced
 
 
 def _attend_uncached(q, k, v):
@@ -358,6 +379,7 @@ class LLMEngine:
     REGISTRY_LIMIT = 4096
     REGISTRY_FLOOR = 2048
 
+    @_engine_init_span
     def __init__(self, model, params, *, max_slots=_DEF, page_size=_DEF,
                  num_pages: Optional[int] = None,
                  max_ctx: Optional[int] = None,
@@ -532,22 +554,20 @@ class LLMEngine:
         self._slot_pages: List[List[int]] = [[] for _ in range(self.max_slots)]
         self._slot_req: Dict[int, _Request] = {}
 
-        # A program is named after its function, and the profile shows it
-        # as jit_<name>: stable names, for whoever reads a trace.
-        self._decode = jax.jit(
-            _named("llm_decode", self._make_decode_step(model)),
+        self._decode = self._program(
+            "llm_decode", self._make_decode_step(model),
             # pools, and the state (``step``'s thirteenth argument)
             donate_argnums=(1, 2) if self._state is None else (1, 2, 12))
         if self._spec:
-            self._draft_decode = jax.jit(
-                _named("llm_draft_decode", self._make_decode_step(
-                    draft_model, window_pages=self._draft_window_pages)),
+            self._draft_decode = self._program(
+                "llm_draft_decode", self._make_decode_step(
+                    draft_model, window_pages=self._draft_window_pages),
                 donate_argnums=(1, 2))
-            self._verify = jax.jit(
-                _named("llm_verify", self._make_verify_step(model)),
+            self._verify = self._program(
+                "llm_verify", self._make_verify_step(model),
                 donate_argnums=(1, 2))
-        self._adopt = jax.jit(
-            _named("llm_adopt", self._make_adopt(self.dtype)),
+        self._adopt = self._program(
+            "llm_adopt", self._make_adopt(self.dtype),
             donate_argnums=(0, 1))
         self._adopt_buf_k = np.zeros(
             (self.num_layers, self.pages_per_slot) + self._k_pages.shape[2:],
@@ -786,9 +806,7 @@ class LLMEngine:
             "page_pool": pool,
             # per-slot recurrent state beside the pools (0: the model
             # carries none)
-            "state_pool_bytes": sum(
-                a.nbytes for layer in self._state or ()
-                for a in layer.values()),
+            "state_pool_bytes": self._state_pool_bytes(),
             "prefill_buckets": len(self._prefills),
             # sampling / speculative decoding
             "greedy_steps": s.get("greedy_steps", 0),
@@ -833,9 +851,16 @@ class LLMEngine:
         cache_size = getattr(self._decode, "_cache_size", None)
         if callable(cache_size):
             out["decode_cache_size"] = cache_size()
+        # what JAX compiled or fetched in this process, and how long it
+        # took: set-up's, and any bucket first reached under load
+        out.update(jax_env.compile_totals())
         out["platform"] = self._device.platform
         out["device_kind"] = self._device.device_kind
         return out
+
+    def _state_pool_bytes(self) -> int:
+        return sum(a.nbytes for layer in self._state or ()
+                   for a in layer.values())
 
     def close(self, timeout: float = 10.0):
         with self._cond:
@@ -991,6 +1016,26 @@ class LLMEngine:
 
         return adopt
 
+    def _program(self, name: str, fn, **jit_kw):
+        """``fn`` jitted under ``name`` (a program is named after its
+        function, and the profile shows it as jit_<name>: stable names,
+        for whoever reads a trace), its first call an ``engine.compile``
+        lifecycle span."""
+        return jax_env.FirstCallSpan(
+            self._jax.jit(_named(name, fn), **jit_kw), "engine.compile",
+            name, before=self._note_compile)
+
+    def _note_compile(self, program: str):
+        """A program first called after a decode step has emitted compiles
+        under load: the requests in flight wait for it.  (The first
+        request's own decode program follows its prefill's token, and is
+        set-up still.)"""
+        if self._stats["steps"]:
+            logger.warning(
+                "LLMEngine compiles %s after %d decode steps: every "
+                "request in flight waits for it (warm each prompt bucket "
+                "before taking traffic)", program, self._stats["steps"])
+
     def _prefill_fn(self, bucket: int):
         """Full-context prefill (empty cache): one program per pow2
         bucket."""
@@ -1047,10 +1092,10 @@ class LLMEngine:
             out = (k_pages, v_pages, next_tok, next_logp)
             return out if state is None else out + (state,)
 
-        fn = jax.jit(_named(f"llm_prefill_{bucket}", prefill),
-                     # pools, and the state (the eleventh argument)
-                     donate_argnums=(1, 2) if self._state is None
-                     else (1, 2, 10))
+        fn = self._program(f"llm_prefill_{bucket}", prefill,
+                           # pools, and the state (the eleventh argument)
+                           donate_argnums=(1, 2) if self._state is None
+                           else (1, 2, 10))
         self._prefills[key] = fn
         return fn
 
@@ -1119,8 +1164,8 @@ class LLMEngine:
                 v_pages = _write_rows(v_pages, newv, page_idx, off)
             return k_pages, v_pages, next_tok, next_logp
 
-        fn = jax.jit(_named(f"llm_tail_prefill_{bucket}", tail_prefill),
-                     donate_argnums=(1, 2))
+        fn = self._program(f"llm_tail_prefill_{bucket}", tail_prefill,
+                           donate_argnums=(1, 2))
         self._prefills[key] = fn
         return fn
 
@@ -1152,8 +1197,8 @@ class LLMEngine:
             v_pages = _write_rows(v_pages, newv, page_idx, off)
             return k_pages, v_pages
 
-        fn = jax.jit(_named(f"llm_draft_prefill_{bucket}", prefill),
-                     donate_argnums=(1, 2))
+        fn = self._program(f"llm_draft_prefill_{bucket}", prefill,
+                           donate_argnums=(1, 2))
         self._prefills[key] = fn
         return fn
 
@@ -2138,7 +2183,21 @@ def build_model(model_kind: str, config_kw: Optional[dict] = None,
     every size.  The leaves are made one by one in the config's
     ``param_dtype`` (``LlamaConfig``; float32 by default and for GPT-2):
     a model served in bfloat16 passes ``"param_dtype": "bfloat16"`` and is
-    never held whole in float32."""
+    never held whole in float32.  Returns when the parameters are on
+    the device: the ``model.build`` lifecycle span is the whole of it."""
+    import jax
+
+    jax_env.ensure_compile_listener()
+    with obs.span("model.build", _lifecycle=True,
+                  model_kind=model_kind) as sp:
+        model, params = _build_model(model_kind, config_kw, seed)
+        leaves = jax.tree_util.tree_leaves(jax.block_until_ready(params))
+        sp.set(param_count=sum(x.size for x in leaves),
+               param_bytes=sum(x.nbytes for x in leaves))
+    return model, params
+
+
+def _build_model(model_kind: str, config_kw: Optional[dict], seed: int):
     import jax
     import jax.numpy as jnp
 

@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
 from ray_tpu import exceptions as exc
+from ray_tpu import observability as obs
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.air.config import ScalingConfig
 from ray_tpu.parallel.mesh_group import gang_get, is_transport_abort
@@ -84,6 +85,11 @@ class BackendExecutor:
         ``num_workers=(min, max)`` range, probe sizes max→min and take
         the largest the cluster can place NOW (never below min —
         min's placement failure propagates)."""
+        with obs.span("train.worker_group_start", _lifecycle=True) as sp:
+            self._start()
+            sp.set(workers=self.num_workers)
+
+    def _start(self):
         res = self.scaling.worker_resources()
         lo, hi = self.scaling.worker_range()
         self.num_workers = lo

@@ -45,13 +45,21 @@ def compile_donated_step(step_fn, carry_argnums=(0,), batch_argnums=(),
     ``step_fn(carry..., batch...) -> (carry..., metrics)``: the caller
     must not reuse donated arguments after the call (donation invalidates
     their buffers) — keep ``donate_batch=False`` when the same host batch
-    is fed to several steps (e.g. synthetic-data benches)."""
+    is fed to several steps (e.g. synthetic-data benches).
+
+    What comes back is the jitted step with its first call inside a
+    ``train.compile`` lifecycle span (``jax_env.FirstCallSpan``)."""
     import jax
 
+    from ray_tpu._private import jax_env
+
+    jax_env.ensure_compile_listener()
     donate = tuple(carry_argnums)
     if donate_batch:
         donate = donate + tuple(batch_argnums)
-    return jax.jit(step_fn, donate_argnums=donate, **jit_kwargs)
+    return jax_env.FirstCallSpan(
+        jax.jit(step_fn, donate_argnums=donate, **jit_kwargs),
+        "train.compile", getattr(step_fn, "__name__", "step"))
 
 
 class AsyncMetrics:

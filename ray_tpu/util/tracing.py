@@ -1,11 +1,7 @@
-"""OpenTelemetry tracing integration.
+"""The tracing switch and the runtime's instrumentation point.
 
-Reference: python/ray/util/tracing/tracing_helper.py — the runtime is
-instrumented against the opentelemetry *API* (present in this image);
-span data goes wherever the application's TracerProvider sends it, so
-wiring an SDK/exporter is the user's call exactly as in the reference
-(`ray.init(_tracing_startup_hook=...)`).  Without a provider the API's
-no-op tracer makes every span free.
+Reference: python/ray/util/tracing/tracing_helper.py.  Spans go to the
+one recorder, ray_tpu.observability, and nowhere else.
 
 Surface:
 - ``enable_tracing()`` / ``tracing_enabled()`` — process-local switch
@@ -15,15 +11,14 @@ Surface:
   Each span joins the active distributed trace context
   (ray_tpu.observability) and becomes the active parent for anything
   submitted inside it, so cross-process timelines assemble.
-- Spans ALSO land in the process's one span ring (ray_tpu.observability;
-  ``pop_local_spans`` drains it) so `ray_tpu.timeline()`-style tooling
-  sees them even with no SDK.  Overflow is counted, not silently
-  truncated, and the counter is exported as
-  ``tracing_spans_dropped_total`` through util.metrics.
+- Spans land in the process's one span ring (ray_tpu.observability;
+  ``pop_local_spans`` drains it), which `ray_tpu.timeline()`-style
+  tooling reads.  Overflow is counted, not silently truncated, and the
+  counter is exported as ``tracing_spans_dropped_total`` through
+  util.metrics.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Any, Dict, List, Optional
 
 from ray_tpu import observability as obs
@@ -55,30 +50,14 @@ def tracing_enabled() -> bool:
     return _enabled
 
 
-def _tracer():
-    try:
-        from opentelemetry import trace
-
-        return trace.get_tracer("ray_tpu")
-    except Exception:
-        return None
-
-
-@contextlib.contextmanager
 def span(name: str, **attributes):
-    """Instrumentation point: otel span (no-op without a provider) plus
-    an ``observability.span`` for timeline tooling.  Joins the active
-    trace context, or roots a new trace, and is the active parent for
-    nested work while open."""
+    """Instrumentation point: an ``observability.span`` that joins the
+    active trace context, or roots a new trace, and is the active parent
+    for nested work while open."""
     if not obs.on():
-        yield
-        return
-    tracer = _tracer()
-    otel = (tracer.start_as_current_span(name, attributes=attributes)
-            if tracer is not None else contextlib.nullcontext())
+        return obs.NO_SPAN
     ctx = obs.get_context() or (obs.new_id(), None)
-    with obs.span(name, _ctx=ctx, **attributes), otel:
-        yield
+    return obs.span(name, _ctx=ctx, **attributes)
 
 
 def pop_local_spans() -> List[Dict[str, Any]]:

@@ -53,7 +53,14 @@ def traced_engine(gpt2, tmp_path_factory):
     try:
         for r in [eng.submit(p, 3) for p in prompts]:  # compile
             eng.result(r, timeout=300)
+        setup = obs.drain_spans()  # lifecycle spans: recorded, flag off
+        # the off path where it is hot: 200 iterations after set-up
+        steps0 = eng.stats()["steps"]
+        while eng.stats()["steps"] - steps0 < 200:
+            for r in [eng.submit(p, 40) for p in prompts]:
+                eng.result(r, timeout=300)
         off = {"span": obs.span("engine.iteration", active=1),
+               "steps": eng.stats()["steps"] - steps0,
                "ring": len(obs.drain_spans())}
         observed = []
         eng._observe_queue_wait = observed.append
@@ -83,6 +90,7 @@ def traced_engine(gpt2, tmp_path_factory):
     finally:
         eng.close()
     return {"spans": obs.drain_spans(), "rids": rids, "off": off,
+            "setup": setup,
             "counts": {k: after[k] - before[k] for k in (
                 "steps", "admitted", "lookahead_steps", "drained_steps")},
             "observed": observed, "lowered": lowered,
@@ -97,6 +105,7 @@ def named(spans, name):
 def test_off_is_one_shared_noop_and_an_empty_ring(traced_engine):
     off = traced_engine["off"]
     assert off["span"] is obs.NO_SPAN and off["ring"] == 0
+    assert off["steps"] >= 200  # iterations after set-up left nothing
     assert obs.span("x", a=1) is obs.NO_SPAN and not obs.on()
     with obs.span("x") as sp:
         sp.set(b=2)
@@ -332,3 +341,275 @@ def test_session_spans_outlive_shutdown(shutdown_only):
     assert [s["args"] for s in obs.session_spans("worker.side")] == [{"n": 1}]
     ray_tpu.init(num_cpus=1)
     assert obs.session_spans("worker.side") == []
+
+
+# lifecycle spans: set-up, recorded whatever the flag says (ISSUE 41) ---------
+LIFECYCLE_ONCE = ("runtime.init", "serve.deploy", "serve.replica_init",
+                  "model.build", "engine.init")
+
+
+def inside(inner, outer):
+    return outer["start"] <= inner["start"] and inner["end"] <= outer["end"]
+
+
+def compiles_under(spans, parent):
+    return [s for s in named(spans, "jax.compile")
+            if s["parent_id"] == parent["span_id"]]
+
+
+def test_a_lifecycle_span_is_recorded_with_everything_off():
+    obs.drain_spans()
+    assert not obs.on()
+    with obs.span("life", _lifecycle=True, k=1) as life:
+        with obs.span("plain"):  # per-call: not recorded while off
+            pass
+        t = time.perf_counter()
+        obs.record("life.after", t - 0.25, t, _lifecycle=True, n=2)
+        assert obs.record("plain.after", t - 0.25, t) is None
+        life.set(m=3)
+    got = {s["name"]: s for s in obs.drain_spans()}
+    assert set(got) == {"life", "life.after"}
+    assert got["life"]["args"] == {"k": 1, "m": 3}
+    assert got["life"]["trace_id"] is None  # pools under UNTRACED
+    assert got["life.after"]["parent_id"] == got["life"]["span_id"]
+    assert got["life.after"]["end"] - got["life.after"]["start"] == \
+        pytest.approx(0.25)
+    assert abs(got["life"]["start"] - time.time()) < 5.0  # time.time()'s clock
+    assert obs.get_context() is None
+
+
+def test_engine_set_up_is_one_init_and_one_compile_a_program(traced_engine):
+    """The fixture's set-up, flag off and no profile: three prompt buckets
+    and the decode step, each compiled in its first call and never again
+    (the 200 iterations and the traced requests add none)."""
+    setup = traced_engine["setup"]
+    [init] = named(setup, "engine.init")
+    assert init["args"]["slots"] == 4 and init["args"]["pool_bytes"] > 0
+    assert init["args"]["state_pool_bytes"] == 0
+    programs = Counter(s["args"]["program"]
+                       for s in named(setup, "engine.compile"))
+    assert programs == {"llm_prefill_8": 1, "llm_prefill_16": 1,
+                        "llm_prefill_32": 1, "llm_decode": 1}
+    assert not named(traced_engine["spans"], "engine.compile")
+    for comp in named(setup, "engine.compile"):
+        under = compiles_under(setup, comp)
+        assert under and all(inside(c, comp) for c in under)
+        assert f"jit({comp['args']['program']})" in {
+            c["args"]["program"] for c in under}
+    for c in named(setup, "jax.compile"):
+        assert c["args"]["event"] in ("compile", "cache_hit")
+        assert c["end"] - c["start"] == pytest.approx(c["args"]["seconds"])
+
+
+def test_a_late_compile_warns_with_the_programs_name(gpt2, caplog):
+    import logging
+
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    model, params, _cfg = gpt2
+    eng = LLMEngine(model, params, max_slots=2, page_size=8, max_ctx=64)
+    try:
+        obs.drain_spans()
+        with caplog.at_level(logging.WARNING,
+                             logger="ray_tpu.serve.llm_engine"):
+            # the first request: its prefill and decode programs are set-up
+            eng.result(eng.submit([1, 2, 3, 4, 5], 3), timeout=300)
+            first = obs.drain_spans()
+            assert not caplog.records
+            before = eng.stats()
+            # the same bucket again: nothing compiles, nothing is recorded
+            eng.result(eng.submit([5, 4, 3, 2, 1, 0], 3), timeout=300)
+            assert eng.stats()["compiles"] == before["compiles"]
+            assert obs.drain_spans() == []
+            # a bucket first reached after decode steps have emitted
+            eng.result(eng.submit(list(range(19)), 3), timeout=300)
+            late = obs.drain_spans()
+        after = eng.stats()
+    finally:
+        eng.close()
+    assert Counter(s["args"]["program"] for s in named(
+        first, "engine.compile")) == {"llm_prefill_8": 1, "llm_decode": 1}
+    [comp] = named(late, "engine.compile")
+    assert comp["args"]["program"] == "llm_prefill_32"
+    [warning] = [r.getMessage() for r in caplog.records]
+    assert "llm_prefill_32" in warning
+    # stats() counts the process's compile requests, and their seconds
+    assert after["compiles"] - before["compiles"] == \
+        len(named(late, "jax.compile")) >= 1
+    assert after["compile_s"] - before["compile_s"] == pytest.approx(
+        sum(s["args"]["seconds"] for s in named(late, "jax.compile")))
+
+
+def test_the_train_steps_first_call_is_one_train_compile_span():
+    import jax.numpy as jnp
+
+    from ray_tpu._private import jax_env
+    from ray_tpu.train.jax import compile_donated_step
+
+    def sgd_step(w, x):
+        return w - 0.1 * x.sum(), (w * w).sum()
+
+    step = compile_donated_step(sgd_step, carry_argnums=(0,))
+    jax_env.ensure_compile_listener()  # a second time: still one listener
+    w, x = jnp.ones((4,)), jnp.ones((3,))
+    obs.drain_spans()
+    w, loss = step(w, x)
+    first = obs.drain_spans()
+    w, loss = step(w, x)
+    assert obs.drain_spans() == [] and float(loss) > 0
+    [comp] = named(first, "train.compile")
+    assert comp["args"] == {"program": "sgd_step"}
+    [under] = compiles_under(first, comp)
+    assert under["args"]["program"] == "jit(sgd_step)"
+    # the jitted step's own attributes, which the benchmark reads
+    assert step._cache_size() == 1
+    assert step.lower(w, x).as_text()
+
+
+def test_each_compile_request_is_one_span_and_one_count(shutdown_only):
+    import jax
+
+    import ray_tpu
+    from ray_tpu._private import jax_env
+    from ray_tpu.util.metrics import Counter as MetricCounter
+
+    ray_tpu.init(num_cpus=1)
+    jax_env.ensure_compile_listener()
+    requests = MetricCounter("jax_compiles_total")
+    seconds = MetricCounter("jax_compile_seconds_total")
+
+    def counted():
+        return (requests.value({"cache": "hit"})
+                + requests.value({"cache": "miss"}), seconds.value())
+
+    @jax.jit
+    def twice_compiled(x):
+        return x * 2 + 1
+
+    obs.drain_spans()
+    n0, s0 = counted()
+    t0 = jax_env.compile_totals()
+    for n in (3, 5, 5):  # two shapes: two compile requests
+        twice_compiled(np.ones((n,), np.float32))
+    spans = named(obs.drain_spans(), "jax.compile")
+    assert [s["args"]["program"] for s in spans] == \
+        ["jit(twice_compiled)"] * 2
+    n1, s1 = counted()
+    assert n1 - n0 == 2
+    took = sum(s["args"]["seconds"] for s in spans)
+    assert s1 - s0 == pytest.approx(took)
+    t1 = jax_env.compile_totals()
+    assert t1["compiles"] - t0["compiles"] == 2
+    assert t1["compile_s"] - t0["compile_s"] == pytest.approx(took)
+
+
+def test_put_get_and_tasks_after_set_up_record_nothing(shutdown_only):
+    """The off path stays free where it is hot: with the flag off, 200
+    put/get pairs and a task after set-up append nothing to the driver's
+    ring, the worker's or the head's store."""
+    import ray_tpu
+    from ray_tpu.util.testing import wait_for_condition
+
+    ray_tpu.init(num_cpus=1)
+
+    @ray_tpu.remote
+    def ring_length():
+        from ray_tpu import observability as o
+
+        return len(o.ring())
+
+    ray_tpu.get(ring_length.remote())  # set-up: the worker is up
+    wait_for_condition(lambda: obs.session_spans("runtime.worker_start"))
+    obs.drain_spans()
+    store = ray_tpu._head.trace_store
+    ingested = store.spans_ingested
+    for i in range(200):
+        assert ray_tpu.get(ray_tpu.put(i)) == i
+    assert ray_tpu.get(ring_length.remote()) == 0
+    assert len(obs.ring()) == 0 and store.spans_ingested == ingested
+
+
+@pytest.mark.timeout(300)
+def test_a_served_model_leaves_its_set_up_in_the_session(shutdown_only):
+    """A tiny ``LLMServer`` behind ``serve.run``, the flag off: what
+    ``session_spans()`` holds after ``shutdown()``."""
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.serve.llm_engine import LLMServer
+    from ray_tpu.util.testing import wait_for_condition
+
+    ray_tpu.init(num_cpus=2)
+    handle = serve.run(serve.deployment(
+        LLMServer, name="tiny_llm", num_replicas=1).bind(
+            "gpt2", {}, seed=0, max_slots=2, page_size=8, max_ctx=64))
+
+    def call(method, *args):
+        return ray_tpu.get(handle.method(method).remote(*args), timeout=240)
+
+    for prompt in ([1, 2, 3], [3, 2, 1, 0]):  # twice the same bucket
+        assert len(call("__call__", {"tokens": prompt,
+                                     "max_new_tokens": 3})["tokens"]) == 3
+    stats = call("stats")
+    # the worker's flusher has handed everything over
+    wait_for_condition(lambda: len(obs.session_spans(
+        "jax.compile")) >= stats["compiles"], timeout=30)
+    serve.shutdown()
+    ray_tpu.shutdown()
+
+    spans = obs.session_spans()
+    once = {name: named(spans, name) for name in LIFECYCLE_ONCE}
+    assert {name: len(found) for name, found in once.items()} == \
+        dict.fromkeys(LIFECYCLE_ONCE, 1)
+    assert all(s["trace_id"] is None for s in spans)
+    assert once["runtime.init"][0]["args"] == {"mode": "head"}
+    assert once["serve.deploy"][0]["args"] == {
+        "deployment": "tiny_llm", "replicas": 1}
+    build = once["model.build"][0]
+    assert build["args"]["model_kind"] == "gpt2"
+    assert build["args"]["param_bytes"] == 4 * build["args"]["param_count"]
+    started = named(spans, "runtime.worker_start")
+    assert any(s["args"]["for"] == "_Replica.__init__" for s in started)
+    # what lies inside what, across processes, on one clock
+    replica = once["serve.replica_init"][0]
+    assert inside(replica, once["serve.deploy"][0])
+    assert inside(build, replica)
+    assert inside(once["engine.init"][0], replica)
+    assert build["end"] <= once["engine.init"][0]["start"]
+    assert replica["proc"] != once["serve.deploy"][0]["proc"]
+    # one engine.compile a program, none for the second request
+    programs = Counter(s["args"]["program"]
+                       for s in named(spans, "engine.compile"))
+    assert programs == {"llm_prefill_8": 1, "llm_decode": 1}
+    for comp in named(spans, "engine.compile"):
+        assert compiles_under(spans, comp)
+    in_worker = [s for s in named(spans, "jax.compile")
+                 if s["proc"] == replica["proc"]]
+    assert len(in_worker) == stats["compiles"]
+    assert obs.session_spans_dropped() == 0
+
+
+@pytest.mark.timeout(300)
+def test_a_train_gang_leaves_its_set_up_in_the_session(shutdown_only):
+    import ray_tpu
+    from ray_tpu.air.config import ScalingConfig
+    from ray_tpu.train import JaxTrainer
+    from ray_tpu.train.jax.config import JaxConfig
+
+    ray_tpu.init(num_cpus=4, object_store_memory=256 * 1024**2)
+
+    def loop(config):
+        from ray_tpu.air import session
+
+        session.report({"rank": session.get_world_rank()})
+
+    result = JaxTrainer(
+        loop, jax_config=JaxConfig(platform="cpu", local_device_count=1),
+        scaling_config=ScalingConfig(num_workers=2)).fit()
+    assert result.error is None
+    ray_tpu.shutdown()
+    [gang] = obs.session_spans("train.worker_group_start")
+    [meet] = obs.session_spans("train.rendezvous")
+    assert gang["args"] == {"workers": 2}
+    assert meet["args"] == {"world": 2, "platform": "cpu"}
+    assert inside(meet, gang)
+    assert len(obs.session_spans("runtime.worker_start")) >= 2
