@@ -253,14 +253,18 @@ class LlamaMoE(nn.Module):
     """The block's FFN as ``num_experts`` SwiGLU experts, each token
     through its ``num_experts_per_tok`` best, none dropped
     (``ops/moe.py``).  Three stacked leaves a layer and the router.  The
-    chosen experts of every token are sown into the ``moe`` collection
-    (``expert_idx``, [B, L, k]) for a caller that asks for it
-    (``mutable=["moe"]``): the serve engine counts the experts a decode
-    step touched from them; any other caller pays nothing."""
+    chosen experts of every token (``expert_idx``, [B, L, k]) and the
+    number of experts whose weights the layer streamed
+    (``experts_streamed``) are sown into the ``moe`` collection for a
+    caller that asks for it (``mutable=["moe"]``): the serve engine counts
+    the experts a decode step touched and read from them; any other caller
+    pays nothing.  ``active`` [B] bool (default: all) marks the sequences
+    that are live: the layer adds zero for any other, and reads no expert
+    on its behalf."""
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, active=None):
         c = self.config
         d, e, f = c.hidden_size, c.num_experts, c.expert_dim
         router = self.param("router", nn.initializers.lecun_normal(),
@@ -276,8 +280,10 @@ class LlamaMoE(nn.Module):
         self.sow("moe", "expert_idx",
                  experts.reshape(B, L, c.num_experts_per_tok))
         with jax.named_scope("experts"):
-            out = experts_dropless(rows, weights, experts, w_gate, w_up,
-                                   w_down)
+            out, streamed = experts_dropless(
+                rows, weights, experts, w_gate, w_up, w_down,
+                active=None if active is None else jnp.repeat(active, L))
+        self.sow("moe", "experts_streamed", streamed)
         return out.reshape(B, L, d)
 
 
@@ -285,7 +291,7 @@ class LlamaBlock(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, kv=None, positions=None):
+    def __call__(self, x, kv=None, positions=None, active=None):
         c = self.config
         attn = LlamaAttention(c, name="attn")(
             _norm(c, "attn_norm")(x), kv=kv, positions=positions)
@@ -293,9 +299,11 @@ class LlamaBlock(nn.Module):
         if kv is not None:
             attn, new_kv = attn
         x = x + attn
-        ffn = LlamaMoE(c, name="moe") if c.num_experts else \
-            LlamaMLP(c, name="mlp")
-        x = x + ffn(_norm(c, "mlp_norm")(x))
+        h = _norm(c, "mlp_norm")(x)
+        if c.num_experts:
+            x = x + LlamaMoE(c, name="moe")(h, active=active)
+        else:
+            x = x + LlamaMLP(c, name="mlp")(h)
         return x if kv is None else (x, new_kv)
 
 
@@ -304,12 +312,13 @@ class Llama(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids: jax.Array, positions: jax.Array = None,
-                 kv_caches=None):
+                 kv_caches=None, active=None):
         """Full-context: input_ids [B, L] → logits [B, L, vocab].  With
         ``kv_caches`` (per-layer ``attend(q, k, v)`` callables over the
         caller's cache, k and v at num_kv_heads) and absolute
         ``positions``: incremental decode, returning (logits, new_kvs) —
-        the same contract as GPT2."""
+        the same contract as GPT2.  ``active`` [B] bool: the live sequences
+        of a decode step, for the expert layers (``LlamaMoE``)."""
         c = self.config
         emb = nn.Embed(c.vocab_size, c.hidden_size, dtype=c.dtype,
                        param_dtype=c.param_dtype, name="embed")
@@ -319,7 +328,7 @@ class Llama(nn.Module):
         for i in range(c.num_layers):
             if decode:
                 x, nkv = LlamaBlock(c, name=f"layer_{i}")(
-                    x, kv=kv_caches[i], positions=positions)
+                    x, kv=kv_caches[i], positions=positions, active=active)
                 new_kvs.append(nkv)
             else:
                 x = LlamaBlock(c, name=f"layer_{i}")(x)

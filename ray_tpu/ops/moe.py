@@ -8,11 +8,19 @@ every token reaches every expert it chose, whatever the load; SwiGLU
 experts; the softmax over all experts, the chosen weights renormalised only
 on request.  What a published sparse model computes, so what serving runs:
 ``models/llama.py`` with ``num_experts`` set (OLMoE through ``LLMServer``).
-Two exact forms, chosen from the static row count alone: few rows compute
-every expert on every row and mask (a decode step is bound by the weights
-it streams, and the masked einsum is one pass over them); many rows are
-sorted by expert and multiplied group by group (``lax.ragged_dot``, which
-the TPU compiler turns into a grouped-matmul kernel of its own).
+Two exact forms, chosen from the static row count alone:
+
+- up to ``DENSE_MAX_ROWS`` rows (a decode step: one row a slot; the shorter
+  prefill buckets) the Pallas kernel ``moe_hit`` runs over a list, made in
+  the same program, of the experts that some live row chose, and fetches
+  those experts' weights and no other's.  A decode step is bound by the
+  weights it streams, and few rows choose few experts: 4 live rows of
+  OLMoE's choose 8 of 64 each and hit ~29 of them.  The caller may say
+  which rows are live (``active``: a free slot of the serve engine chooses
+  nothing).  With every expert hit it is one pass over all the weights;
+- above, the rows are sorted by expert and multiplied group by group
+  (``lax.ragged_dot``, which the TPU compiler turns into a grouped-matmul
+  kernel of its own).
 
 **Capacity-factor** (``moe_apply`` and its expert-parallel twin
 ``moe_apply_expert_parallel``): GShard/Switch dense dispatch/combine
@@ -36,6 +44,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,14 +182,28 @@ def init_moe_params(key, d_model: int, d_ff: int, cfg: MoEConfig):
 # Dropless MoE (serving: logits must equal the reference's, so no capacity)
 # ---------------------------------------------------------------------------
 
-# Rows up to which every expert is computed on every row.  The masked form
-# does E/k times the needed arithmetic but reads each weight once with no
-# sort; the grouped kernel works in tiles of 512 rows an expert, so for few
-# rows it multiplies more padding than the masked form multiplies zeros.
-# One OLMoE layer (64 experts of 2048 x 1024, top-8) on the v5e, ms: masked
-# 1.25 up to 256 rows (the weights' stream), 2.33 at 512, 4.53 at 1024;
-# grouped 2.6-2.8 up to 256, 3.10 at 512, 3.74 at 1024 (PERF.md, PR 27).
+# Rows up to which the experts run through ``moe_hit``, the kernel that
+# fetches only the experts some live row chose; above, the rows are grouped.
+# One OLMoE layer (64 experts of 2048 x 1024, top-8) on the v5e, ms
+# (PERF.md, PR 42): ``moe_hit`` at 16 rows 0.158 with 8 experts hit, 0.289
+# with 16, 0.617 with 36, 0.814 with 48, 1.075 with all 64 (0.03 + 0.0164
+# an expert: its 12.6 MB at 767 GB/s); with all 64 hit 1.08 at 32, 64 and
+# 128 rows, 1.12 at 256, 2.15 at 512, where an expert's three matmuls
+# take longer than its weights' copy.  The grouped kernel works in tiles
+# of 512 rows an expert: 2.6-2.8 up to 256 rows, 3.10 at 512, 3.74 at 1024
+# (PR 27).  Until PR 42 the rows up to here computed every expert on every
+# row and masked, one pass over all the weights whatever was hit: 1.17 up
+# to 64 rows, 1.30 at 128, 1.45 at 256, 2.43 at 512; that form is now
+# ``tests/test_moe.py``'s reference.
 DENSE_MAX_ROWS = 512
+
+# Bytes of one weight tile of a ``moe_hit`` grid step (hidden x a tile of
+# the expert's width).  A step holds three, twice over (the pipeline
+# fetches the next step's while this one's are multiplied): 12.6 MB of VMEM
+# at OLMoE's hidden 2048, in tiles of 512 columns.  With all 64 hit, tiles
+# of 256 columns read 1.150 ms, 512 read 1.079, the whole 1024 read 1.080.
+HIT_TILE_BYTES = 2 << 20
+_SUBLANES = 16  # rows of a bfloat16 tile: x and the accumulator are padded
 
 
 def route_topk(x: jax.Array, w_router: jax.Array, top_k: int,
@@ -200,27 +224,141 @@ def expert_rows(experts: jax.Array, num_experts: int) -> jax.Array:
         experts.reshape(-1)].add(1)
 
 
+def hit_order(chosen: jax.Array):
+    """chosen [N, E] bool (row n chose expert e, and is live) → (order [E]
+    int32, n_hit [1] int32): the experts some row chose, ascending, in the
+    first ``n_hit`` places of ``order`` and zeros behind them.  Compares
+    and sums over [E, E], no sort and no scatter."""
+    e = chosen.shape[1]
+    hit = jnp.any(chosen, axis=0)
+    place = jnp.cumsum(hit) - 1  # a hit expert's position in the order
+    ids = jnp.arange(e, dtype=jnp.int32)
+    at = hit[None, :] & (place[None, :] == ids[:, None])  # [position, e]
+    order = jnp.sum(jnp.where(at, ids[None, :], 0), axis=1)
+    return order.astype(jnp.int32), jnp.sum(hit, dtype=jnp.int32)[None]
+
+
+def _moe_hit_kernel(order_ref, n_hit_ref,  # SMEM
+                    x_ref, combine_ref, w_gate_ref, w_up_ref, w_down_ref,
+                    out_ref):
+    """Grid step (p, j): tile j of the width of expert ``order[p]``.
+    x_ref [N, d] and combine_ref [N, E] whole; w_gate_ref / w_up_ref
+    [d, tile], w_down_ref [tile, d] of that expert; out_ref [N, d] float32,
+    the same block at every step: the accumulator, written back once."""
+    p, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((p == 0) & (j == 0))
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(p < n_hit_ref[0])  # past the list: nothing fetched, nothing done
+    def _():
+        x = x_ref[...]
+        f32 = jnp.float32
+        g = jnp.dot(x, w_gate_ref[...], preferred_element_type=f32)
+        u = jnp.dot(x, w_up_ref[...], preferred_element_type=f32)
+        # This expert's column of the combine weights, [N, 1].
+        lane = lax.broadcasted_iota(jnp.int32, combine_ref.shape, 1)
+        c = jnp.sum(jnp.where(lane == order_ref[p], combine_ref[...], 0.0),
+                    axis=1, keepdims=True)
+        h = (g * jax.nn.sigmoid(g) * u * c).astype(x.dtype)
+        out_ref[...] += jnp.dot(h, w_down_ref[...],
+                                preferred_element_type=f32)
+
+
+def _tile_of(f: int, most: int) -> int:
+    """The widest tile of whole 128-lane registers that divides ``f`` and
+    is at most ``most`` (or is one register); the whole width where there
+    is none."""
+    for tile in range(min(max(most, 128), f) // 128 * 128, 0, -128):
+        if f % tile == 0:
+            return tile
+    return f
+
+
+def moe_hit(x: jax.Array, combine: jax.Array, order: jax.Array,
+            n_hit: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+            w_down: jax.Array) -> jax.Array:
+    """sum over the first ``n_hit`` experts e of ``order`` of
+    (silu(x Wg[e]) * (x Wu[e]) * combine[:, e]) Wd[e] → [N, d] float32.
+    The block of the weights a grid step works on is looked up in
+    ``order``, so the pipeline copies the listed experts' tiles from HBM
+    and no other's; places at or past ``n_hit`` name the last real step's
+    block again, which is not fetched twice, and compute nothing."""
+    n, d = x.shape
+    e, _, f = w_gate.shape
+    size = x.dtype.itemsize
+    tile = _tile_of(f, HIT_TILE_BYTES // (d * size))
+    tiles = f // tile
+    pad = -n % _SUBLANES
+    x = jnp.pad(x, ((0, pad), (0, 0)))
+    combine = jnp.pad(combine, ((0, pad), (0, 0)))
+    rows = n + pad
+
+    def block(p, j, order_ref, n_hit_ref):
+        last = jnp.maximum(n_hit_ref[0], 1) - 1
+        return (order_ref[jnp.minimum(p, last)],
+                jnp.where(p < n_hit_ref[0], j, tiles - 1))
+
+    def up_block(p, j, order_ref, n_hit_ref):
+        expert, col = block(p, j, order_ref, n_hit_ref)
+        return expert, 0, col
+
+    def down_block(p, j, order_ref, n_hit_ref):
+        expert, row = block(p, j, order_ref, n_hit_ref)
+        return expert, row, 0
+
+    whole = lambda shape: pl.BlockSpec(  # noqa: E731
+        shape, lambda p, j, *_: (0, 0))
+    # Two buffers a weight tile; x, combine and the accumulator; g, u and h.
+    vmem = (2 * 3 * d * tile * size + 2 * rows * (d * (size + 4) + e * 4)
+            + 3 * rows * tile * 4)
+    out = pl.pallas_call(
+        _moe_hit_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(e, tiles),
+            in_specs=[whole((rows, d)), whole((rows, e)),
+                      pl.BlockSpec((None, d, tile), up_block),
+                      pl.BlockSpec((None, d, tile), up_block),
+                      pl.BlockSpec((None, tile, d), down_block)],
+            out_specs=whole((rows, d))),
+        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem + (8 << 20)),
+        name="moe_hit",
+        interpret=jax.default_backend() == "cpu",
+    )(order, n_hit, x, combine.astype(jnp.float32), w_gate, w_up, w_down)
+    return out[:n]
+
+
 def experts_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
-                     w_gate: jax.Array, w_up: jax.Array,
-                     w_down: jax.Array) -> jax.Array:
+                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                     active: Optional[jax.Array] = None):
     """sum_j weights[n, j] * down_e(silu(gate_e(x_n)) * up_e(x_n)) with
     e = experts[n, j]: x [N, d], w_gate / w_up [E, d, f], w_down [E, f, d]
-    → [N, d] in x's dtype.  Products in x's dtype, sums in fp32.  N (a
-    static shape) picks the form; both are exact, no token is dropped."""
+    → ([N, d] in x's dtype, the experts whose weights were streamed, int32).
+    Products in x's dtype, sums in fp32.  ``active`` [N] bool (default: all)
+    marks the live rows: any other row weighs nothing, gets zeros and
+    counts for no expert.  N (a static shape) picks the form; both are
+    exact, no token is dropped."""
     n, d = x.shape
     e, k = w_gate.shape[0], experts.shape[1]
     f32 = jnp.float32
     w_gate, w_up, w_down = (w.astype(x.dtype) for w in (w_gate, w_up, w_down))
+    if active is not None:
+        weights = jnp.where(active[:, None], weights, 0.0)
     if n <= DENSE_MAX_ROWS:
         # [N, E] combine weights, zero where an expert was not chosen.
-        combine = jnp.zeros((n, e), f32).at[
-            jnp.arange(n)[:, None], experts].add(weights)
-        g = jnp.einsum("nd,edf->enf", x, w_gate, preferred_element_type=f32)
-        u = jnp.einsum("nd,edf->enf", x, w_up, preferred_element_type=f32)
-        h = (jax.nn.silu(g) * u * combine.T[:, :, None]).astype(x.dtype)
-        out = jnp.einsum("enf,efd->nd", h, w_down,
-                         preferred_element_type=f32)
-        return out.astype(x.dtype)
+        chose = experts[:, :, None] == jnp.arange(e, dtype=experts.dtype)
+        combine = jnp.sum(jnp.where(chose, weights[:, :, None], 0.0), axis=1)
+        chosen = jnp.any(chose, axis=1)
+        if active is not None:
+            chosen &= active[:, None]
+        order, n_hit = hit_order(chosen)
+        out = moe_hit(x, combine, order, n_hit, w_gate, w_up, w_down)
+        return out.astype(x.dtype), n_hit[0]
     # Sort the N*k (row, expert) assignments by expert; each expert then
     # owns one contiguous group of rows, of any size.
     order = jnp.argsort(experts.reshape(-1), stable=True)
@@ -234,7 +372,9 @@ def experts_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
     back = jnp.zeros_like(order).at[order].set(
         jnp.arange(n * k, dtype=order.dtype))
     y = y[back].reshape(n, k, d) * weights[:, :, None]
-    return jnp.sum(y, axis=1).astype(x.dtype)
+    # Which tiles the compiler's grouped kernel fetches for an expert with
+    # no row has not been looked at: counted as every expert.
+    return jnp.sum(y, axis=1).astype(x.dtype), jnp.asarray(e, jnp.int32)
 
 
 def moe_dropless(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
@@ -243,5 +383,5 @@ def moe_dropless(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     """Dropless top-k MoE on a flat token batch: x [N, d] → ([N, d], rows
     per expert [E] int32)."""
     weights, experts = route_topk(x, w_router, top_k, norm_topk_prob)
-    out = experts_dropless(x, weights, experts, w_gate, w_up, w_down)
+    out, _ = experts_dropless(x, weights, experts, w_gate, w_up, w_down)
     return out, expert_rows(experts, w_router.shape[1])

@@ -211,21 +211,28 @@ def _routes(model) -> bool:
 
 
 def _experts_touched(sown, active, num_experts):
-    """From what a decode step's expert layers sowed ([slots, 1, k] chosen
-    experts a layer): int32 [2], summed over layers, of the experts that
-    got at least one row of an active slot, and of the busiest expert's
-    rows.  A free lane's garbage row counts for nothing."""
+    """From what a decode step's expert layers sowed (a layer: the chosen
+    experts ``expert_idx`` [slots, 1, k], and ``experts_streamed``, how
+    many experts' weights it read): int32 [3], summed over layers, of the
+    experts that got at least one row of an active slot, of the busiest
+    expert's rows, and of the experts streamed.  A free lane's garbage row
+    counts for nothing."""
     import jax
     import jax.numpy as jnp
+    from flax import traverse_util
 
-    hit = busiest = 0
-    for idx in jax.tree_util.tree_leaves(sown):
-        rows = jnp.sum(jax.nn.one_hot(idx[:, 0], num_experts,
-                                      dtype=jnp.int32)
-                       * active[:, None, None], axis=(0, 1))
-        hit += jnp.sum(rows > 0)
-        busiest += jnp.max(rows)
-    return jnp.stack([hit, busiest]).astype(jnp.int32)
+    hit = busiest = streamed = 0
+    for path, sowed in traverse_util.flatten_dict(sown).items():
+        for value in sowed:
+            if path[-1] == "experts_streamed":
+                streamed += value
+            elif path[-1] == "expert_idx":
+                rows = jnp.sum(jax.nn.one_hot(value[:, 0], num_experts,
+                                              dtype=jnp.int32)
+                               * active[:, None, None], axis=(0, 1))
+                hit += jnp.sum(rows > 0)
+                busiest += jnp.max(rows)
+    return jnp.stack([hit, busiest, streamed]).astype(jnp.int32)
 
 
 def _cfg(name, given, fallback):
@@ -838,12 +845,15 @@ class LLMEngine:
             "work_seconds": self._work_s,
         }
         if self._moe_experts:
-            # Of the experts a step could touch, the share it did; and the
+            # Of the experts a step could touch, the share it did, and the
+            # share whose weights it read (the same, where the expert
+            # layer follows the step's own list: ``ops/moe.py``); and the
             # busiest expert's share of a step's assignments (1/E when
             # routing is even): means over the decode steps so far.
-            out["moe_experts_hit_share"] = (
-                s.get("moe_experts_hit", 0) / (steps * self._moe_experts)
-                if steps else 0.0)
+            for key in ("moe_experts_hit", "moe_experts_streamed"):
+                out[key + "_share"] = (
+                    s.get(key, 0) / (steps * self._moe_experts)
+                    if steps else 0.0)
             out["moe_max_expert_share"] = (
                 self._moe_busiest_share_sum / steps if steps else 0.0)
         if self._prefix is not None:
@@ -921,9 +931,11 @@ class LLMEngine:
                  state=None):
             if fresh is not None:
                 tokens = jnp.where(fresh, tokens, prev_tokens)
-            # recurrent state: advanced where ``active``
-            carried = {} if state is None else {"state": state,
-                                                "active": active}
+            # recurrent state: advanced where ``active``; expert layers:
+            # only the experts an ``active`` row chose are read
+            carried = {} if state is None else {"state": state}
+            if routes or state is not None:
+                carried["active"] = active
             with scope("attend"):
                 first = None
                 if window_pages is not None and window_pages < pp:
@@ -1903,9 +1915,11 @@ class LLMEngine:
             nxt = np.asarray(step.tokens)
             lps = np.asarray(step.logps)
             if step.touched is not None:  # see _experts_touched
-                hit, busiest = (int(v) for v in np.asarray(step.touched))
-                sp.set(experts_hit=hit)
+                hit, busiest, streamed = (
+                    int(v) for v in np.asarray(step.touched))
+                sp.set(experts_hit=hit, experts_streamed=streamed)
                 self._stats["moe_experts_hit"] += hit
+                self._stats["moe_experts_streamed"] += streamed
                 self._moe_busiest_share_sum += busiest / (
                     n_rows * self._moe_choices)
         self._stats["steps"] += 1

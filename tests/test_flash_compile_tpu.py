@@ -1,5 +1,6 @@
-"""The flash kernels compiled for a described TPU v5e at the widths the
-train cells and the Llama-family models run, without a chip: what Mosaic
+"""The flash kernels, and the expert FFN's ``moe_hit``, compiled for a
+described TPU v5e at the widths the train cells, the Llama-family models
+and OLMoE's decode step run, without a chip: what Mosaic
 refuses (a block that does not fit VMEM, a slice off the tiling) fails here
 and costs no chip time.  Nothing runs, so this gives no result and no time.
 
@@ -63,3 +64,28 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, dtype, kernels):
     whole = _whole_head_fits(length, length, d, jnp.dtype(dtype).itemsize,
                              *_auto_blocks(length, length, d, True), True)
     assert whole == ("flash_bwd" in kernels)
+
+
+@pytest.mark.timeout(120)
+@pytest.mark.parametrize("rows,experts,d,f", [
+    (16, 64, 2048, 1024),   # OLMoE's decode step: 16 slots, one token each
+    (512, 64, 2048, 1024),  # its 512-row prefill bucket: the most rows
+    (1, 8, 1024, 3584),     # one row; a width that 1024 does not divide
+])
+def test_moe_hit_compiles_for_v5e(one_chip, monkeypatch, rows, experts, d, f):
+    """The kernel as ``experts_dropless`` calls it for few rows, with the
+    list made in the same program.  (``jax.default_backend`` is this
+    process's, the CPU: said to be the chip's, so the kernel is compiled
+    and not interpreted.)"""
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    assert rows <= moe.DENSE_MAX_ROWS
+    text = jax.jit(moe.experts_dropless).lower(
+        shape(rows, d), shape(rows, 8, dtype=jnp.float32),
+        shape(rows, 8, dtype=jnp.int32), shape(experts, d, f),
+        shape(experts, d, f), shape(experts, f, d),
+        shape(rows, dtype=jnp.bool_)).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_hit" in text

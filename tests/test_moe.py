@@ -1,6 +1,7 @@
 """MoE / expert parallelism (SURVEY §2.4 EP — net-new TPU scope, no
 reference equivalent): routing math, all_to_all dispatch equivalence on an
-8-device CPU mesh, and the MoE-GPT2 model end to end."""
+8-device CPU mesh, the MoE-GPT2 model end to end, and the dropless op's
+``moe_hit`` kernel (interpreted here) against its two other forms."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -138,3 +139,136 @@ def test_moe_gpt2_shards_over_expert_axis():
     with mesh:
         loss = float(jax.device_get(loss_fn(params, ids)))
     assert np.isfinite(loss)
+
+
+# ---- the dropless op's two forms (ops/moe.py) -----------------------------
+def _dropless_inputs(seed, n, d, e, f, k, dtype=jnp.float32):
+    from ray_tpu.ops import moe
+
+    rng = np.random.default_rng(seed)
+    mk = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape) / np.sqrt(shape[-2]), dtype)
+    x = jnp.asarray(rng.standard_normal((n, d)), dtype)
+    weights, experts = moe.route_topk(x, mk(d, e), k)
+    return x, weights, experts, mk(e, d, f), mk(e, d, f), mk(e, f, d)
+
+
+def _masked(x, weights, experts, w_gate, w_up, w_down, active):
+    """The reference: every expert on every row, masked by the combine
+    weights (what ``experts_dropless`` ran for few rows until PR 42).
+    Products in x's dtype, sums in float32."""
+    n, e, f32 = x.shape[0], w_gate.shape[0], jnp.float32
+    weights = jnp.where(active[:, None], weights, 0.0)
+    combine = jnp.zeros((n, e), f32).at[
+        jnp.arange(n)[:, None], experts].add(weights)
+    g = jnp.einsum("nd,edf->enf", x, w_gate, preferred_element_type=f32)
+    u = jnp.einsum("nd,edf->enf", x, w_up, preferred_element_type=f32)
+    h = (jax.nn.silu(g) * u * combine.T[:, :, None]).astype(x.dtype)
+    return jnp.einsum("enf,efd->nd", h, w_down,
+                      preferred_element_type=f32).astype(x.dtype)
+
+
+def _form(monkeypatch, form, tile=512, itemsize=4, d=32):
+    """Make ``experts_dropless`` take one form whatever the row count,
+    the kernel in tiles of ``tile`` columns."""
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "DENSE_MAX_ROWS",
+                        {"hit": 1 << 20, "grouped": 0}[form])
+    monkeypatch.setattr(moe, "HIT_TILE_BYTES", tile * d * itemsize)
+    return moe.experts_dropless
+
+
+LIVE = {"none": lambda n: np.zeros(n, bool),
+        "one": lambda n: np.arange(n) == n // 2,
+        "half": lambda n: np.arange(n) % 2 == 0,
+        "all": lambda n: np.ones(n, bool)}
+
+
+@pytest.mark.parametrize("live", list(LIVE))
+@pytest.mark.parametrize("e,f,tile", [
+    (8, 256, 128),   # two tiles of the width an expert
+    (6, 192, 128),   # 128 does not divide 192: the width whole
+    (64, 24, 512),   # the tile wider than the width; experts beyond a row's reach
+])
+def test_hit_kernel_agrees_with_the_masked_and_grouped_forms(
+        monkeypatch, live, e, f, tile):
+    """The same rows, choices and weights through the kernel, the grouped
+    form and the masked reference, with the same rows live: the kernel
+    sums the experts a live row chose and no other, which is what the
+    other two compute by masking; a row that is not live gets zeros in all
+    three.  float32: the orders of summation differ, nothing else."""
+    from ray_tpu.ops import moe
+
+    n, d, k = 12, 32, 2
+    args = _dropless_inputs(e + f, n, d, e, f, k)
+    active = jnp.asarray(LIVE[live](n))
+    got, streamed = _form(monkeypatch, "hit", tile)(*args, active=active)
+    assert moe._tile_of(f, tile) == (tile if f % tile == 0 else f)
+    chosen = {int(v) for v in np.asarray(args[2])[np.asarray(active)].ravel()}
+    assert int(streamed) == len(chosen)
+    grouped, read = _form(monkeypatch, "grouped")(*args, active=active)
+    assert int(read) == e
+    for want in (_masked(*args, active), grouped):
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
+    assert not np.asarray(got)[~np.asarray(active)].any()
+    if live == "all":  # no mask is all rows live
+        whole, _ = _form(monkeypatch, "hit", tile)(*args)
+        np.testing.assert_array_equal(whole, got)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_an_expert_no_live_row_chose_is_never_read(monkeypatch, dtype):
+    """Every expert that no live row chose, NaN throughout: the kernel's
+    answer is bit for bit its answer on clean weights, and the masked
+    reference's on clean weights to rounding; the masked reference itself,
+    which multiplies every expert, answers NaN."""
+    n, d, e, f, k = 16, 32, 16, 256, 2
+    x, weights, experts, *clean = _dropless_inputs(3, n, d, e, f, k, dtype)
+    active = jnp.arange(n) % 4 == 0
+    unhit = np.ones(e, bool)
+    unhit[np.asarray(experts)[np.asarray(active)].ravel()] = False
+    assert 0 < unhit.sum() < e
+    poisoned = [jnp.where(unhit[:, None, None], jnp.nan, w) for w in clean]
+    hit = _form(monkeypatch, "hit", 128, jnp.dtype(dtype).itemsize)
+    got, streamed = hit(x, weights, experts, *poisoned, active=active)
+    on_clean, _ = hit(x, weights, experts, *clean, active=active)
+    np.testing.assert_array_equal(got, on_clean)
+    assert int(streamed) == e - unhit.sum() and got.dtype == dtype
+    tol = 2e-5 if dtype == jnp.float32 else 0.02
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(_masked(x, weights, experts, *clean, active), np.float32),
+        atol=tol, rtol=tol)
+    bad = _masked(x, weights, experts, *poisoned, active)
+    assert np.isnan(np.asarray(bad, np.float32)).any()
+
+
+def test_hit_order_lists_the_chosen_experts_first():
+    from ray_tpu.ops import moe
+
+    chosen = np.zeros((5, 9), bool)
+    chosen[0, [7, 2]] = chosen[3, [2, 4]] = True
+    order, n_hit = moe.hit_order(jnp.asarray(chosen))
+    assert n_hit.tolist() == [3] and order.tolist() == [2, 4, 7] + [0] * 6
+    order, n_hit = moe.hit_order(jnp.zeros((5, 9), bool))
+    assert n_hit.tolist() == [0] and order.tolist() == [0] * 9
+    order, n_hit = moe.hit_order(jnp.ones((1, 9), bool))
+    assert n_hit.tolist() == [9] and order.tolist() == list(range(9))
+
+
+def test_the_row_count_alone_picks_the_form(monkeypatch):
+    """At most DENSE_MAX_ROWS rows follow the list, one more is grouped:
+    told apart by what each says it streamed (two choices a row cannot
+    reach every expert of so many, and the grouped form is counted as
+    reading them all)."""
+    from ray_tpu.ops import moe
+
+    assert moe.DENSE_MAX_ROWS == 512
+    monkeypatch.setattr(moe, "DENSE_MAX_ROWS", 12)  # a cheaper boundary
+    for n, follows in ((12, True), (13, False)):
+        args = _dropless_inputs(n, n, 16, 64, 24, 2)
+        _, streamed = jax.jit(moe.experts_dropless)(*args)
+        chosen = len(set(np.asarray(args[2]).ravel().tolist()))
+        assert chosen <= 2 * n < 64
+        assert int(streamed) == (chosen if follows else 64), n

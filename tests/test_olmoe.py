@@ -77,8 +77,8 @@ def _loop(x, w_router, w_gate, w_up, w_down, top_k, norm):
 # ---- ops/moe.py -----------------------------------------------------------
 @pytest.mark.parametrize("n", [5, 512, 513, 700])
 def test_moe_dropless_matches_token_loop(n):
-    """Few rows (masked, every expert), many rows (sorted, grouped), and
-    both sides of the row count where the form changes."""
+    """Few rows (the kernel over the experts hit), many rows (sorted,
+    grouped), and both sides of the row count where the form changes."""
     from ray_tpu.ops import moe
 
     assert moe.DENSE_MAX_ROWS == 512
@@ -91,22 +91,23 @@ def test_moe_dropless_matches_token_loop(n):
 
 
 def test_both_forms_agree_in_bfloat16(monkeypatch):
-    """The same rows through the masked and the grouped form, bfloat16
-    products and float32 sums in both: they differ by rounding only."""
+    """The same rows through the kernel that follows the list of hit
+    experts and through the grouped form, bfloat16 products and float32
+    sums in both: they differ by rounding only."""
     import jax.numpy as jnp
 
     from ray_tpu.ops import moe
 
     args = _moe_weights(np.random.default_rng(1), 40, dtype=jnp.bfloat16)
-    dense, rows_d = moe.moe_dropless(*args, top_k=2)
+    listed, rows_l = moe.moe_dropless(*args, top_k=2)
     monkeypatch.setattr(moe, "DENSE_MAX_ROWS", 0)
     grouped, rows_g = moe.moe_dropless(*args, top_k=2)
-    assert dense.dtype == grouped.dtype == jnp.bfloat16
+    assert listed.dtype == grouped.dtype == jnp.bfloat16
     # one bfloat16 rounding of h (2**-8 relative) and of the output
-    np.testing.assert_allclose(np.asarray(dense, np.float32),
+    np.testing.assert_allclose(np.asarray(listed, np.float32),
                                np.asarray(grouped, np.float32),
                                atol=0.02, rtol=0.02)
-    assert rows_d.tolist() == rows_g.tolist()
+    assert rows_l.tolist() == rows_g.tolist()
 
 
 @pytest.mark.parametrize("n", [7, 600])
@@ -227,6 +228,34 @@ def test_norm_topk_prob_reaches_the_model():
                                atol=2e-4)
 
 
+def test_a_sequence_that_is_not_live_gets_zero_and_reads_no_expert():
+    """``active`` through ``Llama`` to every expert layer: the layer's
+    output for a sequence that is not live is zero, a live one's is what
+    it was, and each layer says it streamed the experts the live chose."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import LlamaConfig, LlamaMoE
+
+    kw = _config_kw("float32")
+    kw.pop("tiny")
+    layer = LlamaMoE(LlamaConfig(**kw))
+    x = jnp.asarray(np.random.default_rng(8).standard_normal((4, 1, 64)),
+                    jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    whole, sown = layer.apply({"params": params}, x, mutable=["moe"])
+    active = jnp.asarray([True, False, False, True])
+    part, sown_part = layer.apply({"params": params}, x, active=active,
+                                  mutable=["moe"])
+    part, whole = np.asarray(part), np.asarray(whole)
+    np.testing.assert_array_equal(part[[0, 3]], whole[[0, 3]])
+    assert not part[[1, 2]].any()
+    chosen = np.asarray(sown["moe"]["expert_idx"][0])[:, 0]  # [4, k]
+    assert int(sown["moe"]["experts_streamed"][0]) == len(set(chosen.ravel()))
+    assert int(sown_part["moe"]["experts_streamed"][0]) == len(
+        set(chosen[[0, 3]].ravel()))
+
+
 # ---- serve/llm_engine.py --------------------------------------------------
 def _drive(eng, rids):
     """The loop thread's work, by hand: deterministic steps."""
@@ -244,8 +273,9 @@ def test_prefill_then_cached_decode_matches_the_references_full_forward(
     reference's one full forward over prompt + answer (log-probabilities,
     not tokens: a rounding flip of an argmax is not an error), the
     ``experts_hit`` of every decode step against the union of the
-    reference's top-k sets of the two tokens of that step, and the
-    shares ``stats()`` reports."""
+    reference's top-k sets of the two tokens of that step, its
+    ``experts_streamed`` against that (two of the four slots stay free),
+    and the shares ``stats()`` reports."""
     import jax
     import jax.numpy as jnp
 
@@ -284,11 +314,17 @@ def test_prefill_then_cached_decode_matches_the_references_full_forward(
     want_hit = [sum(len(set(chosen[0][layer, 12 + t])
                         | set(chosen[1][layer, 12 + t]))
                     for layer in range(2)) for t in range(new - 1)]
-    hits = [s["args"]["experts_hit"] for s in spans
-            if s["name"] == "engine.decode.fetch"]
+    fetches = [s["args"] for s in spans if s["name"] == "engine.decode.fetch"]
+    hits = [a["experts_hit"] for a in fetches]
     assert hits == want_hit and stats["steps"] == new - 1
+    # Two of four slots are free: the expert layers read the experts the two
+    # live rows chose and no other, on every step.
+    assert [a["experts_streamed"] for a in fetches] == hits
+    assert max(hits) <= 2 * 2 * 2 < 2 * 8
     assert stats["moe_experts_hit_share"] == pytest.approx(
         sum(want_hit) / ((new - 1) * 2 * 8))
+    assert stats["moe_experts_streamed_share"] == \
+        stats["moe_experts_hit_share"]
     # Two slots, two choices each: the busiest expert has 1 or 2 of 4.
     assert 0.25 <= stats["moe_max_expert_share"] <= 0.5
     assert stats.get("decode_cache_size", 1) == 1
@@ -309,6 +345,7 @@ def test_free_lanes_touch_no_expert():
     finally:
         eng.close()
     assert stats["moe_experts_hit_share"] == pytest.approx(4 / 16)
+    assert stats["moe_experts_streamed_share"] == pytest.approx(4 / 16)
     assert stats["moe_max_expert_share"] == pytest.approx(0.5)
 
 
