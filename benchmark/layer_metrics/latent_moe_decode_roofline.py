@@ -1,0 +1,35 @@
+"""The routed-expert kernel (``moe_hit_relu2``) in a decode step against the
+memory roofline: the two matrices of every held expert that a live row hit
+(``costs_hybrid_moe.routed_decode_bytes`` of the step's ``experts_hit``,
+summed over the expert layers) over the HBM bandwidth, divided by the device
+time a step spends in the kernel: the ``tpu_custom_call`` rows whose first
+result is ``f32[<slots>,<latent width>]`` (as ``hybrid_paged_attn_roofline``
+tells its kernel by shape; the paged kernel's result has three dimensions,
+and a prefill's call of this kernel has its bucket's rows, never the
+slots').
+
+``experts_hit`` is what the engine says on its ``engine.decode.fetch``
+spans; means over the steps on both sides.  A program without the kernel,
+or a run with no profile, has nothing to read."""
+import statistics
+
+from benchmark import costs_hybrid_moe, program_spans
+
+
+def read(record, ctx):
+    t = record.get("trace") or {}
+    cfg = ctx["config"]
+    if "router_experts" not in cfg or "peak" not in ctx:
+        return None
+    slots = -(-cfg["serve"]["max_slots"] // 16) * 16  # the kernel's padding
+    kernel = f"tpu_custom_call f32[{slots},{cfg['moe_latent_size']}]"
+    spent = (t.get("op_s") or {}).get(kernel, 0.0)
+    steps = sum(len(v) for name, v in (t.get("program_s") or {}).items()
+                if name.endswith("llm_decode"))
+    hit = program_spans.arg_values("engine.decode.fetch", "experts_hit")
+    if spent <= 0 or not steps or not hit:
+        return None
+    size = 2 if cfg["serve"]["dtype"] == "bfloat16" else 4
+    need = costs_hybrid_moe.routed_decode_bytes(cfg, statistics.mean(hit),
+                                                size)
+    return 100.0 * need / ctx["peak"]["hbm_bytes_per_s"] / (spent / steps)

@@ -1,4 +1,4 @@
-"""Model zoo (flax): GPT-2, Llama + Falcon-H1 LM families, ResNets, MLP, NatureCNN.
+"""Model zoo (flax): GPT-2, Llama, Falcon-H1 + Nemotron-H LM families, ResNets, MLP, NatureCNN.
 
 The reference's model layer is RLlib's ModelCatalog + torch/tf ModelV2
 (rllib/models/catalog.py, rllib/models/torch/*) plus whatever user code
@@ -21,6 +21,7 @@ from ray_tpu.models.llama import (  # noqa: F401
     llama_loss_fn,
 )
 from ray_tpu.models.falcon_h1 import FalconH1, FalconH1Config  # noqa: F401
+from ray_tpu.models.nemotron_h import NemotronH, NemotronHConfig  # noqa: F401
 from ray_tpu.models.resnet import ResNet, ResNetConfig  # noqa: F401
 from ray_tpu.models.mlp import MLP  # noqa: F401
 from ray_tpu.models.nature_cnn import NatureCNN  # noqa: F401

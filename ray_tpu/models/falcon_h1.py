@@ -231,11 +231,18 @@ class Mamba2Mixer(nn.Module):
         norm_scale = self.param("norm_scale", nn.initializers.ones,
                                 (c.mamba_d_ssm,), c.param_dtype)
 
-        sections = (c.mamba_d_ssm, c.mamba_d_ssm, g * n, g * n, h)
-        mup = jnp.concatenate([jnp.full((w,), m, c.dtype) for w, m in
-                               zip(sections, c.ssm_multipliers)])
-        proj = _dense(c, c.in_proj_dim, "in_proj")(
-            u * c.ssm_in_multiplier) * mup
+        # A family without muP multipliers (``models/nemotron_h.py``) leaves
+        # them at 1 and multiplies by nothing.
+        mup = None
+        if any(m != 1.0 for m in c.ssm_multipliers):
+            sections = (c.mamba_d_ssm, c.mamba_d_ssm, g * n, g * n, h)
+            mup = jnp.concatenate([jnp.full((w,), m, c.dtype) for w, m in
+                                   zip(sections, c.ssm_multipliers)])
+        if c.ssm_in_multiplier != 1.0:
+            u = u * c.ssm_in_multiplier
+        proj = _dense(c, c.in_proj_dim, "in_proj")(u)
+        if mup is not None:
+            proj = proj * mup
         z, xbc, dt = jnp.split(
             proj, [c.mamba_d_ssm, c.mamba_d_ssm + c.conv_dim], axis=-1)
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias)

@@ -22,6 +22,18 @@ Two exact forms, chosen from the static row count alone:
   (``lax.ragged_dot``, which the TPU compiler turns into a grouped-matmul
   kernel of its own).
 
+**A share of the experts** (``route_sigmoid_topk`` + ``experts_held_relu2``:
+``models/nemotron_h.py``'s latent expert layer).  The router is a float32
+sigmoid over ALL experts of the layer that chooses by score plus a bias
+and weighs by the score; the experts have two matrices and ``relu^2``.  The
+op is told which experts it holds (H of them from ``expert_offset`` on: one
+chip's share under expert parallelism), keeps the choices that land on
+them and computes their part of the result; the other chips' parts add to
+it.  The same two forms: ``moe_hit_relu2`` (``moe_hit``'s grid and block
+look-up, shared in ``_hit_call``) over the held experts that a live row
+hit, and the grouped form over the held experts, the choices that landed
+elsewhere sorted last and in no group.
+
 **Capacity-factor** (``moe_apply`` and its expert-parallel twin
 ``moe_apply_expert_parallel``): GShard/Switch dense dispatch/combine
 einsums with a static per-expert capacity ``C = ceil(k * N *
@@ -218,6 +230,27 @@ def route_topk(x: jax.Array, w_router: jax.Array, top_k: int,
     return top_p, top_i.astype(jnp.int32)
 
 
+def route_sigmoid_topk(x: jax.Array, w_router: jax.Array, bias: jax.Array,
+                       top_k: int, norm_topk_prob: bool, scaling: float):
+    """x [N, d], w_router [d, E], bias [E] → (weights [N, k] fp32, experts
+    [N, k] int32): ``s = sigmoid(x W_r)`` in fp32 over ALL experts; the k
+    largest of ``s + bias`` are chosen (the bias steers the choice and
+    weighs nothing: ``e_score_correction_bias``); the weights are ``s`` at
+    the chosen, divided by their sum where ``norm_topk_prob``, times
+    ``scaling``.  A limit on groups of experts with one group is no
+    limit, and is not here."""
+    # float32 in earnest: at the default precision the chip would round
+    # both operands to bfloat16, and the 22nd and 23rd scores lie close.
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "nd,de->ne", x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, top_i = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    if norm_topk_prob:
+        top_s = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
+    return top_s * scaling, top_i.astype(jnp.int32)
+
+
 def expert_rows(experts: jax.Array, num_experts: int) -> jax.Array:
     """experts [..., k] → rows assigned to each expert, [E] int32."""
     return jnp.zeros((num_experts,), jnp.int32).at[
@@ -276,17 +309,43 @@ def _tile_of(f: int, most: int) -> int:
     return f
 
 
-def moe_hit(x: jax.Array, combine: jax.Array, order: jax.Array,
-            n_hit: jax.Array, w_gate: jax.Array, w_up: jax.Array,
-            w_down: jax.Array) -> jax.Array:
-    """sum over the first ``n_hit`` experts e of ``order`` of
-    (silu(x Wg[e]) * (x Wu[e]) * combine[:, e]) Wd[e] → [N, d] float32.
-    The block of the weights a grid step works on is looked up in
-    ``order``, so the pipeline copies the listed experts' tiles from HBM
-    and no other's; places at or past ``n_hit`` name the last real step's
-    block again, which is not fetched twice, and compute nothing."""
+def _moe_hit_relu2_kernel(order_ref, n_hit_ref,  # SMEM
+                          x_ref, combine_ref, w_up_ref, w_down_ref, out_ref):
+    """``_moe_hit_kernel`` for experts of two matrices and no gate:
+    relu(x Wu)^2 in place of silu(x Wg) * (x Wu).  ``order`` and the
+    columns of ``combine_ref`` count the experts held here."""
+    p, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((p == 0) & (j == 0))
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(p < n_hit_ref[0])
+    def _():
+        x = x_ref[...]
+        f32 = jnp.float32
+        u = jnp.maximum(
+            jnp.dot(x, w_up_ref[...], preferred_element_type=f32), 0.0)
+        lane = lax.broadcasted_iota(jnp.int32, combine_ref.shape, 1)
+        c = jnp.sum(jnp.where(lane == order_ref[p], combine_ref[...], 0.0),
+                    axis=1, keepdims=True)
+        h = (u * u * c).astype(x.dtype)
+        out_ref[...] += jnp.dot(h, w_down_ref[...],
+                                preferred_element_type=f32)
+
+
+def _hit_call(kernel, name: str, x: jax.Array, combine: jax.Array,
+              order: jax.Array, n_hit: jax.Array, w_ins: tuple,
+              w_down: jax.Array) -> jax.Array:
+    """``kernel`` over the grid (place in ``order``, tile of the experts'
+    width): ``w_ins`` are the experts' [E, d, f] matrices (gate and up, or
+    up alone), ``w_down`` [E, f, d].  The block of the weights a grid step
+    works on is looked up in ``order``, so the pipeline copies the listed
+    experts' tiles from HBM and no other's; places at or past ``n_hit``
+    name the last real step's block again, which is not fetched twice, and
+    compute nothing."""
     n, d = x.shape
-    e, _, f = w_gate.shape
+    e, f = w_down.shape[:2]
     size = x.dtype.itemsize
     tile = _tile_of(f, HIT_TILE_BYTES // (d * size))
     tiles = f // tile
@@ -294,6 +353,7 @@ def moe_hit(x: jax.Array, combine: jax.Array, order: jax.Array,
     x = jnp.pad(x, ((0, pad), (0, 0)))
     combine = jnp.pad(combine, ((0, pad), (0, 0)))
     rows = n + pad
+    mats = len(w_ins) + 1
 
     def block(p, j, order_ref, n_hit_ref):
         last = jnp.maximum(n_hit_ref[0], 1) - 1
@@ -310,27 +370,48 @@ def moe_hit(x: jax.Array, combine: jax.Array, order: jax.Array,
 
     whole = lambda shape: pl.BlockSpec(  # noqa: E731
         shape, lambda p, j, *_: (0, 0))
-    # Two buffers a weight tile; x, combine and the accumulator; g, u and h.
-    vmem = (2 * 3 * d * tile * size + 2 * rows * (d * (size + 4) + e * 4)
-            + 3 * rows * tile * 4)
+    # Two buffers a weight tile; x, combine and the accumulator; one
+    # [rows, tile] float32 intermediate a matrix (g, u and h).
+    vmem = (2 * mats * d * tile * size + 2 * rows * (d * (size + 4) + e * 4)
+            + mats * rows * tile * 4)
     out = pl.pallas_call(
-        _moe_hit_kernel,
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(e, tiles),
             in_specs=[whole((rows, d)), whole((rows, e)),
-                      pl.BlockSpec((None, d, tile), up_block),
-                      pl.BlockSpec((None, d, tile), up_block),
+                      *[pl.BlockSpec((None, d, tile), up_block)
+                        for _ in w_ins],
                       pl.BlockSpec((None, tile, d), down_block)],
             out_specs=whole((rows, d))),
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem + (8 << 20)),
-        name="moe_hit",
+        name=name,
         interpret=jax.default_backend() == "cpu",
-    )(order, n_hit, x, combine.astype(jnp.float32), w_gate, w_up, w_down)
+    )(order, n_hit, x, combine.astype(jnp.float32), *w_ins, w_down)
     return out[:n]
+
+
+def moe_hit(x: jax.Array, combine: jax.Array, order: jax.Array,
+            n_hit: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+            w_down: jax.Array) -> jax.Array:
+    """sum over the first ``n_hit`` experts e of ``order`` of
+    (silu(x Wg[e]) * (x Wu[e]) * combine[:, e]) Wd[e] → [N, d] float32
+    (``_hit_call``)."""
+    return _hit_call(_moe_hit_kernel, "moe_hit", x, combine, order, n_hit,
+                     (w_gate, w_up), w_down)
+
+
+def moe_hit_relu2(x: jax.Array, combine: jax.Array, order: jax.Array,
+                  n_hit: jax.Array, w_up: jax.Array,
+                  w_down: jax.Array) -> jax.Array:
+    """sum over the first ``n_hit`` experts e of ``order`` of
+    (relu(x Wu[e])^2 * combine[:, e]) Wd[e] → [N, d] float32: ``moe_hit``
+    for experts of two matrices (``_hit_call``)."""
+    return _hit_call(_moe_hit_relu2_kernel, "moe_hit_relu2", x, combine,
+                     order, n_hit, (w_up,), w_down)
 
 
 def experts_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
@@ -375,6 +456,58 @@ def experts_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
     # Which tiles the compiler's grouped kernel fetches for an expert with
     # no row has not been looked at: counted as every expert.
     return jnp.sum(y, axis=1).astype(x.dtype), jnp.asarray(e, jnp.int32)
+
+
+def experts_held_relu2(x: jax.Array, weights: jax.Array, experts: jax.Array,
+                       w_up: jax.Array, w_down: jax.Array,
+                       expert_offset: int = 0,
+                       active: Optional[jax.Array] = None):
+    """One chip's share of a dropless layer of two-matrix experts:
+    sum over the choices j of row n that land on an expert held here
+    (``expert_offset <= experts[n, j] < expert_offset + H``) of
+    weights[n, j] * relu(x_n Wu[e])^2 Wd[e], e counted from
+    ``expert_offset``: x [N, d], ``experts`` [N, k] ids over ALL experts of
+    the layer, w_up [H, d, f], w_down [H, f, d] the H held
+    → ([N, d] in x's dtype, held experts whose weights were streamed, the
+    live rows' choices that landed here; both int32).  What the absent
+    experts would add is left out: the shares of all chips add up to the
+    whole layer.  ``active`` and the two exact forms are
+    ``experts_dropless``'s; no capacity, no choice of a held expert is
+    dropped."""
+    n, d = x.shape
+    held, k = w_up.shape[0], experts.shape[1]
+    f32 = jnp.float32
+    w_up, w_down = w_up.astype(x.dtype), w_down.astype(x.dtype)
+    local = experts - expert_offset
+    here = (local >= 0) & (local < held)
+    if active is not None:
+        here &= active[:, None]
+    weights = jnp.where(here, weights, 0.0)
+    landed = jnp.sum(here, dtype=jnp.int32)
+    if n <= DENSE_MAX_ROWS:
+        chose = here[:, :, None] & (
+            local[:, :, None] == jnp.arange(held, dtype=local.dtype))
+        combine = jnp.sum(jnp.where(chose, weights[:, :, None], 0.0), axis=1)
+        order, n_hit = hit_order(jnp.any(chose, axis=1))
+        out = moe_hit_relu2(x, combine, order, n_hit, w_up, w_down)
+        return out.astype(x.dtype), n_hit[0], landed
+    # Sorted by held expert, the choices that landed elsewhere last and in
+    # no group: the grouped products leave their rows alone.
+    key = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = expert_rows(key, held + 1)[:held]
+    xs = x[order // k]
+    u = jnp.maximum(
+        lax.ragged_dot(xs, w_up, sizes, preferred_element_type=f32), 0.0)
+    y = lax.ragged_dot((u * u).astype(x.dtype), w_down, sizes,
+                       preferred_element_type=f32)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(n * k, dtype=order.dtype))
+    # A row of no group holds whatever the grouped kernel left there.
+    y = jnp.where(here[:, :, None],
+                  y[back].reshape(n, k, d) * weights[:, :, None], 0.0)
+    return (jnp.sum(y, axis=1).astype(x.dtype), jnp.asarray(held, jnp.int32),
+            landed)
 
 
 def moe_dropless(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,

@@ -90,13 +90,22 @@ Load-bearing ideas:
    token's **behavior logprob** (raw log-softmax — see
    ``sampling.sample_tokens_with_logprobs``), so the generation that
    serves RLHF rollouts yields the exact PPO-ratio denominator with no
-   second forward pass (``rollout()`` / ``generate_rollouts``).
+   second forward pass (``rollout()`` / ``generate_rollouts``).  With
+   ``record_experts=True`` the decode program and the full prefills of a
+   routed model also return what each row's routers chose, and a request
+   that asks (``submit(record_experts=True)``) gets them with its
+   rollout, one [expert layers, k] a row fed: for a learner that replays
+   the routing it sampled under, and for a reference that is given the
+   program's choices.
 
 9. **Per-slot recurrent state** beside the pages.  A model whose layers
    carry a state of fixed size from token to token (a state-space mixer:
    ``models/falcon_h1.py``) names it in ``slot_state``; the engine then
    keeps one array a layer a kind, ``[max_slots, ...]``, on the pools'
    device and donates them through the decode and prefill programs.  A
+   model whose layers differ in kind (``models/nemotron_h.py``) says how
+   many write K/V and how many carry state (``kv_layers``,
+   ``state_layers``): the pool and the state list have that many.  A
    prefill writes its slot's state as it stands after the prompt's last
    real row (the bucket's padding advances nothing), so admission is the
    reset and recompute-preemption rebuilds it; a decode step advances
@@ -118,6 +127,7 @@ import functools
 import logging
 import math
 import queue
+import re
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -205,34 +215,67 @@ def _write_rows(pages, rows, page_idx, off):
 
 
 def _routes(model) -> bool:
-    """Whether the model's FFN is a routed expert layer (it then sows each
+    """Whether the model has routed expert layers (they then sow each
     token's chosen experts, ``models/llama.py::LlamaMoE``)."""
     return bool(getattr(model.config, "num_experts", 0))
 
 
-def _experts_touched(sown, active, num_experts):
+def _kv_layers(model) -> int:
+    """Layers that write K/V rows, one layer of the page pool each: what
+    the model says (``kv_layers``: a model whose layers differ in kind,
+    ``models/nemotron_h.py``), else every layer."""
+    return getattr(model, "kv_layers", model.config.num_layers)
+
+
+def _experts_touched(sown, active, num_experts, held=None):
     """From what a decode step's expert layers sowed (a layer: the chosen
     experts ``expert_idx`` [slots, 1, k], and ``experts_streamed``, how
     many experts' weights it read): int32 [3], summed over layers, of the
     experts that got at least one row of an active slot, of the busiest
     expert's rows, and of the experts streamed.  A free lane's garbage row
-    counts for nothing."""
+    counts for nothing.  ``held`` (first, count): the experts this program
+    holds of each layer's ``num_experts``; only they count as hit, and a
+    fourth number sums what the layers sowed as ``local_choices``, the
+    active rows' choices that landed on them.  Where every expert is held
+    that number is the rows' choices, which the host knows: it is left out
+    there, and the program of such a model stays the one it was."""
     import jax
     import jax.numpy as jnp
     from flax import traverse_util
 
-    hit = busiest = streamed = 0
+    hit = busiest = streamed = local = 0
     for path, sowed in traverse_util.flatten_dict(sown).items():
         for value in sowed:
             if path[-1] == "experts_streamed":
                 streamed += value
+            elif path[-1] == "local_choices":
+                local += value
             elif path[-1] == "expert_idx":
                 rows = jnp.sum(jax.nn.one_hot(value[:, 0], num_experts,
                                               dtype=jnp.int32)
                                * active[:, None, None], axis=(0, 1))
+                if held is not None:
+                    rows = rows[held[0]:held[0] + held[1]]
                 hit += jnp.sum(rows > 0)
                 busiest += jnp.max(rows)
-    return jnp.stack([hit, busiest, streamed]).astype(jnp.int32)
+    counts = [hit, busiest, streamed] + ([] if held is None else [local])
+    return jnp.stack(counts).astype(jnp.int32)
+
+
+def _experts_chosen(sown):
+    """From what a program's expert layers sowed: int32 [expert layers,
+    rows, k], every row's chosen experts in the layers' order (a decode
+    step's rows are its slots, a prefill's its bucket)."""
+    import jax.numpy as jnp
+    from flax import traverse_util
+
+    found = {path: sowed[0] for path, sowed in
+             traverse_util.flatten_dict(sown).items()
+             if path[-1] == "expert_idx"}
+    in_order = sorted(found, key=lambda path: [
+        int(n) for n in re.findall(r"\d+", "/".join(path))])
+    return jnp.stack([found[path].reshape(-1, found[path].shape[-1])
+                      for path in in_order]).astype(jnp.int32)
 
 
 def _cfg(name, given, fallback):
@@ -336,6 +379,11 @@ class _Request:
     # was sampled under (swap_weights bumps the engine version).
     out_logps: List[float] = dataclasses.field(default_factory=list)
     out_versions: List[int] = dataclasses.field(default_factory=list)
+    # ``record_experts``: one [expert layers, k] array for every row fed
+    # so far (the context but for the newest token), what its routers
+    # chose in the program that computed it.
+    record_experts: bool = False
+    fed_experts: List[Any] = dataclasses.field(default_factory=list)
     # Distributed trace the request was submitted under (the caller's
     # (trace_id, span_id) pair): its request.queued and request.decode
     # spans stamp it, so they land in the client's timeline.
@@ -365,6 +413,7 @@ class _Step:
     logps: Any
     touched: Any  # a routed model's _experts_touched, else None
     rows: List[tuple]
+    chosen: Any = None  # _experts_chosen, where the engine records them
 
 
 class LLMEngine:
@@ -395,7 +444,8 @@ class LLMEngine:
                  draft_window: Optional[int] = None,
                  prefix_cache=None, cache_namespace: str = "",
                  prefix_directory=None, directory_timeout_s: float = 5.0,
-                 prefill=None, prefill_min_tokens=_DEF):
+                 prefill=None, prefill_min_tokens=_DEF,
+                 record_experts: bool = False):
         import jax
         import jax.numpy as jnp
 
@@ -403,7 +453,7 @@ class LLMEngine:
         self._model = model
         self._params = params
         c = model.config
-        self.num_layers = c.num_layers
+        self.num_layers = _kv_layers(model)  # of the page pool
         self.head_dim = c.head_dim
         self.kv_heads = getattr(c, "num_kv_heads", c.num_heads)
         self.dtype = c.dtype
@@ -438,9 +488,10 @@ class LLMEngine:
 
         # ---- per-slot recurrent state ----
         # What the model says a slot holds besides pages of K/V
-        # (``slot_state``: name -> (shape, dtype), one set a layer; GPT-2
-        # and Llama say nothing): one array a layer a kind, so that each
-        # is updated in place (idea 9 of the module's docstring).
+        # (``slot_state``: name -> (shape, dtype), one set a layer, or a
+        # layer of those the model counts as ``state_layers``; GPT-2 and
+        # Llama say nothing): one array a layer a kind, so that each is
+        # updated in place (idea 9 of the module's docstring).
         self._state = None
         spec = getattr(model, "slot_state", None)
         if spec:
@@ -457,7 +508,29 @@ class LLMEngine:
             self._state = [
                 {k: jnp.zeros((self.max_slots,) + tuple(shape), dtype)
                  for k, (shape, dtype) in spec.items()}
-                for _ in range(self.num_layers)]
+                for _ in range(getattr(model, "state_layers",
+                                       c.num_layers))]
+
+        # ---- the routers' choices, for whoever asks with a request ----
+        # The decode program and the full prefills then also return what
+        # each row's routers chose, and a request submitted with
+        # ``record_experts=True`` gets them with its rollout (a learner
+        # that replays the routing it sampled under; a reference that is
+        # given the program's choices).  Off, every program is the one it
+        # was.
+        self.record_experts = bool(record_experts)
+        if self.record_experts:
+            if not _routes(model):
+                raise ValueError("record_experts= needs a model with "
+                                 "routed expert layers")
+            for name, given in (("prefix_cache", prefix_cache),
+                                ("prefix_directory", prefix_directory),
+                                ("draft_model", draft_model),
+                                ("prefill", prefill)):
+                if given is not None and given is not False:
+                    raise ValueError(
+                        f"{name}= hands over rows whose routers' choices "
+                        "this engine did not see: not with record_experts=")
 
         # ---- speculative decoding (draft + verify) ----
         self.spec_tokens = int(_cfg("serve_spec_tokens", spec_tokens,
@@ -479,7 +552,7 @@ class LLMEngine:
                     f"(draft vocab {dc.vocab_size} vs {c.vocab_size}, "
                     f"positions {dc.max_position_embeddings} vs "
                     f"{self.max_ctx})")
-            dshape = (dc.num_layers, num_pages, self.page_size,
+            dshape = (_kv_layers(draft_model), num_pages, self.page_size,
                       pool_width(getattr(dc, "num_kv_heads", dc.num_heads),
                                  dc.head_dim))
             self._dk_pages = jnp.zeros(dshape, dc.dtype)
@@ -562,7 +635,8 @@ class LLMEngine:
         self._slot_req: Dict[int, _Request] = {}
 
         self._decode = self._program(
-            "llm_decode", self._make_decode_step(model),
+            "llm_decode", self._make_decode_step(
+                model, record_experts=self.record_experts),
             # pools, and the state (``step``'s thirteenth argument)
             donate_argnums=(1, 2) if self._state is None else (1, 2, 12))
         if self._spec:
@@ -591,11 +665,14 @@ class LLMEngine:
         self._closed = False
         self._stats = collections.Counter()
         self._occupancy_sum = 0.0
-        # Routed models: choices a row makes in one step (layers x top-k)
-        # and experts a step can touch, for the two moe_* shares of stats().
-        self._moe_choices = c.num_layers * getattr(
-            c, "num_experts_per_tok", 0)
-        self._moe_experts = c.num_layers * getattr(c, "num_experts", 0)
+        # Routed models: choices a row makes in one step (expert layers x
+        # top-k) and experts a step can touch (those this program holds,
+        # where the model says it holds a share: ``experts_held``), for the
+        # moe_* shares of stats().
+        routed = getattr(model, "expert_layers", c.num_layers)
+        self._moe_choices = routed * getattr(c, "num_experts_per_tok", 0)
+        self._moe_experts = routed * getattr(
+            c, "experts_held", getattr(c, "num_experts", 0))
         self._moe_busiest_share_sum = 0.0
         self._t0 = time.monotonic()
         # Hot weight swap: queued (params_or_ref, version, event) applied
@@ -630,10 +707,15 @@ class LLMEngine:
                sampling: Optional[SamplingParams] = None,
                temperature: Optional[float] = None,
                top_p: Optional[float] = None,
-               seed: Optional[int] = None) -> int:
+               seed: Optional[int] = None,
+               record_experts: bool = False) -> int:
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         if not prompt:
             raise ValueError("empty prompt")
+        if record_experts and not self.record_experts:
+            raise ValueError("record_experts=True needs an engine built "
+                             "with record_experts=True: its programs "
+                             "return the routers' choices")
         if len(prompt) + max_new_tokens > self.max_ctx:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_new_tokens ({max_new_tokens}) "
@@ -652,7 +734,8 @@ class LLMEngine:
             self._next_id += 1
             req = _Request(rid, prompt, max_new_tokens, eos_id,
                            sampling=sampling, trace_ctx=trace_ctx,
-                           pending_ahead=len(self._pending))
+                           pending_ahead=len(self._pending),
+                           record_experts=record_experts)
             self._requests[rid] = req
             self._pending.append(req)
             self._cond.notify_all()
@@ -726,19 +809,27 @@ class LLMEngine:
                 ) -> Dict[str, Any]:
         """Blocking full result PLUS the per-token behavior logprobs and
         weight-version stamps — the RLHF rollout record (no second
-        forward pass needed for the PPO ratio)."""
+        forward pass needed for the PPO ratio).  For a request submitted
+        with ``record_experts=True`` also ``experts``: int32 [rows fed,
+        expert layers, k], what the routers chose on each row in the
+        program that computed it."""
         req = self._requests[rid]
         if not req.done.wait(timeout):
             raise TimeoutError(f"request {rid} not done within {timeout}s")
         req.consumed = True
         if req.error is not None:
             raise req.error
-        return {
+        out = {
             "prompt": list(req.prompt),
             "tokens": list(req.out),
             "logprobs": list(req.out_logps),
             "versions": list(req.out_versions),
         }
+        if req.record_experts:
+            # [rows fed, expert layers, k]: the prompt's rows and every
+            # answered token's but the last, which no program was fed
+            out["experts"] = np.stack(req.fed_experts)
+        return out
 
     def generate_rollouts(self, prompts: Sequence[Sequence[int]],
                           max_new_tokens: int = 16,
@@ -856,6 +947,18 @@ class LLMEngine:
                     if steps else 0.0)
             out["moe_max_expert_share"] = (
                 self._moe_busiest_share_sum / steps if steps else 0.0)
+            # How many experts a step can touch (those held here), the
+            # totals behind the shares, and of the live rows' choices the
+            # share that landed on a held expert: all of them where every
+            # expert is held, the held share of a layer where the model
+            # holds a share (``experts_held``) and routing is even.
+            out["moe_experts_held"] = self._moe_experts
+            for key in ("moe_experts_hit", "moe_experts_streamed",
+                        "moe_local_choices", "moe_choices"):
+                out[key] = s.get(key, 0)
+            out["moe_local_choice_share"] = (
+                out["moe_local_choices"] / out["moe_choices"]
+                if out["moe_choices"] else 0.0)
         if self._prefix is not None:
             out["prefix_cache"] = self._prefix.stats()
         cache_size = getattr(self._decode, "_cache_size", None)
@@ -895,7 +998,8 @@ class LLMEngine:
     # ------------------------------------------------------------------
     # compiled programs
     # ------------------------------------------------------------------
-    def _make_decode_step(self, model, window_pages: Optional[int] = None):
+    def _make_decode_step(self, model, window_pages: Optional[int] = None,
+                          record_experts: bool = False):
         """One token for every slot (fixed shapes — compiled once).
         Inactive lanes compute garbage routed to the scratch page.
         Shared shape for the target and the draft model (each gets its
@@ -919,12 +1023,14 @@ class LLMEngine:
         compares).  The step also returns the lengths it leaves behind,
         so they too stay on the device."""
         jnp = self._jnp
-        L, ps, pp = model.config.num_layers, self.page_size, \
-            self.pages_per_slot
+        L, ps, pp = _kv_layers(model), self.page_size, self.pages_per_slot
         from ray_tpu.serve.sampling import sample_tokens_with_logprobs
 
         scope = self._jax.named_scope
         routes = _routes(model)
+        held = getattr(model.config, "experts_held", None)
+        if held is not None:  # a share: (first expert, how many)
+            held = (model.config.expert_offset, held)
 
         def step(params, k_pages, v_pages, table, lengths, tokens, active,
                  temps, top_ps, seeds, prev_tokens=None, fresh=None,
@@ -968,7 +1074,9 @@ class LLMEngine:
                    lengths + active.astype(lengths.dtype))
             if routes:
                 out += (_experts_touched(sown, active,
-                                         model.config.num_experts),)
+                                         model.config.num_experts, held),)
+            if record_experts:
+                out += (_experts_chosen(sown),)
             return out + tuple(state)  # the state, last, where there is one
 
         return step
@@ -980,8 +1088,7 @@ class LLMEngine:
         target's sampled token at every position — the host applies
         accept-longest-prefix to the result."""
         jnp = self._jnp
-        L, ps, pp = model.config.num_layers, self.page_size, \
-            self.pages_per_slot
+        L, ps, pp = _kv_layers(model), self.page_size, self.pages_per_slot
         k_win = self.spec_tokens
         from ray_tpu.serve.sampling import sample_tokens_with_logprobs
 
@@ -1060,6 +1167,8 @@ class LLMEngine:
         L, ps = self.num_layers, self.page_size
         from ray_tpu.serve.sampling import sample_tokens_with_logprobs
 
+        record = self.record_experts
+
         def prefill(params, k_pages, v_pages, row, tokens, p, temp, top_p,
                     seed, slot=None, state=None):
             """tokens: [bucket] ids padded past p; row: [pp] page table
@@ -1068,21 +1177,26 @@ class LLMEngine:
             behavior logprob.  With ``state`` (a model that carries
             recurrent state): also the state, ``slot``'s set to what the
             prompt leaves behind (the padding past p advances nothing),
-            and the head taken at row p - 1 only."""
+            and the head taken at row p - 1 only.  Last, where the engine
+            records them: the bucket's rows' chosen experts."""
             ids = tokens[None]
             positions = jnp.arange(bucket)[None]
+            sown = None
             with jax.named_scope("attend"):
+                kw = {} if state is None else {
+                    "lengths": jnp.reshape(p, (1,)),
+                    "logits_at": jnp.reshape(p - 1, (1,))}
+                out = model.apply(
+                    {"params": params}, ids, positions,
+                    [_attend_uncached] * L,
+                    mutable=["moe"] if record else False, **kw)
+                if record:
+                    out, sown = out
                 if state is None:
-                    logits, new_kvs = model.apply(
-                        {"params": params}, ids, positions,
-                        [_attend_uncached] * L)
+                    logits, new_kvs = out
                     last = logits[0, p - 1][None]
                 else:
-                    logits, new_kvs, left = model.apply(
-                        {"params": params}, ids, positions,
-                        [_attend_uncached] * L,
-                        lengths=jnp.reshape(p, (1,)),
-                        logits_at=jnp.reshape(p - 1, (1,)))
+                    logits, new_kvs, left = out
                     last = logits[0]
                     state = [{k: held[k].at[slot].set(
                         new[k][0].astype(held[k].dtype)) for k in held}
@@ -1102,7 +1216,9 @@ class LLMEngine:
                 k_pages = _write_rows(k_pages, newk, page_idx, off)
                 v_pages = _write_rows(v_pages, newv, page_idx, off)
             out = (k_pages, v_pages, next_tok, next_logp)
-            return out if state is None else out + (state,)
+            if state is not None:
+                out += (state,)
+            return out + (_experts_chosen(sown),) if record else out
 
         fn = self._program(f"llm_prefill_{bucket}", prefill,
                            # pools, and the state (the eleventh argument)
@@ -1192,8 +1308,7 @@ class LLMEngine:
             return fn
         jax, jnp = self._jax, self._jnp
         model = self._draft_model
-        dc = model.config
-        L, ps = dc.num_layers, self.page_size
+        L, ps = _kv_layers(model), self.page_size
 
         def prefill(params, k_pages, v_pages, row, tokens, p):
             ids = tokens[None]
@@ -1557,10 +1672,14 @@ class LLMEngine:
                         toks, np.int32(p), np.float32(s.temperature),
                         np.float32(s.top_p), np.int32(s.seed))
                 if self._state is None:
-                    self._k_pages, self._v_pages, nxt, lp = fn(*args)
+                    self._k_pages, self._v_pages, nxt, lp, *chosen = fn(
+                        *args)
                 else:
-                    (self._k_pages, self._v_pages, nxt, lp,
-                     self._state) = fn(*args, np.int32(slot), self._state)
+                    (self._k_pages, self._v_pages, nxt, lp, self._state,
+                     *chosen) = fn(*args, np.int32(slot), self._state)
+                if req.record_experts:  # anew: a re-admission recomputes
+                    req.fed_experts = list(
+                        np.asarray(chosen[0])[:, :p].transpose(1, 0, 2))
             else:
                 fn = self._tail_prefill_fn(bucket)
                 self._k_pages, self._v_pages, nxt, lp = fn(
@@ -1897,6 +2016,8 @@ class LLMEngine:
                 dev("fresh", self._fresh), *stateful)
             if stateful:
                 self._state = touched.pop()
+            # fetched only for a row whose request records them
+            chosen = touched.pop() if self.record_experts else None
             for out in (nxt, lps, *touched):
                 out.copy_to_host_async()
         self._lengths[rows] += 1  # as the program does: that K/V lands
@@ -1906,7 +2027,7 @@ class LLMEngine:
         self._prev_tok = nxt
         return _Step(nxt, lps, touched[0] if touched else None,
                      [(s, self._slot_req[s])
-                      for s in np.flatnonzero(rows).tolist()])
+                      for s in np.flatnonzero(rows).tolist()], chosen)
 
     def _collect(self, step: _Step):
         """Wait for a dispatched step's results and emit them."""
@@ -1915,16 +2036,25 @@ class LLMEngine:
             nxt = np.asarray(step.tokens)
             lps = np.asarray(step.logps)
             if step.touched is not None:  # see _experts_touched
-                hit, busiest, streamed = (
+                hit, busiest, streamed, *local = (
                     int(v) for v in np.asarray(step.touched))
-                sp.set(experts_hit=hit, experts_streamed=streamed)
+                choices = n_rows * self._moe_choices
+                # every choice lands here where every expert is held
+                landed = local[0] if local else choices
+                sp.set(experts_hit=hit, experts_streamed=streamed,
+                       experts_held=self._moe_experts,
+                       local_choices=landed, choices=choices)
                 self._stats["moe_experts_hit"] += hit
                 self._stats["moe_experts_streamed"] += streamed
-                self._moe_busiest_share_sum += busiest / (
-                    n_rows * self._moe_choices)
+                self._stats["moe_local_choices"] += landed
+                self._stats["moe_choices"] += choices
+                self._moe_busiest_share_sum += busiest / choices
         self._stats["steps"] += 1
         self._occupancy_sum += n_rows / self.max_slots
         emitted = 0
+        chosen = None
+        if any(req.record_experts for _, req in step.rows):
+            chosen = np.asarray(step.chosen)  # [expert layers, slots, k]
         with obs.span("engine.emit") as sp:
             for slot, req in step.rows:
                 if self._slot_req.get(slot) is not req:
@@ -1935,6 +2065,8 @@ class LLMEngine:
                     self._stats["late_eos_rows"] += 1
                     continue
                 emitted += 1
+                if req.record_experts:  # of the row this step fed
+                    req.fed_experts.append(chosen[:, slot])
                 self._append_token(slot, req, int(nxt[slot]),
                                    float(lps[slot]))
             sp.set(tokens=emitted)
@@ -2231,6 +2363,11 @@ def _build_model(model_kind: str, config_kw: Optional[dict], seed: int):
 
         model = FalconH1(FalconH1Config.tiny(**config_kw) if config_kw.pop(
             "tiny", True) else FalconH1Config(**config_kw))
+    elif model_kind == "nemotron_h":
+        from ray_tpu.models import NemotronH, NemotronHConfig
+
+        model = NemotronH(NemotronHConfig.tiny(**config_kw) if config_kw.pop(
+            "tiny", True) else NemotronHConfig(**config_kw))
     else:
         raise ValueError(f"unknown model_kind {model_kind!r}")
     ids = jnp.zeros((1, 8), jnp.int32)
@@ -2284,9 +2421,15 @@ class LLMServer:
     deployment handle) disaggregates prefill.
 
     ``model_kind`` is what ``build_model`` binds: ``"gpt2"``, ``"llama"``
-    (with its layer options, OLMoE's decoder) or ``"falcon_h1"`` (a
-    Mamba-2 mixer beside attention in every block; the engine then holds
-    per-slot recurrent state and refuses the four options above).
+    (with its layer options, OLMoE's decoder), ``"falcon_h1"`` (a Mamba-2
+    mixer beside attention in every block) or ``"nemotron_h"`` (a layer is
+    a Mamba-2 mixer, an attention or a latent expert layer alone, by
+    ``hybrid_override_pattern``; ``experts_held`` / ``expert_offset`` give
+    this replica its share of every layer's experts, as one chip of an
+    expert-parallel deployment holds it: the router keeps its width and
+    the absent experts' part of the result is left out).  For the last two
+    the engine holds per-slot recurrent state and refuses the four options
+    above (a cached prefix, a draft, a prefix directory, remote prefill).
     """
 
     def __init__(self, model_kind: str = "gpt2",

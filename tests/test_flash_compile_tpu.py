@@ -1,4 +1,4 @@
-"""The flash kernels, and the expert FFN's ``moe_hit``, compiled for a
+"""The flash kernels, and the expert FFN's ``moe_hit`` kernels, compiled for a
 described TPU v5e at the widths the train cells, the Llama-family models
 and OLMoE's decode step run, without a chip: what Mosaic
 refuses (a block that does not fit VMEM, a slice off the tiling) fails here
@@ -89,3 +89,28 @@ def test_moe_hit_compiles_for_v5e(one_chip, monkeypatch, rows, experts, d, f):
         shape(experts, d, f), shape(experts, f, d),
         shape(rows, dtype=jnp.bool_)).compile().as_text()
     assert "tpu_custom_call" in text and "moe_hit" in text
+
+
+@pytest.mark.timeout(120)
+@pytest.mark.parametrize("rows", [
+    48,    # Nemotron-3-Super's decode step: 48 slots, one token each
+    512,   # its 512-row prefill bucket: the most rows the kernel takes
+])
+def test_moe_hit_relu2_compiles_for_v5e(one_chip, monkeypatch, rows):
+    """The two-matrix kernel as ``experts_held_relu2`` calls it for few
+    rows at the published widths (latent 1024, expert 2688 in tiles of 896,
+    top-22 of 512 of which 128 are held)."""
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    assert rows <= moe.DENSE_MAX_ROWS
+    assert moe._tile_of(2688, moe.HIT_TILE_BYTES // (1024 * 2)) == 896
+    text = jax.jit(moe.experts_held_relu2,
+                   static_argnames="expert_offset").lower(
+        shape(rows, 1024), shape(rows, 22, dtype=jnp.float32),
+        shape(rows, 22, dtype=jnp.int32), shape(128, 1024, 2688),
+        shape(128, 2688, 1024), expert_offset=128,
+        active=shape(rows, dtype=jnp.bool_)).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_hit_relu2" in text
