@@ -9,9 +9,11 @@ The engine says both counts itself, per step: ``experts_hit`` on its
 (never ``counters.live_tokens_at_trace``, one instant's reading: PERF.md
 section 7).  Means over the steps on both sides, so that a step cut by an
 edge of the profile weighs on neither.  The embedding table is a look-up of
-16 rows and is not counted; experts no row reached are not counted, though
-the masked form reads them.  A program that routes nothing (or the parent
-of the PR that brought this) has no ``experts_hit``: nothing to read."""
+16 rows and is not counted; experts no live row reached are not counted,
+and since PR 42 the decode step does not read them (``moe_hit`` streams the
+step's own list of hit experts; the masked form, which read every expert, is
+gone).  A program that routes nothing (or the parent of the PR that brought
+this) has no ``experts_hit``: nothing to read."""
 import statistics
 
 from benchmark import costs_moe, program_spans

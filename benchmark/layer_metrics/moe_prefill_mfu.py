@@ -1,8 +1,8 @@
 """A routed model's prefill programs against the chip's bf16 peak: the
 operations the traced prefills needed (``costs_moe.moe_prefill_flops`` of
 each ``engine.prefill`` span's real ``prompt_tokens`` and ``cached_tokens``:
-eight experts a token, not the bucket's padding, not the masked form's
-other experts) over the peak, divided by the summed device time of the
+eight experts a token, not the bucket's padding, not the experts a token
+did not choose) over the peak, divided by the summed device time of the
 ``*prefill*`` programs.  A program whose spans carry no such arguments has
 nothing to read."""
 from benchmark import costs_moe, program_spans

@@ -98,7 +98,7 @@ def probe(cell, config, traffic, seed):
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("cell", nargs="?", default="olmoe_serve_steady")
+    ap.add_argument("cell", nargs="?", default="olmoe_serve_steady2")
     ap.add_argument("--seeds", type=int, nargs="+",
                     default=[3000000011, 2500000001])
     ap.add_argument("--tiny", action="store_true")

@@ -76,7 +76,8 @@ def test_decode_roofline_counts_only_the_experts_hit(monkeypatch):
     # three steps: 200 of 512 experts hit, 1,000 cached rows, 4 ms each.
     # 476,712,960 + 200 x 12,582,912 + 1000 x 65,536 = 3,058,831,360 bytes
     # = 3.7348 ms: 93.37%.  With all 512 experts (what the masked form
-    # reads) it would be 6,984,826,880 bytes: 213%.
+    # read every step until PR 42; the program has no such form any more)
+    # it would be 6,984,826,880 bytes: 213%.
     fake(monkeypatch, [span("engine.decode.fetch", experts_hit=200),
                        span("engine.decode.dispatch", kv_tokens=1000)] * 3)
     got = reader.read(decode_record(4.0, 3), CTX)
@@ -166,7 +167,7 @@ def test_the_long_comparison_reaches_what_the_short_one_cannot():
     from ray_tpu.ops.moe import DENSE_MAX_ROWS
 
     short, long_ = serve_decoder.comparisons(
-        common.load_traffic("olmoe_chat_steady")["reference"])
+        common.load_traffic("olmoe_chat_steady2")["reference"])
     assert (short["prompt_tokens"], short["new_tokens"]) == (48, 8)
     assert short["prompt_tokens"] + short["new_tokens"] <= DENSE_MAX_ROWS
     assert DENSE_MAX_ROWS < long_["prompt_tokens"] <= 1024
