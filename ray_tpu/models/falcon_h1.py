@@ -21,7 +21,9 @@ the rope tables and the per-layer ``attend(q, k, v)`` cache hook are
 The mixer (``Mamba2Mixer``) exists in two forms that give the same numbers:
 ``ssd_scan``, the chunked scan (matrix products inside a chunk, the state
 carried from chunk to chunk; float32 state and decay) for a whole context,
-and ``ssd_step``, the one-token recurrence for decode.  What a sequence
+and ``ssd_step``, the one-token recurrence: its plain definition, which a
+decode step runs as ``ops/ssm.py::ssm_step`` (a kernel over the live slots
+of the caller's pool, in place).  What a sequence
 carries from token to token is of fixed size, whatever its length: the
 ``[heads, head_dim, d_state]`` float32 state and the last ``d_conv - 1``
 rows of the causal convolution's input.  ``FalconH1.slot_state`` says those
@@ -39,6 +41,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.llama import _dense, _norm, apply_rope, rope_tables
 from ray_tpu.ops.attention import mha_attention
+from ray_tpu.ops.ssm import live_slots, ssm_step
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,13 +211,16 @@ class Mamba2Mixer(nn.Module):
     ([B]: real rows; the rest is padding, which advances nothing) it also
     returns what the sequence carries on: ``{"ssm": [B, H, P, N] float32,
     "conv": [B, d_conv - 1, conv_dim]}`` as they stand after row
-    ``lengths - 1``.  ``state`` given (one new token a sequence): the
-    one-token recurrence; rows where ``active`` is false leave their state
-    as it was."""
+    ``lengths - 1``.  ``state`` given (one new token a sequence; the
+    caller's pool, a row a slot): the one-token recurrence over the rows
+    that ``active`` [B] marks, which ``live`` lists (``ops/ssm.py::
+    live_slots(active)``, made once for all layers).  ``ops/ssm.py::
+    ssm_step`` advances those rows' ``ssm`` in place and neither reads nor
+    writes another's; their ``conv`` rows stay as they were."""
     config: FalconH1Config
 
     @nn.compact
-    def __call__(self, u, state=None, lengths=None, active=None):
+    def __call__(self, u, state=None, lengths=None, active=None, live=None):
         c = self.config
         f32 = jnp.float32
         bsz, length, _ = u.shape
@@ -263,14 +269,12 @@ class Mamba2Mixer(nn.Module):
         new_state = None
         if state is not None:
             with jax.named_scope("mixer.step"):
-                y, ssm = ssd_step(state["ssm"], x[:, 0], dt[:, 0], a,
+                ssm, y = ssm_step(state["ssm"], *live, x[:, 0], dt[:, 0], a,
                                   b[:, 0], cc[:, 0])
                 y = y[:, None]
-            keep = active[:, None, None]
-            new_state = {
-                "ssm": jnp.where(keep[..., None], ssm, state["ssm"]),
-                "conv": jnp.where(keep, window[:, 1:].astype(
-                    state["conv"].dtype), state["conv"])}
+            new_state = {"ssm": ssm, "conv": jnp.where(
+                active[:, None, None],
+                window[:, 1:].astype(state["conv"].dtype), state["conv"])}
         else:
             with jax.named_scope("mixer.scan"):
                 if lengths is not None:  # padding advances nothing
@@ -363,11 +367,11 @@ class FalconH1Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, kv=None, positions=None, state=None, lengths=None,
-                 active=None):
+                 active=None, live=None):
         c = self.config
         u = _norm(c, "in_norm")(x)
         mixed, new_state = Mamba2Mixer(c, name="mixer")(
-            u, state=state, lengths=lengths, active=active)
+            u, state=state, lengths=lengths, active=active, live=live)
         mixed = mixed * c.ssm_out_multiplier
         attn, new_kv = FalconH1Attention(c, name="attn")(
             u * c.attention_in_multiplier, kv=kv, positions=positions)
@@ -404,7 +408,7 @@ class FalconH1(nn.Module):
         of the padded context) whose ``new_state`` is, per layer, what
         ``slot_state`` describes after row ``lengths - 1``; or a decode
         step (``state``: that list, one new token a sequence; ``active``
-        [B]: rows that advance).
+        [B]: rows that advance; the state of any other row is not touched).
 
         ``logits_at`` ([B] row indices): the head on those rows only,
         logits [B, 1, vocab]; no [L, vocab] array is built.  The head
@@ -414,12 +418,14 @@ class FalconH1(nn.Module):
                        param_dtype=c.param_dtype, name="embed")
         x = emb(input_ids) * c.embedding_multiplier
         cached = kv_caches is not None
+        # the list of live rows, once for every layer's state pass
+        live = live_slots(active) if state is not None else None
         new_kvs, new_state = [], []
         for i in range(c.num_layers):
             x, nkv, nst = FalconH1Block(c, name=f"layer_{i}")(
                 x, kv=kv_caches[i] if cached else None, positions=positions,
                 state=state[i] if state is not None else None,
-                lengths=lengths, active=active)
+                lengths=lengths, active=active, live=live)
             new_kvs.append(nkv)
             new_state.append(nst)
         if logits_at is not None:

@@ -11,8 +11,8 @@ layer is
 with ``f_i`` by the letter:
 
 - ``M``: ``models/falcon_h1.py``'s ``Mamba2Mixer`` (``ssd_scan`` for a
-  context, ``ssd_step`` for a token) at this family's numbers and with no
-  muP multiplier.
+  context, ``ops/ssm.py::ssm_step`` for a token) at this family's numbers
+  and with no muP multiplier.
 - ``*``: grouped-query attention, q, k, v, o without bias, scale
   ``1/sqrt(head_dim)``, causal, and NO position embedding: the family's
   published forward applies none (the mixers carry the order), and
@@ -53,6 +53,7 @@ from ray_tpu.models.falcon_h1 import FalconH1Config, Mamba2Mixer
 from ray_tpu.models.llama import _norm
 from ray_tpu.ops.attention import mha_attention
 from ray_tpu.ops.moe import experts_held_relu2, route_sigmoid_topk
+from ray_tpu.ops.ssm import live_slots
 
 
 @dataclasses.dataclass(frozen=True)
@@ -292,13 +293,14 @@ class NemotronHBlock(nn.Module):
     kind: str
 
     @nn.compact
-    def __call__(self, x, kv=None, state=None, lengths=None, active=None):
+    def __call__(self, x, kv=None, state=None, lengths=None, active=None,
+                 live=None):
         c = self.config
         u = _norm(c, "norm")(x)
         new_kv = new_state = None
         if self.kind == "M":
             out, new_state = Mamba2Mixer(c.mixer, name="mixer")(
-                u, state=state, lengths=lengths, active=active)
+                u, state=state, lengths=lengths, active=active, live=live)
             self.sow("branches", "mixer_out", out)
         elif self.kind == "*":
             out, new_kv = NemotronHAttention(c, name="attn")(u, kv=kv)
@@ -357,13 +359,15 @@ class NemotronH(nn.Module):
                                1.0, "fan_in", "normal", out_axis=0)))
         x = emb(input_ids)
         cached = kv_caches is not None
+        # the list of live rows, once for every ``M`` layer's state pass
+        live = live_slots(active) if state is not None else None
         new_kvs, new_state = [], []
         for i, kind in enumerate(c.hybrid_override_pattern):
             kw = {}
             if kind == "*" and cached:
                 kw["kv"] = kv_caches[len(new_kvs)]
             elif kind == "M" and state is not None:
-                kw["state"] = state[len(new_state)]
+                kw.update(state=state[len(new_state)], live=live)
             x, nkv, nst = NemotronHBlock(c, kind, name=f"layer_{i}")(
                 x, lengths=lengths, active=active, **kw)
             if kind == "*":

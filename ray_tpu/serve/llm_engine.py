@@ -108,9 +108,13 @@ Load-bearing ideas:
    ``state_layers``): the pool and the state list have that many.  A
    prefill writes its slot's state as it stands after the prompt's last
    real row (the bucket's padding advances nothing), so admission is the
-   reset and recompute-preemption rebuilds it; a decode step advances
-   the rows of active slots only and stays one step ahead of the host,
-   so the state never visits the host.  Its prefill takes the head at
+   reset and recompute-preemption rebuilds it; a decode step touches the
+   live slots only (``ops/ssm.py``: the state pass follows the step's own
+   list of them and updates the pool in place, so a free slot's state is
+   neither read nor written, and a retired slot keeps its last state
+   until admission overwrites it; ``stats()["state_slots_moved"]``) and
+   stays one step ahead of the host, so the state never visits the host.
+   Its prefill takes the head at
    the sampled row only.  What hands a request over as pages of K/V and
    nothing else (the prefix cache, a draft model, remote prefill, the
    tail prefill) is refused for such a model at construction.
@@ -905,6 +909,10 @@ class LLMEngine:
             # per-slot recurrent state beside the pools (0: the model
             # carries none)
             "state_pool_bytes": self._state_pool_bytes(),
+            # slots whose state the decode steps read and wrote, summed
+            # over the steps dispatched (the spans' ``state_slots``): over
+            # steps x max_slots, the share of the pool a step touches
+            "state_slots_moved": s.get("state_slots_moved", 0),
             "prefill_buckets": len(self._prefills),
             # sampling / speculative decoding
             "greedy_steps": s.get("greedy_steps", 0),
@@ -2000,12 +2008,15 @@ class LLMEngine:
         # kv_tokens: the cached rows this step's attention reads, which is
         # what the benchmark's paged_attn_roofline counts the bytes of.
         # state_slots: the slots whose recurrent state the step advances.
-        stateful = () if self._state is None else (self._state,)
+        stateful, moved = (), {}
+        if self._state is not None:
+            stateful = (self._state,)
+            moved = {"state_slots": int(rows.sum())}
+            self._stats["state_slots_moved"] += moved["state_slots"]
         with obs.span("engine.decode.dispatch",
                       kv_tokens=int(self._lengths[rows].sum()),
                       sampling_rows=sampling_rows, in_flight=in_flight,
-                      **({"state_slots": int(rows.sum())} if stateful
-                         else {})):
+                      **moved):
             (self._k_pages, self._v_pages, nxt, lps, lengths,
              *touched) = self._decode(
                 self._params, self._k_pages, self._v_pages,

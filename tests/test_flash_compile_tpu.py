@@ -114,3 +114,41 @@ def test_moe_hit_relu2_compiles_for_v5e(one_chip, monkeypatch, rows):
         shape(128, 2688, 1024), expert_offset=128,
         active=shape(rows, dtype=jnp.bool_)).compile().as_text()
     assert "tpu_custom_call" in text and "moe_hit_relu2" in text
+
+
+@pytest.mark.timeout(120)
+@pytest.mark.parametrize("heads,head,state,groups", [
+    (32, 128, 256, 2),   # Falcon-H1-34B's mixer: 4 MiB a slot a layer
+    (128, 64, 128, 8),   # Nemotron-3-Super's: likewise
+])
+def test_ssm_step_compiles_for_v5e_and_updates_the_pool_in_place(
+        one_chip, monkeypatch, heads, head, state, groups):
+    """The decode step's state pass over 48 slots at the published widths,
+    with the list made in the same program: the kernel's first result is
+    the pool in its own shape (the benchmark's state rooflines tell the
+    pass by it), and the donated pool is the result's buffer: no second
+    pool, no scratch of its size."""
+    from ray_tpu.ops import ssm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    slots, f32 = 48, jnp.float32
+    pool = shape(slots, heads, head, state, dtype=f32)
+    compiled = jax.jit(
+        lambda pool, active, *rest: ssm.ssm_step(
+            pool, *ssm.live_slots(active), *rest),
+        donate_argnums=0).lower(
+        pool, shape(slots, dtype=jnp.bool_), shape(slots, heads, head),
+        shape(slots, heads, dtype=f32), shape(heads, dtype=f32),
+        shape(slots, groups, state), shape(slots, groups, state)).compile()
+    text = compiled.as_text()
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert "ssm_step" in call
+    assert call.split(" = ")[1].startswith(
+        f"(f32[{slots},{heads},{head},{state}]")
+    memory = compiled.memory_analysis()
+    pool_bytes = slots * heads * head * state * 4
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 48
