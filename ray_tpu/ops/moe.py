@@ -32,7 +32,10 @@ them and computes their part of the result; the other chips' parts add to
 it.  The same two forms: ``moe_hit_relu2`` (``moe_hit``'s grid and block
 look-up, shared in ``_hit_call``) over the held experts that a live row
 hit, and the grouped form over the held experts, the choices that landed
-elsewhere sorted last and in no group.
+elsewhere sorted last and in no group.  ``route_group_sigmoid_topk`` +
+``experts_held_swiglu`` (``models/ling_linear.py``) are the same share for a
+router limited to groups of experts and SwiGLU experts of three matrices,
+through ``moe_hit``.
 
 **Capacity-factor** (``moe_apply`` and its expert-parallel twin
 ``moe_apply_expert_parallel``): GShard/Switch dense dispatch/combine
@@ -230,6 +233,25 @@ def route_topk(x: jax.Array, w_router: jax.Array, top_k: int,
     return top_p, top_i.astype(jnp.int32)
 
 
+def _sigmoid_scores(x: jax.Array, w_router: jax.Array) -> jax.Array:
+    """sigmoid(x W_r) in fp32 over ALL experts, [N, E].  float32 in
+    earnest: at the default precision the chip would round both operands
+    to bfloat16, and the last chosen and the first unchosen score lie
+    close."""
+    return jax.nn.sigmoid(jnp.einsum(
+        "nd,de->ne", x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+
+
+def _score_weights(scores, top_i, norm_topk_prob: bool, scaling: float):
+    """The chosen experts' weights: the score at each, divided by their sum
+    where ``norm_topk_prob``, times ``scaling``."""
+    top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    if norm_topk_prob:
+        top_s = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
+    return top_s * scaling, top_i.astype(jnp.int32)
+
+
 def route_sigmoid_topk(x: jax.Array, w_router: jax.Array, bias: jax.Array,
                        top_k: int, norm_topk_prob: bool, scaling: float):
     """x [N, d], w_router [d, E], bias [E] → (weights [N, k] fp32, experts
@@ -238,17 +260,32 @@ def route_sigmoid_topk(x: jax.Array, w_router: jax.Array, bias: jax.Array,
     weighs nothing: ``e_score_correction_bias``); the weights are ``s`` at
     the chosen, divided by their sum where ``norm_topk_prob``, times
     ``scaling``.  A limit on groups of experts with one group is no
-    limit, and is not here."""
-    # float32 in earnest: at the default precision the chip would round
-    # both operands to bfloat16, and the 22nd and 23rd scores lie close.
-    scores = jax.nn.sigmoid(jnp.einsum(
-        "nd,de->ne", x.astype(jnp.float32), w_router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+    limit, and is not here (``route_group_sigmoid_topk`` has it)."""
+    scores = _sigmoid_scores(x, w_router)
     _, top_i = lax.top_k(scores + bias.astype(jnp.float32), top_k)
-    top_s = jnp.take_along_axis(scores, top_i, axis=-1)
-    if norm_topk_prob:
-        top_s = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
-    return top_s * scaling, top_i.astype(jnp.int32)
+    return _score_weights(scores, top_i, norm_topk_prob, scaling)
+
+
+def route_group_sigmoid_topk(x: jax.Array, w_router: jax.Array,
+                             bias: jax.Array, top_k: int, n_group: int,
+                             topk_group: int, norm_topk_prob: bool,
+                             scaling: float):
+    """``route_sigmoid_topk`` with DeepSeek-V3's limit on groups: the E
+    experts are ``n_group`` groups of neighbours; a group's score is the sum
+    of its 2 largest ``s + bias``; the ``topk_group`` best groups are kept
+    and the ``top_k`` largest ``s + bias`` inside them chosen.  A token's
+    experts then lie in at most ``topk_group`` groups (under expert
+    parallelism: on that share of the chips).  Weights as there."""
+    scores = _sigmoid_scores(x, w_router)
+    n, e = scores.shape
+    grouped = (scores + bias.astype(jnp.float32)).reshape(
+        n, n_group, e // n_group)
+    group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+    _, best = lax.top_k(group_score, topk_group)
+    kept = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
+    _, top_i = lax.top_k(jnp.where(
+        kept[:, :, None], grouped, -jnp.inf).reshape(n, e), top_k)
+    return _score_weights(scores, top_i, norm_topk_prob, scaling)
 
 
 def expert_rows(experts: jax.Array, num_experts: int) -> jax.Array:
@@ -458,6 +495,55 @@ def experts_dropless(x: jax.Array, weights: jax.Array, experts: jax.Array,
     return jnp.sum(y, axis=1).astype(x.dtype), jnp.asarray(e, jnp.int32)
 
 
+def _experts_held(x, weights, experts, w_ins, w_down, expert_offset, active,
+                  hit_kernel, hidden):
+    """One chip's share of a dropless layer (``experts_held_relu2``,
+    ``experts_held_swiglu``): ``w_ins`` the held experts' [H, d, f]
+    matrices, ``hidden`` what an expert does with a row's products by them
+    (float32), ``hit_kernel`` the ``_hit_call`` kernel that does the same
+    over the held experts hit."""
+    n, d = x.shape
+    held, k = w_down.shape[0], experts.shape[1]
+    f32 = jnp.float32
+    w_ins = tuple(w.astype(x.dtype) for w in w_ins)
+    w_down = w_down.astype(x.dtype)
+    local = experts - expert_offset
+    here = (local >= 0) & (local < held)
+    if active is not None:
+        here &= active[:, None]
+    weights = jnp.where(here, weights, 0.0)
+    landed = jnp.sum(here, dtype=jnp.int32)
+    if n <= DENSE_MAX_ROWS:
+        chose = here[:, :, None] & (
+            local[:, :, None] == jnp.arange(held, dtype=local.dtype))
+        combine = jnp.sum(jnp.where(chose, weights[:, :, None], 0.0), axis=1)
+        order, n_hit = hit_order(jnp.any(chose, axis=1))
+        out = hit_kernel(x, combine, order, n_hit, *w_ins, w_down)
+        return out.astype(x.dtype), n_hit[0], landed
+    # Sorted by held expert, the choices that landed elsewhere last and in
+    # no group: the grouped products leave their rows alone.
+    key = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = expert_rows(key, held + 1)[:held]
+    xs = x[order // k]
+    mid = hidden(*[lax.ragged_dot(xs, w, sizes, preferred_element_type=f32)
+                   for w in w_ins])
+    y = lax.ragged_dot(mid.astype(x.dtype), w_down, sizes,
+                       preferred_element_type=f32)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(n * k, dtype=order.dtype))
+    # A row of no group holds whatever the grouped kernel left there.
+    y = jnp.where(here[:, :, None],
+                  y[back].reshape(n, k, d) * weights[:, :, None], 0.0)
+    return (jnp.sum(y, axis=1).astype(x.dtype), jnp.asarray(held, jnp.int32),
+            landed)
+
+
+def _relu2(u):
+    u = jnp.maximum(u, 0.0)
+    return u * u
+
+
 def experts_held_relu2(x: jax.Array, weights: jax.Array, experts: jax.Array,
                        w_up: jax.Array, w_down: jax.Array,
                        expert_offset: int = 0,
@@ -474,40 +560,22 @@ def experts_held_relu2(x: jax.Array, weights: jax.Array, experts: jax.Array,
     whole layer.  ``active`` and the two exact forms are
     ``experts_dropless``'s; no capacity, no choice of a held expert is
     dropped."""
-    n, d = x.shape
-    held, k = w_up.shape[0], experts.shape[1]
-    f32 = jnp.float32
-    w_up, w_down = w_up.astype(x.dtype), w_down.astype(x.dtype)
-    local = experts - expert_offset
-    here = (local >= 0) & (local < held)
-    if active is not None:
-        here &= active[:, None]
-    weights = jnp.where(here, weights, 0.0)
-    landed = jnp.sum(here, dtype=jnp.int32)
-    if n <= DENSE_MAX_ROWS:
-        chose = here[:, :, None] & (
-            local[:, :, None] == jnp.arange(held, dtype=local.dtype))
-        combine = jnp.sum(jnp.where(chose, weights[:, :, None], 0.0), axis=1)
-        order, n_hit = hit_order(jnp.any(chose, axis=1))
-        out = moe_hit_relu2(x, combine, order, n_hit, w_up, w_down)
-        return out.astype(x.dtype), n_hit[0], landed
-    # Sorted by held expert, the choices that landed elsewhere last and in
-    # no group: the grouped products leave their rows alone.
-    key = jnp.where(here, local, held).reshape(-1)
-    order = jnp.argsort(key, stable=True)
-    sizes = expert_rows(key, held + 1)[:held]
-    xs = x[order // k]
-    u = jnp.maximum(
-        lax.ragged_dot(xs, w_up, sizes, preferred_element_type=f32), 0.0)
-    y = lax.ragged_dot((u * u).astype(x.dtype), w_down, sizes,
-                       preferred_element_type=f32)
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(n * k, dtype=order.dtype))
-    # A row of no group holds whatever the grouped kernel left there.
-    y = jnp.where(here[:, :, None],
-                  y[back].reshape(n, k, d) * weights[:, :, None], 0.0)
-    return (jnp.sum(y, axis=1).astype(x.dtype), jnp.asarray(held, jnp.int32),
-            landed)
+    return _experts_held(x, weights, experts, (w_up,), w_down, expert_offset,
+                         active, moe_hit_relu2, _relu2)
+
+
+def experts_held_swiglu(x: jax.Array, weights: jax.Array, experts: jax.Array,
+                        w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                        expert_offset: int = 0,
+                        active: Optional[jax.Array] = None):
+    """``experts_held_relu2`` for SwiGLU experts of three matrices
+    (``experts_dropless``'s, told which it holds): the choices that land
+    here weigh ``silu(x Wg[e]) * (x Wu[e])`` through ``Wd[e]``; up to
+    ``DENSE_MAX_ROWS`` rows through ``moe_hit`` over the held experts
+    hit."""
+    return _experts_held(
+        x, weights, experts, (w_gate, w_up), w_down, expert_offset, active,
+        moe_hit, lambda g, u: jax.nn.silu(g) * u)
 
 
 def moe_dropless(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,

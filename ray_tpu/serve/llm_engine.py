@@ -103,9 +103,12 @@ Load-bearing ideas:
    ``models/falcon_h1.py``) names it in ``slot_state``; the engine then
    keeps one array a layer a kind, ``[max_slots, ...]``, on the pools'
    device and donates them through the decode and prefill programs.  A
-   model whose layers differ in kind (``models/nemotron_h.py``) says how
-   many write K/V and how many carry state (``kv_layers``,
-   ``state_layers``): the pool and the state list have that many.  A
+   model whose layers differ in kind (``models/nemotron_h.py``,
+   ``models/ling_linear.py``) says how many write K/V and how many carry
+   state (``kv_layers``, ``state_layers``): the pool and the state list
+   have that many; what a pool row holds is the config's ``num_kv_heads``
+   x ``head_dim``, which a model with a latent cache states as 1 x the
+   latent row's width.  A
    prefill writes its slot's state as it stands after the prompt's last
    real row (the bucket's padding advances nothing), so admission is the
    reset and recompute-preemption rebuilds it; a decode step touches the
@@ -909,6 +912,12 @@ class LLMEngine:
             # per-slot recurrent state beside the pools (0: the model
             # carries none)
             "state_pool_bytes": self._state_pool_bytes(),
+            # what one cached token costs the page pool, K and V rows of
+            # every layer that writes them, as stored (a row padded to
+            # ``pool_width``; a latent row stored in both pools counts
+            # twice)
+            "kv_bytes_per_token": (self._k_pages.nbytes + self._v_pages.nbytes)
+            // (self._k_pages.shape[1] * self.page_size),
             # slots whose state the decode steps read and wrote, summed
             # over the steps dispatched (the spans' ``state_slots``): over
             # steps x max_slots, the share of the pool a step touches
@@ -2379,6 +2388,13 @@ def _build_model(model_kind: str, config_kw: Optional[dict], seed: int):
 
         model = NemotronH(NemotronHConfig.tiny(**config_kw) if config_kw.pop(
             "tiny", True) else NemotronHConfig(**config_kw))
+    elif model_kind == "ling_linear":
+        # imported here and nowhere else: no other kind's set-up pays for it
+        from ray_tpu.models.ling_linear import LingLinear, LingLinearConfig
+
+        model = LingLinear(LingLinearConfig.tiny(**config_kw)
+                           if config_kw.pop("tiny", True)
+                           else LingLinearConfig(**config_kw))
     else:
         raise ValueError(f"unknown model_kind {model_kind!r}")
     ids = jnp.zeros((1, 8), jnp.int32)
@@ -2433,13 +2449,16 @@ class LLMServer:
 
     ``model_kind`` is what ``build_model`` binds: ``"gpt2"``, ``"llama"``
     (with its layer options, OLMoE's decoder), ``"falcon_h1"`` (a Mamba-2
-    mixer beside attention in every block) or ``"nemotron_h"`` (a layer is
+    mixer beside attention in every block), ``"nemotron_h"`` (a layer is
     a Mamba-2 mixer, an attention or a latent expert layer alone, by
     ``hybrid_override_pattern``; ``experts_held`` / ``expert_offset`` give
     this replica its share of every layer's experts, as one chip of an
     expert-parallel deployment holds it: the router keeps its width and
-    the absent experts' part of the result is left out).  For the last two
-    the engine holds per-slot recurrent state and refuses the four options
+    the absent experts' part of the result is left out) or
+    ``"ling_linear"`` (gated delta-rule layers with one latent-attention
+    layer a group, whose latent rows ride the page pool as ONE KV head; a
+    leading dense layer; group-routed experts with the same share).  For
+    the last three the engine holds per-slot recurrent state and refuses the four options
     above (a cached prefix, a draft, a prefix directory, remote prefill).
     """
 

@@ -117,6 +117,59 @@ def test_moe_hit_relu2_compiles_for_v5e(one_chip, monkeypatch, rows):
 
 
 @pytest.mark.timeout(120)
+@pytest.mark.parametrize("rows", [
+    48,    # Ling-3.0-flash's decode step: 48 slots, one token each
+    512,   # its 512-row prefill bucket: the most rows the kernel takes
+])
+def test_moe_hit_compiles_for_v5e_as_a_share_of_swiglu_experts(
+        one_chip, monkeypatch, rows):
+    """The three-matrix kernel as ``experts_held_swiglu`` calls it for few
+    rows at the published widths (hidden 2560, expert 768 in tiles of 384,
+    top-8 of 512 of which 128 are held)."""
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    assert rows <= moe.DENSE_MAX_ROWS
+    assert moe._tile_of(768, moe.HIT_TILE_BYTES // (2560 * 2)) == 384
+    text = jax.jit(moe.experts_held_swiglu,
+                   static_argnames="expert_offset").lower(
+        shape(rows, 2560), shape(rows, 8, dtype=jnp.float32),
+        shape(rows, 8, dtype=jnp.int32), shape(128, 2560, 768),
+        shape(128, 2560, 768), shape(128, 768, 2560), expert_offset=0,
+        active=shape(rows, dtype=jnp.bool_)).compile().as_text()
+    assert "tpu_custom_call" in text and "moe_hit" in text
+
+
+@pytest.mark.timeout(120)
+def test_paged_attention_compiles_for_v5e_over_one_latent_head(
+        one_chip, monkeypatch):
+    """The paged kernel as an absorbed latent-attention layer calls it: 48
+    slots, 32 query heads on ONE KV head of 576 columns in a pool 640 wide,
+    ``sm_scale`` given; its first result is ``f32[48,32,640]``, which the
+    benchmark's ``latent_paged_attn_roofline`` tells it by."""
+    from ray_tpu.ops.paged_attention import paged_attention, pool_width
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    slots, pages = 48, 512
+    assert pool_width(1, 576) == 640
+    pool = shape(1, slots * pages + 1, 16, 640)
+    text = jax.jit(lambda q, k, v, kp, vp, table, lengths: paged_attention(
+        q, k, v, kp, vp, 0, table, lengths, sm_scale=192 ** -0.5)).lower(
+        shape(slots, 1, 32, 576), shape(slots, 1, 1, 576),
+        shape(slots, 1, 1, 576), pool, pool,
+        shape(slots, pages, dtype=jnp.int32),
+        shape(slots, dtype=jnp.int32)).compile().as_text()
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert "paged_attn" in call
+    assert call.split(" = ")[1].startswith("(f32[48,32,640]")
+
+
+@pytest.mark.timeout(120)
 @pytest.mark.parametrize("heads,head,state,groups", [
     (32, 128, 256, 2),   # Falcon-H1-34B's mixer: 4 MiB a slot a layer
     (128, 64, 128, 8),   # Nemotron-3-Super's: likewise
