@@ -24,7 +24,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import mha_attention
+from ray_tpu.ops.attention import mha_attention_qkv
 from ray_tpu.ops.layers import gelu
 
 
@@ -110,18 +110,17 @@ class Block(nn.Module):
         c = self.config
         h = nn.LayerNorm(dtype=jnp.float32, name="ln_1")(x)
         qkv = nn.Dense(3 * c.hidden_size, dtype=c.dtype, name="attn_qkv")(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        b, l, _ = q.shape
-        q = q.reshape(b, l, c.num_heads, c.head_dim)
-        k = k.reshape(b, l, c.num_heads, c.head_dim)
-        v = v.reshape(b, l, c.num_heads, c.head_dim)
-        if kv is not None:
-            attn = kv(q, k, v)
-        elif self.attn_fn is not None:
-            attn = self.attn_fn(q, k, v)
+        if kv is None and self.attn_fn is None:
+            # q, k and v stay where the projection wrote them: the flash
+            # kernels read their blocks out of the one array.
+            attn = mha_attention_qkv(qkv, c.num_heads, causal=True,
+                                     use_flash=c.use_flash)
         else:
-            attn = mha_attention(q, k, v, causal=True, use_flash=c.use_flash)
-        attn = attn.reshape(b, l, c.hidden_size)
+            b, l, _ = qkv.shape
+            q, k, v = (t.reshape(b, l, c.num_heads, c.head_dim)
+                       for t in jnp.split(qkv, 3, axis=-1))
+            attn = (kv if kv is not None else self.attn_fn)(q, k, v)
+            attn = attn.reshape(b, l, c.hidden_size)
         x = x + nn.Dense(c.hidden_size, dtype=c.dtype, name="attn_proj")(attn)
         h = nn.LayerNorm(dtype=jnp.float32, name="ln_2")(x)
         if c.moe is not None:

@@ -7,7 +7,9 @@ jit/scan-friendly per XLA's compilation model.
 """
 from ray_tpu.ops.attention import (  # noqa: F401
     mha_attention,
+    mha_attention_qkv,
     flash_attention,
+    flash_attention_qkv,
     blockwise_update,
 )
 from ray_tpu.ops.layers import gelu, layer_norm, rms_norm, rope  # noqa: F401

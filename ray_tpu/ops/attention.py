@@ -27,8 +27,12 @@ def mha_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     Dispatches to the Pallas flash kernel on real TPU backends for long
     sequences, XLA reference otherwise.  On a v5e, forward and backward
-    of [8, 1024, 16, 64] bf16 causal: the kernels 1.36 ms, this XLA path
-    4.44 (PR 39's chip runs; PERF.md section 6).  Below 1k ctx the XLA
+    of [8, 1024, 16, 64] bf16 causal as a function of a fused projection,
+    its split and the gradient's concatenation included: the kernels 1.23
+    ms reading column blocks of ``[B, L, H * D]`` (1.74 head-major, with a
+    transpose each way, as they read until PR 49; 1.04 out of the fused
+    array itself, ``mha_attention_qkv``); this XLA path 4.44 (PR 39's and
+    PR 49's chip runs; PERF.md section 6).  Below 1k ctx the XLA
     path is still chosen, as it was when the kernels were 2.2 times slower
     than now; at [16, 512, 16, 64] they read 1.17 ms against XLA's 2.06,
     so the crossover lies lower than this rule puts it, and nobody has
@@ -54,25 +58,45 @@ def mha_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                              out_specs=spec, check_vma=False)(q, k, v)
     b, lq, h, _ = q.shape
-    lk = k.shape[1]
     if use_flash is None:
-        # [B, H, Lq, Lk] score-matrix footprint the XLA path materializes.
-        score_bytes = b * h * lq * lk * q.dtype.itemsize
-        use_flash = (jax.default_backend() not in ("cpu",)
-                     and lq % 128 == 0 and lk % 128 == 0
-                     # From 1k ctx on (the docstring has the times);
-                     # memory can force flash even earlier:
-                     # per-layer score matrices past ~512MB OOM real
-                     # training steps on a 16G chip.
-                     and (lq >= 1024 or score_bytes > 512 * 1024 * 1024)
-                     # Flash's causal mask is diagonal-aligned (self-
-                     # attention); the XLA path's is bottom-right-aligned
-                     # for lq != lk (decode), so only lq == lk may
-                     # auto-dispatch.
-                     and (not causal or lq == lk))
+        use_flash = _flash_by_default(b, lq, k.shape[1], h, q.dtype, causal)
     if use_flash:
         return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
     return _xla_attention(q, k, v, causal, sm_scale)
+
+
+def mha_attention_qkv(qkv: jax.Array, num_heads: int, causal: bool = True,
+                      sm_scale: Optional[float] = None,
+                      use_flash: Optional[bool] = None) -> jax.Array:
+    """``mha_attention`` on a fused projection, for a caller that has q, k
+    and v as the columns of one array (GPT-2's ``attn_qkv``): qkv ``[B, L,
+    3 * H * D]`` → ``[B, L, H * D]``, the same dispatch.  On the kernels'
+    side nothing is split or concatenated (``flash_attention_qkv``)."""
+    b, l, columns = qkv.shape
+    if use_flash is None:
+        use_flash = _flash_by_default(b, l, l, num_heads, qkv.dtype, causal)
+    if use_flash:
+        return flash_attention_qkv(qkv, num_heads, causal=causal,
+                                   sm_scale=sm_scale)
+    q, k, v = (x.reshape(b, l, num_heads, columns // (3 * num_heads))
+               for x in jnp.split(qkv, 3, axis=-1))
+    return _xla_attention(q, k, v, causal, sm_scale).reshape(
+        b, l, columns // 3)
+
+
+def _flash_by_default(b, lq, lk, h, dtype, causal) -> bool:
+    # [B, H, Lq, Lk] score-matrix footprint the XLA path materializes.
+    score_bytes = b * h * lq * lk * dtype.itemsize
+    return (jax.default_backend() not in ("cpu",)
+            and lq % 128 == 0 and lk % 128 == 0
+            # From 1k ctx on (mha_attention's docstring has the times);
+            # memory can force flash even earlier: per-layer score matrices
+            # past ~512MB OOM real training steps on a 16G chip.
+            and (lq >= 1024 or score_bytes > 512 * 1024 * 1024)
+            # Flash's causal mask is diagonal-aligned (self-attention); the
+            # XLA path's is bottom-right-aligned for lq != lk (decode), so
+            # only lq == lk may auto-dispatch.
+            and (not causal or lq == lk))
 
 
 def _xla_attention(q, k, v, causal, sm_scale):
@@ -183,7 +207,16 @@ def finalize_blockwise(o, l):
 # ``causal_tile_schedule`` (the number a test and PERF.md quote) both read
 # them, so what is counted is what runs.
 # ---------------------------------------------------------------------------
-_LSE_SUBLANES = 8  # minimum sublane tiling for an f32 operand
+# lse and delta lie along the lanes of one float32 tile of 8 sublanes a
+# column block, ``[B, blocks, 8, L]``: TPU block shapes need the last two
+# dims (sublane, lane)-tiled, and a lane dim of 1 would pad 128x in HBM.
+# The block's heads share the tile, head j of g on sublanes j * 8 / g and
+# the 8 / g - 1 after it (``_pack_rows``; one head: all eight).  The
+# forward's result is the residual as it is and the backward reads it with
+# nothing in between.  (One sublane a head, ``[B, H, 1, L]``, compiles and is
+# compact, and its single-sublane stores made the forward 30% slower: my
+# chip run, PR 49.)
+_LSE_SUBLANES = 8
 
 
 def _clamp(x, hi):
@@ -267,10 +300,58 @@ def _tile_rel(rows: int, cols: int, transposed: bool = False):
     return c - r if transposed else r - c
 
 
+def _head_lanes(x, j: int, heads: int):
+    """x [rows, heads * d] with every lane outside head ``j``'s set to zero:
+    a product that contracts all the lanes with it is head j's alone, and
+    one that it multiplies from the left lands on head j's lanes only.  A
+    mask, not a slice: nothing moves across lanes.  One head: x."""
+    if heads == 1:
+        return x
+    d = x.shape[1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= j * d) & (lane < (j + 1) * d), x,
+                     jnp.zeros_like(x))
+
+
+def _merge_heads(parts):
+    """``parts[j]`` [rows, heads * d] is right on head j's lanes and holds
+    anything elsewhere: the array that is right on every lane."""
+    heads = len(parts)
+    if heads == 1:
+        return parts[0]
+    d = parts[0].shape[1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, parts[0].shape, 1)
+    out = parts[-1]
+    for j in range(heads - 2, -1, -1):
+        out = jnp.where(lane < (j + 1) * d, parts[j], out)
+    return out
+
+
+def _row_of(j: int, heads: int) -> int:
+    """The first sublane of head j's row in a block's tile of rows."""
+    return j * (_LSE_SUBLANES // heads)
+
+
+def _pack_rows(rows):
+    """One ``[n]`` row a head → the ``[8, n]`` tile that holds them all,
+    head j's on sublanes ``_row_of(j, heads)`` and after: whole tiles are
+    stored, no single sublane."""
+    heads, n = len(rows), rows[0].shape[0]
+    if heads == 1:
+        return jnp.broadcast_to(rows[0][None, :], (_LSE_SUBLANES, n))
+    sub = jax.lax.broadcasted_iota(jnp.int32, (_LSE_SUBLANES, n), 0)
+    out = rows[-1][None, :]
+    for j in range(heads - 2, -1, -1):
+        out = jnp.where(sub < _row_of(j + 1, heads), rows[j][None, :], out)
+    return out
+
+
 def _fwd_q_tile(q, q_off, k_ref, v_ref, causal, sm_scale, block_k):
-    """One q tile against its k tiles: (o [block_q, d] float32, normalised;
-    lse [block_q]).  ``q_off`` is a Python int in the unrolled kernel and
-    a traced value where the grid walks the q tiles."""
+    """One q tile against its k tiles: (o [block_q, lanes] float32,
+    normalised; lse [block_q]).  ``q_off`` is a Python int in the unrolled
+    kernel and a traced value where the grid walks the q tiles.  Where the
+    block holds several heads, ``q`` is one head's (``_head_lanes``) and o
+    is that head's on its own lanes."""
     import jax.experimental.pallas as pl
 
     # Inputs stay in their storage dtype (bf16 on the training path): the
@@ -318,28 +399,26 @@ def _fwd_q_tile(q, q_off, k_ref, v_ref, causal, sm_scale, block_k):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref, causal,
-                      sm_scale, block_q, block_k, unrolled):
-    """``unrolled``: one grid step is a whole head, its q tiles walked
-    here with static offsets; otherwise the grid's second axis walks them
-    and ``q_ref`` is the tile."""
+                      sm_scale, block_q, block_k, unrolled, heads):
+    """``unrolled``: one grid step is a whole block of ``heads`` heads, its
+    q tiles walked here with static offsets; otherwise the grid's last axis
+    walks them and ``q_ref`` is the tile."""
     import jax.experimental.pallas as pl
 
     if unrolled:
         offs = [i * block_q for i in range(q_ref.shape[0] // block_q)]
     else:
-        offs = [pl.program_id(1) * block_q]
+        offs = [pl.program_id(2) * block_q]
     for q_off in offs:
         rows = pl.ds(q_off, block_q) if unrolled else slice(None)
-        o, lse = _fwd_q_tile(q_ref[rows, :], q_off, k_ref, v_ref, causal,
-                             sm_scale, block_k)
-        o_ref[rows, :] = o.astype(o_ref.dtype)
+        q = q_ref[rows, :]
+        parts = [_fwd_q_tile(_head_lanes(q, j, heads), q_off, k_ref, v_ref,
+                             causal, sm_scale, block_k)
+                 for j in range(heads)]
+        o_ref[rows, :] = _merge_heads([o for o, _ in parts]).astype(
+            o_ref.dtype)
         if maybe_lse_ref:  # omitted on the inference path: nothing reads it
-            # lse is broadcast across an 8-sublane dim: TPU block shapes
-            # need the last two dims (sublane, lane)-tiled; a lane dim of 1
-            # would pad 128x in HBM, blowing up the residuals kept for the
-            # backward.
-            maybe_lse_ref[0][:, rows] = jnp.broadcast_to(
-                lse[None, :], (_LSE_SUBLANES, block_q))
+            maybe_lse_ref[0][:, rows] = _pack_rows([lse for _, lse in parts])
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -352,7 +431,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     lse = lse_ref[0, :]                # [block_q] (sublane 0 of 8)
     delta = delta_ref[0, :]            # [block_q]
     block_q = q.shape[0]
-    q_off = pl.program_id(1) * block_q
+    q_off = pl.program_id(2) * block_q
     num_k_blocks = k_ref.shape[0] // block_k
     rel = _tile_rel(block_q, block_k) if causal else None
 
@@ -385,14 +464,17 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_k_tile(k_blk, v_blk, k_off, q_ref, do_ref, lse_ref, delta_ref,
-                dq, causal, sm_scale, block_q):
+                dq, causal, sm_scale, block_q, row=0):
     """One k tile against the q tiles at or under its diagonal: (dk, dv)
     float32, unscaled.  The tiles are [block_k, block_q], the transposed
     orientation: lse and delta then broadcast along sublanes as they are
     stored, and dk, dv need no transpose.  With ``dq`` (the unrolled
-    kernel's list of float32 [block_q, d] sums, one a q tile) every tile
-    also adds its dq there, so s, p, dp and ds are computed once a tile: 5
-    matmuls where the two-kernel form runs 7."""
+    kernel's list of float32 [block_q, lanes] sums, one a q tile) every
+    tile also adds its dq there, so s, p, dp and ds are computed once a
+    tile: 5 matmuls where the two-kernel form runs 7.  Where the block holds
+    several heads, ``k_blk`` and ``v_blk`` are one head's (``_head_lanes``):
+    dq lands on that head's lanes, and dk and dv are right on them;
+    ``row`` is the sublane of the head's lse and delta."""
     import jax.experimental.pallas as pl
 
     block_k = k_blk.shape[0]
@@ -411,11 +493,12 @@ def _bwd_k_tile(k_blk, v_blk, k_off, q_ref, do_ref, lse_ref, delta_ref,
                 st = st * s_scale
             if masked:
                 st = jnp.where(rel >= k_off - qb * block_q, st, NEG_INF)
-            pt = jnp.exp(st - lse_ref[0:1, rows])
+            pt = jnp.exp(st - lse_ref[row:row + 1, rows])
             dv = dv + jnp.dot(pt.astype(do_blk.dtype), do_blk,
                               preferred_element_type=jnp.float32)
             dpt = _dot_nt(v_blk, do_blk)
-            dst = (pt * (dpt - delta_ref[0:1, rows])).astype(q_blk.dtype)
+            dst = (pt * (dpt - delta_ref[row:row + 1, rows])).astype(
+                q_blk.dtype)
             dk = dk + jnp.dot(dst, q_blk, preferred_element_type=jnp.float32)
             if dq is not None:
                 dq[qb] = dq[qb] + jax.lax.dot_general(
@@ -438,7 +521,7 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     """The two-kernel backward's dk/dv half: the grid walks the k tiles."""
     import jax.experimental.pallas as pl
 
-    k_off = pl.program_id(1) * k_ref.shape[0]
+    k_off = pl.program_id(2) * k_ref.shape[0]
     dk, dv = _bwd_k_tile(k_ref[...], v_ref[...], k_off, q_ref, do_ref,
                          lse_ref, delta_ref, None, causal, sm_scale, block_q)
     # ds = p * (dp - delta) * scale: the scale goes once onto the
@@ -447,26 +530,98 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
-def _flash_bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dq_ref, *, causal, sm_scale, block_q,
-                      block_k):
-    """The fused backward: one grid step is a whole head, its k tiles
-    walked here with static offsets, dq summed a q tile beside dk and dv."""
+def _flash_bwd_kernel(k_ref, v_ref, q_ref, do_ref, o_ref, lse_ref, dk_ref,
+                      dv_ref, dq_ref, delta_ref, *, causal, sm_scale,
+                      block_q, block_k, heads):
+    """The fused backward: one grid step is a whole block of ``heads``
+    heads, its k tiles walked here with static offsets, dq summed a q tile
+    beside dk and dv (every head's on its own lanes of the one sum).
+
+    delta_i = sum_d dO_i * O_i, the softmax-normalisation term of dS, is
+    made here first, into the scratch ``delta_ref`` laid out as lse is: the
+    blocks are in VMEM anyway, and outside the kernel a sum over 64 of a
+    row's 1,024 columns cost a float32 product written out, a relayout
+    and a reduction (3.4 ms of a 146.6 ms train step: my chip run, PR 49)."""
     import jax.experimental.pallas as pl
 
-    d = q_ref.shape[1]
-    dq = [jnp.zeros((block_q, d), jnp.float32)
-          for _ in range(q_ref.shape[0] // block_q)]
+    lanes = q_ref.shape[1]
+    num_q_blocks = q_ref.shape[0] // block_q
+    for i in range(num_q_blocks):
+        rows = pl.ds(i * block_q, block_q)
+        prod = (do_ref[rows, :].astype(jnp.float32)
+                * o_ref[rows, :].astype(jnp.float32))
+        delta_ref[:, rows] = _pack_rows([
+            jnp.sum(_head_lanes(prod, j, heads), axis=-1)
+            for j in range(heads)])
+    dq = [jnp.zeros((block_q, lanes), jnp.float32)
+          for _ in range(num_q_blocks)]
     for k_off in range(0, k_ref.shape[0], block_k):
         cols = pl.ds(k_off, block_k)
-        dk, dv = _bwd_k_tile(k_ref[cols, :], v_ref[cols, :], k_off, q_ref,
+        k_blk, v_blk = k_ref[cols, :], v_ref[cols, :]
+        parts = [_bwd_k_tile(_head_lanes(k_blk, j, heads),
+                             _head_lanes(v_blk, j, heads), k_off, q_ref,
                              do_ref, lse_ref, delta_ref, dq, causal,
-                             sm_scale, block_q)
+                             sm_scale, block_q, row=_row_of(j, heads))
+                 for j in range(heads)]
+        dk = _merge_heads([dk for dk, _ in parts])
+        dv = _merge_heads([dv for _, dv in parts])
         dk_ref[cols, :] = (dk * sm_scale).astype(dk_ref.dtype)
         dv_ref[cols, :] = dv.astype(dv_ref.dtype)
     for i, dq_i in enumerate(dq):
         dq_ref[pl.ds(i * block_q, block_q), :] = (dq_i * sm_scale).astype(
             dq_ref.dtype)
+
+
+def _flash_bwd_kernel_one_result(k_ref, v_ref, q_ref, do_ref, o_ref, lse_ref,
+                                 dqkv_ref, delta_ref, buf, sem, **kw):
+    """The fused backward where q, k and v are columns of one array, and so
+    are dq, dk and dv: a grid step may write one block of a result through
+    its BlockSpec and this one writes three, so the result stays in HBM.
+    The kernel puts the step's three pieces into ``buf[slot]`` and three
+    copies carry them out under the next step's work; a slot is written
+    again only after its copies of two steps before are through, and the
+    last step waits for all.  (Grid steps run one after the other on a
+    chip's one core, which this counts on.  A third grid axis that handed
+    dk and dv over through the BlockSpec read 0.085 ms a layer more: its
+    two steps had no work to hide their copy behind.  My chip run, PR 49.)"""
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    b, n = pl.program_id(0), pl.program_id(1)
+    blocks, lanes = pl.num_programs(1), q_ref.shape[1]
+    step = b * blocks + n
+    last = pl.num_programs(0) * blocks - 1
+    slot = step % 2
+
+    def copies(slot):
+        # A wait needs the copy's size and semaphore only, so the steps
+        # before are waited for through this step's descriptors.
+        return [pltpu.make_async_copy(
+            buf.at[slot, part],
+            dqkv_ref.at[b, :, pl.ds(pl.multiple_of(
+                (part * blocks + n) * lanes, lanes), lanes)],
+            sem.at[slot, part]) for part in range(3)]
+
+    @pl.when(step >= 2)
+    def _():
+        for copy in copies(slot):
+            copy.wait()
+
+    _flash_bwd_kernel(k_ref, v_ref, q_ref, do_ref, o_ref, lse_ref,
+                      buf.at[slot, 1], buf.at[slot, 2], buf.at[slot, 0],
+                      delta_ref, **kw)
+    for copy in copies(slot):
+        copy.start()
+
+    @pl.when(step == last)
+    def _():
+        for copy in copies(slot):
+            copy.wait()
+
+    @pl.when((step == last) & (step >= 1))
+    def _():
+        for copy in copies(1 - slot):
+            copy.wait()
 
 
 # What a kernel may take in VMEM without asking for more (Mosaic's scoped
@@ -475,178 +630,281 @@ def _flash_bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 # compiled here for a described v5e) and is part of a train cell's set-up.
 _VMEM_BUDGET = 16 * 1024 * 1024
 _MAX_UNROLLED_TILES = 16
+_LANES = 128  # of a vector register, and of a column block's tiling
 
 
 def _whole_head_fits(lq: int, lk: int, d: int, itemsize: int, block_q: int,
-                     block_k: int, causal: bool) -> bool:
-    """Whether the kernels take a whole head a grid step, their tile loops
-    unrolled (the forward) and dq accumulated beside dk and dv (the fused
-    backward).  The backward's residency decides: Q, K, V, dO in and dQ,
-    dK, dV out whole, the lane dimension padded to 128 and every pipelined
-    block held twice, lse and delta on 8 sublanes; half the budget is left
-    to the [block_k, block_q] float32 tiles (s, p, dp, ds and their casts),
-    the float32 sums and the compiler's own scratch."""
+                     block_k: int, causal: bool, heads: int = 1) -> bool:
+    """Whether the kernels take a whole block of ``heads`` heads a grid
+    step, their tile loops unrolled (the forward) and dq accumulated beside
+    dk and dv (the fused backward).  The backward's residency decides: Q, K,
+    V, dO in and dQ, dK, dV out whole, the lane dimension padded to 128 (so
+    two heads of 64 take what one took) and every pipelined block held
+    twice, lse and delta on 8 sublanes; half the budget is left to O (read once, for delta), the [block_k, block_q] float32 tiles
+    (s, p, dp, ds and their casts), the float32 sums and the compiler's own
+    scratch.  Every head of the block has tile bodies of its own."""
     tiles = (causal_tile_schedule(lq, lk, block_q, block_k)["visited"]
              if causal else (lq // block_q) * (lk // block_k))
-    lanes = -(-d // 128) * 128
+    lanes = -(-heads * d // _LANES) * _LANES
     blocks = 2 * (3 * lq + 4 * lk) * lanes * itemsize
     rows = 2 * 2 * _LSE_SUBLANES * lq * 4
-    return (tiles <= _MAX_UNROLLED_TILES
+    return (tiles * heads <= _MAX_UNROLLED_TILES
             and blocks + rows <= _VMEM_BUDGET // 2)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-               with_lse=True):
+def _heads_per_block(lq: int, lk: int, h: int, d: int, itemsize: int,
+                     block_q: int, block_k: int, causal: bool) -> int:
+    """How many heads a column block of ``[B, L, H * D]`` holds where the
+    kernels can read the operands as the projection wrote them: the heads
+    that fill 128 lanes (two of 64, four of 32), or one whose width is a
+    multiple of 128.  0 where they cannot, and the operands go head-major
+    (``[B * H, L, D]``, a transpose each way) as they always did: a head
+    count or width that does not fill whole blocks of 128 lanes, a block of
+    heads past the whole-head kernels' residency or tile count, and the
+    rolled forms past 2,048 tokens."""
+    if d % _LANES == 0:
+        heads = 1
+    elif _LANES % d == 0 and h % (_LANES // d) == 0:
+        heads = _LANES // d
+    else:
+        return 0
+    fits = _whole_head_fits(lq, lk, d, itemsize, block_q, block_k, causal,
+                            heads)
+    return heads if fits else 0
+
+
+def _column_spec(rows: int, lanes: int, first: int):
+    """The [rows, lanes] block of a ``[B, L, columns]`` array at (batch i,
+    column block ``first + n``) of a grid whose first two axes are (i, n):
+    ``first`` is where in the array the operand starts, 0 for an array of
+    its own and H * D / lanes, twice that, for k and v inside a fused qkv."""
     import jax.experimental.pallas as pl
 
-    b, lq, h, d = q.shape
-    lk = k.shape[1]
-    scale = sm_scale if sm_scale is not None else d ** -0.5
-    # Fold batch and heads into the grid's first dimension.
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, lq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
+    return pl.BlockSpec((None, rows, lanes),
+                        lambda i, n, *_: (i, 0, first + n))
 
-    unrolled = _whole_head_fits(lq, lk, d, q.dtype.itemsize, block_q,
-                                block_k, causal)
+
+def _tile_spec(rows: int, lanes: int):
+    """The [rows, lanes] tile j of column block n: the rolled kernels'."""
+    import jax.experimental.pallas as pl
+
+    return pl.BlockSpec((None, rows, lanes), lambda i, n, j: (i, j, n))
+
+
+def _row_spec(rows: int, tiled: bool = False):
+    """lse and delta, ``[B, blocks, 8, L]``: a column block's tile of rows,
+    whole or the tile j of the rolled kernels."""
+    import jax.experimental.pallas as pl
+
+    return pl.BlockSpec((None, None, _LSE_SUBLANES, rows),
+                        (lambda i, n, j: (i, n, 0, j)) if tiled
+                        else (lambda i, n, *_: (i, n, 0, 0)))
+
+
+def _operands(ops, lanes: int):
+    """(q, k, v, column blocks of one of them, the column block at which
+    each starts) of the kernels' operands: three ``[B, L, C]`` arrays of
+    their own, or one ``[B, L, 3 * C]`` that is all three side by side."""
+    if len(ops) == 3:
+        return ops + (ops[0].shape[2] // lanes, (0, 0, 0))
+    blocks = ops[0].shape[2] // (3 * lanes)
+    return ops * 3 + (blocks, (0, blocks, 2 * blocks))
+
+
+# The kernels are jitted on their own so that the layers of a program share
+# one trace and one lowering of each: a kernel's body is unrolled over its
+# tiles and heads, and a program lowers again in every process that runs
+# it, cached or not (trace and lowering of the 24-layer train step for a
+# described v5e: PERF.md section 6, PR 49).  The compiler inlines the call.
+@functools.partial(jax.jit, static_argnames=(
+    "d", "heads", "whole", "causal", "sm_scale", "block_q", "block_k",
+    "interpret", "with_lse"))
+def _fwd_call(ops, *, d, heads, whole, causal, sm_scale, block_q, block_k,
+              interpret, with_lse=True):
+    """ops: (q, k, v) ``[B, L, C]`` or (qkv,) ``[B, L, 3 * C]``, C a
+    multiple of the block's ``heads * d`` lanes → (out ``[B, Lq, C]``, lse
+    ``[B, C / lanes, 8, Lq]`` float32 as ``_pack_rows`` lays it, or None).
+    The two forms differ in where the index maps find k and v, and in
+    nothing else.  ``whole``: the grid is (batch, column blocks) with whole
+    blocks, the q tiles unrolled; otherwise (batch, column blocks, q
+    tiles)."""
+    import jax.experimental.pallas as pl
+
+    lanes = heads * d
+    q, k, v, blocks, first = _operands(ops, lanes)
+    b, lq, lk = q.shape[0], q.shape[1], k.shape[1]
     kernel = functools.partial(_flash_fwd_kernel, causal=causal,
-                               sm_scale=scale, block_q=block_q,
-                               block_k=block_k, unrolled=unrolled)
-    # The grid is (heads,) with whole blocks when unrolled, (heads, q
-    # tiles) otherwise; ``whole``'s index map takes either.
-    whole = lambda *block: pl.BlockSpec((None,) + block,
-                                        lambda i, *_: (i, 0, 0))
-    if unrolled:
-        grid, q_spec = (b * h,), whole(lq, d)
-        lse_spec = whole(_LSE_SUBLANES, lq)
+                               sm_scale=sm_scale, block_q=block_q,
+                               block_k=block_k, unrolled=whole, heads=heads)
+    if whole:
+        grid = (b, blocks)
+        q_spec = o_spec = _column_spec(lq, lanes, 0)
+        lse_spec = _row_spec(lq)
     else:
-        grid = (b * h, lq // block_q)
-        q_spec = pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0))
-        lse_spec = pl.BlockSpec((None, _LSE_SUBLANES, block_q),
-                                lambda i, j: (i, 0, j))
-    out_specs = [q_spec]
-    out_shape = [jax.ShapeDtypeStruct((b * h, lq, d), q.dtype)]
+        grid = (b, blocks, lq // block_q)
+        q_spec = o_spec = _tile_spec(block_q, lanes)
+        lse_spec = _row_spec(block_q, tiled=True)
+    out_specs = [o_spec]
+    out_shape = [jax.ShapeDtypeStruct((b, lq, blocks * lanes), q.dtype)]
     if with_lse:
         out_specs.append(lse_spec)
         out_shape.append(jax.ShapeDtypeStruct(
-            (b * h, _LSE_SUBLANES, lq), jnp.float32))
+            (b, blocks, _LSE_SUBLANES, lq), jnp.float32))
     res = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, whole(lk, d), whole(lk, d)],
+        in_specs=[q_spec, _column_spec(lk, lanes, first[1]),
+                  _column_spec(lk, lanes, first[2])],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
         name="flash_fwd",
-    )(qf, kf, vf)
-    if not with_lse:
-        return res[0], None, (qf, kf, vf)
-    out, lse = res
-    # Keep only sublane 0 as the residual: [bh, lq] is compact in HBM,
-    # while the broadcast copy would be carried for every layer.
-    return out, lse[:, 0, :], (qf, kf, vf)
+    )(q, k, v)
+    return res if with_lse else (res[0], None)
 
 
-def _flash_bwd(q, k, v, out, lse, do, causal, sm_scale, block_q, block_k,
-               fused, interpret):
-    """``fused``: ``flash_bwd``, one kernel, a whole head a grid step;
-    otherwise ``flash_dkv`` and ``flash_dq``, each walking its tiles on
-    the grid with K, V (or Q, dO) whole beside them."""
+@functools.partial(jax.jit, static_argnames=(
+    "d", "heads", "whole", "causal", "sm_scale", "block_q", "block_k",
+    "interpret"))
+def _bwd_call(ops, out, lse, do, *, d, heads, whole, causal, sm_scale,
+               block_q, block_k, interpret):
+    """The gradients of ``ops`` in their own form: (dq, dk, dv), or the one
+    (dqkv,) of a fused qkv.  ``whole``: ``flash_bwd``, one kernel, a whole
+    block of heads a grid step; otherwise ``flash_dkv`` and ``flash_dq``,
+    each walking its tiles on the grid with K, V (or Q, dO) whole beside
+    them (one head a block, three operands)."""
     import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
 
-    bh, lq, d = q.shape
-    lk = k.shape[1]
-    scale = sm_scale if sm_scale is not None else d ** -0.5
-    # delta_i = sum_d dO_i * O_i — the softmax-normalization term of dS.
-    delta2 = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                     axis=-1)  # [bh, lq]
-    # Re-broadcast the row vectors across the 8-sublane tiling dim the
-    # kernels read (transient, not a residual).
-    lse8 = jnp.broadcast_to(lse[:, None, :], (bh, _LSE_SUBLANES, lq))
-    delta8 = jnp.broadcast_to(delta2[:, None, :], (bh, _LSE_SUBLANES, lq))
-
-    whole = lambda *block: pl.BlockSpec((None,) + block,
-                                        lambda i, *_: (i, 0, 0))
-    q_whole, k_whole = whole(lq, d), whole(lk, d)
-    row_whole = whole(_LSE_SUBLANES, lq)
+    lanes = heads * d
+    q, k, v, blocks, first = _operands(ops, lanes)
+    b, lq, lk = q.shape[0], q.shape[1], k.shape[1]
+    q_whole, k_whole, v_whole = (
+        _column_spec(x.shape[1], lanes, f) for x, f in zip((q, k, v), first))
+    do_whole, row_whole = _column_spec(lq, lanes, 0), _row_spec(lq)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
-    if fused:
-        dk, dv, dq = pl.pallas_call(
-            functools.partial(_flash_bwd_kernel, causal=causal,
-                              sm_scale=scale, block_q=block_q,
-                              block_k=block_k),
-            grid=(bh,),
-            in_specs=[k_whole, k_whole, q_whole, q_whole, row_whole,
+    statics = dict(causal=causal, sm_scale=sm_scale, block_q=block_q)
+    if whole:
+        call = functools.partial(
+            pl.pallas_call, grid=(b, blocks),
+            in_specs=[k_whole, v_whole, q_whole, do_whole, do_whole,
                       row_whole],
-            out_specs=[k_whole, k_whole, q_whole],
-            out_shape=[like(k), like(v), like(q)],
-            interpret=interpret,
-            name="flash_bwd",
-        )(k, v, q, do, lse8, delta8)
-        return dq, dk, dv
+            interpret=interpret, name="flash_bwd")
+        statics.update(block_k=block_k, heads=heads)
+        delta = pltpu.VMEM((_LSE_SUBLANES, lq), jnp.float32)
+        if len(ops) == 3:
+            dk, dv, dq = call(
+                functools.partial(_flash_bwd_kernel, **statics),
+                out_specs=[_column_spec(lk, lanes, 0)] * 2 + [do_whole],
+                out_shape=[like(k), like(v), like(q)],
+                scratch_shapes=[delta],
+            )(k, v, q, do, out, lse)
+            return dq, dk, dv
+        return (call(
+            functools.partial(_flash_bwd_kernel_one_result, **statics),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            out_shape=like(q),
+            scratch_shapes=[delta, pltpu.VMEM((2, 3, lq, lanes), q.dtype),
+                            pltpu.SemaphoreType.DMA((2, 3))],
+        )(k, v, q, do, out, lse),)
 
-    k_tile = pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0))
+    # delta_i = sum_d dO_i * O_i — the softmax-normalization term of dS —
+    # as the kernels read lse (head-major operands: a sum over the minor
+    # dimension, which the compiler fuses).
+    delta = jnp.sum((do.astype(jnp.float32) * out.astype(jnp.float32))
+                    .reshape(b, lq, blocks, d), axis=-1)
+    delta = jnp.broadcast_to(delta.transpose(0, 2, 1)[:, :, None, :],
+                             lse.shape)
+    k_tile, q_tile = _tile_spec(block_k, lanes), _tile_spec(block_q, lanes)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, causal=causal, sm_scale=scale,
-                          block_q=block_q),
-        grid=(bh, lk // block_k),
-        in_specs=[k_tile, k_tile, q_whole, q_whole, row_whole, row_whole],
+        functools.partial(_flash_dkv_kernel, **statics),
+        grid=(b, blocks, lk // block_k),
+        in_specs=[k_tile, k_tile, q_whole, do_whole, row_whole, row_whole],
         out_specs=[k_tile, k_tile],
         out_shape=[like(k), like(v)],
         interpret=interpret,
         name="flash_dkv",
-    )(k, v, q, do, lse8, delta8)
-    q_tile = pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0))
-    row_tile = pl.BlockSpec((None, _LSE_SUBLANES, block_q),
-                            lambda i, j: (i, 0, j))
+    )(k, v, q, do, lse, delta)
+    row_tile = _row_spec(block_q, tiled=True)
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, causal=causal, sm_scale=scale,
+        functools.partial(_flash_dq_kernel, causal=causal, sm_scale=sm_scale,
                           block_k=block_k),
-        grid=(bh, lq // block_q),
-        in_specs=[q_tile, k_whole, k_whole, q_tile, row_tile, row_tile],
+        grid=(b, blocks, lq // block_q),
+        in_specs=[q_tile, k_whole, v_whole, q_tile, row_tile, row_tile],
         out_specs=q_tile,
         out_shape=like(q),
         interpret=interpret,
         name="flash_dq",
-    )(q, k, v, do, lse8, delta8)
+    )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _head_major(x, h: int):
+    """[B, L, H * D] → [B * H, L, D]: a head is an array of one column."""
+    b, l, c = x.shape
+    return x.reshape(b, l, h, c // h).transpose(0, 2, 1, 3).reshape(
+        b * h, l, c // h)
+
+
+def _head_minor(x, h: int):
+    """[B * H, L, D] → [B, L, H * D]."""
+    bh, l, d = x.shape
+    return x.reshape(bh // h, h, l, d).transpose(0, 2, 1, 3).reshape(
+        bh // h, l, h * d)
+
+
+def _form(lq, lk, h, d, dtype, causal, sm_scale, block_q, block_k, interpret):
+    """(heads a column block, or 0 for head-major operands; the kernels'
+    static arguments), read off the call's shapes."""
+    heads = _heads_per_block(lq, lk, h, d, dtype.itemsize, block_q, block_k,
+                             causal)
+    whole = bool(heads) or _whole_head_fits(lq, lk, d, dtype.itemsize,
+                                            block_q, block_k, causal)
+    return heads, dict(
+        d=d, heads=max(heads, 1), whole=whole, causal=causal,
+        sm_scale=sm_scale if sm_scale is not None else d ** -0.5,
+        block_q=block_q, block_k=block_k, interpret=interpret)
+
+
+def _flash_forward(ops, h, causal, sm_scale, block_q, block_k, interpret,
+                   with_lse):
+    """(out [B, Lq, H * D], the VJP's residuals).  The residuals of the
+    column-block form are the caller's own arrays and the result itself."""
+    _, lq, columns = ops[0].shape
+    d = columns // h // (3 if len(ops) == 1 else 1)
+    heads, statics = _form(lq, ops[-1].shape[1], h, d, ops[0].dtype, causal,
+                           sm_scale, block_q, block_k, interpret)
+    if not heads:
+        ops = tuple(_head_major(x, h) for x in ops)
+    out, lse = _fwd_call(ops, with_lse=with_lse, **statics)
+    return (out if heads else _head_minor(out, h)), (ops, out, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+def _flash(ops, h, causal, sm_scale, block_q, block_k, interpret):
+    """ops: (q, k, v), ``[B, L, H * D]`` each, or (qkv,) ``[B, L, 3 * H *
+    D]`` where ``_heads_per_block`` holds it → ``[B, Lq, H * D]``."""
     # Primal (inference) path: skip the lse output entirely — nothing
     # reads it outside the VJP, and it costs an HBM write per call.
-    out, _lse, _res = _flash_fwd(q, k, v, causal, sm_scale, block_q,
-                                 block_k, interpret, with_lse=False)
-    b, lq, h, d = q.shape
-    return out.reshape(b, h, lq, d).transpose(0, 2, 1, 3)
+    return _flash_forward(ops, h, causal, sm_scale, block_q, block_k,
+                          interpret, with_lse=False)[0]
 
 
-def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    out, lse, (qf, kf, vf) = _flash_fwd(q, k, v, causal, sm_scale,
-                                        block_q, block_k, interpret)
-    b, lq, h, d = q.shape
-    return (out.reshape(b, h, lq, d).transpose(0, 2, 1, 3),
-            (qf, kf, vf, out, lse))
+def _flash_vjp_fwd(ops, h, causal, sm_scale, block_q, block_k, interpret):
+    return _flash_forward(ops, h, causal, sm_scale, block_q, block_k,
+                          interpret, with_lse=True)
 
 
-def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret,
+def _flash_vjp_bwd(h, causal, sm_scale, block_q, block_k, interpret,
                    residuals, g):
-    qf, kf, vf, out, lse = residuals
-    bh, lq, d = qf.shape
-    h = bh // g.shape[0]
-    b = g.shape[0]
-    gf = g.transpose(0, 2, 1, 3).reshape(bh, lq, d)
-    lk = kf.shape[1]
-    fused = _whole_head_fits(lq, lk, d, qf.dtype.itemsize, block_q, block_k,
-                             causal)
-    dq, dk, dv = _flash_bwd(qf, kf, vf, out, lse, gf, causal, sm_scale,
-                            block_q, block_k, fused, interpret)
-
-    def unfold(x, l):
-        return x.reshape(b, h, l, d).transpose(0, 2, 1, 3)
-
-    return unfold(dq, lq), unfold(dk, lk), unfold(dv, lk)
+    ops, out, lse = residuals
+    _, lq, columns = g.shape
+    heads, statics = _form(lq, ops[-1].shape[1], h, columns // h, g.dtype,
+                           causal, sm_scale, block_q, block_k, interpret)
+    if heads:
+        return (_bwd_call(ops, out, lse, g, **statics),)
+    grads = _bwd_call(ops, out, lse, _head_major(g, h), **statics)
+    return (tuple(_head_minor(x, h) for x in grads),)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -678,7 +936,19 @@ def _auto_blocks(lq: int, lk: int, d: int, causal: bool) -> Tuple[int, int]:
     unrolled).  Non-causal calls keep (256, 1024): (512, 512) read the
     same there (1,024: 0.58 + 1.17 against 0.57 + 1.19).  Under 1,024 keys
     the choice is the one the code always made, (128, 128): not measured
-    beyond [16, 512, 16, 64] (``mha_attention``)."""
+    beyond [16, 512, 16, 64] (``mha_attention``).
+
+    The table's operands were head-major arrays of their own.  Since PR 49
+    the whole-head forms read column blocks of ``[B, L, H * D]`` where
+    ``_heads_per_block`` allows; at these tiles, forward + backward of one
+    layer as a function of a fused projection (its split and the gradient's
+    concatenation included; ``tools/flash_layout_probe.py``, my chip run,
+    PR 49), ms: head-major / column blocks / out of the fused array:
+
+        [8, 1024, 16, 64]   1.74 / 1.23 / 1.04     (two heads a block)
+        [8, 1024,  8, 128]  0.77 / 0.73 / 0.54     (a head a block)
+        [4, 2048,  8, 128]  1.07 / 1.04 / 0.85
+        [8, 1024, 32, 32]   3.28 / 2.22 / 2.04     (four heads a block)"""
     def pick(l, target):
         b = target
         while b > 128 and l % b:
@@ -692,19 +962,7 @@ def _auto_blocks(lq: int, lk: int, d: int, causal: bool) -> Tuple[int, int]:
     return pick(lq, 256), pick(lk, 1024)
 
 
-def flash_attention(q, k, v, causal: bool = True,
-                    sm_scale: Optional[float] = None,
-                    block_q: Optional[int] = None,
-                    block_k: Optional[int] = None,
-                    interpret: bool = False) -> jax.Array:
-    """Fused attention on TPU via Pallas, differentiable (custom VJP
-    recomputes P blockwise from the saved log-sum-exp — the flash
-    backward). q,k,v: [B, L, H, D] → [B, L, H, D].
-
-    Block sizes default to a measured per-length choice (_auto_blocks);
-    pass them explicitly to override."""
-    b, lq, h, d = q.shape
-    lk = k.shape[1]
+def _blocks(lq, lk, d, causal, block_q, block_k):
     auto_q, auto_k = _auto_blocks(lq, lk, d, causal)
     block_q = auto_q if block_q is None else block_q
     block_k = auto_k if block_k is None else block_k
@@ -718,4 +976,48 @@ def flash_attention(q, k, v, causal: bool = True,
         raise ValueError(f"causal flash attention requires lq == lk "
                          f"(got {lq} vs {lk}); use the XLA path for "
                          f"decode-style windows")
-    return _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+    return block_q, block_k
+
+
+def flash_attention(q, k, v, causal: bool = True,
+                    sm_scale: Optional[float] = None,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
+                    interpret: bool = False) -> jax.Array:
+    """Fused attention on TPU via Pallas, differentiable (custom VJP
+    recomputes P blockwise from the saved log-sum-exp — the flash
+    backward). q,k,v: [B, L, H, D] → [B, L, H, D].
+
+    Block sizes default to a measured per-length choice (_auto_blocks);
+    pass them explicitly to override.  The kernels read the operands as
+    ``[B, L, H * D]``, which costs nothing, where ``_heads_per_block`` says
+    they can, and head-major otherwise."""
+    b, lq, h, d = q.shape
+    lk = k.shape[1]
+    block_q, block_k = _blocks(lq, lk, d, causal, block_q, block_k)
+    ops = (q.reshape(b, lq, h * d), k.reshape(b, lk, h * d),
+           v.reshape(b, lk, h * d))
+    return _flash(ops, h, causal, sm_scale, block_q, block_k,
+                  interpret).reshape(b, lq, h, d)
+
+
+def flash_attention_qkv(qkv, num_heads: int, causal: bool = True,
+                        sm_scale: Optional[float] = None,
+                        block_q: Optional[int] = None,
+                        block_k: Optional[int] = None,
+                        interpret: bool = False) -> jax.Array:
+    """``flash_attention`` on a fused projection: qkv ``[B, L, 3 * H * D]``
+    (q's columns, then k's, then v's, a head's D columns together) →
+    ``[B, L, H * D]``.  The same kernels find q, k and v in the one array
+    through their index maps, and the backward writes the one dqkv: no
+    split before, no concatenation after.  Where the shapes do not allow
+    it (``_heads_per_block``) the array is split and goes the other way."""
+    b, l, columns = qkv.shape
+    d = columns // (3 * num_heads)
+    block_q, block_k = _blocks(l, l, d, causal, block_q, block_k)
+    ops = (qkv,)
+    if not _heads_per_block(l, l, num_heads, d, qkv.dtype.itemsize, block_q,
+                            block_k, causal):
+        ops = tuple(jnp.split(qkv, 3, axis=-1))
+    return _flash(ops, num_heads, causal, sm_scale, block_q, block_k,
+                  interpret)

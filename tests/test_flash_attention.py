@@ -6,12 +6,16 @@ parity tests any flash implementation carries).
 Matmul precision is pinned to float32 for the comparisons: at default
 precision the XLA einsums round through bf16 on some backends, which
 would drown the kernel's actual error."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops.attention import _xla_attention, flash_attention, mha_attention
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import (_xla_attention, flash_attention,
+                                   flash_attention_qkv, mha_attention)
 
 
 def _rand_qkv(B, L, H, D, seed=0):
@@ -20,20 +24,59 @@ def _rand_qkv(B, L, H, D, seed=0):
                  for k in jax.random.split(key, 3))
 
 
+@pytest.fixture
+def ran(monkeypatch):
+    """What the forward kernels were given: (first operand's shape, heads a
+    column block) a call."""
+    calls = []
+
+    def spy(ops, **kw):
+        calls.append((ops[0].shape, kw["heads"]))
+        return forward(ops, **kw)
+
+    forward = attention._fwd_call
+    monkeypatch.setattr(attention, "_fwd_call", spy)
+    return calls
+
+
+# (shape, heads a 128-lane column block): 0 where the operands go head-major
+LAYOUTS = [
+    ((2, 256, 3, 32), 0),   # three heads of 32 do not fill 128 lanes
+    ((1, 384, 2, 64), 2),   # a pair of heads a block, parted by lane masks
+                            # (causal: 6 tiles a head; not: 9, head-major)
+    ((2, 256, 4, 64), 2),   # two such blocks
+    ((1, 256, 3, 64), 0),   # an odd head count: no pair for the last one
+    ((1, 256, 2, 128), 1),  # a head a block: no mask
+    ((1, 256, 8, 32), 4),   # four heads a block
+]
+
+
+def _check_layout(ran, shape, heads):
+    """The form that ran is the one the shape names."""
+    b, l, h, d = shape
+    assert ran and all(call == ran[0] for call in ran)
+    assert ran[0] == (((b, l, h * d), heads) if heads
+                      else ((b * h, l, d), 1))
+
+
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("shape", [(2, 256, 3, 32), (1, 384, 2, 64)])
-def test_flash_forward_matches_xla(causal, shape):
+@pytest.mark.parametrize("shape,heads", LAYOUTS)
+def test_flash_forward_matches_xla(causal, shape, heads, ran):
     q, k, v = _rand_qkv(*shape)
     with jax.default_matmul_precision("float32"):
         out_f = flash_attention(q, k, v, causal=causal, interpret=True)
         out_x = _xla_attention(q, k, v, causal, None)
     np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_x),
                                atol=1e-5, rtol=1e-5)
+    if shape == (1, 384, 2, 64) and not causal:
+        heads = 0  # 18 tile bodies a pair: past what the kernels unroll
+    _check_layout(ran, shape, heads)
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_gradients_match_xla(causal):
-    q, k, v = _rand_qkv(2, 256, 3, 32)
+@pytest.mark.parametrize("shape,heads", [LAYOUTS[0]] + LAYOUTS[2:])
+def test_flash_gradients_match_xla(causal, shape, heads, ran):
+    q, k, v = _rand_qkv(*shape)
 
     with jax.default_matmul_precision("float32"):
         def loss_f(q, k, v):
@@ -49,6 +92,44 @@ def test_flash_gradients_match_xla(causal):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=2e-4, rtol=1e-3,
             err_msg=f"d{name} mismatch (causal={causal})")
+    _check_layout(ran, shape, heads)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("shape,heads", LAYOUTS[2:])
+def test_fused_qkv_equals_the_three_operand_call(causal, shape, heads, ran):
+    """One ``[B, L, 3 * H * D]`` array read through the index maps, and the
+    same columns as three arrays: the same kernels on the same numbers, so
+    the same output, and a dqkv that is dq, dk and dv side by side.  Where
+    the shape does not allow column blocks the array is split."""
+    b, l, h, d = shape
+    q, k, v = _rand_qkv(*shape)
+    qkv = jnp.concatenate([x.reshape(b, l, h * d) for x in (q, k, v)], -1)
+    w = _rand_qkv(*shape, seed=5)[0]
+
+    def fused(qkv):
+        return flash_attention_qkv(qkv, h, causal=causal, interpret=True)
+
+    def three(q, k, v):
+        return flash_attention(q, k, v, causal=causal, interpret=True)
+
+    with jax.default_matmul_precision("float32"):
+        out_1, vjp_1 = jax.vjp(fused, qkv)
+        out_3, vjp_3 = jax.vjp(three, q, k, v)
+        (dqkv,) = vjp_1(w.reshape(b, l, h * d))
+        grads = vjp_3(w)
+    np.testing.assert_array_equal(np.asarray(out_1).reshape(shape),
+                                  np.asarray(out_3))
+    np.testing.assert_array_equal(
+        np.asarray(dqkv),
+        np.concatenate([np.asarray(g).reshape(b, l, h * d) for g in grads],
+                       -1))
+    assert ran[0] == (((b, l, 3 * h * d), heads) if heads
+                      else ((b * h, l, d), 1))
+    np.testing.assert_allclose(
+        np.asarray(out_1).reshape(shape),
+        np.asarray(_xla_attention(q, k, v, causal, None)), atol=1e-5,
+        rtol=1e-5)
 
 
 @pytest.mark.parametrize("blocks", [(128, 64), (64, 128)])
@@ -74,6 +155,9 @@ def test_flash_causal_lq_gt_lk_kernel_bounds():
     bottom-right alignment."""
     from ray_tpu.ops.attention import NEG_INF, _flash
 
+    def flat(x):
+        return x.reshape(x.shape[:2] + (-1,))
+
     q, _, _ = _rand_qkv(1, 256, 2, 32, seed=1)
     _, k, v = _rand_qkv(1, 128, 2, 32, seed=2)
 
@@ -86,7 +170,8 @@ def test_flash_causal_lq_gt_lk_kernel_bounds():
         return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
     def flash(q):
-        return _flash(q, k, v, True, None, 64, 64, True)
+        return _flash((flat(q), flat(k), flat(v)), 2, True, None, 64, 64,
+                      True).reshape(q.shape)
 
     with jax.default_matmul_precision("float32"):
         np.testing.assert_allclose(np.asarray(flash(q)), np.asarray(ref(q)),
@@ -121,27 +206,35 @@ def test_explicit_flash_propagates_the_kernel_error():
         mha_attention(q, k, v, causal=True, use_flash=True)
 
 
-def test_mesh_aware_attention_matches_unsharded():
+@pytest.mark.parametrize("kernels,shape", [
+    (False, (8, 128, 4, 32)),  # the XLA path inside the shard_map
+    (True, (8, 128, 4, 64)),   # the kernels, a pair of heads a device
+    (True, (8, 128, 2, 64)),   # one head a device: head-major there
+])
+def test_mesh_aware_attention_matches_unsharded(kernels, shape, monkeypatch):
     """mha_attention(mesh=...) under a plain jit with batch- and head-
     sharded inputs: the shard_map over the logical-axis rules gives every
     device its own rows, and the result and gradient equal the unsharded
     ones.  (On the chip this is what lets the Mosaic kernel run under a
-    sharded jit at all; here the XLA path runs inside the shard_map.)"""
+    sharded jit at all; here the XLA path runs inside the shard_map, or
+    the three-operand kernels interpreted.)"""
     from ray_tpu.parallel import MeshSpec, batch_sharding, make_mesh
 
+    if kernels:
+        monkeypatch.setattr(attention, "flash_attention", functools.partial(
+            flash_attention, interpret=True))
+    attend = functools.partial(mha_attention, use_flash=kernels or None)
     mesh = make_mesh(MeshSpec({"data": 4, "model": 2}))
-    q, k, v = _rand_qkv(8, 128, 4, 32)
+    q, k, v = _rand_qkv(*shape)
     qs, ks, vs = (jax.device_put(x, batch_sharding(mesh, 4))
                   for x in (q, k, v))
-    got = jax.jit(lambda q, k, v: mha_attention(q, k, v, mesh=mesh))(
-        qs, ks, vs)
+    got = jax.jit(lambda q, k, v: attend(q, k, v, mesh=mesh))(qs, ks, vs)
     assert got.sharding.spec[0] == "data" and got.sharding.spec[2] == "model"
-    np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(mha_attention(q, k, v)),
+    np.testing.assert_allclose(np.asarray(got), np.asarray(attend(q, k, v)),
                                atol=1e-6, rtol=1e-6)
     g = jax.jit(jax.grad(lambda q: jnp.sum(
-        mha_attention(q, ks, vs, mesh=mesh) ** 2)))(qs)
-    gw = jax.grad(lambda q: jnp.sum(mha_attention(q, k, v) ** 2))(q)
+        attend(q, ks, vs, mesh=mesh) ** 2)))(qs)
+    gw = jax.grad(lambda q: jnp.sum(attend(q, k, v) ** 2))(q)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gw),
                                atol=1e-5, rtol=1e-5)
 
@@ -219,19 +312,19 @@ def test_flash_auto_blocks_at_1k_match_xla(shape, dtype):
 def test_fused_backward_equals_the_two_kernel_form(blocks, causal):
     """One kernel that computes a tile's s, p, dp, ds once, and the dq and
     dk/dv kernels that each compute them: the same gradients."""
-    from ray_tpu.ops.attention import _flash_bwd, _flash_fwd
+    from ray_tpu.ops.attention import _bwd_call, _fwd_call, _head_major
 
     bq, bk = blocks
-    q, k, v = _rand_qkv(1, 512, 2, 32)
-    do = _rand_qkv(1, 512, 2, 32, seed=3)[0]
+    ops = tuple(_head_major(x.reshape(1, 512, 64), 2)
+                for x in _rand_qkv(1, 512, 2, 32))
+    dof = _head_major(_rand_qkv(1, 512, 2, 32, seed=3)[0].reshape(
+        1, 512, 64), 2)
+    kw = dict(d=32, heads=1, causal=causal, sm_scale=32 ** -0.5, block_q=bq,
+              block_k=bk, interpret=True)
     with jax.default_matmul_precision("float32"):
-        out, lse, (qf, kf, vf) = _flash_fwd(q, k, v, causal, None, bq, bk,
-                                            True)
-        dof = do.transpose(0, 2, 1, 3).reshape(qf.shape)
-        one = _flash_bwd(qf, kf, vf, out, lse, dof, causal, None, bq, bk,
-                         True, True)
-        two = _flash_bwd(qf, kf, vf, out, lse, dof, causal, None, bq, bk,
-                         False, True)
+        out, lse = _fwd_call(ops, whole=True, **kw)
+        one = _bwd_call(ops, out, lse, dof, whole=True, **kw)
+        two = _bwd_call(ops, out, lse, dof, whole=False, **kw)
     for a, b, name in zip(one, two, ("dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5,
                                    rtol=1e-5, err_msg=name)
@@ -360,3 +453,75 @@ def test_whole_head_form_follows_the_shape(length, d, itemsize, whole):
     bq, bk = _auto_blocks(length, length, d, True)
     assert _whole_head_fits(length, length, d, itemsize, bq, bk,
                             True) is whole
+
+
+# ---------------------------------------------------------------------------
+# One trace of each kernel a program, whatever its depth (PR 49).
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("fused_qkv", [True, False])
+def test_unrolled_blocks_trace_each_kernel_once(fused_qkv, monkeypatch):
+    """A model of four unrolled ``Block``s, differentiated: the forward
+    kernel's body and the backward's are each traced once, not once a
+    layer, because the kernels sit behind one jitted call that the layers
+    share (the lowering follows the trace: one function, four calls).  Both
+    ways into the kernels: GPT-2's own fused qkv, and ``attn_fn=`` with
+    three operands (the several-chip cells')."""
+    from ray_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_loss_fn
+
+    traced = {"_flash_fwd_kernel": 0, "_flash_bwd_kernel": 0}
+
+    def counting(name):
+        body = getattr(attention, name)
+
+        def counted(*refs, **kw):
+            traced[name] += 1
+            return body(*refs, **kw)
+        return counted
+
+    cfg = GPT2Config.tiny(num_layers=4, num_heads=4, hidden_size=256,
+                          max_position_embeddings=256, use_flash=True)
+    assert cfg.num_layers < cfg.scan_layers_threshold
+    model = GPT2(cfg, attn_fn=None if fused_qkv else functools.partial(
+        mha_attention, use_flash=True))
+    ids = jnp.zeros((2, 256), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"]
+    for name in traced:
+        monkeypatch.setattr(attention, name, counting(name))
+    jax.clear_caches()  # an earlier test's trace of these shapes
+    # Traced and never lowered: compiled kernels do not lower for the CPU.
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: gpt2_loss_fn(p, model.apply, {"input_ids": ids})))(params))
+    for call in ("_fwd_call", "_bwd_call"):  # bound once, called four times
+        assert text.count(f"jaxpr={call}") == 4, call
+    assert traced == {"_flash_fwd_kernel": 1, "_flash_bwd_kernel": 1}
+
+
+@pytest.mark.parametrize("attn_fn", [False, True])
+def test_gpt2_through_the_kernels_equals_the_xla_path(attn_fn, monkeypatch):
+    """GPT-2's block hands the kernels its fused qkv (or, with ``attn_fn=``,
+    three operands split off it): loss and gradients equal those of the XLA
+    path on the same weights."""
+    from ray_tpu.models.gpt2 import GPT2, GPT2Config, gpt2_loss_fn
+
+    for name in ("flash_attention", "flash_attention_qkv"):
+        monkeypatch.setattr(attention, name, functools.partial(
+            getattr(attention, name), interpret=True))
+
+    def model(use_flash):
+        cfg = GPT2Config.tiny(num_heads=2, hidden_size=128,
+                              use_flash=use_flash, dtype=jnp.float32)
+        return GPT2(cfg, attn_fn=functools.partial(
+            mha_attention, use_flash=use_flash) if attn_fn else None)
+
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 512)
+    params = model(False).init(jax.random.PRNGKey(0), ids)["params"]
+    with jax.default_matmul_precision("float32"):
+        (lf, gf), (lx, gx) = (jax.value_and_grad(
+            lambda p: gpt2_loss_fn(p, model(flash).apply,
+                                   {"input_ids": ids}))(params)
+            for flash in (True, False))
+    np.testing.assert_allclose(float(lf), float(lx), rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(gf),
+                    jax.tree_util.tree_leaves(gx)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   rtol=1e-3)

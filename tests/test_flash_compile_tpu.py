@@ -11,8 +11,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops.attention import (_auto_blocks, _whole_head_fits,
-                                   flash_attention)
+from ray_tpu.ops.attention import (_auto_blocks, _heads_per_block,
+                                   _whole_head_fits, flash_attention,
+                                   flash_attention_qkv)
 
 
 @pytest.fixture(scope="module")
@@ -39,31 +40,62 @@ def one_chip():
 
 
 @pytest.mark.timeout(120)
-@pytest.mark.parametrize("shape,dtype,kernels", [
-    # the train cells: [8, 1024, 16, 64] bf16 on every chip
-    ((8, 1024, 16, 64), jnp.bfloat16, ("flash_fwd", "flash_bwd")),
-    # Llama-family heads, the longest length the fused backward holds
-    ((1, 2048, 8, 128), jnp.bfloat16, ("flash_fwd", "flash_bwd")),
+@pytest.mark.parametrize("shape,dtype,kernels,first", [
+    # the train cells: [8, 1024, 16, 64] bf16 on every chip, a pair of
+    # heads a column block of the projection's own [B, L, H * D]
+    ((8, 1024, 16, 64), jnp.bfloat16, ("flash_fwd", "flash_bwd"),
+     "bf16[8,1024,1024]"),
+    # ... and as GPT-2's block calls them, q, k and v inside one qkv: the
+    # backward's one result is dqkv
+    ((8, 1024, 16, 64), "qkv", ("flash_fwd", "flash_bwd"),
+     "bf16[8,1024,3072]"),
+    # Llama-family heads, a head a block: no mask
+    ((2, 1024, 16, 128), jnp.bfloat16, ("flash_fwd", "flash_bwd"),
+     "bf16[2,1024,2048]"),
+    # ... at the longest length the fused backward holds
+    ((1, 2048, 8, 128), jnp.bfloat16, ("flash_fwd", "flash_bwd"),
+     "bf16[1,2048,1024]"),
+    # a pair of heads of 64 at 2,048 is 20 tile bodies, past what the
+    # kernels unroll: head-major, a head a grid step
+    ((4, 2048, 16, 64), jnp.bfloat16, ("flash_fwd", "flash_bwd"),
+     "bf16[64,2048,64]"),
     # past its residency: the two-kernel form, K and V whole beside a tile
     ((1, 4096, 12, 64), jnp.bfloat16, ("flash_fwd", "flash_dq",
-                                       "flash_dkv")),
+                                       "flash_dkv"), "bf16[12,4096,64]"),
     ((1, 8192, 4, 128), jnp.bfloat16, ("flash_fwd", "flash_dq",
-                                       "flash_dkv")),
-    ((2, 1024, 4, 64), jnp.float32, ("flash_fwd", "flash_bwd")),
+                                       "flash_dkv"), "bf16[4,8192,128]"),
+    ((2, 1024, 4, 64), jnp.float32, ("flash_fwd", "flash_bwd"),
+     "f32[2,1024,256]"),
 ])
-def test_flash_kernels_compile_for_v5e(one_chip, shape, dtype, kernels):
-    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+def test_flash_kernels_compile_for_v5e(one_chip, shape, dtype, kernels,
+                                       first):
+    """``first``: the first result of the backward's (last) kernel, which
+    is how a device trace names it and what says which layout ran."""
+    b, length, h, d = shape
+    if dtype == "qkv":
+        dtype = jnp.bfloat16
+        args = (jax.ShapeDtypeStruct((b, length, 3 * h * d), dtype,
+                                     sharding=one_chip),)
+        attend = lambda qkv: flash_attention_qkv(qkv, h, causal=True)  # noqa: E731
+    else:
+        args = (jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip),) * 3
+        attend = lambda q, k, v: flash_attention(q, k, v, causal=True)  # noqa: E731
     grad = jax.jit(jax.grad(
-        lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True)
-                                .astype(jnp.float32)), argnums=(0, 1, 2)))
-    text = grad.lower(x, x, x).compile().as_text()
+        lambda *a: jnp.sum(attend(*a).astype(jnp.float32)),
+        argnums=tuple(range(len(args)))))
+    text = grad.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     for name in ("flash_fwd", "flash_bwd", "flash_dq", "flash_dkv"):
         assert (name in text) == (name in kernels), name
-    _, length, _, d = shape
-    whole = _whole_head_fits(length, length, d, jnp.dtype(dtype).itemsize,
-                             *_auto_blocks(length, length, d, True), True)
+    calls = [line.split(" = ")[1].lstrip("(") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls[-1].startswith(first), calls
+    itemsize, blocks = jnp.dtype(dtype).itemsize, _auto_blocks(
+        length, length, d, True)
+    whole = _whole_head_fits(length, length, d, itemsize, *blocks, True)
     assert whole == ("flash_bwd" in kernels)
+    lanes = _heads_per_block(length, length, h, d, itemsize, *blocks, True)
+    assert bool(lanes) == (f"[{b},{length}," in first)
 
 
 @pytest.mark.timeout(120)
