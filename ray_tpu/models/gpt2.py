@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.attention import mha_attention_qkv
 from ray_tpu.ops.layers import gelu
+from ray_tpu.ops.losses import next_token_cross_entropy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -261,11 +262,7 @@ def gpt2_loss_fn(params, apply_fn, batch) -> jax.Array:
     """Next-token cross-entropy. batch: {"input_ids": [B, L]} (labels are the
     shifted inputs, standard LM objective)."""
     ids = batch["input_ids"]
-    logits = apply_fn({"params": params}, ids)[:, :-1]
-    labels = ids[:, 1:]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    return next_token_cross_entropy(apply_fn({"params": params}, ids), ids)
 
 
 class GPT2Stage(nn.Module):
@@ -312,11 +309,7 @@ class GPT2Stage(nn.Module):
 
 def _stage_ce_loss(logits: jax.Array, ids: jax.Array) -> jax.Array:
     """Next-token CE on a microbatch (same objective as gpt2_loss_fn)."""
-    logits = logits[:, :-1]
-    labels = ids[:, 1:]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    return next_token_cross_entropy(logits, ids)
 
 
 def gpt2_head_cost(config: GPT2Config) -> float:

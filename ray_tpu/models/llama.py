@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import mha_attention
+from ray_tpu.ops.losses import next_token_cross_entropy
 from ray_tpu.ops.moe import experts_dropless, route_topk
 
 
@@ -378,11 +379,7 @@ class LlamaStage(nn.Module):
 
 def _stage_ce_loss(logits: jax.Array, ids: jax.Array) -> jax.Array:
     """Next-token CE on a microbatch (same objective as llama_loss_fn)."""
-    logits = logits[:, :-1]
-    labels = ids[:, 1:]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    return next_token_cross_entropy(logits, ids)
 
 
 def llama_head_cost(config: LlamaConfig) -> float:
@@ -439,8 +436,4 @@ def split_stages(config: LlamaConfig, num_stages: int, *,
 def llama_loss_fn(params, apply_fn, batch) -> jax.Array:
     """Next-token cross-entropy (same contract as gpt2_loss_fn)."""
     ids = batch["input_ids"]
-    logits = apply_fn({"params": params}, ids)[:, :-1]
-    labels = ids[:, 1:]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    return next_token_cross_entropy(apply_fn({"params": params}, ids), ids)

@@ -15,6 +15,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.losses import softmax_cross_entropy
+
 
 @dataclasses.dataclass(frozen=True)
 class ResNetConfig:
@@ -108,7 +110,6 @@ def resnet_loss_fn(params, batch_stats, apply_fn, batch):
     logits, new_state = apply_fn(
         {"params": params, "batch_stats": batch_stats}, batch["image"],
         train=True, mutable=["batch_stats"])
-    logp = jax.nn.log_softmax(logits)
-    ll = jnp.take_along_axis(logp, batch["label"][:, None], axis=-1)[:, 0]
     acc = jnp.mean(jnp.argmax(logits, -1) == batch["label"])
-    return -jnp.mean(ll), (new_state["batch_stats"], acc)
+    loss = softmax_cross_entropy(logits, batch["label"])
+    return loss, (new_state["batch_stats"], acc)

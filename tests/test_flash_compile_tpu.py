@@ -237,3 +237,50 @@ def test_ssm_step_compiles_for_v5e_and_updates_the_pool_in_place(
     pool_bytes = slots * heads * head * state * 4
     assert memory.alias_size_in_bytes == pool_bytes
     assert memory.temp_size_in_bytes < pool_bytes // 48
+
+
+@pytest.mark.timeout(120)
+@pytest.mark.parametrize("wants_float32", [False, True], ids=[
+    "next_token_cross_entropy",
+    "log_softmax_left_to_autodiff",  # the test can see one
+])
+def test_loss_head_compiles_for_v5e_without_float32_logits(
+        one_chip, wants_float32):
+    """GPT-2's tied head and loss at the train cells' vocabulary (50,257: no
+    multiple of 128) and widths, a few rows: ``ops/losses.py``'s backward
+    pass leaves no float32 array of the logits' shape in the program, forward
+    or backward, where autodiff of ``log_softmax`` writes one."""
+    from ray_tpu.ops.losses import next_token_cross_entropy
+    from tools.step_fusions import entry_operations
+
+    def left_to_autodiff(logits, ids):
+        logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
+        return -jnp.mean(jnp.take_along_axis(
+            logp, ids[:, 1:, None], axis=-1))
+
+    fn = left_to_autodiff if wants_float32 else next_token_cross_entropy
+    b, l, d, v = 2, 256, 1024, 50257
+
+    def head_loss(x, wte, ids):
+        logits = jnp.einsum("bld,vd->blv", x.astype(jnp.bfloat16),
+                            wte.astype(jnp.bfloat16)).astype(jnp.float32)
+        return fn(logits, ids)
+
+    shape = lambda *s, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    # results of the program's own instructions: what is written to memory,
+    # not what a fusion holds in registers
+    written = entry_operations(
+        jax.jit(jax.value_and_grad(head_loss, argnums=(0, 1))).lower(
+            shape(b, l, d), shape(v, d),
+            shape(b, l, dtype=jnp.int32)).compile().as_text())
+    found = [(o["name"], o["shapes"]) for o in written
+             if {f"f32[{b},{l},{v}]", f"f32[{b},{l - 1},{v}]"}
+             & set(o["shapes"])]
+    assert bool(found) == wants_float32, found
+    if not wants_float32:
+        # ... and one bf16 array of that shape, the logits: the gradient is
+        # made inside the two backward matmuls, as their operand
+        kept = [o["name"] for o in written
+                if o["op"] == "fusion" and f"bf16[{b},{l},{v}]" in o["shapes"]]
+        assert len(kept) == 1, kept
