@@ -109,14 +109,6 @@ CONFIG = RayTpuConfig()
 # ---- the registry (one declaration per tunable; grep for CONFIG.<name>
 # to find the consumer) ----
 CONFIG \
-    .declare("native_store", bool, False,
-             "Use the C++ shared-memory arena for driver puts.  Off by "
-             "default: the arena path predates the segment-pool + "
-             "batched-notify object plane (put_many coalescing, pooled "
-             "pre-faulted segments) and bypasses both; opt in only "
-             "until it learns those semantics.  The library is built "
-             "from shm_store.cpp on first use (the binary is not "
-             "committed); asked for and unbuildable, it raises.") \
     .declare("health_check_period_s", float, 0.5,
              "Worker liveness poll interval in the head monitor.") \
     .declare("transfer_chunk_bytes", int, 4 * 1024 * 1024,

@@ -96,14 +96,6 @@ class Head:
         # registration during head failover: replayed in add_remote_node.
         self._pending_worker_regs: Dict[NodeID, list] = defaultdict(list)
         self._pending_pgs: List[PlacementGroupInfo] = []
-        # Arena reader leases: oid -> {holder worker id: count}.  Granted when
-        # an arena resolution is handed to a reader, released when the reader
-        # drops its last zero-copy view.  The equivalent of plasma's client
-        # in-use counts (the reference never reuses memory while a client
-        # holds the buffer): an arena slot must not be recycled while any
-        # process may still read it.
-        self._arena_leases: Dict[ObjectID, Dict[bytes, int]] = defaultdict(dict)
-        self._arena_pending_free: set = set()
         self._cancelled: set = set()  # task ids cancelled while running
         # task id -> host usage fraction at kill time (memory-monitor
         # victims, head- or agent-side): the death handler surfaces a
@@ -761,8 +753,6 @@ class Head:
                     self.on_seal_batch(msg)
                 elif mtype == "put_inline_batch":
                     self.on_put_inline_batch(msg)
-                elif mtype == "arena_release":
-                    self.on_arena_release(msg)
                 elif mtype == "request":
                     self._handle_request(msg, conn, worker_id)
                 elif mtype == "notify":
@@ -824,19 +814,25 @@ class Head:
             raylet.try_dispatch()
 
     def on_worker_oom(self, worker_id: WorkerID, usage: float):
-        """A node agent's memory monitor is about to kill (or just killed)
-        one of its workers: mark the victim's running task so its death
-        surfaces as a typed, retryable OutOfMemoryError instead of a
-        generic WorkerCrashedError (the head-side monitor marks its own
-        victims the same way in memory_monitor.tick)."""
+        """A node agent's memory monitor chose one of its workers as the
+        victim: mark the victim's running task so its death surfaces as a
+        typed, retryable OutOfMemoryError instead of a generic
+        WorkerCrashedError (the head-side monitor marks its own victims
+        the same way in memory_monitor.tick), THEN have the agent kill
+        it.  The kill goes out from here because the worker's own socket
+        tells the head of the death on another thread than the agent's
+        messages: only mark-then-kill in one place orders the two."""
         from ray_tpu._private.recovery import note
 
         with self._lock:
-            _, h = self._find_worker(worker_id)
-            if h is None or h.current_task is None:
-                return
-            note("oom_worker_kills")
-            self._oom_killed[h.current_task.task_id] = usage
+            raylet, h = self._find_worker(worker_id)
+            if h is None:
+                return  # the agent kills it itself when no answer comes
+            if h.current_task is not None:
+                note("oom_worker_kills")
+                self._oom_killed[h.current_task.task_id] = usage
+            raylet.send_agent({"type": "oom_kill",
+                               "worker_id": worker_id.binary()})
 
     def on_object_replicated(self, node_id: NodeID, msg: dict):
         """An agent finished pulling a durability replica into its store:
@@ -880,14 +876,13 @@ class Head:
 
     def _reclaim_lessee_locked(self, lessee: bytes):
         """Lessee (worker or remote driver) died: release every worker
-        lease it held plus its arena leases — leaked leases are permanent
-        capacity loss (reference: lease reclaim on lessee death,
-        lease_policy / raylet).  Under the head lock."""
+        lease it held — leaked leases are permanent capacity loss
+        (reference: lease reclaim on lessee death, lease_policy / raylet).
+        Under the head lock."""
         for raylet in self.raylets.values():
             for h in list(raylet.workers.values()):
                 if h.leased_to == lessee:
                     self._release_lease_locked(raylet, h)
-        self._drop_arena_leases_for(lessee)
 
     def _on_register(self, worker_id: WorkerID, node_id: NodeID, conn,
                      direct_addr=None):
@@ -1140,8 +1135,6 @@ class Head:
             "seal_batch": self.on_seal_batch,
             "put_inline_batch": self.on_put_inline_batch,
             "task_done": self.on_task_done,
-            "arena_sealed": self.on_arena_sealed,
-            "arena_release": self.on_arena_release,
             "worker_blocked":
                 lambda m: self.on_worker_blocked(WorkerID(m["worker_id"])),
             "worker_unblocked":
@@ -1164,18 +1157,15 @@ class Head:
 
     def req_resolve_batch(self, payload, reply, caller):
         """Resolve many objects in one round trip: returns {hex: msg} for
-        every object that is available RIGHT NOW (arena leases granted as
-        in req_get_locations); callers fall back to the blocking per-object
-        path for the rest.  Collapses the driver's get([refs...]) from one
-        request per ref to one request per batch."""
+        every object that is available RIGHT NOW; callers fall back to the
+        blocking per-object path for the rest.  Collapses the driver's
+        get([refs...]) from one request per ref to one request per batch."""
         caller_host = self._caller_host(caller)
         out = {}
         with self._lock:
             for oid in payload["oids"]:
                 resolved = self._resolve_object(oid, caller_host=caller_host)
                 if resolved is not None:
-                    if resolved.get("kind") == "arena":
-                        self._grant_arena_lease(oid, caller)
                     self._note_pull_resolution(resolved)
                     out[oid.binary()] = resolved
         reply(out)
@@ -1188,8 +1178,6 @@ class Head:
         with self._lock:
             resolved = self._resolve_object(oid, caller_host=caller_host)
             if resolved is not None:
-                if resolved.get("kind") == "arena":
-                    self._grant_arena_lease(oid, caller)
                 if not payload.get("recheck"):
                     # A puller re-confirming its resolution already paid
                     # the wire-bytes count at the original handout.
@@ -1206,8 +1194,6 @@ class Head:
                 # of parking a callback nothing will ever fire.
                 resolved = self._resolve_object(oid, caller_host=caller_host)
                 if resolved is not None:
-                    if resolved.get("kind") == "arena":
-                        self._grant_arena_lease(oid, caller)
                     self._note_pull_resolution(resolved)
                     reply(resolved)
                     return
@@ -1224,8 +1210,6 @@ class Head:
                 if resolved_msg is None:
                     return
                 record["done"] = True
-                if resolved_msg.get("kind") == "arena":
-                    self._grant_arena_lease(oid, caller)
                 self._note_pull_resolution(resolved_msg)
                 reply(resolved_msg)
 
@@ -2423,16 +2407,6 @@ class Head:
                 self._link_contained(oid, item.get("contained"))
                 self._notify_object(oid)
 
-    def on_arena_sealed(self, msg: dict):
-        """Driver wrote directly into the head raylet's native arena."""
-        oid = ObjectID(msg["oid"])
-        with self._lock:
-            self.gcs.object_sealed(oid, NodeID(msg["node_id"]), msg["size"],
-                                   lineage_task=msg.get("lineage_task"))
-            self._link_contained(oid, msg.get("contained"))
-            self._maybe_make_durable(oid, msg["size"])
-            self._notify_object(oid)
-
     def on_put_inline(self, msg: dict):
         oid = ObjectID(msg["oid"])
         with self._lock:
@@ -2568,9 +2542,6 @@ class Head:
                     return {"kind": "store", "oid": oid, "meta": entry.meta,
                             "segment": entry.segments.get(node_id)}
             else:
-                hit = raylet.store.arena_lookup(oid)
-                if hit is not None:
-                    return hit
                 meta = raylet.store.meta(oid)
                 if meta is not None:
                     return {"kind": "store", "oid": oid, "meta": meta,
@@ -2752,13 +2723,6 @@ class Head:
             return
         if b"task:" in {h[:5] for h in entry.holders}:
             return
-        if self._arena_leases.get(oid):
-            # A reader still holds a zero-copy view over the arena slot:
-            # defer the free until the last lease is returned (plasma
-            # semantics — never recycle memory under a client).
-            self._arena_pending_free.add(oid)
-            return
-        self._arena_pending_free.discard(oid)
         for node_id in list(entry.locations):
             raylet = self.raylets.get(node_id)
             if raylet is not None:
@@ -2779,44 +2743,6 @@ class Head:
             for coid in contained:
                 if self.gcs.remove_reference(coid, holder):
                     self._free_object(coid)
-
-    # ----- arena reader leases -----
-    def _grant_arena_lease(self, oid: ObjectID, caller: Optional[WorkerID]):
-        holder = caller.binary() if caller is not None else b"driver"
-        with self._lock:
-            holders = self._arena_leases[oid]
-            holders[holder] = holders.get(holder, 0) + 1
-
-    def on_arena_release(self, msg: dict):
-        oid = ObjectID(msg["oid"])
-        holder = msg["holder"]
-        with self._lock:
-            holders = self._arena_leases.get(oid)
-            if holders is not None and holder in holders:
-                if holders[holder] <= 1:
-                    holders.pop(holder)
-                else:
-                    holders[holder] -= 1
-                if not holders:
-                    self._arena_leases.pop(oid, None)
-            self._maybe_complete_deferred_free(oid)
-
-    def _drop_arena_leases_for(self, holder: bytes):
-        for oid in list(self._arena_leases.keys()):
-            # .get(): a reentrant on_arena_release (GC finalizer on this
-            # thread — the RLock does not exclude it) may have removed the
-            # entry since the snapshot.
-            holders = self._arena_leases.get(oid)
-            if holders is not None and holder in holders:
-                holders.pop(holder)
-                if not holders:
-                    self._arena_leases.pop(oid, None)
-                self._maybe_complete_deferred_free(oid)
-
-    def _maybe_complete_deferred_free(self, oid: ObjectID):
-        if oid in self._arena_pending_free and not self._arena_leases.get(oid):
-            self._arena_pending_free.discard(oid)
-            self._free_object(oid)
 
     # ================= object durability =================
     def _maybe_make_durable(self, oid: ObjectID, size: int):
@@ -2888,24 +2814,13 @@ class Head:
 
     @staticmethod
     def _read_store_bytes(store) -> "Callable[[ObjectID], tuple]":
-        """Reader over a local store covering all three residences a
-        sealed object can have: shm segment, native arena, spill file."""
+        """Reader over a local store covering both residences a sealed
+        object can have: shm segment, spill file."""
         def read(oid: ObjectID):
             got = store.get(oid)
             if got is not None:
                 meta, view = got
                 return meta, bytes(view)
-            lock = getattr(store, "_lock", None)
-            if lock is not None:
-                with lock:
-                    hit = store.arena_lookup(oid)
-                    if hit is not None:
-                        from ray_tpu._native import ArenaReader
-
-                        view = ArenaReader.view(hit["store"], hit["offset"],
-                                                hit["size"],
-                                                hit["capacity"])
-                        return hit["meta"], bytes(view)
             rec = store.read_spilled(oid)
             if rec is not None:
                 return rec

@@ -191,6 +191,13 @@ class NodeAgent:
                 self._spawn_worker(msg)
             elif t == "kill_worker":
                 self._kill_worker(msg["worker_id"])
+            elif t == "oom_kill":
+                # The head's answer to our worker_oom.  The child stays in
+                # _children: the reap loop reports its exit as any other.
+                with self._children_lock:
+                    proc = self._children.get(msg["worker_id"])
+                if proc is not None:
+                    proc.kill()
             elif t == "store_adopt":
                 self.store.adopt(ObjectID(msg["oid"]), msg["size"],
                                  msg["meta"], segment=msg.get("segment"))
@@ -424,17 +431,26 @@ class NodeAgent:
                     victim = (wid, proc)  # dict order: newest spawn last
             if victim is None:
                 continue
+            wid, proc = victim
+            asked = getattr(proc, "_rtpu_oom_asked", None)
+            if asked is None:
+                # The head marks the victim's task and sends oom_kill
+                # back, so the mark is there whichever connection tells
+                # it of the death first (the worker's own socket closes
+                # on another thread than this conn's reader) and it types
+                # the death as an OOM (OutOfMemoryError w/ usage,
+                # retryable) instead of a generic worker crash.
+                proc._rtpu_oom_asked = now
+                try:
+                    self.send({"type": "worker_oom",
+                               "worker_id": wid, "usage": usage})
+                    continue
+                except Exception:
+                    pass  # no head to ask: relieve the host ourselves
+            elif now - asked < 5.0:
+                continue  # the head's oom_kill is on its way
             try:
-                # Mark BEFORE the kill on the same ordered conn the exit
-                # report rides, so the head types the death as an OOM
-                # (OutOfMemoryError w/ usage, retryable) instead of a
-                # generic worker crash.
-                self.send({"type": "worker_oom",
-                           "worker_id": victim[0], "usage": usage})
-            except Exception:
-                pass
-            try:
-                victim[1].kill()
+                proc.kill()
             except Exception:
                 pass
 

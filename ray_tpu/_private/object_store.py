@@ -6,11 +6,10 @@ to clients.  Our TPU-native design keeps the same *contract* — named,
 immutable, sealed, zero-copy-readable shared-memory objects with create/seal/
 get/delete and eviction accounting — but maps each object to its own POSIX
 shm segment (``multiprocessing.shared_memory``), which any worker process on
-the node can attach by name.  A C++ arena allocator (ray_tpu/_native) can be
-slotted under the same interface later for allocation-rate-bound workloads;
-for ML workloads the store holds few, large, numpy-backed objects
-(SampleBatches, checkpoints, dataset blocks) where per-object segments are
-ideal: the kernel does the zero-copy, and there is no fragmentation.
+the node can attach by name.  For ML workloads the store holds few, large,
+numpy-backed objects (SampleBatches, checkpoints, dataset blocks) where
+per-object segments are ideal: the kernel does the zero-copy, and there is no
+fragmentation.
 
 Small objects never come here — they live in the in-process memory store
 (memory_store.py), exactly like the reference's CoreWorkerMemoryStore
@@ -446,7 +445,6 @@ class SharedMemoryStore:
     """
 
     def __init__(self, capacity_bytes: int = 2 * 1024**3,
-                 use_native_arena: bool = True,
                  spill_dir: Optional[str] = None):
         self.capacity = capacity_bytes
         self.used = 0
@@ -468,19 +466,8 @@ class SharedMemoryStore:
         # directory so unreferenced objects are simply dropped.
         self.should_spill = None
         self.spill_callback = None  # notified with (oid) after a spill
-        # Native C++ arena (plasma-core equivalent, ray_tpu/_native): used for
-        # owner-process writes (driver puts).  Worker-created objects keep
-        # the per-segment zero-round-trip path; both are zero-copy reads.
-        self.arena = None
         from ray_tpu._private.config import CONFIG
 
-        if use_native_arena and CONFIG.native_store:
-            # Asked for by configuration: a build failure raises here — the
-            # store does not quietly become the segment store.
-            from ray_tpu import _native
-
-            self.arena = _native.NativeArenaStore(
-                "rtpu_arena_" + os.urandom(6).hex(), capacity_bytes)
         # Segment pool: steady-state large puts reuse pre-faulted recycled
         # segments instead of paying shm_open + kernel page-zeroing per
         # object (see SegmentPool).  Free-list bytes are NOT charged to
@@ -667,8 +654,6 @@ class SharedMemoryStore:
     def delete(self, object_id: ObjectID, evicted: bool = False,
                keep_spilled: bool = False):
         with self._lock:
-            if self.arena is not None:
-                self.arena.delete(object_id.binary())
             if not keep_spilled:
                 self._drop_spill_file(object_id)
             obj = self._objects.pop(object_id, None)
@@ -807,25 +792,6 @@ class SharedMemoryStore:
             except OSError:
                 pass
 
-    # -- native arena paths (owner process only) --
-    def arena_write(self, object_id: ObjectID, size: int) -> Optional[memoryview]:
-        if self.arena is None:
-            return None
-        return self.arena.allocate(object_id.binary(), size)
-
-    def arena_seal(self, object_id: ObjectID, metadata: bytes):
-        self.arena.seal(object_id.binary(), metadata)
-
-    def arena_lookup(self, object_id: ObjectID):
-        if self.arena is None:
-            return None
-        hit = self.arena.lookup(object_id.binary())
-        if hit is None:
-            return None
-        offset, size, meta = hit
-        return {"kind": "arena", "store": self.arena.name, "offset": offset,
-                "size": size, "meta": meta, "capacity": self.arena.capacity}
-
     def shutdown(self, keep_spilled: bool = False):
         """``keep_spilled=True`` is the node-death teardown: in-memory
         objects die with the store, but on-disk spill/backup copies are
@@ -833,9 +799,6 @@ class SharedMemoryStore:
         with self._lock:
             for oid in list(self._objects.keys()):
                 self.delete(oid, keep_spilled=keep_spilled)
-            if self.arena is not None:
-                self.arena.close()
-                self.arena = None
             self.pool.close()
 
     def stats(self) -> Dict[str, int]:

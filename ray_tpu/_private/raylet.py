@@ -406,7 +406,6 @@ class RemoteStoreProxy:
 
     def __init__(self, raylet: "RemoteRaylet"):
         self._raylet = raylet
-        self.arena = None
         self.evict_callback = None  # agents report via "object_evicted" msgs
         # Spill records reported by the agent ("object_spilled"): lets the
         # head hand same-host callers a direct spill-file resolution.
@@ -431,9 +430,6 @@ class RemoteStoreProxy:
         self._spilled[object_id] = (path, meta, size)
 
     def meta(self, object_id):
-        return None
-
-    def arena_lookup(self, object_id):
         return None
 
     def spilled_lookup(self, object_id):

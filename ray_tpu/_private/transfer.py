@@ -508,24 +508,6 @@ class ObjectTransferServer:
             o, ln = self._clamp(len(data), off, length)
             return (meta, len(data), ln,
                     _view_chunks(memoryview(data)[o:o + ln], chunk))
-        # Arena-resident object (owner-process put): copy out under the
-        # store lock — an arena slot can be recycled by a concurrent
-        # delete, and unlike shm segments the mapping gives no lifetime
-        # guarantee to readers in this process.
-        lock = getattr(self.store, "_lock", None)
-        if lock is None:
-            return None
-        with lock:
-            hit = self.store.arena_lookup(oid)
-            if hit is not None:
-                from ray_tpu._native import ArenaReader
-
-                view = ArenaReader.view(hit["store"], hit["offset"],
-                                        hit["size"], hit["capacity"])
-                data = memoryview(bytes(view))
-                o, ln = self._clamp(len(data), off, length)
-                return (hit["meta"], len(data), ln,
-                        _view_chunks(data[o:o + ln], chunk))
         # Spilled-to-disk fallback: stream straight off the spill file
         # (reference: spilled_object_reader.h) — chunked reads feed the
         # pipelined sender, so the whole object is never buffered here.

@@ -209,10 +209,6 @@ class DirectTransport:
             self.head.on_put_inline_batch(msg)
         elif t == "task_done":
             self.head.on_task_done(msg)
-        elif t == "arena_sealed":
-            self.head.on_arena_sealed(msg)
-        elif t == "arena_release":
-            self.head.on_arena_release(msg)
         elif t == "object_partial":
             self.head.on_object_partial(msg, self.head.host_key)
         elif t == "object_partial_drop":
@@ -220,8 +216,7 @@ class DirectTransport:
 
     def store_for(self, node_id):
         """In-process fast path: the driver writes straight into the head
-        raylet's store — the native arena when present, pooled shm
-        segments otherwise (zero IPC either way)."""
+        raylet's store, pooled shm segments (zero IPC)."""
         raylet = self.head.raylets.get(node_id)
         return raylet.store if raylet is not None else None
 
@@ -670,20 +665,6 @@ from ray_tpu._private.runtime_env_pkg import PyModulesOverlay  # noqa: E402
 _pymods_overlay = PyModulesOverlay()
 
 
-def _arena_lease_releaser(transport, oid_bin: bytes, holder_bin: bytes):
-    """Standalone finalizer (must not capture the buffer owner) that returns
-    this process's reader lease on an arena object to the head."""
-
-    def release():
-        try:
-            transport.notify({"type": "arena_release", "oid": oid_bin,
-                              "holder": holder_bin})
-        except Exception:
-            pass
-
-    return release
-
-
 # Sentinel: _put_object_deferred consumed the put AND its first local ref
 # (owner-resident fast path) — no notify, no ObjectRef-side add_ref.
 _OWNED_WITH_REF = {"type": "_owned_with_ref"}
@@ -1020,8 +1001,8 @@ class CoreWorker:
     def put_many(self, values: Sequence[Any]) -> List[ObjectRef]:
         """Put a burst of K objects with O(1) control-plane messages.
 
-        Bytes move exactly as in put() (owner store / arena / pooled shm
-        segments), but the per-object ``seal``/``put_inline`` notifies are
+        Bytes move exactly as in put() (owner store / pooled shm segments),
+        but the per-object ``seal``/``put_inline`` notifies are
         coalesced into one ``seal_batch``/``put_inline_batch`` message, and
         the head registers this process as holder of every store-resident
         object in the same message — so a K-put burst costs at most two
@@ -1044,7 +1025,7 @@ class CoreWorker:
             if t == "put_inline":
                 inline_items.append(msg)
                 plan.append((oid, "inline"))
-            elif t == "seal":
+            else:  # "seal"
                 # Holder rides the batch: pre-register the local ref and
                 # let the head's batch handler record it, instead of one
                 # add_ref message per object.
@@ -1052,10 +1033,6 @@ class CoreWorker:
                 with self._refs_lock:
                     self._local_refs[oid] = self._local_refs.get(oid, 0) + 1
                 plan.append((oid, "seal"))
-            else:  # arena_sealed — rare; keep its dedicated handler
-                msg["type"] = t
-                self.transport.notify(msg)
-                plan.append((oid, "inline"))
         if inline_items:
             self.transport.notify({"type": "put_inline_batch",
                                    "items": inline_items})
@@ -1115,18 +1092,6 @@ class CoreWorker:
         store = getattr(self.transport, "store_for",
                         lambda n: None)(self.node_id)
         if store is not None:
-            view = store.arena_write(oid, size)
-            if view is not None:
-                try:
-                    meta = ser.pack_into(s, view)
-                finally:
-                    view.release()
-                store.arena_seal(oid, meta)
-                self._cache_value(oid, value)
-                return {"type": "arena_sealed", "oid": oid.binary(),
-                        "node_id": self.node_id.binary(), "size": size,
-                        "contained": contained,
-                        "lineage_task": lineage_task}
             # In-process pooled path: allocate from the node store (a
             # recycled, already-faulted pool segment in steady state —
             # no shm_open, no kernel page-zeroing), pack straight in.
@@ -1202,9 +1167,8 @@ class CoreWorker:
         if len(ref_list) > 1:
             # One round trip resolves everything already available; only
             # the stragglers take the blocking per-object path.
-            # Dedup: a repeated ref must not be granted two arena leases
-            # when only one materialize (and lease release) will happen.
-            # Owner-resident (non-EXTERN) refs never go to the head.
+            # Dedup: a repeated ref is resolved once.  Owner-resident
+            # (non-EXTERN) refs never go to the head.
             from ray_tpu._private.direct import EXTERN
 
             def _head_resident(oid: ObjectID) -> bool:
@@ -1223,41 +1187,26 @@ class CoreWorker:
         owned_lookup = self._owned.lookup
         from ray_tpu._private.direct import READY
 
-        try:
-            for r in ref_list:
-                oid = r.id
-                msg = resolved.pop(oid.binary(), None)
-                if msg is not None and oid not in value_cache:
-                    out.append(self._materialize(oid, msg))
-                    continue
-                if msg is not None and msg.get("kind") == "arena":
-                    # Batch granted a lease but the cache won: give the
-                    # lease back instead of dropping it on the floor.
-                    self._release_arena_lease(oid)
-                # Fast path: cached value or owner-resident READY bytes
-                # (the common case for direct-task results).
-                v = value_cache.get(oid, value_cache)
-                if v is not value_cache:
-                    out.append(v)
-                    continue
-                e = owned_lookup(oid)
-                if e is not None and e.state == READY:
-                    value, _ = ser.unpack(e.meta, memoryview(e.data))
-                    self._cache_value(oid, value)
-                    out.append(value)
-                    continue
-                out.append(self._get_one(oid, timeout,
-                                         getattr(r, "owner_addr", None)))
-        finally:
-            # If an earlier ref's materialization raised, release the
-            # leases of every unconsumed arena resolution — otherwise the
-            # slots stay pinned until the driver disconnects.
-            for oid_bin, msg in resolved.items():
-                if msg.get("kind") == "arena":
-                    try:
-                        self._release_arena_lease(ObjectID(oid_bin))
-                    except Exception:
-                        pass
+        for r in ref_list:
+            oid = r.id
+            msg = resolved.pop(oid.binary(), None)
+            if msg is not None and oid not in value_cache:
+                out.append(self._materialize(oid, msg))
+                continue
+            # Fast path: cached value or owner-resident READY bytes
+            # (the common case for direct-task results).
+            v = value_cache.get(oid, value_cache)
+            if v is not value_cache:
+                out.append(v)
+                continue
+            e = owned_lookup(oid)
+            if e is not None and e.state == READY:
+                value, _ = ser.unpack(e.meta, memoryview(e.data))
+                self._cache_value(oid, value)
+                out.append(value)
+                continue
+            out.append(self._get_one(oid, timeout,
+                                     getattr(r, "owner_addr", None)))
         return out[0] if single else out
 
     def get_many(self, refs: Sequence[ObjectRef],
@@ -1297,15 +1246,11 @@ class CoreWorker:
         for oid_bin, msg in (batch or {}).items():
             oid = ObjectID(oid_bin)
             if oid in self._value_cache:
-                if msg.get("kind") == "arena":
-                    self._release_arena_lease(oid)
                 continue
             try:
                 self._materialize(oid, msg)
             except Exception:
                 pass  # the per-arg path re-raises with proper context
-                # (arena failure paths inside _materialize already
-                # released their lease)
 
     def _cache_value(self, oid: ObjectID, value):
         self._value_cache[oid] = value
@@ -1457,42 +1402,6 @@ class CoreWorker:
             value, _ = ser.unpack(msg["meta"], shm.buf)
             self._cache_value(oid, value)
             self._shm_registry[oid] = shm  # keep mapping alive for zero-copy views
-            return value
-        if kind == "arena":
-            import weakref
-
-            import numpy as np
-
-            from ray_tpu._native import ArenaReader
-
-            # The head granted this process a reader lease on the arena slot
-            # when it handed out this resolution; the slot will not be
-            # recycled until we release it (plasma in-use-count semantics).
-            try:
-                view = ArenaReader.view(msg["store"], msg["offset"],
-                                        msg["size"], msg["capacity"])
-            except FileNotFoundError:
-                self._release_arena_lease(oid)
-                raise exc.ObjectLostError(f"arena object {oid} vanished")
-            try:
-                # Wrap the raw view in a weakref-able carrier: every
-                # zero-copy array deserialized out of this object keeps a
-                # buffer chain back to `owner`, so its finalizer fires
-                # exactly when the last view is garbage-collected.
-                owner = np.frombuffer(view, dtype=np.uint8)
-                value, _ = ser.unpack(msg["meta"], memoryview(owner))
-            except BaseException:
-                self._release_arena_lease(oid)
-                raise
-            if ser.num_oob_buffers(msg["meta"]):
-                weakref.finalize(
-                    owner, _arena_lease_releaser(
-                        self.transport, oid.binary(),
-                        self.worker_id.binary()))
-            else:
-                # Nothing in `value` views the arena (in-band pickle only).
-                self._release_arena_lease(oid)
-            self._cache_value(oid, value)
             return value
         if kind == "spilled":
             # Same-host spill file: zero-copy mmap read (reference:
@@ -1889,14 +1798,6 @@ class CoreWorker:
                     and not fresh.get("local_partial"):
                 return None  # leader failed/vanished: pull it ourselves
         return None
-
-    def _release_arena_lease(self, oid: ObjectID):
-        try:
-            self.transport.notify({"type": "arena_release",
-                                   "oid": oid.binary(),
-                                   "holder": self.worker_id.binary()})
-        except Exception:
-            pass
 
     def get_async(self, ref: ObjectRef) -> Future:
         fut: Future = Future()

@@ -177,10 +177,10 @@ class RLHFLoop:
         rewards = self.scorer.score_rollouts(rollouts)
         batch = SequenceBatch.from_rollouts(rollouts, self.pad_to)
         sgd_t0 = time.monotonic()
-        work0 = self.engine.stats()["work_seconds"]
+        before = self.engine.stats()
         metrics = self.learner.update(batch.as_dict())
         sgd_t1 = time.monotonic()
-        work1 = self.engine.stats()["work_seconds"]
+        after = self.engine.stats()
 
         # Versioned one-put broadcast: put once, every engine replica
         # resolves the same ref (in-process engines take the tree).
@@ -211,7 +211,11 @@ class RLHFLoop:
             "sgd_seconds": sgd_t1 - sgd_t0,
             "swap_seconds": swap_s,
             "gen_busy_frac_during_sgd": (
-                (work1 - work0) / max(sgd_t1 - sgd_t0, 1e-9)),
+                (after["work_seconds"] - before["work_seconds"])
+                / max(sgd_t1 - sgd_t0, 1e-9)),
+            # Decode steps the engine finished while this SGD ran: the
+            # overlap as a count (tools/perf_smoke.run_rlhf_smoke).
+            "decode_steps_during_sgd": after["steps"] - before["steps"],
             "response_tokens": batch.num_response_tokens,
         })
         return metrics

@@ -125,6 +125,7 @@ class SampleStream:
         self._lag_sum = 0
         self._lag_max = 0
         self._lag_hist: Dict[int, int] = {}
+        self._stale_versions: Dict[int, int] = {}  # version carried -> drops
         self._idle_s = 0.0
         self._busy_s = 0.0
         self._wait_s = 0.0
@@ -210,7 +211,14 @@ class SampleStream:
         t_wait0 = time.perf_counter()
         while True:
             self._refill()
-            pendings = [p for win in self._windows.values() for p in win]
+            # Oldest dispatch first: wait() answers in the order asked,
+            # so a worker that always has a fragment ready cannot keep the
+            # consumer from ever reaching the others' (their windows would
+            # stay full of finished fragments, stale ones among them, and
+            # the workers idle).
+            pendings = sorted(
+                (p for win in self._windows.values() for p in win),
+                key=lambda p: p.dispatched_at)
             if not pendings:
                 return None  # no workers at all
             ready, _ = ray_tpu.wait([p.future for p in pendings],
@@ -247,6 +255,8 @@ class SampleStream:
             if self.max_weight_staleness is not None and \
                     lag > self.max_weight_staleness:
                 self.stale_dropped += 1
+                self._stale_versions[version] = \
+                    self._stale_versions.get(version, 0) + 1
                 if self._metrics is not None:
                     try:
                         self._metrics["stale"].mark()
@@ -292,6 +302,8 @@ class SampleStream:
             "fragments_per_s": self.fragments_consumed / dt if dt else 0.0,
             "steps_per_s": self.steps_consumed / dt if dt else 0.0,
             "stale_dropped": self.stale_dropped,
+            "stale_dropped_versions": dict(sorted(
+                self._stale_versions.items())),
             "failures_seen": self.failures_seen,
             "weights_version": self.weights_version,
             "weight_lag_mean": self._lag_sum / n,

@@ -39,8 +39,8 @@ strike machinery) — the Ray design's canonical object-store workload
 - **Gather/SGD overlap** — :meth:`ReplayPlane.prefetch` returns a
   ``flow.Stage`` that keeps K gathered batches in flight, so the
   gather + host assembly of batch i+1 runs while the learner's SGD step
-  consumes batch i (tools/perf_smoke.run_replay_smoke proves it with
-  wall stamps).
+  consumes batch i (tools/perf_smoke.run_replay_smoke counts the
+  gathers issued ahead of the consumer).
 - **Shard death** — shards live behind the existing WorkerSet strike
   machinery: a failed RPC strikes the shard, a struck-out shard is
   replaced (empty) and the missing draw mass is re-spread over the
@@ -456,7 +456,6 @@ class ReplayPlane:
         self._metrics = None
         self._metrics_dead = False
         self.gather_calls = 0          # batched get_many gathers issued
-        self.sample_stamps: List[Tuple[float, float]] = []  # (t0, t1)
         self.stale_rows = 0
         self._closed = False
 
@@ -688,7 +687,6 @@ class ReplayPlane:
         """One ``[B, ...]`` batch: two-level priority draw resolved with
         ONE batched get_many gather (distributed) or direct views
         (local)."""
-        t0 = time.monotonic()
         beta = self.beta if beta is None else float(beta)
         if self._core is not None:
             with self._lock:
@@ -704,10 +702,6 @@ class ReplayPlane:
                                        p_mins, beta, int(batch_size), rng)
         else:
             batch = self._sample_distributed(int(batch_size), beta, rng)
-        t1 = time.monotonic()
-        self.sample_stamps.append((t0, t1))
-        if len(self.sample_stamps) > 256:
-            del self.sample_stamps[:128]
         self._mark("samples")
         self._mark("sample_rows", len(batch))
         return batch

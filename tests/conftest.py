@@ -30,30 +30,48 @@ from ray_tpu._private.jax_env import ensure_compile_cache  # noqa: E402
 ensure_compile_cache()
 
 
-@pytest.hookimpl(hookwrapper=True)
-def pytest_runtest_call(item):
-    """``@pytest.mark.timeout(seconds)``: a limit of the test's own (the
-    installation has no pytest-timeout).  A test that hangs fails after
-    its limit instead of running the whole suite into the driver's clock
-    (PR 29 ended in exit code 124).  SIGALRM interrupts the main thread,
-    where pytest and every xdist worker run their tests."""
-    marker = item.get_closest_marker("timeout")
-    if marker is None \
-            or threading.current_thread() is not threading.main_thread():
-        yield
-        return
-    seconds = float(marker.args[0])
+# Every test has a limit of its own: ``@pytest.mark.timeout(seconds)`` where
+# it carries one, this otherwise.  The slowest test of a whole tier-1 run
+# took 50.5 s (PR 52, six workers on a loaded sandbox; the junit file's
+# times); a test still going after six times that is waiting on something
+# that will not come.
+DEFAULT_TIMEOUT_S = 300.0
 
-    def on_alarm(signum, frame):
-        raise TimeoutError(f"{item.nodeid} passed its limit of {seconds} s")
 
-    previous = signal.signal(signal.SIGALRM, on_alarm)
-    signal.setitimer(signal.ITIMER_REAL, seconds)
-    try:
-        yield
-    finally:
-        signal.setitimer(signal.ITIMER_REAL, 0)
-        signal.signal(signal.SIGALRM, previous)
+def _limited(phase):
+    """A hook wrapper that gives one phase of a test (its fixtures' set-up,
+    its body, their tear-down) the test's limit (the installation has no
+    pytest-timeout).  A test that hangs fails after its limit instead of
+    running the whole suite into the driver's clock (PR 29 ended in exit
+    code 124; one run of PR 43 sat in a tear-down, waiting on a defunct
+    child, until the run's own limit cut it).  SIGALRM interrupts the main
+    thread, where pytest and every xdist worker run their tests."""
+
+    def wrapper(item):
+        if threading.current_thread() is not threading.main_thread():
+            yield
+            return
+        marker = item.get_closest_marker("timeout")
+        seconds = float(marker.args[0]) if marker else DEFAULT_TIMEOUT_S
+
+        def on_alarm(signum, frame):
+            raise TimeoutError(
+                f"{item.nodeid} ({phase}) passed its limit of {seconds} s")
+
+        previous = signal.signal(signal.SIGALRM, on_alarm)
+        signal.setitimer(signal.ITIMER_REAL, seconds)
+        try:
+            yield
+        finally:
+            signal.setitimer(signal.ITIMER_REAL, 0)
+            signal.signal(signal.SIGALRM, previous)
+
+    return pytest.hookimpl(hookwrapper=True)(wrapper)
+
+
+pytest_runtest_setup = _limited("set-up")
+pytest_runtest_call = _limited("call")
+pytest_runtest_teardown = _limited("tear-down")
 
 
 @pytest.fixture

@@ -17,9 +17,7 @@ def fresh():
 
 
 def test_defaults_and_attr_access():
-    # native_store defaults OFF: the arena path bypasses the segment-pool
-    # + batched-notify object plane (see the registry declaration).
-    assert CONFIG.native_store is False
+    assert CONFIG.tracing_enabled is False
     assert CONFIG.serve_max_slots == 8
     assert CONFIG.get("transfer_chunk_bytes") == 4 * 1024 * 1024
 
@@ -48,8 +46,8 @@ def test_undeclared_flag_rejected():
 
 def test_dump_lists_every_flag():
     d = CONFIG.dump()
-    assert "native_store" in d and "gcs_snapshot_period_s" in d
-    assert len(d) >= 15
+    assert "tracing_enabled" in d and "gcs_snapshot_period_s" in d
+    assert len(d) == 45
 
 
 def test_every_declared_flag_is_read():
@@ -71,9 +69,9 @@ def test_system_config_string_bool_goes_through_parser():
     """'0'/'false' strings must disable a bool flag — bool('0') is True,
     which would silently invert the user's intent."""
     CONFIG.reset()
-    CONFIG.apply_system_config({"native_store": "0"})
-    assert CONFIG.native_store is False
+    CONFIG.apply_system_config({"direct_transport": "0"})
+    assert CONFIG.direct_transport is False
     CONFIG.reset()
-    CONFIG.apply_system_config({"native_store": "true"})
-    assert CONFIG.native_store is True
+    CONFIG.apply_system_config({"tracing_enabled": "true"})
+    assert CONFIG.tracing_enabled is True
     CONFIG.reset()

@@ -213,12 +213,26 @@ def test_remote_agent_relieves_own_pressure(tmp_path, monkeypatch):
         CONFIG.reset()
 
 
-def test_agent_oom_kill_is_typed_and_carries_usage(tmp_path, monkeypatch):
+@pytest.mark.parametrize("mark_late_s", [0.0, 0.5])
+def test_agent_oom_kill_is_typed_and_carries_usage(tmp_path, monkeypatch,
+                                                   mark_late_s):
     """ISSUE 7 satellite: a worker killed by the node agent's memory loop
     must surface as OutOfMemoryError with the host usage fraction in the
     message (not a generic WorkerCrashedError) once retries run out —
-    the agent marks the victim over its ordered head conn BEFORE the
-    kill, so the death handler can type it."""
+    the head marks the victim's task BEFORE it has the agent kill it, so
+    the death handler can type it whichever connection reports the death.
+    ``mark_late_s`` holds the head's handling of the agent's message back,
+    as a loaded host does: while the agent itself killed, the worker's own
+    socket told the head first and the death came out untyped (PR 52)."""
+    from ray_tpu._private.head import Head
+
+    mark = Head.on_worker_oom
+
+    def late_mark(self, worker_id, usage):
+        time.sleep(mark_late_s)
+        return mark(self, worker_id, usage)
+
+    monkeypatch.setattr(Head, "on_worker_oom", late_mark)
     gauge = tmp_path / "agent_oom_gauge"
     gauge.write_text("0.1")
     monkeypatch.setenv("RAY_TPU_MEMORY_MONITOR_TEST_FILE", str(gauge))

@@ -2,6 +2,7 @@
 batched puts/gets + coalesced control-plane notifies, spill→restore under
 eviction pressure, and the bookkeeping bounds that keep long-lived
 drivers leak-free."""
+import gc
 import os
 import pickle
 import tempfile
@@ -31,8 +32,7 @@ def test_pool_size_classes():
 
 
 def test_pooled_segment_reuse_across_put_delete_cycles():
-    store = SharedMemoryStore(capacity_bytes=64 * 1024**2,
-                              use_native_arena=False)
+    store = SharedMemoryStore(capacity_bytes=64 * 1024**2)
     try:
         data = os.urandom(2 * 1024 * 1024)
         seg_names = set()
@@ -55,8 +55,7 @@ def test_pooled_segment_reuse_across_put_delete_cycles():
 
 
 def test_pool_cap_unlinks_overflow():
-    store = SharedMemoryStore(capacity_bytes=64 * 1024**2,
-                              use_native_arena=False)
+    store = SharedMemoryStore(capacity_bytes=64 * 1024**2)
     try:
         store.pool.max_bytes = SegmentPool.MIN_CLASS  # room for ONE segment
         data = os.urandom(1024 * 1024 + 1)  # 2 MiB class
@@ -87,8 +86,7 @@ def test_pool_prewarm_spec_parses_and_prefaults():
 
 
 def test_unlinked_segment_drops_untracked_entry():
-    store = SharedMemoryStore(capacity_bytes=64 * 1024**2,
-                              use_native_arena=False)
+    store = SharedMemoryStore(capacity_bytes=64 * 1024**2)
     try:
         oid = _oid()
         store.put(oid, b"", os.urandom(512))  # tiny: dedicated segment
@@ -213,13 +211,72 @@ def test_put_many_refs_survive_free_cycle(ray_start_regular):
     assert store.stats()["num_objects"] == base
 
 
+def test_driver_put_lands_in_a_pooled_segment_and_worker_reads_it_in_place(
+        ray_start_regular):
+    """A driver's large put is one object in the head raylet's store, in a
+    pooled segment; a worker that takes the ref as an argument reads the
+    segment where it lies: its array borrows the mapping, it owns no
+    copy."""
+    from ray_tpu._private.worker import global_worker as gw
+
+    store = gw.transport.head.raylets[gw.node_id].store
+    base = store.stats()["num_objects"]
+    x = np.arange(300_000, dtype=np.float64)  # 2.4 MB: a pooled size class
+    ref = ray_tpu.put(x)
+    assert store.stats()["num_objects"] == base + 1
+    assert store.segment_of(ref.id).startswith("rtpu_pool_")
+
+    @ray_tpu.remote
+    def total(a):
+        return float(a.sum()), bool(a.flags.owndata)
+
+    assert ray_tpu.get(total.remote(ref)) == (float(x.sum()), False)
+    # The driver reads it back through the store too, once its cache is
+    # cleared, and as a view.
+    gw._value_cache.clear()
+    y = ray_tpu.get(ref)
+    np.testing.assert_array_equal(x, y)
+    assert not y.flags.owndata
+
+
+def test_batched_get_with_a_failing_ref_strands_nothing_on_the_others(
+        ray_start_regular):
+    """get([bad, good]) raises at bad.  The resolution already handed out
+    for good must leave no pin in the store: good is still readable, and
+    dropping its ref frees its bytes."""
+    from ray_tpu import exceptions as exc
+    from ray_tpu._private.worker import global_worker as gw
+
+    store = gw.transport.head.raylets[gw.node_id].store
+    base = store.stats()["num_objects"]
+
+    @ray_tpu.remote
+    def boom():
+        raise ValueError("nope")
+
+    bad = boom.remote()
+    good = ray_tpu.put(np.ones((1024, 512), np.float32))  # 2 MB: the store
+    ray_tpu.wait([bad], num_returns=1)
+    gw._value_cache.clear()  # force a real store read
+    with pytest.raises(exc.TaskError):
+        ray_tpu.get([bad, good])  # bad materializes first and raises
+    assert store.stats()["num_pinned"] == 0
+    assert float(ray_tpu.get(good).sum()) == 1024 * 512
+    del good
+    gw._value_cache.clear()
+    gc.collect()  # the TaskError's traceback held get()'s list of refs
+    gw._drain_ref_gc_queue()
+    stats = store.stats()
+    assert stats["num_objects"] == base and stats["num_pinned"] == 0
+
+
 # ---------------------------------------------------------------------------
 # Spill → restore under eviction pressure
 # ---------------------------------------------------------------------------
 def test_spill_and_restore_under_pressure():
     spill_dir = tempfile.mkdtemp()
     store = SharedMemoryStore(capacity_bytes=4 * 1024 * 1024,
-                              use_native_arena=False, spill_dir=spill_dir)
+                              spill_dir=spill_dir)
     try:
         a, b, c = _oid(), _oid(), _oid()
         da = os.urandom(2 * 1024 * 1024)
@@ -245,7 +302,7 @@ def test_adopt_over_capacity_triggers_spill():
     objects (spill/evict) instead of only logging."""
     spill_dir = tempfile.mkdtemp()
     store = SharedMemoryStore(capacity_bytes=3 * 1024 * 1024,
-                              use_native_arena=False, spill_dir=spill_dir)
+                              spill_dir=spill_dir)
     try:
         resident = _oid()
         store.put(resident, b"r", os.urandom(2 * 1024 * 1024))
@@ -271,8 +328,7 @@ def test_adopt_over_capacity_triggers_spill():
 
 def test_adopt_pooled_segment_name():
     """adopt() must attach by the explicit segment name when given."""
-    store = SharedMemoryStore(capacity_bytes=16 * 1024 * 1024,
-                              use_native_arena=False)
+    store = SharedMemoryStore(capacity_bytes=16 * 1024 * 1024)
     try:
         from multiprocessing import shared_memory
 

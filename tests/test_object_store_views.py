@@ -17,8 +17,7 @@ def _oid():
 
 @pytest.fixture
 def store():
-    s = SharedMemoryStore(capacity_bytes=64 * 1024 * 1024,
-                          use_native_arena=False)
+    s = SharedMemoryStore(capacity_bytes=64 * 1024 * 1024)
     yield s
     s.shutdown()
 
@@ -73,6 +72,44 @@ def test_reader_chunk_slices_survive_parent_reclaim(store):
     store.delete(oid)
     assert bytes(chunk) == b"abab"  # still valid until the reader drops it
     del chunk
+
+
+@pytest.mark.parametrize("reader", ["export", "pin", "none"])
+def test_segment_under_a_reader_is_not_recycled(store, reader):
+    """A pooled segment goes back to the pool's free list at delete only
+    when nothing in this process still reads it: a C-level export of the
+    canonical view (a numpy array over it) or a pin (a transfer mid-send)
+    keeps it out, so the next put of that size class gets other memory and
+    the reader keeps seeing the bytes it was given."""
+    import pickle
+
+    payload = b"\x07" * (2 * 1024 * 1024)  # 2 MiB: a pooled size class
+    oid = _oid()
+    store.put(oid, b"m", payload)
+    first = store.segment_of(oid)
+    assert first is not None  # pooled, non-canonical name
+    _, view = store.get(oid)
+    held = None
+    if reader == "export":
+        # Holds a Py_buffer taken from the view itself (numpy's frombuffer
+        # gives its one back at once and would not count).
+        held = pickle.PickleBuffer(view)
+    elif reader == "pin":
+        store.pin(oid)
+        held = view[:16]  # the chunk in flight
+    del view
+    store.delete(oid)
+    recycled = store.stats()["pool_free_segments"]
+    nxt = _oid()
+    store.put(nxt, b"m", b"\x00" * len(payload))
+    if reader == "none":
+        assert recycled == 1 and store.segment_of(nxt) == first
+    else:
+        assert recycled == 0 and store.segment_of(nxt) != first
+        assert bytes(memoryview(held)[:16]) == payload[:16]  # not zeros
+        if reader == "export":
+            held.release()
+    del held
 
 
 def test_defuse_shm_silences_del_with_live_exports():

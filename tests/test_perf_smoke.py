@@ -1,7 +1,10 @@
 """Tier-1 wrapper for tools/perf_smoke.py: the pipelined hot path must
 dispatch step N+1 before step N's result is fetched (overlap), with zero
 blocking driver↔worker syncs — so an overlap regression fails the normal
-test pass instead of only surfacing in the full bench."""
+test pass instead of only surfacing in the full bench.
+
+Every gate here compares counts, orderings and byte sizes; the clock
+appears only as a timeout (tools/perf_smoke.py's header says why)."""
 import ray_tpu  # noqa: F401 — conftest sets the virtual-device env first
 
 from tools.perf_smoke import (
@@ -49,13 +52,13 @@ def test_checkpoint_overlap_smoke(shutdown_only):
 
 def test_rollout_plane_smoke(shutdown_only):
     """The streaming rollout plane must overlap sampling with learning
-    (a fragment is consumed while others are still in flight / being
-    produced) and broadcast weights as ONE put per version — the tier-1
-    guard for ISSUE 5's async rollout plane."""
+    (whenever a fragment is consumed, every other slot of every worker's
+    window is still queued at its worker) and broadcast weights as ONE
+    put per version — the tier-1 guard for ISSUE 5's async rollout
+    plane."""
     out = run_rollout_smoke()
     assert out["one_put_per_version"], f"broadcast fan-out regressed: {out}"
     assert out["inflight_ok"], f"stream drained at consume time: {out}"
-    assert out["produce_consume_overlap"], f"lockstep sampling: {out}"
     assert out["ok"], out
 
 
@@ -121,14 +124,15 @@ def test_zero_smoke(shutdown_only):
 
 
 def test_mpmd_smoke(shutdown_only):
-    """The MPMD pipeline must genuinely parallelize stages (stage 0 on
-    microbatch m+1 while stage 1 works m), stream steps with zero
-    driver syncs, hold the 1F1B residual bound, and never retrace its
-    compiled stage programs — the tier-1 guard for ISSUE 10."""
+    """The MPMD pipeline must run every stage's ops in 1F1B order (stage
+    0 forwards microbatch m+1 before it takes m's backward, so it has
+    work while stage 1 holds m), stream steps with zero driver syncs,
+    hold the 1F1B residual bound, and never retrace its compiled stage
+    programs — the tier-1 guard for ISSUE 10."""
     out = run_mpmd_smoke()
     assert out["results_ok"], out
     assert out["driver_syncs_steady"] == 0, f"lockstep regression: {out}"
-    assert out["overlap_ok"], f"stages serialized: {out}"
+    assert out["schedule_order_ok"], f"a stage left the 1F1B order: {out}"
     assert out["jit_cache_constant"], f"stage program retraced: {out}"
     assert out["inflight_bound_ok"], f"1F1B bound violated: {out}"
     assert out["ok"], out
@@ -153,11 +157,11 @@ def test_3d_smoke(shutdown_only):
 
 def test_flow_smoke(shutdown_only):
     """Streaming Dataset execution on the flow substrate must genuinely
-    stream — a later block read (worker wall-clock stamps) overlaps an
-    earlier block's consume — while the RefStream holds at most `window`
-    blocks in flight, results byte-match the eager engine, and the loop
-    performs zero driver syncs (the tier-1 guard for ISSUE 11's async
-    dataflow substrate)."""
+    stream — later blocks' reads are submitted, a window's worth, before
+    an earlier block is handed to the consumer — while the RefStream
+    holds at most `window` blocks in flight, results byte-match the
+    eager engine, and the loop performs zero driver syncs (the tier-1
+    guard for ISSUE 11's async dataflow substrate)."""
     out = run_flow_smoke()
     assert out["exact_results"], f"streaming diverged from eager: {out}"
     assert out["residency_ok"], f"window bound violated: {out}"
@@ -167,11 +171,11 @@ def test_flow_smoke(shutdown_only):
 
 
 def test_rlhf_smoke():
-    """The RLHF loop must keep its two planes genuinely concurrent: a
-    decode-step wall-clock stamp lands inside an SGD window (generation
-    of batch i+1 overlaps training on batch i), >= 2 hot weight swaps
-    apply with the decode step compiled exactly once and zero
-    dropped/errored rollouts, and the engine-captured behavior logprobs
+    """The RLHF loop must keep its two planes genuinely concurrent: the
+    engine's count of decode steps goes up while an SGD update runs
+    (generation of batch i+1 overlaps training on batch i), >= 2 hot
+    weight swaps apply with the decode step compiled exactly once and
+    zero dropped/errored rollouts, and the engine-captured behavior logprobs
     match a full-context forward pass (the tier-1 guard for ISSUE 14)."""
     out = run_rlhf_smoke()
     assert out["overlap_windows"] >= 1, f"drain-then-train regression: {out}"
@@ -198,15 +202,16 @@ def test_flow_usage_static_check():
 
 
 def test_tracing_smoke(shutdown_only):
-    """The tracing plane must be free when off (zero spans recorded, the
-    small-put rate unchanged within noise after an enable→disable
-    cycle) and assemble when on: one driver boundary produces a single
-    trace whose spans span >= 3 processes on >= 2 virtual nodes, with
-    the chrome dump json-clean and carrying cross-process flow edges —
-    the tier-1 guard for the observability PR."""
+    """The tracing plane must be free when off (zero spans recorded before
+    and after an enable→disable cycle, which leaves the switch off and
+    no trace context on the thread) and assemble when on: one driver
+    boundary produces a single trace whose spans span >= 3 processes on
+    >= 2 virtual nodes, with the chrome dump json-clean and carrying
+    cross-process flow edges — the tier-1 guard for the observability
+    PR."""
     out = run_tracing_smoke()
     assert out["off_zero_spans"] and out["off_still_zero_spans"], out
-    assert out["off_overhead_ok"], f"tracing-off path got slower: {out}"
+    assert out["off_path_restored"], f"the cycle left tracing on: {out}"
     assert out["assembled_ok"], f"trace did not assemble: {out}"
     assert out["flow_edges"] >= 1, f"no cross-process flow edges: {out}"
     assert out["chrome_json_ok"], out
@@ -232,10 +237,10 @@ def test_trace_context_static_check():
 
 def test_node_loss_smoke(shutdown_only):
     """One scheduled node kill mid-run must be survivable: the job
-    completes with exact results in bounded wall clock, replicated puts
-    restore from a surviving holder, sealed outputs reconstruct from
-    lineage — and the recovery counters prove it (the tier-1 guard for
-    ISSUE 7's node-loss survivability plane)."""
+    completes with exact results, every get inside its timeout,
+    replicated puts restore from a surviving holder, sealed outputs
+    reconstruct from lineage — and the recovery counters prove it (the
+    tier-1 guard for ISSUE 7's node-loss survivability plane)."""
     out = run_node_loss_smoke()
     assert out["killed"], out
     assert out["exact_results"], out
@@ -252,7 +257,8 @@ def test_locality_smoke(shutdown_only):
     on its producer's host and read the arg with zero demand wire bytes
     (zero-copy segment attach), and a forced-remote consumer must find
     its arg prefetched into the target host's store WHILE the task was
-    still queued (wall-stamp overlap, wire counter flat) — the tier-1
+    still queued (one prefetch, started at that task's placement; wire
+    counter flat) — the tier-1
     guard for ISSUE 17's place-compute-where-the-bytes-live plane."""
     out = run_locality_smoke()
     assert out["local_on_producer_host"], f"compute left the bytes: {out}"
@@ -288,12 +294,12 @@ def test_replay_smoke(shutdown_only):
     inserts are zero-copy (ring eviction recycles pooled segments — no
     new shm segments while the ring churns), sampling resolves each batch
     with exactly ONE batched get_many gather, and the flow prefetcher
-    keeps a gather in flight during the learner's SGD window."""
+    issues the next gather while the learner still holds a batch."""
     out = run_replay_smoke()
     assert out["zero_copy_ok"], \
         f"insert path copied or leaked segments: {out}"
     assert out["gather_ok"], f"sampling issued extra gathers: {out}"
-    assert out["overlap_ok"], f"no gather ran during an SGD window: {out}"
+    assert out["overlap_ok"], f"the prefetcher did not run ahead: {out}"
     assert out["ok"], out
 
 

@@ -12,17 +12,10 @@ MB = 1024 * 1024
 
 
 @pytest.fixture
-def small_store_cluster(monkeypatch):
-    # Per-segment store only: the native arena has its own capacity pool and
-    # would absorb the first puts, making the pressure pattern nondeterministic.
-    from ray_tpu._private.config import CONFIG
-
-    monkeypatch.setenv("RAY_TPU_NATIVE_STORE", "0")
-    CONFIG.reset()  # drop cached flag values so the env override applies
+def small_store_cluster():
     ray_tpu.init(num_cpus=2, object_store_memory=8 * MB)
     yield
     ray_tpu.shutdown()
-    CONFIG.reset()
 
 
 def test_put_twice_capacity_then_get_all(small_store_cluster):
@@ -64,9 +57,17 @@ def test_worker_reads_spilled_object(small_store_cluster):
 
 
 def test_unreferenced_objects_do_not_spill(small_store_cluster):
+    from ray_tpu._private.worker import global_worker as gw
+
     for i in range(6):
         ref = ray_tpu.put(np.zeros(2 * MB // 8, dtype=np.int64))
         del ref  # release: eviction should drop, not spill
+        # The drop rides the ref-gc thread, which lets drops settle into
+        # batches; on a loaded host the next puts fill the store first and
+        # the object, still referenced as far as the head knows, spills,
+        # as it should.  The promise is about released objects: hand the
+        # release over before the next put.
+        gw._drain_ref_gc_queue()
     head = ray_tpu._head
     raylet = next(iter(head.raylets.values()))
     spill_dir = raylet.store.spill_dir
@@ -79,13 +80,9 @@ def test_unreferenced_objects_do_not_spill(small_store_cluster):
 # head process, and restores are byte-exact.
 # ---------------------------------------------------------------------------
 @pytest.fixture
-def two_node_spill_cluster(monkeypatch):
+def two_node_spill_cluster():
     """Head node with room + a second tiny-store node whose referenced
     puts spill under pressure."""
-    from ray_tpu._private.config import CONFIG
-
-    monkeypatch.setenv("RAY_TPU_NATIVE_STORE", "0")
-    CONFIG.reset()
     ray_tpu.init(num_cpus=2, object_store_memory=64 * MB)
     from ray_tpu.cluster_utils import Cluster
 
@@ -93,7 +90,6 @@ def two_node_spill_cluster(monkeypatch):
     node2 = cluster.add_node(num_cpus=2, object_store_memory=8 * MB)
     yield ray_tpu._head, node2
     ray_tpu.shutdown()
-    CONFIG.reset()
 
 
 def test_spill_then_owner_node_death_restores_byte_exact(
@@ -171,7 +167,6 @@ def test_spill_record_survives_head_kill9_restart(tmp_path, monkeypatch):
     from ray_tpu.util.testing import wait_for_condition
 
     monkeypatch.setenv("RAY_TPU_OBJECT_DURABILITY", "spill")
-    monkeypatch.setenv("RAY_TPU_NATIVE_STORE", "0")
     CONFIG.reset()
     session = str(tmp_path / "session")
     head1 = Head(session_dir=session)

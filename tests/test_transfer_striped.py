@@ -33,8 +33,7 @@ def _oid():
 
 @pytest.fixture
 def store():
-    s = SharedMemoryStore(capacity_bytes=64 * 1024**2,
-                          use_native_arena=False)
+    s = SharedMemoryStore(capacity_bytes=64 * 1024**2)
     yield s
     s.shutdown()
 

@@ -240,7 +240,10 @@ def test_duplicated_frame_applied_once(fast_rpc):
     with no_hang(20.0):
         out = tr.request("kv", {"verb": "get"}, timeout=10.0)
     assert out == {"op": "kv"}
-    deadline = time.monotonic() + 2.0
+    # The reply to the first frame can beat the head's reader thread to
+    # the second: wait for it as long as the request itself may take,
+    # not for a share of a loaded host's next two seconds.
+    deadline = time.monotonic() + 20.0
     while len(head.frames) < 2 and time.monotonic() < deadline:
         time.sleep(0.01)
     assert len(head.frames) == 2, "dup frame did not reach the head"
