@@ -1,5 +1,9 @@
 """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434, section 2.1)
-with no query compression, in the two forms a serve engine needs.
+in the two forms a serve engine needs.  The head widths are the caller's
+(128 + 64 / 128 in ``models/ling_linear.py``, 192 + 64 / 256 in
+``models/glm_dsa.py``), and so is where the queries come from: projected
+from the hidden state, or from a compressed query latent
+(``compressed_query``: ``q_lora_rank``).
 
 What is cached for a token is one latent row ``[c | rope(k_r)]``: ``c``
 [R] the RMSNorm'd compressed key/value, ``k_r`` [P] the rope part every
@@ -30,6 +34,25 @@ def latent_rows(c: jax.Array, k_rope: jax.Array):
     k_row = jnp.concatenate([c, k_rope], axis=-1)[:, :, None]
     v_row = jnp.concatenate([c, jnp.zeros_like(k_rope)], axis=-1)[:, :, None]
     return k_row, v_row
+
+
+def index_rows(c: jax.Array, k_index: jax.Array) -> jax.Array:
+    """The V row of a cache that also holds a learned-sparse-attention
+    indexer's key (``ops/dsa.py``): ``[c | k_index]``, c [B, L, R], k_index
+    [B, L, D] → [B, L, 1, R + D].  The key takes the columns that
+    ``latent_rows``' V row leaves at zero and what the pool pads a row out
+    with (R + D = 512 + 128 = 640 = ``pool_width(1, 576)``: no byte
+    added); the values a reader wants are still the first R columns."""
+    return jnp.concatenate([c, k_index.astype(c.dtype)], axis=-1)[:, :, None]
+
+
+def compressed_query(cq: jax.Array, w_qb: jax.Array, heads: int, nope: int):
+    """Query compression: cq [B, L, Q] the RMSNorm'd query latent
+    (``W_qa h``, ``q_lora_rank`` wide), w_qb [Q, H * (N + P)] → (q_nope
+    [B, L, H, N], q_rope [B, L, H, P] before rope)."""
+    q = jnp.dot(cq, w_qb.astype(cq.dtype)).reshape(
+        cq.shape[:2] + (heads, -1))
+    return q[..., :nope], q[..., nope:]
 
 
 def mla_expanded(q_nope: jax.Array, q_rope: jax.Array, c: jax.Array,

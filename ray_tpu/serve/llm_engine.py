@@ -186,19 +186,60 @@ def _attend_uncached(q, k, v):
 
 
 def _paged_attend(n_layers, k_pages, v_pages, table, lengths, active,
-                  first_page=None):
+                  first_page=None, attend=None):
     """The models' per-layer cache hooks of the decode programs: attention
     that reads each slot's live pages from the pool in place
-    (``ops/paged_attention.py``).  A free lane reads nothing."""
+    (``ops/paged_attention.py``).  A free lane reads nothing.  ``attend``:
+    the model's own reader of the pool in the kernel's place, with its
+    arguments (``_sparse_attend``: a model whose layers choose the rows
+    they attend to hands the hook what the choice needs and gets the chosen
+    rows alone, ``ops/dsa.py``)."""
     import jax.numpy as jnp
 
     from ray_tpu.ops.paged_attention import paged_attention
 
     cached = jnp.where(active, lengths, 0)
     return [functools.partial(
-        paged_attention, k_pool=k_pages, v_pool=v_pages, layer=i,
+        attend or paged_attention, k_pool=k_pages, v_pool=v_pages, layer=i,
         table=table, lengths=cached, first_page=first_page)
         for i in range(n_layers)]
+
+
+def _sparse_attend(model):
+    """The model's own cache hook (``sparse_paged_attend``), where its
+    layers select the cached rows they attend to (``models/glm_dsa.py``);
+    None for every other model, whose programs stay what they were."""
+    return getattr(model, "sparse_paged_attend", None)
+
+
+def _rows_read(sown):
+    """From what a decode step's selecting layers sowed
+    (``kv_rows_read``): int32, the rows the live slots' softmax ran over,
+    summed over the layers.  The program's own count, as
+    ``experts_streamed`` is the expert layer's."""
+    import jax.numpy as jnp
+    from flax import traverse_util
+
+    return sum(value for path, sowed in
+               traverse_util.flatten_dict(sown).items()
+               if path[-1] == "kv_rows_read"
+               for value in sowed).astype(jnp.int32)
+
+
+def _rows_selected(sown):
+    """From what a decode step's selecting layers sowed into ``dsa`` for a
+    recording engine (``selected``): int32 [layers, slots, k], the
+    positions each slot's softmax ran over in the layers' order, -1 past
+    the rows there are."""
+    import jax.numpy as jnp
+    from flax import traverse_util
+
+    found = {path: sowed[0] for path, sowed in
+             traverse_util.flatten_dict(sown).items()
+             if path[-1] == "selected"}
+    in_order = sorted(found, key=lambda path: [
+        int(n) for n in re.findall(r"\d+", "/".join(path))])
+    return jnp.stack([found[path] for path in in_order]).astype(jnp.int32)
 
 
 def _write_rows(pages, rows, page_idx, off):
@@ -391,6 +432,10 @@ class _Request:
     # chose in the program that computed it.
     record_experts: bool = False
     fed_experts: List[Any] = dataclasses.field(default_factory=list)
+    # beside them, where the model's layers select their rows: one
+    # [layers, k] array of positions for every row a DECODE step fed since
+    # the request's last prefill
+    fed_selected: List[Any] = dataclasses.field(default_factory=list)
     # Distributed trace the request was submitted under (the caller's
     # (trace_id, span_id) pair): its request.queued and request.decode
     # spans stamp it, so they land in the client's timeline.
@@ -421,6 +466,8 @@ class _Step:
     touched: Any  # a routed model's _experts_touched, else None
     rows: List[tuple]
     chosen: Any = None  # _experts_chosen, where the engine records them
+    rows_read: Any = None  # _rows_read, where the model's layers select
+    selected: Any = None  # _rows_selected, where it records and they select
 
 
 class LLMEngine:
@@ -501,22 +548,38 @@ class LLMEngine:
         # updated in place (idea 9 of the module's docstring).
         self._state = None
         spec = getattr(model, "slot_state", None)
+        # the options that hand a request over as pages of K/V, where given
+        handed_over = [name for name, given in (
+            ("prefix_cache", prefix_cache),
+            ("prefix_directory", prefix_directory),
+            ("draft_model", draft_model), ("prefill", prefill))
+            if given is not None and given is not False]
         if spec:
-            for name, given in (("prefix_cache", prefix_cache),
-                                ("prefix_directory", prefix_directory),
-                                ("draft_model", draft_model),
-                                ("prefill", prefill)):
-                if given is not None and given is not False:
-                    raise ValueError(
-                        f"{name}= cannot serve a model with per-slot "
-                        "recurrent state: a cached prefix, a draft's "
-                        "window and a remote prefill all hand over pages "
-                        "of K/V and no state snapshot")
+            if handed_over:
+                raise ValueError(
+                    f"{handed_over[0]}= cannot serve a model with per-slot "
+                    "recurrent state: a cached prefix, a draft's "
+                    "window and a remote prefill all hand over pages "
+                    "of K/V and no state snapshot")
             self._state = [
                 {k: jnp.zeros((self.max_slots,) + tuple(shape), dtype)
                  for k, (shape, dtype) in spec.items()}
                 for _ in range(getattr(model, "state_layers",
                                        c.num_layers))]
+
+        # ---- layers that select the rows they attend to ----
+        # (``models/glm_dsa.py``): a decode step reads the pool through the
+        # model's own hook.  What hands a request over without this
+        # engine's full prefill is refused: a tail prefill attends to the
+        # cached prefix densely, a remote prefill and a draft's verify
+        # window know nothing of the selection.
+        self._sparse = _sparse_attend(model) is not None
+        if self._sparse and handed_over:
+            raise ValueError(
+                f"{handed_over[0]}= cannot serve a model with learned "
+                "sparse attention: the tail prefill, the verify window and "
+                "a remote prefill attend to every cached row, not to the "
+                "rows the indexer selects")
 
         # ---- the routers' choices, for whoever asks with a request ----
         # The decode program and the full prefills then also return what
@@ -530,14 +593,11 @@ class LLMEngine:
             if not _routes(model):
                 raise ValueError("record_experts= needs a model with "
                                  "routed expert layers")
-            for name, given in (("prefix_cache", prefix_cache),
-                                ("prefix_directory", prefix_directory),
-                                ("draft_model", draft_model),
-                                ("prefill", prefill)):
-                if given is not None and given is not False:
-                    raise ValueError(
-                        f"{name}= hands over rows whose routers' choices "
-                        "this engine did not see: not with record_experts=")
+            if handed_over:
+                raise ValueError(
+                    f"{handed_over[0]}= hands over rows whose routers' "
+                    "choices this engine did not see: not with "
+                    "record_experts=")
 
         # ---- speculative decoding (draft + verify) ----
         self.spec_tokens = int(_cfg("serve_spec_tokens", spec_tokens,
@@ -819,7 +879,10 @@ class LLMEngine:
         forward pass needed for the PPO ratio).  For a request submitted
         with ``record_experts=True`` also ``experts``: int32 [rows fed,
         expert layers, k], what the routers chose on each row in the
-        program that computed it."""
+        program that computed it, and, where the model's layers select the
+        rows they attend to, ``selected``: int32 [rows a decode step fed,
+        layers, index_topk], the positions each of those rows' softmax ran
+        over (-1 past the rows there were)."""
         req = self._requests[rid]
         if not req.done.wait(timeout):
             raise TimeoutError(f"request {rid} not done within {timeout}s")
@@ -836,6 +899,9 @@ class LLMEngine:
             # [rows fed, expert layers, k]: the prompt's rows and every
             # answered token's but the last, which no program was fed
             out["experts"] = np.stack(req.fed_experts)
+            if self._sparse:
+                out["selected"] = np.stack(req.fed_selected) \
+                    if req.fed_selected else np.zeros((0, 0, 0), np.int32)
         return out
 
     def generate_rollouts(self, prompts: Sequence[Sequence[int]],
@@ -976,6 +1042,17 @@ class LLMEngine:
             out["moe_local_choice_share"] = (
                 out["moe_local_choices"] / out["moe_choices"]
                 if out["moe_choices"] else 0.0)
+        if self._sparse:
+            # Learned sparse attention: the cached rows the decode steps'
+            # indexers scored (the spans' ``index_rows``: what dense
+            # attention would have read), the rows their softmax ran over
+            # (``kv_rows_read``, the programs' own count, the token's own
+            # row among it), and the second over the first.
+            out["dsa_rows_scored"] = s.get("dsa_rows_scored", 0)
+            out["dsa_rows_read"] = s.get("dsa_rows_read", 0)
+            out["dsa_selected_share"] = (
+                out["dsa_rows_read"] / out["dsa_rows_scored"]
+                if out["dsa_rows_scored"] else 0.0)
         if self._prefix is not None:
             out["prefix_cache"] = self._prefix.stats()
         cache_size = getattr(self._decode, "_cache_size", None)
@@ -1045,6 +1122,7 @@ class LLMEngine:
 
         scope = self._jax.named_scope
         routes = _routes(model)
+        sparse = _sparse_attend(model)
         held = getattr(model.config, "experts_held", None)
         if held is not None:  # a share: (first expert, how many)
             held = (model.config.expert_offset, held)
@@ -1069,8 +1147,9 @@ class LLMEngine:
                 out = model.apply(
                     {"params": params}, tokens[:, None], lengths[:, None],
                     _paged_attend(L, k_pages, v_pages, table, lengths,
-                                  active, first),
-                    mutable=["moe"] if routes else False, **carried)
+                                  active, first, sparse),
+                    mutable=(["moe", "dsa"] if record_experts and sparse
+                             else ["moe"]) if routes else False, **carried)
                 (logits, new_kvs, *state), sown = out if routes else (
                     out, None)
             # The generated token sits at absolute position lengths + 1.
@@ -1094,6 +1173,10 @@ class LLMEngine:
                                          model.config.num_experts, held),)
             if record_experts:
                 out += (_experts_chosen(sown),)
+                if sparse is not None:
+                    out += (_rows_selected(sown["dsa"]),)
+            if sparse is not None:
+                out += (_rows_read(sown),)
             return out + tuple(state)  # the state, last, where there is one
 
         return step
@@ -1185,6 +1268,7 @@ class LLMEngine:
         from ray_tpu.serve.sampling import sample_tokens_with_logprobs
 
         record = self.record_experts
+        sparse = self._sparse
 
         def prefill(params, k_pages, v_pages, row, tokens, p, temp, top_p,
                     seed, slot=None, state=None):
@@ -1194,13 +1278,15 @@ class LLMEngine:
             behavior logprob.  With ``state`` (a model that carries
             recurrent state): also the state, ``slot``'s set to what the
             prompt leaves behind (the padding past p advances nothing),
-            and the head taken at row p - 1 only.  Last, where the engine
-            records them: the bucket's rows' chosen experts."""
+            and the head taken at row p - 1 only (so too for a model whose
+            layers select their rows: its buckets reach 16k rows, and its
+            padding chooses no expert).  Last, where the engine records
+            them: the bucket's rows' chosen experts."""
             ids = tokens[None]
             positions = jnp.arange(bucket)[None]
             sown = None
             with jax.named_scope("attend"):
-                kw = {} if state is None else {
+                kw = {} if state is None and not sparse else {
                     "lengths": jnp.reshape(p, (1,)),
                     "logits_at": jnp.reshape(p - 1, (1,))}
                 out = model.apply(
@@ -1211,7 +1297,7 @@ class LLMEngine:
                     out, sown = out
                 if state is None:
                     logits, new_kvs = out
-                    last = logits[0, p - 1][None]
+                    last = logits[0] if sparse else logits[0, p - 1][None]
                 else:
                     logits, new_kvs, left = out
                     last = logits[0]
@@ -1258,6 +1344,10 @@ class LLMEngine:
                 "a tail prefill cannot serve a model with per-slot "
                 "recurrent state: the cached prefix holds K/V and no "
                 "state snapshot to start the tail from")
+        if self._sparse:
+            raise ValueError(
+                "a tail prefill cannot serve a model with learned sparse "
+                "attention: it attends to every row of the cached prefix")
         key = ("tail", bucket)
         fn = self._prefills.get(key)
         if fn is not None:
@@ -1677,8 +1767,13 @@ class LLMEngine:
         bucket = self._bucket_for(tail_len)
         # scanned_rows / padded_rows: what a recurrent scan ran over, the
         # prompt's real rows and the bucket's padding that advanced nothing
+        # selecting_rows: real rows at positions from index_topk on, whose
+        # attention runs over a selection and not over every earlier row
         scanned = {} if self._state is None else {
             "scanned_rows": tail_len, "padded_rows": bucket - tail_len}
+        if self._sparse:
+            scanned["selecting_rows"] = max(
+                0, p - self._model.config.index_topk)
         with obs.span("engine.prefill", request_id=req.id, bucket=bucket,
                       prompt_tokens=p, cached_tokens=start, **scanned):
             toks = np.zeros((bucket,), np.int32)
@@ -1697,6 +1792,7 @@ class LLMEngine:
                 if req.record_experts:  # anew: a re-admission recomputes
                     req.fed_experts = list(
                         np.asarray(chosen[0])[:, :p].transpose(1, 0, 2))
+                    req.fed_selected = []
             else:
                 fn = self._tail_prefill_fn(bucket)
                 self._k_pages, self._v_pages, nxt, lp = fn(
@@ -2017,13 +2113,18 @@ class LLMEngine:
         # kv_tokens: the cached rows this step's attention reads, which is
         # what the benchmark's paged_attn_roofline counts the bytes of.
         # state_slots: the slots whose recurrent state the step advances.
+        # index_rows: the cached rows the step's indexers score, every
+        # selecting layer's (a model with learned sparse attention).
         stateful, moved = (), {}
+        kv_tokens = int(self._lengths[rows].sum())
         if self._state is not None:
             stateful = (self._state,)
             moved = {"state_slots": int(rows.sum())}
             self._stats["state_slots_moved"] += moved["state_slots"]
-        with obs.span("engine.decode.dispatch",
-                      kv_tokens=int(self._lengths[rows].sum()),
+        if self._sparse:
+            moved["index_rows"] = kv_tokens * self.num_layers
+            self._stats["dsa_rows_scored"] += moved["index_rows"]
+        with obs.span("engine.decode.dispatch", kv_tokens=kv_tokens,
                       sampling_rows=sampling_rows, in_flight=in_flight,
                       **moved):
             (self._k_pages, self._v_pages, nxt, lps, lengths,
@@ -2036,10 +2137,14 @@ class LLMEngine:
                 dev("fresh", self._fresh), *stateful)
             if stateful:
                 self._state = touched.pop()
+            rows_read = touched.pop() if self._sparse else None
             # fetched only for a row whose request records them
-            chosen = touched.pop() if self.record_experts else None
-            for out in (nxt, lps, *touched):
-                out.copy_to_host_async()
+            recording = self.record_experts
+            selected = touched.pop() if recording and self._sparse else None
+            chosen = touched.pop() if recording else None
+            for out in (nxt, lps, rows_read, *touched):
+                if out is not None:
+                    out.copy_to_host_async()
         self._lengths[rows] += 1  # as the program does: that K/V lands
         self._resident["lengths"] = (lengths, self._lengths.copy())
         self._budget[rows] -= 1
@@ -2047,7 +2152,8 @@ class LLMEngine:
         self._prev_tok = nxt
         return _Step(nxt, lps, touched[0] if touched else None,
                      [(s, self._slot_req[s])
-                      for s in np.flatnonzero(rows).tolist()], chosen)
+                      for s in np.flatnonzero(rows).tolist()], chosen,
+                     rows_read, selected)
 
     def _collect(self, step: _Step):
         """Wait for a dispatched step's results and emit them."""
@@ -2069,12 +2175,18 @@ class LLMEngine:
                 self._stats["moe_local_choices"] += landed
                 self._stats["moe_choices"] += choices
                 self._moe_busiest_share_sum += busiest / choices
+            if step.rows_read is not None:  # see _rows_read
+                read = int(np.asarray(step.rows_read))
+                sp.set(kv_rows_read=read)
+                self._stats["dsa_rows_read"] += read
         self._stats["steps"] += 1
         self._occupancy_sum += n_rows / self.max_slots
         emitted = 0
-        chosen = None
+        chosen = selected = None
         if any(req.record_experts for _, req in step.rows):
             chosen = np.asarray(step.chosen)  # [expert layers, slots, k]
+            if step.selected is not None:  # [layers, slots, index_topk]
+                selected = np.asarray(step.selected)
         with obs.span("engine.emit") as sp:
             for slot, req in step.rows:
                 if self._slot_req.get(slot) is not req:
@@ -2087,6 +2199,8 @@ class LLMEngine:
                 emitted += 1
                 if req.record_experts:  # of the row this step fed
                     req.fed_experts.append(chosen[:, slot])
+                    if selected is not None:
+                        req.fed_selected.append(selected[:, slot])
                 self._append_token(slot, req, int(nxt[slot]),
                                    float(lps[slot]))
             sp.set(tokens=emitted)
@@ -2395,6 +2509,13 @@ def _build_model(model_kind: str, config_kw: Optional[dict], seed: int):
         model = LingLinear(LingLinearConfig.tiny(**config_kw)
                            if config_kw.pop("tiny", True)
                            else LingLinearConfig(**config_kw))
+    elif model_kind == "glm_dsa":
+        # imported here and nowhere else, as ``ling_linear``
+        from ray_tpu.models.glm_dsa import GlmDsa, GlmDsaConfig
+
+        model = GlmDsa(GlmDsaConfig.tiny(**config_kw)
+                       if config_kw.pop("tiny", True)
+                       else GlmDsaConfig(**config_kw))
     else:
         raise ValueError(f"unknown model_kind {model_kind!r}")
     ids = jnp.zeros((1, 8), jnp.int32)
@@ -2457,9 +2578,16 @@ class LLMServer:
     the absent experts' part of the result is left out) or
     ``"ling_linear"`` (gated delta-rule layers with one latent-attention
     layer a group, whose latent rows ride the page pool as ONE KV head; a
-    leading dense layer; group-routed experts with the same share).  For
-    the last three the engine holds per-slot recurrent state and refuses the four options
-    above (a cached prefix, a draft, a prefix directory, remote prefill).
+    leading dense layer; group-routed experts with the same share) or
+    ``"glm_dsa"`` (latent attention with a compressed query in every layer,
+    whose indexer selects the ``index_topk`` cached rows a query attends
+    to: a decode step scores the slot's cached index keys, which ride the V
+    row, and gathers the selected latent rows alone; a leading dense layer;
+    a sigmoid router's experts with the same share).  For
+    ``"falcon_h1"``, ``"nemotron_h"`` and ``"ling_linear"`` the engine holds
+    per-slot recurrent state, and for them and ``"glm_dsa"`` it refuses the
+    four options above (a cached prefix, a draft, a prefix directory,
+    remote prefill).
     """
 
     def __init__(self, model_kind: str = "gpt2",

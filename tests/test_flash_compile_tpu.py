@@ -284,3 +284,54 @@ def test_loss_head_compiles_for_v5e_without_float32_logits(
         kept = [o["name"] for o in written
                 if o["op"] == "fusion" and f"bf16[{b},{l},{v}]" in o["shapes"]]
         assert len(kept) == 1, kept
+
+
+@pytest.mark.timeout(180)
+@pytest.mark.parametrize("keep", [False, True])
+def test_sparse_decode_compiles_for_v5e_and_copies_no_pool(one_chip,
+                                                           monkeypatch, keep):
+    """One layer's decode attention under a learned selection at GLM-5's
+    widths (16 slots of 16,384 rows, 64 heads on ONE latent head of 576 in
+    a pool 640 wide of five layers, an indexer of 32 heads of 128 keeping
+    2,048 rows): the index kernel ``dsa_index`` reads the keys where they
+    lie (its result ``f32[16,1,16384]``, which the benchmark's
+    ``dsa_indexer_roofline`` tells it by), the selected rows are one gather
+    by row (``[16,2048,640]``, ``sparse_paged_attn_roofline``'s), and no
+    operation writes anything of the pool's size (indexed as [layers,
+    pages, page, width] for the keys' columns, the compiler re-laid the
+    whole V pool out: 12.5 GB).  ``keep``: the form a recording engine
+    runs, whose sort carries each row's position too."""
+    from ray_tpu.ops.dsa import sparse_paged_attention
+    from tools.step_fusions import entry_operations
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    slots, pages = 16, 1024
+    pool = shape(5, slots * pages + 1, 16, 640)
+
+    def attend(q, k, v, kp, vp, table, lengths, qi, wi, ki):
+        return sparse_paged_attention(
+            q, k, v, k_pool=kp, v_pool=vp, layer=3, table=table,
+            lengths=lengths, index=(qi, wi, ki), topk=2048,
+            sm_scale=256 ** -0.5, rank=512, keep=keep)
+
+    compiled = jax.jit(attend).lower(
+        shape(slots, 1, 64, 576), shape(slots, 1, 1, 576),
+        shape(slots, 1, 1, 576), pool, pool,
+        shape(slots, pages, dtype=jnp.int32), shape(slots, dtype=jnp.int32),
+        shape(slots, 1, 32, 128), shape(slots, 1, 32, dtype=jnp.float32),
+        shape(slots, 1, 128)).compile()
+    text = compiled.as_text()
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert "dsa_index" in call
+    assert call.split(" = ")[1].startswith("f32[16,1,16384]")
+    written = entry_operations(text)
+    assert any("bf16[16,2048,640]" in o["shapes"] for o in written)
+    big = [o["name"] for o in written
+           if any(s.startswith(("bf16[5,16385", "bf16[1310800"))
+                  for s in o["shapes"])
+           and o["op"] not in ("parameter", "bitcast")]
+    assert not big, big
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
