@@ -31,8 +31,9 @@ def mha_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     its split and the gradient's concatenation included: the kernels 1.23
     ms reading column blocks of ``[B, L, H * D]`` (1.74 head-major, with a
     transpose each way, as they read until PR 49; 1.04 out of the fused
-    array itself, ``mha_attention_qkv``); this XLA path 4.44 (PR 39's and
-    PR 49's chip runs; PERF.md section 6).  Below 1k ctx the XLA
+    array itself, ``mha_attention_qkv``, and 0.85 since PR 54 cut the
+    masked tiles: ``_auto_blocks``); this XLA path 4.44 (PR 39's and PR
+    49's chip runs; PERF.md section 6).  Below 1k ctx the XLA
     path is still chosen, as it was when the kernels were 2.2 times slower
     than now; at [16, 512, 16, 64] they read 1.17 ms against XLA's 2.06,
     so the crossover lies lower than this rule puts it, and nobody has
@@ -206,6 +207,21 @@ def finalize_blockwise(o, l):
 # k tiles, a k tile's range of q tiles); the kernels' loops and
 # ``causal_tile_schedule`` (the number a test and PERF.md quote) both read
 # them, so what is counted is what runs.
+#
+# Inside a masked tile (PR 54).  A square tile that the diagonal crosses
+# corner to corner is half zeros, so it is cut into chunks of ``_CHUNK``
+# columns (``_diagonal_chunks``) and a chunk is multiplied only with the
+# rows that reach it: of a (512, 512) tile's 16 sub-blocks of 128 x 128 the
+# ten at or under the diagonal go to the MXU.  The cut runs along the
+# operand the MXU holds still (a chunk of keys in a q tile, a chunk of
+# queries in a k tile), so that the other operand's rows stream past it in
+# one long run: cut the other way, into bands of rows that each meet all
+# their columns, the same sub-blocks took as long as the whole tile (my
+# chip runs, PR 54; ``_auto_blocks`` has the times).  The softmax's row
+# maximum and sum still need a row's scores side by side, so the forward
+# puts the sub-blocks of a band of rows together again; every such cut and
+# join is static and lies on a multiple of 128 rows or lanes, so nothing
+# moves.  A row meets the tile once: no rescale, no maximum is added.
 # ---------------------------------------------------------------------------
 # lse and delta lie along the lanes of one float32 tile of 8 sublanes a
 # column block, ``[B, blocks, 8, L]``: TPU block shapes need the last two
@@ -244,21 +260,58 @@ def _q_tile_bounds(k_off, block_k, block_q, num_q_blocks):
     return first, full
 
 
+_CHUNK = 128  # columns a chunk of a masked tile: one lane tile, the width
+#               of the MXU's still operand (``_auto_blocks`` has the times)
+
+
+def _diagonal_chunks(block_q: int, block_k: int):
+    """(g, the chunks' first columns) of a masked (block_q, block_k) tile
+    that the kernels cut: the chunk of g columns from ``first`` on meets the
+    rows that reach it and no others (in a q tile the rows from ``first``
+    on, in a k tile's transposed one the rows before ``first + g``).  (0,
+    []) where the tile is multiplied whole.  Square tiles only: there every
+    masked tile starts on the diagonal (k_off == q_off, in the rolled
+    kernels too, where the offsets are traced), so the cut is static; an
+    oblong tile's mask starts at an offset that differs tile by tile."""
+    if block_q != block_k or block_q % _CHUNK or block_q < 2 * _CHUNK:
+        return 0, []
+    return _CHUNK, list(range(0, block_q, _CHUNK))
+
+
+def _sum_bands(parts, g: int):
+    """``parts``: (first row, value) each, a chunk's product lying on some
+    of a tile's rows.  Their sum, as the list of the tile's bands of g
+    rows: every band covered by some part, static slices of g rows."""
+    last = max(first + x.shape[0] for first, x in parts)
+    return [functools.reduce(jnp.add, [
+        x[r - first:r - first + g] for first, x in parts
+        if first <= r < first + x.shape[0]]) for r in range(0, last, g)]
+
+
 def causal_tile_schedule(lq: int, lk: int, block_q: int, block_k: int
                          ) -> dict:
     """What the causal kernels visit at these tile sizes: tiles ``visited``
     (``masked`` of them under the iota/select), ``skipped``, ``total``, and
-    ``visited_share`` of the lq x lk square.  The mask itself needs
-    1/2 + 1/(2 * lq) of it when lq == lk."""
+    ``visited_share`` of the lq x lk square; ``multiplied_share`` of it
+    reaches the MXU, a masked tile counted by its ``_diagonal_chunks`` of
+    ``chunk`` columns (0: masked tiles are multiplied whole, and the two
+    shares are one).  The mask itself needs 1/2 + 1/(2 * lq) of the square
+    when lq == lk."""
     nq, nk = lq // block_q, lk // block_k
     visited = masked = 0
     for i in range(nq):
         full, end = _k_tile_bounds(i * block_q, block_q, block_k, nk)
         visited += end
         masked += end - full
+    g, chunks = _diagonal_chunks(block_q, block_k)
+    in_masked = (sum(g * (block_q - first) for first in chunks) if g
+                 else block_q * block_k)
+    multiplied = (visited - masked) * block_q * block_k + masked * in_masked
     return {"total": nq * nk, "visited": visited, "masked": masked,
             "skipped": nq * nk - visited,
-            "visited_share": visited / (nq * nk)}
+            "visited_share": visited / (nq * nk),
+            "chunk": g,
+            "multiplied_share": multiplied / (lq * lk)}
 
 
 def _dot_nt(a, b):
@@ -348,10 +401,12 @@ def _pack_rows(rows):
 
 def _fwd_q_tile(q, q_off, k_ref, v_ref, causal, sm_scale, block_k):
     """One q tile against its k tiles: (o [block_q, lanes] float32,
-    normalised; lse [block_q]).  ``q_off`` is a Python int in the unrolled
-    kernel and a traced value where the grid walks the q tiles.  Where the
-    block holds several heads, ``q`` is one head's (``_head_lanes``) and o
-    is that head's on its own lanes."""
+    normalised; lse, a ``[rows]`` piece for each band of the q tile's rows,
+    one under the other: one piece where masked tiles are not cut).
+    ``q_off`` is a Python int in the unrolled kernel and a traced value
+    where the grid walks the q tiles.  Where the block holds several heads,
+    ``q`` is one head's (``_head_lanes``) and o is that head's on its own
+    lanes."""
     import jax.experimental.pallas as pl
 
     # Inputs stay in their storage dtype (bf16 on the training path): the
@@ -361,30 +416,56 @@ def _fwd_q_tile(q, q_off, k_ref, v_ref, causal, sm_scale, block_k):
     q, s_scale = _fold_scale(q, sm_scale)
     block_q, d = q.shape
     num_k_blocks = k_ref.shape[0] // block_k
-    rel = _tile_rel(block_q, block_k) if causal else None
+    g, chunks = _diagonal_chunks(block_q, block_k) if causal else (0, [])
 
-    def make_body(masked):
-        def body(kb, carry):
-            m, l, o = carry
-            k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
-            v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-            s = _dot_nt(q, k_blk)
-            if s_scale is not None:
-                s = s * s_scale
-            if masked:
-                s = jnp.where(rel >= kb * block_k - q_off, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            corr = jnp.exp(m - m_new)
-            # No second select on p: rows >= cols shows column 0 to every
-            # row and the loop starts there, so m_new is finite from a
-            # row's first tile on and exp(NEG_INF - m_new) is exactly 0.
-            p = jnp.exp(s - m_new[:, None])
-            l_new = l * corr + jnp.sum(p, axis=-1)
-            o_new = o * corr[:, None] + jnp.dot(
-                p.astype(v_blk.dtype), v_blk,
-                preferred_element_type=jnp.float32)
-            return m_new, l_new, o_new
-        return body
+    def scores(q, keys):
+        s = _dot_nt(q, k_ref[keys, :])
+        return s if s_scale is None else s * s_scale
+
+    def softmax_step(m, l, s):
+        """(m', l', the rescale of o, p) of rows that meet the scores s."""
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        corr = jnp.exp(m - m_new)
+        # No second select on p: rows >= cols shows column 0 to every row
+        # and the loop starts there, so m_new is finite from a row's first
+        # tile on and exp(NEG_INF - m_new) is exactly 0.
+        p = jnp.exp(s - m_new[:, None])
+        return m_new, l * corr + jnp.sum(p, axis=-1), corr, p
+
+    def whole(kb, carry, shown=None):
+        m, l, o = carry
+        keys = pl.ds(kb * block_k, block_k)
+        s = scores(q, keys)
+        if shown is not None:
+            s = jnp.where(shown, s, NEG_INF)
+        m, l, corr, p = softmax_step(m, l, s)
+        return m, l, o * corr[:, None] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[keys, :],
+            preferred_element_type=jnp.float32)
+
+    def cut(kb, carry):
+        """A masked tile on the diagonal, ``carry`` a band of rows each: both
+        matmuls run a chunk of keys at a time, against the rows from the
+        chunk's first on, and the softmax a band at a time."""
+        keys = [pl.ds(kb * block_k + first, g) for first in chunks]
+        by_chunk = [scores(q[first:], key)        # [block_q - first, g]
+                    for first, key in zip(chunks, keys)]
+        shown = _tile_rel(g, g) >= 0
+        stepped, by_band = [], []
+        for r, (m, l, o) in enumerate(carry):
+            s = jnp.concatenate(
+                [by_chunk[j][(r - j) * g:(r - j + 1) * g] for j in range(r)]
+                + [jnp.where(shown, by_chunk[r][:g], NEG_INF)], axis=1)
+            m, l, corr, p = softmax_step(m, l, s)
+            stepped.append((m, l, o * corr[:, None]))
+            by_band.append(p.astype(v_ref.dtype))
+        pv = _sum_bands([
+            (first, jnp.dot(
+                jnp.concatenate([p[:, first:first + g] for p in by_band[j:]],
+                                axis=0),
+                v_ref[key, :], preferred_element_type=jnp.float32))
+            for j, (first, key) in enumerate(zip(chunks, keys))], g)
+        return [(m, l, o + pv_r) for (m, l, o), pv_r in zip(stepped, pv)]
 
     carry = (jnp.full((block_q,), NEG_INF, jnp.float32),
              jnp.zeros((block_q,), jnp.float32),
@@ -392,10 +473,23 @@ def _fwd_q_tile(q, q_off, k_ref, v_ref, causal, sm_scale, block_k):
     num_full, num_iter = (
         _k_tile_bounds(q_off, block_q, block_k, num_k_blocks) if causal
         else (num_k_blocks, num_k_blocks))
-    carry = _loop(0, num_full, make_body(False), carry)
-    m, l, o = _loop(num_full, num_iter, make_body(True), carry)
-    l_safe = jnp.maximum(l, 1e-30)
-    return o / l_safe[:, None], m + jnp.log(l_safe)
+    carry = _loop(0, num_full, whole, carry)
+    if g:
+        # Each band goes on with its own rows of m, l and o, and ends with
+        # its own piece of lse: one-dimensional values are cut and never put
+        # together again, which Mosaic does not lower.
+        bands = _loop(num_full, num_iter, cut, [
+            tuple(x[first:first + g] for x in carry) for first in chunks])
+    else:
+        rel = _tile_rel(block_q, block_k) if causal else None
+        bands = [_loop(num_full, num_iter, lambda kb, c: whole(
+            kb, c, rel >= kb * block_k - q_off), carry)]
+    outs, lses = [], []
+    for m, l, o in bands:
+        l_safe = jnp.maximum(l, 1e-30)
+        outs.append(o / l_safe[:, None])
+        lses.append(m + jnp.log(l_safe))
+    return jnp.concatenate(outs, axis=0), lses
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref, causal,
@@ -418,7 +512,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse_ref, causal,
         o_ref[rows, :] = _merge_heads([o for o, _ in parts]).astype(
             o_ref.dtype)
         if maybe_lse_ref:  # omitted on the inference path: nothing reads it
-            maybe_lse_ref[0][:, rows] = _pack_rows([lse for _, lse in parts])
+            first = q_off if unrolled else 0
+            for pieces in zip(*(lse for _, lse in parts)):  # a band's heads
+                n = pieces[0].shape[0]
+                maybe_lse_ref[0][:, pl.ds(first, n)] = _pack_rows(pieces)
+                first += n
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -433,31 +531,46 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     block_q = q.shape[0]
     q_off = pl.program_id(2) * block_q
     num_k_blocks = k_ref.shape[0] // block_k
-    rel = _tile_rel(block_q, block_k) if causal else None
+    g, chunks = _diagonal_chunks(block_q, block_k) if causal else (0, [])
 
-    def make_body(masked):
-        def body(kb, dq):
-            k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
-            v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-            s = _dot_nt(q, k_blk)
-            if s_scale is not None:
-                s = s * s_scale
-            if masked:
-                s = jnp.where(rel >= kb * block_k - q_off, s, NEG_INF)
-            # lse is finite (every row sees column 0), so a masked entry's
-            # exp(NEG_INF - lse) is exactly 0 with no second select.
-            p = jnp.exp(s - lse[:, None])
-            dp = _dot_nt(do, v_blk)
-            ds = (p * (dp - delta[:, None])).astype(k_blk.dtype)
-            return dq + jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
-        return body
+    def product(first, keys, shown=None):
+        """ds @ k of the q rows from ``first`` on and the keys ``keys``."""
+        k_blk, v_blk = k_ref[keys, :], v_ref[keys, :]
+        s = _dot_nt(q[first:], k_blk)
+        if s_scale is not None:
+            s = s * s_scale
+        if shown is not None:
+            s = jnp.where(shown, s, NEG_INF)
+        # lse is finite (every row sees column 0), so a masked entry's
+        # exp(NEG_INF - lse) is exactly 0 with no second select.
+        p = jnp.exp(s - lse[first:][:, None])
+        dp = _dot_nt(do[first:], v_blk)
+        ds = (p * (dp - delta[first:][:, None])).astype(k_blk.dtype)
+        return jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
+
+    def whole(kb, dq, shown=None):
+        return dq + product(0, pl.ds(kb * block_k, block_k), shown)
+
+    def cut(kb, dq):
+        """A masked tile on the diagonal, a chunk of keys at a time against
+        the rows from the chunk's first on (``_fwd_q_tile``); lse is known,
+        so the rows are never put side by side."""
+        return dq + jnp.concatenate(_sum_bands([
+            (first, product(first, pl.ds(kb * block_k + first, g),
+                            _tile_rel(block_q - first, g) >= 0))
+            for first in chunks], g), axis=0)
 
     dq = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
     num_full, num_iter = (
         _k_tile_bounds(q_off, block_q, block_k, num_k_blocks) if causal
         else (num_k_blocks, num_k_blocks))
-    dq = _loop(0, num_full, make_body(False), dq)
-    dq = _loop(num_full, num_iter, make_body(True), dq)
+    dq = _loop(0, num_full, whole, dq)
+    if g:
+        dq = _loop(num_full, num_iter, cut, dq)
+    else:
+        rel = _tile_rel(block_q, block_k) if causal else None
+        dq = _loop(num_full, num_iter, lambda kb, dq: whole(
+            kb, dq, rel >= kb * block_k - q_off), dq)
     # ds = p * (dp - delta) * scale: the scale goes once onto the
     # [block_q, d] result, not onto every [block_q, block_k] tile.
     dq_ref[...] = (dq * sm_scale).astype(dq_ref.dtype)
@@ -480,40 +593,68 @@ def _bwd_k_tile(k_blk, v_blk, k_off, q_ref, do_ref, lse_ref, delta_ref,
     block_k = k_blk.shape[0]
     num_q_blocks = q_ref.shape[0] // block_q
     k_s, s_scale = _fold_scale(k_blk, sm_scale)
-    rel = _tile_rel(block_k, block_q, transposed=True) if causal else None
+    g, chunks = _diagonal_chunks(block_k, block_q) if causal else (0, [])
 
-    def make_body(masked):
-        def body(qb, carry):
-            dk, dv = carry
-            rows = pl.ds(qb * block_q, block_q)
-            q_blk = q_ref[rows, :]
-            do_blk = do_ref[rows, :]
-            st = _dot_nt(k_s, q_blk)       # [block_k, block_q]
-            if s_scale is not None:
-                st = st * s_scale
-            if masked:
-                st = jnp.where(rel >= k_off - qb * block_q, st, NEG_INF)
-            pt = jnp.exp(st - lse_ref[row:row + 1, rows])
-            dv = dv + jnp.dot(pt.astype(do_blk.dtype), do_blk,
-                              preferred_element_type=jnp.float32)
-            dpt = _dot_nt(v_blk, do_blk)
-            dst = (pt * (dpt - delta_ref[row:row + 1, rows])).astype(
-                q_blk.dtype)
-            dk = dk + jnp.dot(dst, q_blk, preferred_element_type=jnp.float32)
-            if dq is not None:
-                dq[qb] = dq[qb] + jax.lax.dot_general(
-                    dst, k_blk, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            return dk, dv
-        return body
+    def products(n, rows, shown=None):
+        """(dk, dv, ds^T) of the first ``n`` k rows and the queries
+        ``rows``: [n, lanes] float32 twice, and [n, rows] as the operands
+        are stored."""
+        q_blk, do_blk = q_ref[rows, :], do_ref[rows, :]
+        st = _dot_nt(k_s[:n], q_blk)
+        if s_scale is not None:
+            st = st * s_scale
+        if shown is not None:
+            st = jnp.where(shown, st, NEG_INF)
+        pt = jnp.exp(st - lse_ref[row:row + 1, rows])
+        dv = jnp.dot(pt.astype(do_blk.dtype), do_blk,
+                     preferred_element_type=jnp.float32)
+        dpt = _dot_nt(v_blk[:n], do_blk)
+        dst = (pt * (dpt - delta_ref[row:row + 1, rows])).astype(q_blk.dtype)
+        return (jnp.dot(dst, q_blk, preferred_element_type=jnp.float32), dv,
+                dst)
+
+    def dq_product(dst, k_rows):
+        return jax.lax.dot_general(dst, k_rows, (((0,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    def whole(qb, carry, shown=None):
+        dk, dv, dst = products(block_k, pl.ds(qb * block_q, block_q), shown)
+        if dq is not None:
+            dq[qb] = dq[qb] + dq_product(dst, k_blk)
+        return carry[0] + dk, carry[1] + dv
+
+    def cut(qb, carry):
+        """A masked tile on the diagonal, a chunk of queries at a time
+        against the k rows up to the chunk's last; dq, which contracts the
+        k rows, a chunk of k rows at a time against the queries from its
+        first on."""
+        dks, dvs, dsts = zip(*(
+            products(first + g, pl.ds(qb * block_q + first, g),
+                     _tile_rel(first + g, g, transposed=True) >= -first)
+            for first in chunks))
+        if dq is not None:
+            dq[qb] = dq[qb] + jnp.concatenate(_sum_bands([
+                (first, dq_product(
+                    jnp.concatenate([dst[first:first + g]
+                                     for dst in dsts[j:]], axis=1),
+                    k_blk[first:first + g]))
+                for j, first in enumerate(chunks)], g), axis=0)
+        return tuple(c + jnp.concatenate(_sum_bands(
+            [(0, part) for part in parts], g), axis=0)
+            for c, parts in zip(carry, (dks, dvs)))
 
     carry = (jnp.zeros(k_blk.shape, jnp.float32),
              jnp.zeros(v_blk.shape, jnp.float32))
     first, first_full = (
         _q_tile_bounds(k_off, block_k, block_q, num_q_blocks) if causal
         else (0, 0))
-    carry = _loop(first, first_full, make_body(True), carry)
-    return _loop(first_full, num_q_blocks, make_body(False), carry)
+    if g:
+        carry = _loop(first, first_full, cut, carry)
+    else:
+        rel = _tile_rel(block_k, block_q, transposed=True) if causal else None
+        carry = _loop(first, first_full, lambda qb, c: whole(
+            qb, c, rel >= k_off - qb * block_q), carry)
+    return _loop(first_full, num_q_blocks, whole, carry)
 
 
 def _flash_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
@@ -642,7 +783,13 @@ def _whole_head_fits(lq: int, lk: int, d: int, itemsize: int, block_q: int,
     two heads of 64 take what one took) and every pipelined block held
     twice, lse and delta on 8 sublanes; half the budget is left to O (read once, for delta), the [block_k, block_q] float32 tiles
     (s, p, dp, ds and their casts), the float32 sums and the compiler's own
-    scratch.  Every head of the block has tile bodies of its own."""
+    scratch.  Every head of the block has tile bodies of its own; a masked
+    tile that is cut into chunks (PR 54) is still one body, smaller than
+    the uncut one: the compiler's time follows the elements a body holds,
+    and the kernels compile for a described v5e in the time they took uncut
+    ([8, 1024, 16, 64] forward and backward 1.8 s uncut, 1.5 s cut; [1,
+    2048, 8, 128] 3.3 s either way).  With and without the cut, ms a layer
+    on a v5e: ``_auto_blocks``."""
     tiles = (causal_tile_schedule(lq, lk, block_q, block_k)["visited"]
              if causal else (lq // block_q) * (lk // block_k))
     lanes = -(-heads * d // _LANES) * _LANES
@@ -948,7 +1095,35 @@ def _auto_blocks(lq: int, lk: int, d: int, causal: bool) -> Tuple[int, int]:
         [8, 1024, 16, 64]   1.74 / 1.23 / 1.04     (two heads a block)
         [8, 1024,  8, 128]  0.77 / 0.73 / 0.54     (a head a block)
         [4, 2048,  8, 128]  1.07 / 1.04 / 0.85
-        [8, 1024, 32, 32]   3.28 / 2.22 / 2.04     (four heads a block)"""
+        [8, 1024, 32, 32]   3.28 / 2.22 / 2.04     (four heads a block)
+
+    Since PR 54 a masked tile is cut (``_diagonal_chunks``) and multiplies
+    only its sub-blocks at or under the diagonal.  The same probe with
+    ``--chunks`` (my chip runs, PR 54), the form the shape rule gives, ms a
+    layer, forward alone + forward and backward; the share of the square
+    that is multiplied under each:
+
+        chunk of columns     none         256          128 (taken)
+        [8, 1024, 16, 64]    0.325 1.047  0.287 0.900  0.285 0.847
+        [8, 1024,  8, 128]   0.208 0.548  0.209 0.485  0.207 0.463
+        [4, 2048,  8, 128]   0.266 0.856  0.243 0.783  0.246 0.760
+        [8, 1024, 32, 32]    0.642 2.040  0.574 1.763  0.575 1.654
+        [2, 4096,  8, 128]*  0.825 2.753  0.787 2.636  0.794 2.641
+        multiplied at 1,024  0.75         0.625        0.5625
+
+    Which way a tile is cut decides whether the cut pays.  The MXU holds a
+    128 x 128 block of one operand still and streams the other's rows past
+    it, and a block costs about as much to load as 128 rows to stream.
+    Bands of *rows* that each meet all their keys multiply the same
+    sub-blocks with runs of 128 or 256 rows a block where the whole tile
+    has 512, and read, at [8, 1024, 16, 64]: bands of 256 rows 0.330 and
+    0.954, bands of 128 rows 0.356 and 0.972: the forward no faster than
+    uncut.  Chunks of *columns* (of the operand that stands still) keep the
+    runs as long as the mask allows, 512, 384, 256 and 128 rows, and are
+    the numbers above.  The chunk is 128 columns, the block's own width:
+    the most the mask lets one skip, and no slower than 256 anywhere.  The
+    cut adds 0.2-0.3 s of lowering a program and nothing to its compile
+    (1.2-3.3 s a kernel for a described v5e, with and without)."""
     def pick(l, target):
         b = target
         while b > 128 and l % b:

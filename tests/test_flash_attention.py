@@ -96,8 +96,10 @@ def test_flash_gradients_match_xla(causal, shape, heads, ran):
 
 
 @pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blocks", [None, 256])  # 256: one tile, cut in two
 @pytest.mark.parametrize("shape,heads", LAYOUTS[2:])
-def test_fused_qkv_equals_the_three_operand_call(causal, shape, heads, ran):
+def test_fused_qkv_equals_the_three_operand_call(causal, shape, heads, blocks,
+                                                 ran):
     """One ``[B, L, 3 * H * D]`` array read through the index maps, and the
     same columns as three arrays: the same kernels on the same numbers, so
     the same output, and a dqkv that is dq, dk and dv side by side.  Where
@@ -106,12 +108,13 @@ def test_fused_qkv_equals_the_three_operand_call(causal, shape, heads, ran):
     q, k, v = _rand_qkv(*shape)
     qkv = jnp.concatenate([x.reshape(b, l, h * d) for x in (q, k, v)], -1)
     w = _rand_qkv(*shape, seed=5)[0]
+    kw = dict(causal=causal, block_q=blocks, block_k=blocks, interpret=True)
 
     def fused(qkv):
-        return flash_attention_qkv(qkv, h, causal=causal, interpret=True)
+        return flash_attention_qkv(qkv, h, **kw)
 
     def three(q, k, v):
-        return flash_attention(q, k, v, causal=causal, interpret=True)
+        return flash_attention(q, k, v, **kw)
 
     with jax.default_matmul_precision("float32"):
         out_1, vjp_1 = jax.vjp(fused, qkv)
@@ -146,7 +149,11 @@ def test_flash_mixed_block_sizes_stay_correct(blocks):
                                atol=1e-5, rtol=1e-5)
 
 
-def test_flash_causal_lq_gt_lk_kernel_bounds():
+@pytest.mark.parametrize("lq,lk,block", [
+    (256, 128, 64),
+    (512, 256, 256),  # tiles that are cut: the tail q tile meets none
+])
+def test_flash_causal_lq_gt_lk_kernel_bounds(lq, lk, block):
     """lq > lk causal: the fwd/dq interior-block loop bound must clamp to
     num_k_blocks (matching the dkv kernel) — tail query blocks sit fully
     past the last K block, and an unclamped bound reads past K/V.  The
@@ -158,20 +165,20 @@ def test_flash_causal_lq_gt_lk_kernel_bounds():
     def flat(x):
         return x.reshape(x.shape[:2] + (-1,))
 
-    q, _, _ = _rand_qkv(1, 256, 2, 32, seed=1)
-    _, k, v = _rand_qkv(1, 128, 2, 32, seed=2)
+    q, _, _ = _rand_qkv(1, lq, 2, 32, seed=1)
+    _, k, v = _rand_qkv(1, lk, 2, 32, seed=2)
 
     def ref(q):
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (32 ** -0.5)
-        rows = jnp.arange(256)[:, None]
-        cols = jnp.arange(128)[None, :]
+        rows = jnp.arange(lq)[:, None]
+        cols = jnp.arange(lk)[None, :]
         s = jnp.where((rows >= cols)[None, None], s, NEG_INF)
         p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
     def flash(q):
-        return _flash((flat(q), flat(k), flat(v)), 2, True, None, 64, 64,
-                      True).reshape(q.shape)
+        return _flash((flat(q), flat(k), flat(v)), 2, True, None, block,
+                      block, True).reshape(q.shape)
 
     with jax.default_matmul_precision("float32"):
         np.testing.assert_allclose(np.asarray(flash(q)), np.asarray(ref(q)),
@@ -307,8 +314,52 @@ def test_flash_auto_blocks_at_1k_match_xla(shape, dtype):
         assert np.abs(a - b).max() <= bound, (name, np.abs(a - b).max())
 
 
+@pytest.mark.parametrize("entry", ["three", "qkv"])
+@pytest.mark.parametrize("shape,block,heads", [
+    ((1, 512, 2, 64), 256, 2),    # a pair of heads a block; two chunks a tile
+    ((1, 512, 1, 128), 256, 1),   # a head a block
+    ((1, 1024, 2, 64), 512, 2),   # the train cells' tiles: four chunks
+    ((1, 1024, 1, 128), 512, 1),
+    ((1, 512, 3, 32), 256, 0),    # head-major
+])
+def test_cut_tiles_match_xla(entry, shape, block, heads, ran):
+    """Masked tiles cut into chunks (``_diagonal_chunks``), by both ways
+    into the kernels and every layout: output and all three gradients."""
+    from ray_tpu.ops.attention import causal_tile_schedule
+
+    b, l, h, d = shape
+    sched = causal_tile_schedule(l, l, block, block)
+    assert sched["chunk"] == 128
+    assert sched["multiplied_share"] < sched["visited_share"]
+    q, k, v = _rand_qkv(*shape)
+    kw = dict(causal=True, block_q=block, block_k=block, interpret=True)
+
+    def by_qkv(q, k, v):
+        qkv = jnp.concatenate([x.reshape(b, l, h * d) for x in (q, k, v)],
+                              -1)
+        return flash_attention_qkv(qkv, h, **kw).reshape(shape)
+
+    attend = by_qkv if entry == "qkv" else functools.partial(
+        flash_attention, **kw)
+    with jax.default_matmul_precision("float32"):
+        out = attend(q, k, v)
+        gf = _grads(attend, q, k, v)
+        gx = _grads(lambda q, k, v: _xla_attention(q, k, v, True, None),
+                    q, k, v)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(_xla_attention(q, k, v, True, None)),
+            atol=1e-5, rtol=1e-5)
+    for a, b_, name in zip(gf, gx, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-4,
+                                   rtol=1e-3, err_msg=f"d{name}")
+    width = (3 if entry == "qkv" and heads else 1) * h * d
+    assert ran[0] == (((b, l, width), heads) if heads
+                      else ((b * h, l, d), 1))
+
+
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("blocks", [(128, 128), (128, 256), (256, 128)])
+@pytest.mark.parametrize("blocks", [(128, 128), (128, 256), (256, 128),
+                                    (256, 256)])  # the last: cut tiles
 def test_fused_backward_equals_the_two_kernel_form(blocks, causal):
     """One kernel that computes a tile's s, p, dp, ds once, and the dq and
     dk/dv kernels that each compute them: the same gradients."""
@@ -331,16 +382,19 @@ def test_fused_backward_equals_the_two_kernel_form(blocks, causal):
 
 
 @pytest.mark.parametrize("fused", [True, False])
-def test_both_backward_forms_match_xla(fused, monkeypatch):
+@pytest.mark.parametrize("block", [128, 256])  # 256: the masked tiles cut
+def test_both_backward_forms_match_xla(fused, block, monkeypatch):
     """The custom VJP takes whichever form ``_fused_bwd_fits`` names; both
-    are held to the XLA reference here."""
+    are held to the XLA reference here (not fused: the rolled forward and
+    the dq and dk/dv kernels, their offsets traced)."""
     from ray_tpu.ops import attention
 
     monkeypatch.setattr(attention, "_whole_head_fits", lambda *a: fused)
+    jax.clear_caches()  # the rule is read while tracing
     q, k, v = _rand_qkv(1, 512, 2, 32)
     with jax.default_matmul_precision("float32"):
         gf = _grads(lambda q, k, v: flash_attention(
-            q, k, v, causal=True, block_q=128, block_k=128,
+            q, k, v, causal=True, block_q=block, block_k=block,
             interpret=True), q, k, v)
         gx = _grads(lambda q, k, v: _xla_attention(q, k, v, True, None),
                     q, k, v)
@@ -426,12 +480,62 @@ def test_schedule_counter_on_the_parent_choice():
 
     assert causal_tile_schedule(1024, 1024, 256, 1024) == {
         "total": 4, "visited": 4, "masked": 4, "skipped": 0,
-        "visited_share": 1.0}
+        "visited_share": 1.0, "chunk": 0, "multiplied_share": 1.0}
     assert causal_tile_schedule(4096, 4096, 256, 1024)["visited_share"] \
         == 0.625
     assert causal_tile_schedule(1024, 1024, 256, 256) == {
         "total": 16, "visited": 10, "masked": 4, "skipped": 6,
-        "visited_share": 0.625}
+        "visited_share": 0.625, "chunk": 128, "multiplied_share": 0.5625}
+
+
+@pytest.mark.parametrize("chunk,share", [(128, 0.5625), (256, 0.625),
+                                         (512, 0.75)])
+def test_schedule_counts_what_a_cut_tile_multiplies(chunk, share,
+                                                    monkeypatch):
+    """(512, 512) tiles at 1,024 tokens visit 0.75 of the square; cut into
+    chunks of 128 columns the masked tiles multiply 10 of their 16
+    sub-blocks and the square 0.5625 (0.625 at 256; the mask needs
+    0.5005).  The counter and the kernels read the one ``_diagonal_chunks``:
+    with another chunk width the counter follows, the kernels are called
+    with the tiles' sizes in both orientations, and forward and gradients
+    still equal the XLA path's."""
+    from ray_tpu.ops.attention import causal_tile_schedule
+
+    asked = []
+    chunks = attention._diagonal_chunks
+
+    def spy(block_q, block_k):
+        asked.append((block_q, block_k))
+        return chunks(block_q, block_k)
+
+    monkeypatch.setattr(attention, "_CHUNK", chunk)
+    monkeypatch.setattr(attention, "_diagonal_chunks", spy)
+    jax.clear_caches()  # an earlier test's trace of these shapes
+    sched = causal_tile_schedule(1024, 1024, 512, 512)
+    assert sched["visited_share"] == 0.75
+    assert sched["chunk"] == (chunk if chunk < 512 else 0)
+    assert sched["multiplied_share"] == share
+    # ... which is the sub-blocks at or under the diagonal, counted
+    g, firsts = chunks(512, 512)
+    under = (512 // chunk) * (512 // chunk + 1) // 2
+    assert (g, len(firsts)) == ((chunk, 512 // chunk) if g else (0, 0))
+    assert (512 * 512 + 2 * under * chunk * chunk) / 1024 ** 2 == share
+    del asked[:]
+    q, k, v = _rand_qkv(1, 1024, 2, 64)
+    with jax.default_matmul_precision("float32"):
+        out = flash_attention(q, k, v, causal=True, interpret=True)
+        gf = _grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=True), q, k, v)
+        gx = _grads(lambda q, k, v: _xla_attention(q, k, v, True, None),
+                    q, k, v)
+    assert asked and set(asked) == {(512, 512)}
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_xla_attention(q, k, v, True, None)),
+        atol=1e-5, rtol=1e-5)
+    for a, b, name in zip(gf, gx, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   rtol=1e-3, err_msg=f"d{name}")
+    jax.clear_caches()  # the kernels traced here read the patched width
 
 
 @pytest.mark.parametrize("length,d,itemsize,whole", [

@@ -99,6 +99,43 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, dtype, kernels,
 
 
 @pytest.mark.timeout(120)
+@pytest.mark.parametrize("shape,fused,bodies", [
+    ((8, 1024, 16, 64), True, 6),    # the one-chip train cell: qkv, dqkv
+    ((8, 1024, 16, 64), False, 6),   # the four-chip one: three operands
+    ((2, 1536, 4, 64), False, 12),   # the most tile bodies a block of two
+                                     # heads has where it fits VMEM
+    ((1, 2048, 8, 128), False, 10),  # the longest: four masked tiles a head
+])
+def test_cut_tiles_compile_for_v5e(one_chip, shape, fused, bodies):
+    """The whole-head kernels with their masked tiles cut into chunks of 128
+    columns (PR 54): the slices and joins the cut makes lie on the tiling,
+    the kernels fit VMEM, and the tile bodies ``_whole_head_fits`` counts
+    (by tiles visited: a chunk is part of its tile's body) compile."""
+    from ray_tpu.ops.attention import (_MAX_UNROLLED_TILES,
+                                       causal_tile_schedule)
+
+    b, length, h, d = shape
+    blocks = _auto_blocks(length, length, d, True)
+    sched = causal_tile_schedule(length, length, *blocks)
+    assert sched["chunk"] == 128
+    assert sched["multiplied_share"] < sched["visited_share"]
+    heads = _heads_per_block(length, length, h, d, 2, *blocks, True)
+    assert heads and sched["visited"] * heads == bodies <= _MAX_UNROLLED_TILES
+    if fused:
+        args = (jax.ShapeDtypeStruct((b, length, 3 * h * d), jnp.bfloat16,
+                                     sharding=one_chip),)
+        attend = lambda qkv: flash_attention_qkv(qkv, h, causal=True)  # noqa: E731
+    else:
+        args = (jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                     sharding=one_chip),) * 3
+        attend = lambda q, k, v: flash_attention(q, k, v, causal=True)  # noqa: E731
+    text = jax.jit(jax.grad(
+        lambda *a: jnp.sum(attend(*a).astype(jnp.float32)),
+        argnums=tuple(range(len(args))))).lower(*args).compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd" in text
+
+
+@pytest.mark.timeout(120)
 @pytest.mark.parametrize("rows,experts,d,f", [
     (16, 64, 2048, 1024),   # OLMoE's decode step: 16 slots, one token each
     (512, 64, 2048, 1024),  # its 512-row prefill bucket: the most rows

@@ -17,6 +17,12 @@ rule gives the shape, and the largest error of each form's output and dqkv
 against ``head_major`` relative to the largest value.  The last line says
 whether every form agreed.  Times come from a chip only: on the CPU the
 kernels are interpreted (``--tiny``) and the line says so.
+
+``--chunks``: also ``chunk_ms``, the form the shape rule gives (``fused``
+where there is one) with a masked tile multiplied whole (``0``, as until PR
+54) and cut into chunks of 256 and 128 columns (``attention._CHUNK``):
+``fwd`` the forward alone, ``both`` forward and backward, and the error of
+each against the uncut tile.
 """
 import argparse
 import json
@@ -37,6 +43,7 @@ SHAPES = [  # batch, length, heads, head
     (8, 1024, 8, 128),   # Llama-family heads: a head a block
     (4, 2048, 8, 128),   # ... at the longest the whole-head kernels hold
     (8, 1024, 32, 32),   # four heads a block
+    (2, 4096, 8, 128),   # the rolled, two-kernel form (head-major)
 ]
 TINY = [(1, 256, 4, 64), (1, 256, 2, 128)]
 
@@ -74,7 +81,7 @@ def _ms(fn, args, calls):
     return (time.perf_counter() - t0) / calls * 1e3
 
 
-def case(shape, calls, seed, interpret):
+def case(shape, calls, seed, interpret, chunks=False):
     b, l, h, d = shape
     keys = jax.random.split(jax.random.PRNGKey(seed), 2)
     qkv = jax.random.normal(keys[0], (b, l, 3 * h * d), jnp.bfloat16)
@@ -103,13 +110,36 @@ def case(shape, calls, seed, interpret):
     errs = {name: {"out": err(r[0], results["head_major"][0]),
                    "dqkv": err(r[1], results["head_major"][1])}
             for name, r in results.items() if name != "head_major"}
-    return {"shape": list(shape), "blocks": list(blocks),
+    line = {"shape": list(shape), "blocks": list(blocks),
             "heads_per_block": rule(l, l, h, d, 2, *blocks, True),
             "ms": ms, "kernels_ms": kernels_ms,
-            "rel_err_vs_head_major": errs,
-            # bf16: p and ds are rounded to 8 bits before their matmuls
-            "ok": all(e <= 2 ** -6 for form in errs.values()
-                      for e in form.values())}
+            "rel_err_vs_head_major": errs}
+    if chunks:
+        line["chunk_ms"], chunk_errs = _chunk_case(fused, (w, qkv), calls, err)
+        errs = dict(errs, **chunk_errs)
+        line["rel_err_vs_uncut"] = chunk_errs
+    # bf16: p and ds are rounded to 8 bits before their matmuls
+    line["ok"] = all(e <= 2 ** -6 for form in errs.values()
+                     for e in form.values())
+    return line
+
+
+def _chunk_case(form, args, calls, err):
+    """({columns a chunk: {"fwd", "both"} ms}, {columns: errors against 0})."""
+    chunk = attention._CHUNK
+    ms, results = {}, {}
+    for g in (0, 256, 128):
+        attention._CHUNK = g or 1 << 30  # wider than any tile: never cut
+        jax.clear_caches()  # the constant is read while tracing
+        forward, both = jax.jit(form), _grad(form)
+        ms[str(g)] = {"fwd": _ms(forward, args[1:], calls),
+                      "both": _ms(both, args, calls)}
+        results[g] = both(*args)
+    attention._CHUNK = chunk
+    jax.clear_caches()
+    return ms, {str(g): {"out": err(r[0], results[0][0]),
+                         "dqkv": err(r[1], results[0][1])}
+                for g, r in results.items() if g}
 
 
 def main() -> int:
@@ -118,6 +148,9 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--shapes", type=int, default=len(SHAPES),
                     help="only the first so many shapes")
+    ap.add_argument("--chunks", action="store_true",
+                    help="also the rule's form by the columns of a masked "
+                         "tile's chunk (0: multiplied whole)")
     ap.add_argument("--tiny", action="store_true",
                     help="toy shapes, the kernels interpreted (the CPU)")
     args = ap.parse_args()
@@ -129,7 +162,7 @@ def main() -> int:
     ok = True
     for shape in TINY if args.tiny else SHAPES[:args.shapes]:
         line = case(shape, 2 if args.tiny else args.calls, args.seed,
-                    interpret=args.tiny)
+                    interpret=args.tiny, chunks=args.chunks)
         line["device"] = {"platform": device.platform,
                           "kind": device.device_kind}
         line["interpreted"] = args.tiny
