@@ -1,12 +1,90 @@
 """Nature-DQN CNN trunk for pixel RL (equivalent of RLlib's visionnet,
-rllib/models/torch/visionnet.py).  NHWC, bfloat16-friendly."""
+rllib/models/torch/visionnet.py).  NHWC, bfloat16-friendly.
+
+The first layer reads **packed frames**.  An 8x8 convolution of stride 4,
+padded ``SAME``, is exactly a 2x2 convolution of stride 1, ``VALID``, over
+the padded frame folded four by four pixels into channels
+(``pack_frames``: ``[H, W, C]`` -> ``[ceil(H/4) + 1, ceil(W/4) + 1, 16 C]``,
+the dtype kept) with the kernel ``[8, 8, C, 32]`` reshaped to
+``[2, 2, 16 C, 32]`` inside the forward pass: the same products, summed in
+another order.  A caller that keeps many frames and reads them more than
+once (anakin PPO's trajectory) packs them once, as uint8, and hands them in
+packed: the bytes stay bytes until the convolution's operand, and a packed
+frame is a row of whole 128-byte lane tiles, which a gather of samples and
+the transposition in front of the convolution move at twice the pace of
+``[84, 84, 4]`` (PERF.md, PR 55).  The parameter tree is the unpacked
+kernel's: ``Conv_0/kernel`` stays ``[8, 8, C, 32]`` with ``nn.Conv``'s
+initialiser.
+"""
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+_KERNEL, _STRIDE = 8, 4
+_FOLD = _KERNEL // _STRIDE  # the packed kernel's side
+
+
+def _pads(height: int, width: int):
+    """What ``SAME`` puts around a frame for the first layer's window."""
+    return jax.lax.padtype_to_pads(
+        (height, width), (_KERNEL, _KERNEL), (_STRIDE, _STRIDE), "SAME")
+
+
+def packed_shape(frame_shape: Tuple[int, ...]) -> Tuple[int, int, int]:
+    """``[H, W, C]`` of a raw frame -> the shape ``pack_frames`` gives it."""
+    h, w, c = frame_shape
+    (top, bottom), (left, right) = _pads(h, w)
+    return ((h + top + bottom) // _STRIDE, (w + left + right) // _STRIDE,
+            _STRIDE * _STRIDE * c)
+
+
+def pack_frames(x: jax.Array) -> jax.Array:
+    """``[..., H, W, C]`` -> ``[..., H', W', 16 C]``: the frame padded as the
+    first layer's ``SAME`` convolution pads it (with zeros, by
+    ``lax.padtype_to_pads``, so 210x160 packs by the same rule as 84x84)
+    and folded four by four pixels into channels, channel
+    ``(dy * 4 + dx) * C + c``.  The dtype is kept: uint8 in, uint8 out."""
+    *lead, h, w, c = x.shape
+    ph, pw, pc = packed_shape((h, w, c))
+    (top, bottom), (left, right) = _pads(h, w)
+    # A row is W * C values from here on, so dx and c stay side by side:
+    # one dimension fewer to fold (4% of the rollout on the chip).
+    x = jnp.pad(x.reshape(*lead, h, w * c), [(0, 0)] * len(lead) + [
+        (top, bottom), (left * c, right * c)])
+    x = x.reshape(*lead, ph, _STRIDE, pw, _STRIDE * c)
+    n = len(lead)
+    return x.transpose(*range(n), n, n + 2, n + 1, n + 3).reshape(
+        *lead, ph, pw, pc)
+
+
+class PackedConv(nn.Module):
+    """``nn.Conv(features, (8, 8), strides=(4, 4))`` (``SAME``, with bias)
+    computed on ``pack_frames``'s form.  Parameters, their shapes and their
+    initialisers are ``nn.Conv``'s."""
+
+    features: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, packed: jax.Array) -> jax.Array:
+        c = packed.shape[-1] // (_STRIDE * _STRIDE)
+        kernel = self.param("kernel", nn.linear.default_kernel_init,
+                            (_KERNEL, _KERNEL, c, self.features))
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (self.features,))
+        # [8, 8, C, F] -> [2, 2, 16 C, F], channel (dy * 4 + dx) * C + c.
+        folded = kernel.reshape(_FOLD, _STRIDE, _FOLD, _STRIDE, c,
+                                self.features)
+        folded = folded.transpose(0, 2, 1, 3, 4, 5).reshape(
+            _FOLD, _FOLD, packed.shape[-1], self.features)
+        y = jax.lax.conv_general_dilated(
+            packed.astype(self.dtype), folded.astype(self.dtype), (1, 1),
+            "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        return y + bias.astype(self.dtype)
 
 
 class NatureCNN(nn.Module):
@@ -14,15 +92,27 @@ class NatureCNN(nn.Module):
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
-        """x: [B, H, W, C] uint8 or float → [B, out_dim]."""
+    def __call__(self, x: jax.Array, packed: bool = False) -> jax.Array:
+        """x: [B, H, W, C] uint8 or float → [B, out_dim]; with ``packed``,
+        x is ``pack_frames`` of such frames (``packed_shape``).  uint8 is
+        scaled by 1/255, float frames pass as they are."""
+        if not packed:
+            x = pack_frames(x)
         if x.dtype == jnp.uint8:
-            x = x.astype(self.dtype) / 255.0
+            # The barrier keeps the bytes bytes up to the convolution's own
+            # operand: without it the TPU compiler converts first, wherever
+            # the frames come from, and folds, transposes and stores 2-byte
+            # values (a rollout then folds twice, once to keep and once to
+            # read).
+            x = jax.lax.optimization_barrier(x).astype(self.dtype) / 255.0
         else:
             x = x.astype(self.dtype)
-        x = nn.relu(nn.Conv(32, (8, 8), strides=(4, 4), dtype=self.dtype)(x))
-        x = nn.relu(nn.Conv(64, (4, 4), strides=(2, 2), dtype=self.dtype)(x))
-        x = nn.relu(nn.Conv(64, (3, 3), strides=(1, 1), dtype=self.dtype)(x))
+        # Named by hand: the tree keeps nn.Conv's automatic names.
+        x = nn.relu(PackedConv(32, dtype=self.dtype, name="Conv_0")(x))
+        x = nn.relu(nn.Conv(64, (4, 4), strides=(2, 2), dtype=self.dtype,
+                            name="Conv_1")(x))
+        x = nn.relu(nn.Conv(64, (3, 3), strides=(1, 1), dtype=self.dtype,
+                            name="Conv_2")(x))
         x = x.reshape((x.shape[0], -1))
         return nn.relu(nn.Dense(self.out_dim, dtype=self.dtype)(x))
 

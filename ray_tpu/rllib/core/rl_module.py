@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.mlp import MLP
-from ray_tpu.models.nature_cnn import MinAtarCNN, NatureCNN
+from ray_tpu.models.nature_cnn import (MinAtarCNN, NatureCNN, pack_frames,
+                                       packed_shape)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -26,6 +27,15 @@ class RLModuleSpec:
 
     def build(self) -> "DiscreteActorCritic":
         return DiscreteActorCritic(self)
+
+    @property
+    def packed_obs_shape(self) -> Optional[Tuple[int, ...]]:
+        """The shape of one frame as NatureCNN's first layer reads it
+        (``nature_cnn.pack_frames``); None where the trunk is another one
+        (flat observations, boards under 32 pixels)."""
+        if not self.conv or min(self.obs_shape[:2]) < 32:
+            return None
+        return packed_shape(self.obs_shape)
 
     def example_obs(self, batch: int = 1) -> np.ndarray:
         """A zero observation batch matching this spec's trunk input —
@@ -51,19 +61,40 @@ class RLModuleSpec:
 
 class DiscreteActorCritic(nn.Module):
     """Categorical policy + value baseline (separate heads, shared trunk for
-    pixels, separate trunks for vectors — matching RLlib PPO defaults)."""
+    pixels, separate trunks for vectors — matching RLlib PPO defaults).
+
+    Frames of 32 pixels or more may come raw (``spec.obs_shape``) or packed
+    (``spec.packed_obs_shape``, what ``pack_obs`` returns): the static shape
+    tells which, and raw frames are packed first, so both run the same
+    convolution."""
 
     spec: RLModuleSpec
+
+    def pack_obs(self, obs):
+        """Observations as the trunk reads them: frames for NatureCNN packed
+        (uint8 stays uint8), anything else as it is.  For a caller that
+        keeps many observations and reads them more than once; behind the
+        barrier the copy it keeps and the one the trunk reads are one
+        array (without it the TPU compiler lays each out for itself)."""
+        if self.spec.packed_obs_shape is None:
+            return obs
+        return jax.lax.optimization_barrier(pack_frames(obs))
 
     @nn.compact
     def __call__(self, obs) -> Tuple[jax.Array, jax.Array]:
         s = self.spec
         if s.conv:
-            small = (s.obs_shape is not None
-                     and min(s.obs_shape[0], s.obs_shape[1]) < 32)
-            trunk_net = (MinAtarCNN(out_dim=128) if small
-                         else NatureCNN(out_dim=256))
-            trunk = trunk_net(obs)
+            if s.packed_obs_shape is None:
+                trunk = MinAtarCNN(out_dim=128)(obs)
+            else:
+                frame = tuple(obs.shape[1:])
+                packed = frame == s.packed_obs_shape
+                if not packed and frame != tuple(s.obs_shape):
+                    raise ValueError(
+                        f"frames of shape {frame}: neither "
+                        f"{tuple(s.obs_shape)} nor packed "
+                        f"{s.packed_obs_shape}")
+                trunk = NatureCNN(out_dim=256)(obs, packed=packed)
             logits = nn.Dense(s.num_actions, name="pi")(trunk)
             value = nn.Dense(1, name="vf")(trunk)[..., 0]
         else:

@@ -372,3 +372,37 @@ def test_sparse_decode_compiles_for_v5e_and_copies_no_pool(one_chip,
            and o["op"] not in ("parameter", "bitcast")]
     assert not big, big
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+@pytest.mark.timeout(420)
+def test_anakin_ppo_step_keeps_its_frames_bytes_for_v5e(one_chip):
+    """``ppo_atari84_anakin``'s whole step at the cell's sizes (2,048 envs
+    x 64 steps, minibatches of 8,192), built as the benchmark's driver
+    builds it (``tools/step_fusions.py``; ~35 s alone).  The trajectory and
+    a minibatch hold packed frames, uint8, an observation a run of 128-byte
+    rows; nothing frame-sized is ever written in two bytes or four (the
+    parent wrote ``bf16[8192,84,84,4]`` twice a minibatch, a conversion and
+    a relayout), and a minibatch's frames are moved twice, as bytes: the
+    gather, and the one transposition that puts the batch in the lanes,
+    where the compiler wants it for every convolution of the trunk.  Nothing
+    chooses at run time, so this is the mechanism's witness."""
+    import math
+    import re
+
+    from tools.step_fusions import compile_step, entry_operations
+
+    text = compile_step("ppo_atari84_anakin",
+                        sorted(one_chip.device_set, key=lambda d: d.id)
+                        ).as_text()
+    assert "bf16[8192,84,84,4]" not in text
+    assert "u8[64,2048,242,128]" in text       # the trajectory, packed
+    assert "u8[64,2048,84,84,4]" not in text   # ... and not raw beside it
+    moved = []
+    for o in entry_operations(text):
+        if o["times"] != 32 or o["op"] in ("get-tuple-element", "bitcast",
+                                           "parameter", "tuple", "while"):
+            continue  # 2 epochs x 16 minibatches: the inner loop's body
+        kind, dims = re.fullmatch(r"(\w+)\[([\d,]*)\]", o["shapes"][0]).groups()
+        if math.prod(int(d) for d in dims.split(",") if d) >= 8192 * 22 * 22 * 64:
+            moved.append((o["op"], kind))
+    assert moved == [("fusion", "u8"), ("copy", "u8")]

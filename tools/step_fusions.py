@@ -1,18 +1,21 @@
-"""What a train cell's step is made of, read off the compiled program without
-a chip: the step compiled for a described ``v5e:2x2`` (the route of
-``benchmark/rehearsal/compile_check.py``), its entry computation's
-operations grouped as a profile's breakdown names them
-(``benchmark/trace_reduce.py::op_key``), and its collectives
-with their place in the schedule.
+"""What a train cell's or the anakin PPO cell's step is made of, read off the
+compiled program without a chip: the step compiled for a described
+``v5e:2x2`` (the route of ``benchmark/rehearsal/compile_check.py``), the
+operations of its entry computation and of its ``while`` bodies (each as
+often as the loops around it turn) grouped as a profile's breakdown names
+them (``benchmark/trace_reduce.py::op_key``), and its collectives with
+their place in the schedule.
 
     JAX_PLATFORMS=cpu python3 tools/step_fusions.py --cell gpt2m_train_1k \
         [--min-ms 1] [--shape 8,1023,50257] [--save step.hlo.txt]
+    JAX_PLATFORMS=cpu python3 tools/step_fusions.py --cell ppo_atari84_anakin
     python3 tools/step_fusions.py --text step.hlo.txt
 
 A line a group: how many a step, the compiler's cost model for all of them
 (``estimated_cycles`` at 1.5 GHz: an estimate and never a measurement; 0-35%
 above what traced runs read on most operations, several times above on a
-few: PERF.md section 5),
+few, and on the PPO cell's byte copies four times off either way, a
+transposition too high and a gather too low: PERF.md section 5),
 whether a matmul (``convolution``) is fused inside, and the ``op_name`` of
 the first.  ``--shape`` lists instead, in schedule order, every operation
 with that shape among its results.  Nothing runs, so this gives no time.
@@ -42,29 +45,69 @@ def _benchmark():
     return benchmark
 
 
-def compile_step(cell_name: str):
-    """The cell's step program, compiled for the described chips."""
+def compile_step(cell_name: str, described=None):
+    """The cell's step program, compiled for the described chips:
+    ``described``, or a ``v5e:2x2`` described here (a test hands in its
+    fixture's; the persistent cache is then the caller's to keep out)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
     _benchmark()
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.experimental import topologies
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
     from benchmark import common
-    from benchmark.drivers import train_lm
 
-    jax.config.update("jax_enable_compilation_cache", False)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         cell = next(w for w in json.load(f)["workloads"]
                     if w["name"] == cell_name)
     config = common.load_json("configs", cell["config"] + ".json")
     traffic = common.load_traffic(cell["traffic"])
-    chips = cell["chips"]
-    topo = topologies.get_topology_desc(platform="tpu",
-                                        topology_name="v5e:2x2")
-    mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+    if described is None:
+        import jax
+        from jax.experimental import topologies
+
+        jax.config.update("jax_enable_compilation_cache", False)
+        described = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    devices = described[:cell["chips"]]
+    if traffic["driver"] == "rl_anakin":
+        return _compile_anakin_step(config, devices)
+    return _compile_train_step(config, traffic, devices)
+
+
+def _compile_anakin_step(config, devices):
+    """``make_anakin_ppo``'s step from the configuration that
+    ``drivers/rl_anakin.py::build_algo`` makes, on the shapes of its state.
+    The algorithm's constructor would run ``init`` on ``jax.devices()``:
+    ``build`` hands back the configuration instead, and the data mesh is
+    laid over the described chips."""
+    from unittest import mock
+
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from benchmark.drivers import rl_anakin
+    from ray_tpu.rllib.algorithms.algorithm_config import AlgorithmConfig
+    from ray_tpu.rllib.algorithms.ppo import make_anakin_ppo
+    from ray_tpu.rllib.utils import mesh as mesh_util
+
+    def described_mesh(n):
+        return Mesh(np.array(devices[:n]), (mesh_util.DATA_AXIS,))
+
+    with mock.patch.object(AlgorithmConfig, "build", lambda self: self), \
+            mock.patch.object(mesh_util, "data_mesh", described_mesh):
+        algo_config = rl_anakin.build_algo(config, len(devices), 0)
+        _module, init, step, _total = make_anakin_ppo(algo_config)
+    return step.lower(jax.eval_shape(init, 0)).compile()
+
+
+def _compile_train_step(config, traffic, devices):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from benchmark.drivers import train_lm
+
+    chips = len(devices)
+    mesh = Mesh(np.array(devices), ("data",))
     rep = NamedSharding(mesh, PartitionSpec())
     _m, init, _p, _s = train_lm.build_step(config, traffic, mesh, chips)
     params, opt = jax.tree.map(
@@ -94,35 +137,54 @@ def computations(text: str) -> dict:
     return found
 
 
+def _trips(condition: list) -> int:
+    """How often a ``while`` turns, where its condition is ``lax.scan``'s: a
+    counter from 0 held under the one integer constant there; 1 otherwise."""
+    bounds = [int(n) for x in condition
+              for n in re.findall(r"s32\[\]\S* constant\((\d+)\)", x)]
+    return bounds[0] if len(bounds) == 1 else 1
+
+
 def entry_operations(text: str) -> list:
-    """The entry computation's instructions in schedule order: ``at`` (its
-    place, 0 to 1), ``name``, ``key`` (what a profile's breakdown calls it),
-    ``shapes`` of its results, ``op``, ``op_name``, ``cycles`` by the cost
-    model, whether a ``convolution`` is fused inside, ``retries``."""
+    """The entry computation's instructions in schedule order, a ``while``
+    followed by its body's (and theirs): ``at`` (its place in the entry
+    computation, 0 to 1), ``name``, ``key`` (what a profile's breakdown
+    calls it), ``shapes`` of its results, ``op``, ``op_name``, ``cycles`` by
+    the cost model, ``times`` it runs a step (the trips of the loops around
+    it), whether a ``convolution`` is fused inside, ``retries``."""
     _benchmark()
     from benchmark.trace_reduce import op_key
 
     comps = computations(text)
     matmul = {n for n, lines in comps.items()
               if any(" convolution(" in x for x in lines)}
-    entry, out = comps["ENTRY"], []
-    for i, line in enumerate(entry):
-        m = _INSTRUCTION.match(line)
-        if not m:
-            continue
-        called = re.search(r"calls=%([\w.\-]+)", line)
-        cycles = re.search(r'"estimated_cycles":"(\d+)"', line)
-        op_name = re.search(r'op_name="([^"]*)"', line)
-        retries = re.search(r'"retry_count":"(\d+)"', line)
-        out.append({
-            "at": i / len(entry), "name": m["name"],
-            "key": op_key(line.strip().removeprefix("ROOT ")),
-            "shapes": _SHAPE.findall(m["type"]), "op": m["op"],
-            "op_name": op_name[1] if op_name else "",
-            "cycles": int(cycles[1]) if cycles else 0,
-            "matmul": bool(called and called[1] in matmul)
-            or m["op"] == "convolution",
-            "retries": int(retries[1]) if retries else 0})
+    out = []
+
+    def walk(lines, times, at):
+        for i, line in enumerate(lines):
+            m = _INSTRUCTION.match(line)
+            if not m:
+                continue
+            here = i / len(lines) if at is None else at
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            cycles = re.search(r'"estimated_cycles":"(\d+)"', line)
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            retries = re.search(r'"retry_count":"(\d+)"', line)
+            out.append({
+                "at": here, "name": m["name"],
+                "key": op_key(line.strip().removeprefix("ROOT ")),
+                "shapes": _SHAPE.findall(m["type"]), "op": m["op"],
+                "op_name": op_name[1] if op_name else "",
+                "cycles": int(cycles[1]) if cycles else 0, "times": times,
+                "matmul": bool(called and called[1] in matmul)
+                or m["op"] == "convolution",
+                "retries": int(retries[1]) if retries else 0})
+            loop = re.search(
+                r"condition=%([\w.\-]+), body=%([\w.\-]+)", line)
+            if m["op"] == "while" and loop:
+                walk(comps[loop[2]], times * _trips(comps[loop[1]]), here)
+
+    walk(comps["ENTRY"], 1, None)
     return out
 
 
@@ -133,9 +195,11 @@ def _ms(cycles: int) -> float:
 def report(text: str, min_ms: float, shape: str | None) -> None:
     ops = entry_operations(text)
     timed = [o for o in ops if o["cycles"]]
-    print(f"entry computation: {len(ops)} instructions, {len(timed)} with a "
-          f"cost, {_ms(sum(o['cycles'] for o in timed)):.2f} ms by the cost "
-          f"model (custom calls, the flash kernels among them, have none); "
+    print(f"entry computation and loop bodies: {len(ops)} instructions, "
+          f"{len(timed)} with a cost, "
+          f"{_ms(sum(o['cycles'] * o['times'] for o in timed)):.2f} ms by the "
+          f"cost model, each as often as its loops turn "
+          f"(custom calls, the flash kernels among them, have none); "
           f"rematerialised {text.count('.remat')}, mosaic calls "
           f"{text.count('tpu_custom_call')}")
     if shape:
@@ -144,7 +208,7 @@ def report(text: str, min_ms: float, shape: str | None) -> None:
             if o["op"] != "get-tuple-element" and any(
                     s.endswith(want) for s in o["shapes"]):
                 print(f"  {o['at']:.3f} {o['name']} ({', '.join(o['shapes'])})"
-                      f" {_ms(o['cycles']):.2f} ms"
+                      f" {_ms(o['cycles']):.2f} ms x {o['times']}"
                       f"{' matmul' if o['matmul'] else ''}"
                       f"{' retries ' + str(o['retries']) if o['retries'] else ''}"
                       f" {o['op_name']}")
@@ -153,14 +217,17 @@ def report(text: str, min_ms: float, shape: str | None) -> None:
     for o in timed:
         groups[o["key"]].append(o)
     print("  count  est.ms  matmul  group: op_name of the first")
-    for key, members in sorted(
-            groups.items(), key=lambda kv: -sum(o["cycles"] for o in kv[1])):
-        total = _ms(sum(o["cycles"] for o in members))
+    def cost(members):
+        return sum(o["cycles"] * o["times"] for o in members)
+
+    for key, members in sorted(groups.items(), key=lambda kv: -cost(kv[1])):
+        total = _ms(cost(members))
         if total < min_ms:
             continue
         inside = sum(o["matmul"] for o in members)
-        print(f"  {len(members):5d} {total:7.2f}  {inside:3d}/{len(members):<3d}"
-              f" {key}: {members[0]['op_name']}")
+        print(f"  {sum(o['times'] for o in members):5d} {total:7.2f}  "
+              f"{inside:3d}/{len(members):<3d} {key}: "
+              f"{members[0]['op_name']}")
     print("collectives, by their place in the schedule (0 first, 1 last):")
     for o in ops:
         if o["op"].startswith(COLLECTIVES):
