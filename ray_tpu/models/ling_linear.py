@@ -44,6 +44,7 @@ last layers (``expert_swiglu_limit_list``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -57,6 +58,7 @@ from ray_tpu.models.falcon_h1 import _a_log_init, _dt_bias_init
 from ray_tpu.ops.kda import kda_chunked, kda_gate, kda_step
 from ray_tpu.ops.mla import latent_rows, mla_absorbed, mla_expanded
 from ray_tpu.ops.moe import experts_held_swiglu, route_group_sigmoid_topk
+from ray_tpu.ops.rope import cos_sin_mscale, inv_freq, softmax_mscale
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,6 +168,11 @@ class LingLinearConfig:
         return i < self.first_k_dense_replace
 
 
+# A context up to this long is attended to with all heads at once, whatever
+# a config's ``head_block`` says.
+ROWS_ALL_HEADS = 2048
+
+
 def _norm(c: LingLinearConfig, name: str) -> RMSNorm:
     return RMSNorm(c.rms_norm_eps, c.dtype, c.param_dtype, name=name)
 
@@ -176,14 +183,18 @@ def _dense(c: LingLinearConfig, feats: int, name: str) -> nn.Dense:
                     name=name)
 
 
-def _rope(x, positions, theta: float):
+def _rope(x, positions, theta: float, scaling=None):
     """Rotate-half rope over the whole of x's last dimension: x
     [B, L, H, P], positions [B, L] absolute.  The angles are made from the
-    positions, no table to ``max_position_embeddings``."""
-    p = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, p, 2, dtype=jnp.float32) / p))
-    angles = positions.astype(jnp.float32)[..., None] * freqs
-    return apply_rope(x, jnp.cos(angles), jnp.sin(angles))
+    positions, no table to ``max_position_embeddings``.  ``scaling``: a
+    ``YarnScaling`` (``ops/rope.py``), its blend of frequencies and its
+    factor on cos and sin."""
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq(
+        x.shape[-1], theta, scaling)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None and cos_sin_mscale(scaling) != 1.0:
+        cos, sin = (a * cos_sin_mscale(scaling) for a in (cos, sin))
+    return apply_rope(x, cos, sin)
 
 
 class _Float32Out(nn.Module):
@@ -280,8 +291,16 @@ class MLAttention(nn.Module):
     """The MLA layer on the normed input ``u``.  ``kv`` None: a whole
     context, expanded.  ``kv`` the caller's ``attend(q, k, v, sm_scale=)``
     over its cache of latent rows: absorbed.  ``rows`` True: expanded, and
-    the latent rows come back for a cache that is empty yet (a prefill)."""
-    config: LingLinearConfig
+    the latent rows come back for a cache that is empty yet (a prefill).
+
+    Shared with ``models/latent_moe.py``, whose config has the same keys
+    and three more, read here where a config has them: ``rope_scaling`` (a
+    ``YarnScaling``: the rope's frequencies are blended and the softmax
+    scale carries ``mscale^2``), ``latent_cache`` (the cache holds ONE row
+    a token, ``[c | rope(k_r)]``: no V row is made) and ``head_block``
+    (over a context of more than ``ROWS_ALL_HEADS`` rows that many heads
+    at a time, from the query's projection to the output's)."""
+    config: Any
 
     @nn.compact
     def __call__(self, u, positions, kv=None, rows=False):
@@ -289,31 +308,76 @@ class MLAttention(nn.Module):
         bsz, length, _ = u.shape
         h, nope, rope_d = c.num_attention_heads, c.qk_nope_head_dim, \
             c.qk_rope_head_dim
-        q = _dense(c, h * c.qk_head_dim, "q_proj")(u).reshape(
-            bsz, length, h, c.qk_head_dim)
-        q = _norm(c, "q_norm")(q)  # one learned scale, every head's 192
-        q_nope, q_rope = q[..., :nope], _rope(q[..., nope:], positions,
-                                              c.rope_theta)
+        scaling = getattr(c, "rope_scaling", None)
+        one_row = getattr(c, "latent_cache", False)
+        rope = functools.partial(_rope, positions=positions,
+                                 theta=c.rope_theta, scaling=scaling)
         kva = _dense(c, c.kv_lora_rank + rope_d, "kv_a_proj")(u)
         latent = _norm(c, "kv_norm")(kva[..., :c.kv_lora_rank])
-        k_rope = _rope(kva[..., None, c.kv_lora_rank:], positions,
-                       c.rope_theta)[:, :, 0]
+        k_rope = rope(kva[..., None, c.kv_lora_rank:])[:, :, 0]
         w_kvb = self.param("kv_b_proj", _kernel_init,
                            (c.kv_lora_rank, h * (nope + c.v_head_dim)),
                            c.param_dtype).reshape(c.kv_lora_rank, h, -1)
-        scale = c.qk_head_dim ** -0.5
+        scale = c.qk_head_dim ** -0.5 * softmax_mscale(scaling)
         new_kv = None
+        if kv is None and rows:
+            new_kv = latent_rows(latent, k_rope)
+            if one_row:
+                new_kv = (new_kv[0], None)
+        block = getattr(c, "head_block", 0)
+        if kv is None and 0 < block < h and length > ROWS_ALL_HEADS:
+            return self._by_head_blocks(u, rope, latent, k_rope, w_kvb,
+                                        scale, block), new_kv
+        q = _dense(c, h * c.qk_head_dim, "q_proj")(u).reshape(
+            bsz, length, h, c.qk_head_dim)
+        q = _norm(c, "q_norm")(q)  # one learned scale, every head's 192
+        q_nope, q_rope = q[..., :nope], rope(q[..., nope:])
         if kv is not None:
             out, new_kv = mla_absorbed(kv, q_nope, q_rope, latent, k_rope,
-                                       w_kvb, nope, scale)
+                                       w_kvb, nope, scale, one_row=one_row)
         else:
             out = mla_expanded(q_nope, q_rope, latent, k_rope, w_kvb, nope,
                                scale)
-            if rows:
-                new_kv = latent_rows(latent, k_rope)
         out = _dense(c, c.hidden_size, "o_proj")(
             out.reshape(bsz, length, h * c.v_head_dim))
         return out, new_kv
+
+    def _by_head_blocks(self, u, rope, latent, k_rope, w_kvb, scale, block):
+        """The expanded form ``block`` heads at a time, from ``q_proj`` to
+        ``o_proj`` (whose products the blocks add up in float32): a context
+        of 16,384 rows at 64 heads of 192 never holds its queries, keys and
+        values of all heads at once (2 GB beside a serving engine's pool),
+        and the flash kernel sees ``block`` heads a call.  The weights are
+        the leaves the whole-context form made.
+
+        Unrolled, and a block (its slices of the weights too) starts when
+        the one before is done: inside a ``scan`` the compiler set every
+        layer's sums aside at once, and left alone it re-lays every block's
+        slice of every layer's ``q_proj`` out at the program's start and
+        runs blocks side by side (a 16,384-row prefill's scratch: 3.1 GiB
+        so, 1.4 GiB in order; compiled for a described v5e, PR 56)."""
+        c = self.config
+        bsz, length, _ = u.shape
+        nope, qk, vd = c.qk_nope_head_dim, c.qk_head_dim, c.v_head_dim
+        p = self.variables["params"]
+        q_norm = _norm(c, None)
+        w_q, w_o = p["q_proj"]["kernel"], p["o_proj"]["kernel"]
+        acc = None
+        for first in range(0, c.num_attention_heads, block):
+            u, acc, w_q, w_kvb, w_o = jax.lax.optimization_barrier(
+                (u, acc, w_q, w_kvb, w_o))
+            q = jnp.dot(u.astype(c.dtype),
+                        w_q[:, first * qk:(first + block) * qk].astype(
+                            c.dtype)).reshape(bsz, length, block, qk)
+            q = q_norm.apply({"params": p["q_norm"]}, q)
+            out = mla_expanded(
+                q[..., :nope], rope(q[..., nope:]), latent, k_rope,
+                w_kvb[:, first:first + block], nope, scale).reshape(
+                bsz, length, block * vd)
+            part = jnp.dot(out, w_o[first * vd:(first + block) * vd].astype(
+                c.dtype), preferred_element_type=jnp.float32)
+            acc = part if acc is None else acc + part
+        return acc.astype(c.dtype)
 
 
 class SwiGLU(nn.Module):

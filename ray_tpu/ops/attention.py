@@ -897,6 +897,18 @@ def _fwd_call(ops, *, d, heads, whole, causal, sm_scale, block_q, block_k,
         out_specs.append(lse_spec)
         out_shape.append(jax.ShapeDtypeStruct(
             (b, blocks, _LSE_SUBLANES, lq), jnp.float32))
+    # K and V lie whole beside the q tiles, each held twice by the pipeline:
+    # where that nears the compiler's default of 16 MiB of scoped VMEM (a
+    # head of 192, padded to 256 lanes, over 8,192 rows is 16 MiB and over
+    # 16,384 rows 32 MiB: neither compiles under the default) the call asks
+    # for what it holds; every smaller call is compiled as it was.
+    resident = 2 * 2 * lk * (-(-lanes // _LANES) * _LANES) * k.dtype.itemsize
+    more = {}
+    if resident > _VMEM_BUDGET * 3 // 4:
+        import jax.experimental.pallas.tpu as pltpu
+
+        more["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=resident + _VMEM_BUDGET)
     res = pl.pallas_call(
         kernel,
         grid=grid,
@@ -906,6 +918,7 @@ def _fwd_call(ops, *, d, heads, whole, causal, sm_scale, block_q, block_k,
         out_shape=out_shape,
         interpret=interpret,
         name="flash_fwd",
+        **more,
     )(q, k, v)
     return res if with_lse else (res[0], None)
 

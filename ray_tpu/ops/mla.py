@@ -1,9 +1,11 @@
 """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434, section 2.1)
 in the two forms a serve engine needs.  The head widths are the caller's
-(128 + 64 / 128 in ``models/ling_linear.py``, 192 + 64 / 256 in
-``models/glm_dsa.py``), and so is where the queries come from: projected
-from the hidden state, or from a compressed query latent
-(``compressed_query``: ``q_lora_rank``).
+(128 + 64 / 128 in ``models/ling_linear.py`` and ``models/latent_moe.py``,
+192 + 64 / 256 in ``models/glm_dsa.py``), and so are where the queries come
+from (projected from the hidden state, or from a compressed query latent:
+``compressed_query``, ``q_lora_rank``), the rope's frequencies and the
+softmax scale (``sm_scale``: a YaRN-scaled rope multiplies it,
+``ops/rope.py``).
 
 What is cached for a token is one latent row ``[c | rope(k_r)]``: ``c``
 [R] the RMSNorm'd compressed key/value, ``k_r`` [P] the rope part every
@@ -16,9 +18,17 @@ value ``W_UV,h c`` (V wide); ``w_kvb`` [R, H, N + V] holds both.
   ``attend(q, k, v, sm_scale=)`` (the serve engine's paged kernel) with ONE
   KV head: ``q_nope . W_UK,h c = (W_UK,h^T q_nope) . c``, so the query
   presented for head h is ``[W_UK,h^T q_nope,h | rope(q_rope,h)]``, the K
-  row ``[c | rope(k_r)]``, the V row ``[c | 0]`` (a row as wide as K's, so
-  that both pools are one shape), and ``W_UV,h`` acts on the R columns that
-  come back.  The same numbers as the expanded form, up to rounding.
+  row ``[c | rope(k_r)]``, and ``W_UV,h`` acts on the R columns that come
+  back.  The same numbers as the expanded form, up to rounding.  What the
+  hook is handed as V is the cache's business:
+  *one row* (``one_row``: ``models/latent_moe.py``, whose cache is ONE pool
+  of latent rows): nothing; the values are the first R columns of the K
+  row, which the latent form of the paged kernel reads out of the block it
+  already holds (``ops/paged_attention.py::latent_paged_attention``);
+  *two rows* (``models/ling_linear.py``): ``[c | 0]``, a row as wide as
+  K's for a V pool of the K pool's shape; ``models/glm_dsa.py`` puts its
+  indexer's key where the zeros are (``index_rows``), so its second row is
+  not a copy.
 """
 from __future__ import annotations
 
@@ -74,14 +84,17 @@ def mla_expanded(q_nope: jax.Array, q_rope: jax.Array, c: jax.Array,
 
 def mla_absorbed(attend, q_nope: jax.Array, q_rope: jax.Array, c: jax.Array,
                  k_rope: jax.Array, w_kvb: jax.Array, nope: int,
-                 sm_scale: float):
+                 sm_scale: float, one_row: bool = False):
     """The new tokens' q_nope / q_rope / c / k_rope as above, ``attend``
     the caller's cache hook → ([B, L, H, V], the (K row, V row) for the
-    caller's cache)."""
+    caller's cache; ``one_row``: the V row is None, and ``attend`` takes
+    its values from the K rows' first R columns)."""
     rank = c.shape[-1]
     w = w_kvb.astype(c.dtype)
     q_lat = jnp.einsum("blhn,rhn->blhr", q_nope, w[..., :nope])
     k_row, v_row = latent_rows(c, k_rope)
+    if one_row:
+        v_row = None
     out = attend(jnp.concatenate([q_lat, q_rope], axis=-1), k_row, v_row,
                  sm_scale=sm_scale)[..., :rank]
     return jnp.einsum("blhr,rhv->blhv", out, w[..., nope:]), (k_row, v_row)

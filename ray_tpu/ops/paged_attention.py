@@ -43,6 +43,10 @@ from ray_tpu.ops.attention import (NEG_INF, blockwise_update,
 # page of GPT-2 medium is 32 KB, too little for one DMA round to hide
 # behind; 8 of them are 128 rows, one MXU pass.
 PAGES_PER_STEP = 8
+# The latent form: 64 heads read ONE row of 640 columns, so a step of 128
+# rows is 160 KB and done in a fraction of a microsecond; more pages a step
+# mean fewer turns of the loop for a context of thousands of rows.
+LATENT_PAGES_PER_STEP = 16
 _LANES = 128
 
 
@@ -54,15 +58,23 @@ def pool_width(kv_heads: int, head_dim: int) -> int:
 
 
 def _paged_kernel(layer_ref, table_ref, lengths_ref, first_ref,  # SMEM
-                  q_ref, k_hbm, v_hbm, acc_ref, m_ref, l_ref,
-                  k_buf, v_buf, sems, *, page_size, pages_per_step,
-                  sm_scale):
+                  q_ref, *refs, page_size, pages_per_step, sm_scale,
+                  rank=None):
     """One slot: the flash recurrence over its live pages.
 
     q_ref [R, kv_heads*head_dim] block-diagonal queries; k_hbm / v_hbm the
     whole pools, left in HBM; acc_ref [R, kv_heads*head_dim], m_ref and
     l_ref [R, 128] (the value in every lane), all float32 and not
-    normalised; k_buf / v_buf [2, pages_per_step*page_size, ...] VMEM."""
+    normalised; k_buf / v_buf [2, pages_per_step*page_size, ...] VMEM.
+
+    ``rank`` (the latent form: a pool of rows ``[c | rope(k_r)]``,
+    ``ops/mla.py``): there is no V pool and no V buffer; a page is copied
+    once, and the values are the first ``rank`` columns of the block the
+    keys are; acc_ref is [R, rank]."""
+    if rank is None:
+        k_hbm, v_hbm, acc_ref, m_ref, l_ref, k_buf, v_buf, sems = refs
+    else:
+        k_hbm, acc_ref, m_ref, l_ref, k_buf, sems = refs
     slot = pl.program_id(0)
     layer = layer_ref[0]
     first = first_ref[slot]
@@ -74,8 +86,11 @@ def _paged_kernel(layer_ref, table_ref, lengths_ref, first_ref,  # SMEM
     def page_copies(step, buf, i):
         page = table_ref[slot, first + step * pages_per_step + i]
         dst = pl.ds(i * page_size, page_size)
-        return (pltpu.make_async_copy(k_hbm.at[layer, page],
-                                      k_buf.at[buf, dst], sems.at[0, buf]),
+        k_copy = pltpu.make_async_copy(k_hbm.at[layer, page],
+                                       k_buf.at[buf, dst], sems.at[0, buf])
+        if rank is not None:
+            return (k_copy,)
+        return (k_copy,
                 pltpu.make_async_copy(v_hbm.at[layer, page],
                                       v_buf.at[buf, dst], sems.at[1, buf]))
 
@@ -103,7 +118,8 @@ def _paged_kernel(layer_ref, table_ref, lengths_ref, first_ref,  # SMEM
             for_live_pages(step + 1, 1 - buf, lambda c: c.start())
 
         for_live_pages(step, buf, lambda c: c.wait())
-        k, v = k_buf[buf], v_buf[buf]
+        k = k_buf[buf]
+        v = v_buf[buf] if rank is None else k[:, :rank]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [R, span]
@@ -134,37 +150,43 @@ def _paged_kernel(layer_ref, table_ref, lengths_ref, first_ref,  # SMEM
 
 
 def _paged_partial(q_bd, k_pool, v_pool, layer, table, lengths, first_page,
-                   sm_scale):
+                   sm_scale, rank=None):
     """(acc, m, l) of the block-diagonal queries ``q_bd [slots, R, HD]``
-    over each slot's cached rows."""
+    over each slot's cached rows.  ``rank``: the latent form
+    (``_paged_kernel``), of a kernel named ``latent_paged_attn``; ``v_pool``
+    is not looked at and acc is [slots, R, rank]."""
     slots, r, hd = q_bd.shape
     page_size = k_pool.shape[2]
-    span = PAGES_PER_STEP * page_size
+    latent = rank is not None
+    pages_per_step = LATENT_PAGES_PER_STEP if latent else PAGES_PER_STEP
+    span = pages_per_step * page_size
     kernel = functools.partial(
-        _paged_kernel, page_size=page_size, pages_per_step=PAGES_PER_STEP,
-        sm_scale=sm_scale)
+        _paged_kernel, page_size=page_size, pages_per_step=pages_per_step,
+        sm_scale=sm_scale, rank=rank)
     per_slot = lambda width: pl.BlockSpec(  # noqa: E731
         (None, r, width), lambda s, *_: (s, 0, 0))
+    pools = (k_pool,) if latent else (k_pool, v_pool)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(slots,),
-            in_specs=[per_slot(hd),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=[per_slot(hd), per_slot(_LANES), per_slot(_LANES)],
-            scratch_shapes=[pltpu.VMEM((2, span, hd), k_pool.dtype),
-                            pltpu.VMEM((2, span, hd), v_pool.dtype),
-                            pltpu.SemaphoreType.DMA((2, 2))]),
-        out_shape=[jax.ShapeDtypeStruct((slots, r, hd), jnp.float32),
+            in_specs=[per_slot(hd)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=[per_slot(rank if latent else hd), per_slot(_LANES),
+                       per_slot(_LANES)],
+            scratch_shapes=[pltpu.VMEM((2, span, hd), pool.dtype)
+                            for pool in pools]
+            + [pltpu.SemaphoreType.DMA((len(pools), 2))]),
+        out_shape=[jax.ShapeDtypeStruct(
+            (slots, r, rank if latent else hd), jnp.float32),
                    jax.ShapeDtypeStruct((slots, r, _LANES), jnp.float32),
                    jax.ShapeDtypeStruct((slots, r, _LANES), jnp.float32)],
-        name="paged_attn",
+        name="latent_paged_attn" if latent else "paged_attn",
         interpret=jax.default_backend() == "cpu",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), table.astype(jnp.int32),
       lengths.astype(jnp.int32), first_page.astype(jnp.int32),
-      q_bd, k_pool, v_pool)
+      q_bd, *pools)
 
 
 def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
@@ -226,4 +248,48 @@ def _paged_attention(q, k_new, v_new, k_pool, v_pool, layer, table, lengths,
     causal = jnp.tril(jnp.ones((t, t), bool))
     o, l, _ = blockwise_update(q, k_new, v_new, o, l, m, mask=causal,
                                sm_scale=sm_scale)
+    return finalize_blockwise(o, l).astype(q.dtype)
+
+
+def latent_paged_attention(q: jax.Array, row_new: jax.Array, v_new,
+                           k_pool: jax.Array, v_pool, layer,
+                           table: jax.Array, lengths: jax.Array,
+                           first_page: Optional[jax.Array] = None,
+                           sm_scale: Optional[float] = None, *,
+                           rank: int) -> jax.Array:
+    """``paged_attention`` over a pool of latent rows (``ops/mla.py``'s
+    absorbed form, one row a token): q [slots, T, H, R + P] every head's
+    query against ONE shared row ``[c | rope(k_r)]`` (row_new [slots, T, 1,
+    R + P]: the new tokens'; k_pool [layers, pages, page_size,
+    pool_width(1, R + P)]: the cached ones), whose first ``rank`` (R)
+    columns are also its value.  A page is copied once and multiplied
+    twice, as K whole and as V by its first R columns; ``v_new`` and
+    ``v_pool`` are not looked at (the engine's hook hands over what it has:
+    None and a pool with no page).  → [slots, T, H, R] in q's dtype, with
+    ``paged_attention``'s numerics."""
+    if first_page is None:
+        first_page = jnp.zeros(lengths.shape, jnp.int32)
+    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    return _latent_paged_attention(
+        q, row_new, k_pool, jnp.asarray(layer, jnp.int32), table, lengths,
+        first_page, sm_scale=scale, rank=rank)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "rank"))
+def _latent_paged_attention(q, row_new, k_pool, layer, table, lengths,
+                            first_page, *, sm_scale, rank):
+    slots, t, h, d = q.shape
+    # one KV head: every (t, h) row of the queries is whole, no diagonal
+    q_rows = q.reshape(slots, t * h, d).astype(k_pool.dtype)
+    q_rows = jnp.pad(q_rows, ((0, 0), (0, -(t * h) % 16),
+                              (0, k_pool.shape[-1] - d)))
+    acc, m, l = _paged_partial(q_rows, k_pool, None, layer, table, lengths,
+                               first_page, sm_scale, rank=rank)
+    o = acc[:, :t * h].reshape(slots, t, h, rank)
+    m = m[:, :t * h, 0].reshape(slots, t, h).transpose(0, 2, 1)
+    l = l[:, :t * h, 0].reshape(slots, t, h).transpose(0, 2, 1)
+    k_new = jnp.repeat(row_new, h, axis=2)
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    o, l, _ = blockwise_update(q, k_new, k_new[..., :rank], o, l, m,
+                               mask=causal, sm_scale=sm_scale)
     return finalize_blockwise(o, l).astype(q.dtype)

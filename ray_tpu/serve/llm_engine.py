@@ -193,7 +193,8 @@ def _paged_attend(n_layers, k_pages, v_pages, table, lengths, active,
     the model's own reader of the pool in the kernel's place, with its
     arguments (``_sparse_attend``: a model whose layers choose the rows
     they attend to hands the hook what the choice needs and gets the chosen
-    rows alone, ``ops/dsa.py``)."""
+    rows alone, ``ops/dsa.py``; ``_latent_attend``: a model whose cache is
+    one pool of latent rows gets the kernel's latent form)."""
     import jax.numpy as jnp
 
     from ray_tpu.ops.paged_attention import paged_attention
@@ -210,6 +211,25 @@ def _sparse_attend(model):
     layers select the cached rows they attend to (``models/glm_dsa.py``);
     None for every other model, whose programs stay what they were."""
     return getattr(model, "sparse_paged_attend", None)
+
+
+def _latent_attend(model):
+    """The cache hook of a model whose cache is ONE pool of latent rows
+    (``latent_paged_attend``: ``models/latent_moe.py``), the latent form of
+    the paged kernel; None for every other model, whose pools and programs
+    stay what they were."""
+    return getattr(model, "latent_paged_attend", None)
+
+
+def _write_new(pages, rows, page_idx, off):
+    """``_write_rows`` of the layers' new rows (a list, one a layer).  A
+    model whose cache is one row a token hands no V rows (None), and its V
+    pool, which has no page, is left as it is."""
+    import jax.numpy as jnp
+
+    if rows[0] is None:
+        return pages
+    return _write_rows(pages, jnp.stack(rows), page_idx, off)
 
 
 def _rows_read(sown):
@@ -536,7 +556,12 @@ class LLMEngine:
         shape = (self.num_layers, num_pages, self.page_size,
                  pool_width(self.kv_heads, self.head_dim))
         self._k_pages = jnp.zeros(shape, self.dtype)
-        self._v_pages = jnp.zeros(shape, self.dtype)
+        # A model whose cache is one latent row a token (``[c | rope(k_r)]``,
+        # the values its first columns: ``models/latent_moe.py``) gets ONE
+        # pool: its V pool has no page, and every program hands it through.
+        self._latent = _latent_attend(model) is not None
+        self._v_pages = jnp.zeros(
+            (shape[0], 0) + shape[2:] if self._latent else shape, self.dtype)
         # Where the page pool lives is where the engine decodes.
         self._device = next(iter(self._k_pages.devices()))
 
@@ -573,7 +598,16 @@ class LLMEngine:
         # engine's full prefill is refused: a tail prefill attends to the
         # cached prefix densely, a remote prefill and a draft's verify
         # window know nothing of the selection.
+        if self._latent and handed_over:
+            raise ValueError(
+                f"{handed_over[0]}= cannot serve a model whose cache is one "
+                "pool of latent rows: a cached prefix, a draft's window and "
+                "a remote prefill all hand over pages of K AND V")
         self._sparse = _sparse_attend(model) is not None
+        # a prefill that is told its bucket's real rows and takes the head
+        # at the last of them alone
+        self._ragged = self._sparse or getattr(model, "prefill_lengths",
+                                               False)
         if self._sparse and handed_over:
             raise ValueError(
                 f"{handed_over[0]}= cannot serve a model with learned "
@@ -981,7 +1015,7 @@ class LLMEngine:
             # what one cached token costs the page pool, K and V rows of
             # every layer that writes them, as stored (a row padded to
             # ``pool_width``; a latent row stored in both pools counts
-            # twice)
+            # twice, one in the one pool of a ``latent_cache`` model once)
             "kv_bytes_per_token": (self._k_pages.nbytes + self._v_pages.nbytes)
             // (self._k_pages.shape[1] * self.page_size),
             # slots whose state the decode steps read and wrote, summed
@@ -1123,6 +1157,7 @@ class LLMEngine:
         scope = self._jax.named_scope
         routes = _routes(model)
         sparse = _sparse_attend(model)
+        own_hook = sparse or _latent_attend(model)
         held = getattr(model.config, "experts_held", None)
         if held is not None:  # a share: (first expert, how many)
             held = (model.config.expert_offset, held)
@@ -1147,7 +1182,7 @@ class LLMEngine:
                 out = model.apply(
                     {"params": params}, tokens[:, None], lengths[:, None],
                     _paged_attend(L, k_pages, v_pages, table, lengths,
-                                  active, first, sparse),
+                                  active, first, own_hook),
                     mutable=(["moe", "dsa"] if record_experts and sparse
                              else ["moe"]) if routes else False, **carried)
                 (logits, new_kvs, *state), sown = out if routes else (
@@ -1157,15 +1192,15 @@ class LLMEngine:
                 next_tok, next_logp = sample_tokens_with_logprobs(
                     logits[:, -1], lengths + 1, temps, top_ps, seeds)
             with scope("scatter"):
-                # [L, slots, 1, Hkv, D]
-                newk = jnp.stack([nk[0] for nk in new_kvs])
-                newv = jnp.stack([nk[1] for nk in new_kvs])
+                # a layer's rows: [slots, 1, Hkv, D]
                 slot_ix = jnp.arange(table.shape[0])
                 page_col = jnp.minimum(lengths // ps, pp - 1)
                 page_idx = jnp.where(active, table[slot_ix, page_col], 0)
                 off = lengths % ps
-                k_pages = _write_rows(k_pages, newk, page_idx, off)
-                v_pages = _write_rows(v_pages, newv, page_idx, off)
+                k_pages = _write_new(k_pages, [nk[0] for nk in new_kvs],
+                                     page_idx, off)
+                v_pages = _write_new(v_pages, [nk[1] for nk in new_kvs],
+                                     page_idx, off)
             out = (k_pages, v_pages, next_tok, next_logp,
                    lengths + active.astype(lengths.dtype))
             if routes:
@@ -1268,7 +1303,7 @@ class LLMEngine:
         from ray_tpu.serve.sampling import sample_tokens_with_logprobs
 
         record = self.record_experts
-        sparse = self._sparse
+        ragged = self._ragged
 
         def prefill(params, k_pages, v_pages, row, tokens, p, temp, top_p,
                     seed, slot=None, state=None):
@@ -1279,14 +1314,14 @@ class LLMEngine:
             recurrent state): also the state, ``slot``'s set to what the
             prompt leaves behind (the padding past p advances nothing),
             and the head taken at row p - 1 only (so too for a model whose
-            layers select their rows: its buckets reach 16k rows, and its
-            padding chooses no expert).  Last, where the engine records
+            prefills are told their real rows, ``_ragged``: its buckets
+            reach 16k rows, and its padding chooses no expert).  Last, where the engine records
             them: the bucket's rows' chosen experts."""
             ids = tokens[None]
             positions = jnp.arange(bucket)[None]
             sown = None
             with jax.named_scope("attend"):
-                kw = {} if state is None and not sparse else {
+                kw = {} if state is None and not ragged else {
                     "lengths": jnp.reshape(p, (1,)),
                     "logits_at": jnp.reshape(p - 1, (1,))}
                 out = model.apply(
@@ -1297,7 +1332,7 @@ class LLMEngine:
                     out, sown = out
                 if state is None:
                     logits, new_kvs = out
-                    last = logits[0] if sparse else logits[0, p - 1][None]
+                    last = logits[0] if ragged else logits[0, p - 1][None]
                 else:
                     logits, new_kvs, left = out
                     last = logits[0]
@@ -1314,10 +1349,12 @@ class LLMEngine:
                 t = jnp.arange(bucket)
                 page_idx = jnp.where(t < p, row[t // ps], 0)
                 off = t % ps
-                newk = jnp.stack([nk[0][0] for nk in new_kvs])  # [L,bkt,Hkv,D]
-                newv = jnp.stack([nk[1][0] for nk in new_kvs])
-                k_pages = _write_rows(k_pages, newk, page_idx, off)
-                v_pages = _write_rows(v_pages, newv, page_idx, off)
+                # a layer's rows: [bucket, Hkv, D]
+                k_pages = _write_new(k_pages, [nk[0][0] for nk in new_kvs],
+                                     page_idx, off)
+                v_pages = _write_new(
+                    v_pages, [None if nk[1] is None else nk[1][0]
+                              for nk in new_kvs], page_idx, off)
             out = (k_pages, v_pages, next_tok, next_logp)
             if state is not None:
                 out += (state,)
@@ -2516,6 +2553,13 @@ def _build_model(model_kind: str, config_kw: Optional[dict], seed: int):
         model = GlmDsa(GlmDsaConfig.tiny(**config_kw)
                        if config_kw.pop("tiny", True)
                        else GlmDsaConfig(**config_kw))
+    elif model_kind == "latent_moe":
+        # imported here and nowhere else, as ``ling_linear``
+        from ray_tpu.models.latent_moe import LatentMoE, LatentMoEConfig
+
+        model = LatentMoE(LatentMoEConfig.tiny(**config_kw)
+                          if config_kw.pop("tiny", True)
+                          else LatentMoEConfig(**config_kw))
     else:
         raise ValueError(f"unknown model_kind {model_kind!r}")
     ids = jnp.zeros((1, 8), jnp.int32)
@@ -2583,11 +2627,15 @@ class LLMServer:
     whose indexer selects the ``index_topk`` cached rows a query attends
     to: a decode step scores the slot's cached index keys, which ride the V
     row, and gathers the selected latent rows alone; a leading dense layer;
-    a sigmoid router's experts with the same share).  For
-    ``"falcon_h1"``, ``"nemotron_h"`` and ``"ling_linear"`` the engine holds
-    per-slot recurrent state, and for them and ``"glm_dsa"`` it refuses the
-    four options above (a cached prefix, a draft, a prefix directory,
-    remote prefill).
+    a sigmoid router's experts with the same share) or ``"latent_moe"``
+    (latent attention over the whole cache in every layer under a
+    YaRN-scaled rope; the cache is ONE pool of latent rows, read by the
+    latent form of the paged kernel; a leading dense layer; a sigmoid
+    router's experts with the same share).  For ``"falcon_h1"``,
+    ``"nemotron_h"`` and ``"ling_linear"`` the engine holds per-slot
+    recurrent state, and for them, ``"glm_dsa"`` and ``"latent_moe"`` it
+    refuses the four options above (a cached prefix, a draft, a prefix
+    directory, remote prefill).
     """
 
     def __init__(self, model_kind: str = "gpt2",

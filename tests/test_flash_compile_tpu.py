@@ -7,6 +7,8 @@ and costs no chip time.  Nothing runs, so this gives no result and no time.
 The topology is described inside a fixture, never at import: only the
 worker that is handed this file loads the TPU's library, and every worker
 collects the same tests (on-chip-measurement guide, section 2)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -236,6 +238,69 @@ def test_paged_attention_compiles_for_v5e_over_one_latent_head(
                 if 'custom_call_target="tpu_custom_call"' in line)
     assert "paged_attn" in call
     assert call.split(" = ")[1].startswith("(f32[48,32,640]")
+
+
+@pytest.mark.timeout(120)
+def test_latent_paged_attention_compiles_for_v5e_one_row_a_token(
+        one_chip, monkeypatch):
+    """The latent form of the paged kernel as ``models/latent_moe.py``'s
+    decode step calls it: 32 slots of up to 16,384 rows, 64 query heads on
+    ONE cached row of 576 columns in a pool 640 wide of eight layers, no V
+    pool; its first result is ``f32[32,64,512]`` (the accumulator is
+    ``kv_lora_rank`` wide), which the benchmark's
+    ``mla_paged_attn_roofline`` tells it by, and nothing of the pool's size
+    is written."""
+    from ray_tpu.ops import paged_attention as pa
+    from tools.step_fusions import entry_operations
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    slots, pages = 32, 1024
+    pool = shape(8, slots * pages + 1, 16, pa.pool_width(1, 576))
+    compiled = jax.jit(lambda q, row, kp, table, lengths:
+                       pa.latent_paged_attention(
+                           q, row, None, kp, None, 5, table, lengths,
+                           sm_scale=192 ** -0.5 * 1.3689 ** 2,
+                           rank=512)).lower(
+        shape(slots, 1, 64, 576), shape(slots, 1, 1, 576), pool,
+        shape(slots, pages, dtype=jnp.int32),
+        shape(slots, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert "latent_paged_attn" in call
+    assert call.split(" = ")[1].startswith("(f32[32,64,512]")
+    big = [o["name"] for o in entry_operations(text)
+           if any(s.startswith("bf16[8,32769") for s in o["shapes"])
+           and o["op"] not in ("parameter", "bitcast")]
+    assert not big, big
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.timeout(180)
+def test_flash_forward_compiles_for_v5e_at_16k_rows_of_192(one_chip):
+    """A block of 16 heads of 192 over 16,384 rows, as the latent-attention
+    prefill of the 16,384 bucket calls the forward kernel: K and V whole
+    beside the q tiles are 32 MiB, past the compiler's default of 16 MiB of
+    scoped VMEM, so the call asks for its own limit (``_fwd_call``), as the
+    8,192 bucket's does (16 MiB); at 4,096 rows, the longest such call
+    before PR 56 (Ling's), it asks for nothing and is compiled as it
+    was."""
+    shape = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.bfloat16, sharding=one_chip)
+    for rows, asks in ((16384, True), (8192, True), (4096, False)):
+        text = jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, sm_scale=0.1)).lower(
+            *[shape(1, rows, 16, 192)] * 3).compile().as_text()
+        call = next(line for line in text.splitlines()
+                    if 'custom_call_target="tpu_custom_call"' in line)
+        assert "flash_fwd" in call
+        assert call.split(" = ")[1].startswith(f"bf16[16,{rows},192]")
+        found = re.search(
+            r'"scoped_memory_configs":\[\{[^\]]*?"size":"(\d+)"', call)
+        scoped = int(found.group(1)) if found else 0
+        assert (scoped > 16 * 2 ** 20) == asks, scoped
 
 
 @pytest.mark.timeout(120)
