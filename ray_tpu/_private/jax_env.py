@@ -124,13 +124,17 @@ class FirstCallSpan:
     """A jitted program whose first call runs inside a lifecycle span
     (``engine.compile``, ``train.compile``): trace, lower, compile or
     fetch, and that first run, waited for.  The ``jax.compile`` spans of
-    the call are its children.  Later calls go straight through; every
-    other attribute (``lower``, ``_cache_size``) is the program's own."""
+    the call are its children.  ``attrs``: facts settled when the program
+    was built (which of two paths a shape chose), recorded as the span's
+    arguments.  Later calls go straight through; every other attribute
+    (``lower``, ``_cache_size``) is the program's own."""
 
-    def __init__(self, fn, span_name: str, program: str, before=None):
+    def __init__(self, fn, span_name: str, program: str, before=None,
+                 **attrs):
         self._fn, self._span_name, self._program = fn, span_name, program
         self._before = before  # called with ``program`` as the span opens
         self._first = True
+        self.attrs = attrs
 
     def __call__(self, *args, **kw):
         if not self._first:
@@ -143,7 +147,7 @@ class FirstCallSpan:
         if self._before is not None:
             self._before(self._program)
         with obs.span(self._span_name, _lifecycle=True,
-                      program=self._program):
+                      program=self._program, **self.attrs):
             return jax.block_until_ready(self._fn(*args, **kw))
 
     def __getattr__(self, name):

@@ -10,11 +10,14 @@ the dtype kept) with the kernel ``[8, 8, C, 32]`` reshaped to
 another order.  A caller that keeps many frames and reads them more than
 once (anakin PPO's trajectory) packs them once, as uint8, and hands them in
 packed: the bytes stay bytes until the convolution's operand, and a packed
-frame is a row of whole 128-byte lane tiles, which a gather of samples and
-the transposition in front of the convolution move at twice the pace of
-``[84, 84, 4]`` (PERF.md, PR 55).  The parameter tree is the unpacked
-kernel's: ``Conv_0/kernel`` stays ``[8, 8, C, 32]`` with ``nn.Conv``'s
-initialiser.
+frame is a row of whole 128-byte lane tiles (PERF.md, PR 55).  The chip
+holds a convolution's frames with the batch in the lanes, whatever order
+the program names; packed frames may therefore come with the batch LAST,
+``[H', W', 16 C, B]``, which is what ``ops.gather_rows`` writes when it
+picks a minibatch out of a sample-major trajectory in one pass, and the
+first layer then reads them as they lie, with no gather-then-transpose in
+front (PERF.md, PR 58).  The parameter tree is the unpacked kernel's:
+``Conv_0/kernel`` stays ``[8, 8, C, 32]`` with ``nn.Conv``'s initialiser.
 """
 from __future__ import annotations
 
@@ -70,8 +73,12 @@ class PackedConv(nn.Module):
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, packed: jax.Array) -> jax.Array:
-        c = packed.shape[-1] // (_STRIDE * _STRIDE)
+    def __call__(self, packed: jax.Array,
+                 batch_last: bool = False) -> jax.Array:
+        """packed: ``[B, H', W', 16 C]``, or ``[H', W', 16 C, B]`` with
+        ``batch_last``; ``[B, H' - 1, W' - 1, features]`` either way."""
+        channels = packed.shape[2 if batch_last else 3]
+        c = channels // (_STRIDE * _STRIDE)
         kernel = self.param("kernel", nn.linear.default_kernel_init,
                             (_KERNEL, _KERNEL, c, self.features))
         bias = self.param("bias", nn.initializers.zeros_init(),
@@ -80,10 +87,11 @@ class PackedConv(nn.Module):
         folded = kernel.reshape(_FOLD, _STRIDE, _FOLD, _STRIDE, c,
                                 self.features)
         folded = folded.transpose(0, 2, 1, 3, 4, 5).reshape(
-            _FOLD, _FOLD, packed.shape[-1], self.features)
+            _FOLD, _FOLD, channels, self.features)
         y = jax.lax.conv_general_dilated(
             packed.astype(self.dtype), folded.astype(self.dtype), (1, 1),
-            "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            "VALID", dimension_numbers=(
+                "HWCN" if batch_last else "NHWC", "HWIO", "NHWC"))
         return y + bias.astype(self.dtype)
 
 
@@ -92,10 +100,17 @@ class NatureCNN(nn.Module):
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x: jax.Array, packed: bool = False) -> jax.Array:
+    def __call__(self, x: jax.Array, packed: bool = False,
+                 batch_last: bool = False) -> jax.Array:
         """x: [B, H, W, C] uint8 or float → [B, out_dim]; with ``packed``,
-        x is ``pack_frames`` of such frames (``packed_shape``).  uint8 is
-        scaled by 1/255, float frames pass as they are."""
+        x is ``pack_frames`` of such frames (``packed_shape``), and with
+        ``batch_last`` too those with the batch as their last dimension,
+        ``[H', W', 16 C, B]``: the order in which the chip holds a
+        convolution's frames, so what ``ops.gather_rows`` hands over is
+        read as it lies.  uint8 is scaled by 1/255, float frames pass as
+        they are."""
+        if batch_last and not packed:
+            raise ValueError("batch-last frames are packed frames")
         if not packed:
             x = pack_frames(x)
         if x.dtype == jnp.uint8:
@@ -108,7 +123,8 @@ class NatureCNN(nn.Module):
         else:
             x = x.astype(self.dtype)
         # Named by hand: the tree keeps nn.Conv's automatic names.
-        x = nn.relu(PackedConv(32, dtype=self.dtype, name="Conv_0")(x))
+        x = nn.relu(PackedConv(32, dtype=self.dtype, name="Conv_0")(
+            x, batch_last=batch_last))
         x = nn.relu(nn.Conv(64, (4, 4), strides=(2, 2), dtype=self.dtype,
                             name="Conv_1")(x))
         x = nn.relu(nn.Conv(64, (3, 3), strides=(1, 1), dtype=self.dtype,

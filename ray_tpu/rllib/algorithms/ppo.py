@@ -17,6 +17,7 @@ multi_gpu_train_one_step SGD → sync weights).  The TPU-first redesign:
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
@@ -25,6 +26,7 @@ import numpy as np
 import optax
 
 import ray_tpu
+from ray_tpu.ops import gather_rows as rows_op
 from ray_tpu.rllib.algorithms.algorithm import Algorithm
 from ray_tpu.rllib.algorithms.algorithm_config import AlgorithmConfig
 from ray_tpu.rllib.core.rl_module import RLModuleSpec
@@ -122,14 +124,29 @@ def run_ppo_sgd(params, opt_state, rng, loss_fn, make_mb, total, mb_size,
 def _tile_rows(x):
     """``[n, ...]`` -> ``[n, k, 128]`` where an observation holds a multiple
     of 128 values, ``[n, -1]`` otherwise.  In a sample-major buffer an
-    observation is then a run of whole lane tiles of its own, and a gather
-    of observations copies tiles; a row of 30,976 bytes shares its tiles
-    with its neighbours and is picked out of them byte by byte (2.07 ms
-    against 0.89 for 8,192 packed frames: PERF.md, PR 55)."""
+    observation is then a run of whole lane tiles of its own, and ``v[idx]``
+    copies tiles; a row of 30,976 bytes shares its tiles with its
+    neighbours and is picked out of them byte by byte (PERF.md, PR 55).
+    The form of the trajectory where ``_frames_by_dma`` says no: there the
+    compiler gathers a minibatch's observations and then lays them out for
+    the trunk, two passes."""
     x = x.reshape(x.shape[0], -1)
     if x.shape[1] % 128:
         return x
     return x.reshape(x.shape[0], -1, 128)
+
+
+def _frames_by_dma(seen) -> bool:
+    """Whether a minibatch's observations come through ``ops.gather_rows``
+    (one pass: a DMA a row, written batch-minor as the trunk's first
+    convolution reads them) and not through ``v[idx]``: packed frames, which
+    the module reads batch-last, where an observation fills whole tiles of
+    words (under one it would be mostly padding) and the backend is a TPU.
+    ``seen``: the shape and dtype of observations as the trunk reads them."""
+    return (rows_op.backend() == "tpu" and len(seen.shape) == 4
+            and rows_op.tiles_rows(seen)
+            and math.prod(seen.shape[1:]) * seen.dtype.itemsize
+            >= rows_op.TILE_BYTES)
 
 
 class AnakinState(NamedTuple):
@@ -168,7 +185,8 @@ def make_anakin_ppo(config: AlgorithmConfig):
     device rolls out N/D envs and runs the minibatch scan on its shard,
     with gradients/moments pmean'd across the axis — the only cross-chip
     traffic is the grad all-reduce riding ICI."""
-    from ray_tpu._private.jax_env import ensure_compile_listener
+    from ray_tpu._private.jax_env import (FirstCallSpan,
+                                          ensure_compile_listener)
     from ray_tpu.rllib.utils import mesh as mesh_util
 
     ensure_compile_listener()
@@ -215,38 +233,54 @@ def make_anakin_ppo(config: AlgorithmConfig):
     else:
         init_fn = _init
 
+    # An observation as the trunk reads it (packed, where frames are), and
+    # which way a minibatch of them is gathered: settled here, by shape.
+    seen_as = jax.eval_shape(module.pack_obs, spec.example_obs())
+    by_dma = _frames_by_dma(seen_as)
+    frame, frame_size = seen_as.shape[1:], math.prod(seen_as.shape[1:])
+
     loss_fn = functools.partial(
         ppo_loss, clip_param=config.clip_param,
         vf_clip_param=config.vf_clip_param,
         vf_loss_coeff=config.vf_loss_coeff,
         entropy_coeff=config.entropy_coeff)
 
-    def rollout_step(carry, _):
-        params, env_states, obs, rng, ep_ret, dsum, dcnt = carry
+    def rollout_step(carry, t):
+        params, env_states, obs, rng, ep_ret, dsum, dcnt, frames = carry
         rng, k_act, k_step = jax.random.split(rng, 3)
         # Frames are packed here, once, and the trajectory holds that form:
         # SGD gathers and reads it as it is (models/nature_cnn.py).
         seen = module.pack_obs(obs)
         action, logp, value = module.forward_exploration(params, seen, k_act)
+        if by_dma:
+            # Into the trajectory where it lies, a frame a run of word
+            # tiles; batch-minor is how the chip holds what the trunk read.
+            frames = rows_op.tile_columns(
+                seen.reshape(seen.shape[0], -1).T, into=frames,
+                at=t * seen.shape[0])
         env_states, next_obs, reward, done, _ = vector_step(
             env, env_states, action, k_step)
         ep_ret = ep_ret + reward
         dsum = dsum + jnp.sum(jnp.where(done, ep_ret, 0.0))
         dcnt = dcnt + jnp.sum(done)
         ep_ret = jnp.where(done, 0.0, ep_ret)
-        out = (_tile_rows(seen), action, logp, value, reward, done)
-        return (params, env_states, next_obs, rng, ep_ret, dsum, dcnt), out
+        out = (None if by_dma else _tile_rows(seen), action, logp, value,
+               reward, done)
+        return (params, env_states, next_obs, rng, ep_ret, dsum, dcnt,
+                frames), out
 
     def train_step(state: AnakinState) -> Tuple[AnakinState, Dict[str, jax.Array]]:
         # Inside shard_map every array is the per-device block: N_loc envs,
         # a [1, 2] rng row (unwrapped to this device's key), and the
         # replicated params/opt/counters.
         rng_in = mesh_util.unwrap_rng(state.rng, sharded)
+        frames = rows_op.empty_tiles(batch_loc, frame_size, seen_as.dtype) \
+            if by_dma else None
         carry = (state.params, state.env_states, state.obs, rng_in,
-                 state.ep_return, jnp.zeros(()), jnp.zeros(()))
+                 state.ep_return, jnp.zeros(()), jnp.zeros(()), frames)
         with jax.named_scope("rollout"):
-            carry, traj = jax.lax.scan(rollout_step, carry, None, length=T)
-        params, env_states, obs, rng, ep_ret, dsum_d, dcnt_d = carry
+            carry, traj = jax.lax.scan(rollout_step, carry, jnp.arange(T))
+        params, env_states, obs, rng, ep_ret, dsum_d, dcnt_d, frames = carry
         obs_t, act_t, logp_t, val_t, rew_t, done_t = traj  # [T, N_loc, ...]
 
         dsum = state.done_return_sum + mesh_util.psum_if(dsum_d, sharded)
@@ -259,18 +293,24 @@ def make_anakin_ppo(config: AlgorithmConfig):
             adv = mesh_util.normalize_global(adv, sharded)
 
         flat = {
-            "obs": obs_t.reshape(batch_loc, *obs_t.shape[2:]),
+            "obs": frames if by_dma
+            else obs_t.reshape(batch_loc, *obs_t.shape[2:]),
             "actions": act_t.reshape(batch_loc),
             "action_logp": logp_t.reshape(batch_loc),
             "advantages": adv.reshape(batch_loc),
             "value_targets": vtarg.reshape(batch_loc),
         }
 
-        seen_shape = jax.eval_shape(module.pack_obs, state.obs).shape[1:]
-
         def make_mb(idx):
-            mb = {k_: v[idx] for k_, v in flat.items()}
-            mb["obs"] = mb["obs"].reshape(-1, *seen_shape)
+            mb = {k_: v[idx] for k_, v in flat.items() if k_ != "obs"}
+            if by_dma:
+                # [*frame, mb]: the batch last, as the chip holds the frames
+                # of a convolution; the module reads them so
+                mb["obs"] = rows_op.gather_rows(
+                    flat["obs"], idx, width=frame_size,
+                    dtype=seen_as.dtype).reshape(*frame, -1)
+            else:
+                mb["obs"] = flat["obs"][idx].reshape(-1, *frame)
             return mb
 
         with jax.named_scope("sgd"):
@@ -302,6 +342,10 @@ def make_anakin_ppo(config: AlgorithmConfig):
         step = mesh_util.shard_train_step(train_step, mesh, state_specs)
     else:
         step = jax.jit(train_step)
+    # The first call inside a lifecycle span that says which way the frames
+    # of a minibatch go: the run's record of a choice made by shape.
+    step = FirstCallSpan(step, "train.compile", "anakin_ppo",
+                         frame_gather="rows_dma" if by_dma else "xla")
     return module, init_fn, step, batch_total
 
 

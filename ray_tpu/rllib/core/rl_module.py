@@ -63,10 +63,11 @@ class DiscreteActorCritic(nn.Module):
     """Categorical policy + value baseline (separate heads, shared trunk for
     pixels, separate trunks for vectors — matching RLlib PPO defaults).
 
-    Frames of 32 pixels or more may come raw (``spec.obs_shape``) or packed
-    (``spec.packed_obs_shape``, what ``pack_obs`` returns): the static shape
-    tells which, and raw frames are packed first, so both run the same
-    convolution."""
+    Frames of 32 pixels or more may come raw (``spec.obs_shape``), packed
+    (``spec.packed_obs_shape``, what ``pack_obs`` returns) or packed with
+    the batch last (``[*spec.packed_obs_shape, B]``, what a minibatch
+    gathered by ``ops.gather_rows`` is): the static shape tells which, and
+    raw frames are packed first, so all run the same convolution."""
 
     spec: RLModuleSpec
 
@@ -88,13 +89,17 @@ class DiscreteActorCritic(nn.Module):
                 trunk = MinAtarCNN(out_dim=128)(obs)
             else:
                 frame = tuple(obs.shape[1:])
-                packed = frame == s.packed_obs_shape
+                batch_last = (
+                    frame not in (s.packed_obs_shape, tuple(s.obs_shape))
+                    and tuple(obs.shape[:-1]) == s.packed_obs_shape)
+                packed = batch_last or frame == s.packed_obs_shape
                 if not packed and frame != tuple(s.obs_shape):
                     raise ValueError(
-                        f"frames of shape {frame}: neither "
-                        f"{tuple(s.obs_shape)} nor packed "
-                        f"{s.packed_obs_shape}")
-                trunk = NatureCNN(out_dim=256)(obs, packed=packed)
+                        f"frames of shape {tuple(obs.shape)}: neither "
+                        f"[B, ...] of {tuple(s.obs_shape)} or of packed "
+                        f"{s.packed_obs_shape}, nor packed with B last")
+                trunk = NatureCNN(out_dim=256)(obs, packed=packed,
+                                               batch_last=batch_last)
             logits = nn.Dense(s.num_actions, name="pi")(trunk)
             value = nn.Dense(1, name="vf")(trunk)[..., 0]
         else:

@@ -443,14 +443,18 @@ def test_sparse_decode_compiles_for_v5e_and_copies_no_pool(one_chip,
 def test_anakin_ppo_step_keeps_its_frames_bytes_for_v5e(one_chip):
     """``ppo_atari84_anakin``'s whole step at the cell's sizes (2,048 envs
     x 64 steps, minibatches of 8,192), built as the benchmark's driver
-    builds it (``tools/step_fusions.py``; ~35 s alone).  The trajectory and
-    a minibatch hold packed frames, uint8, an observation a run of 128-byte
-    rows; nothing frame-sized is ever written in two bytes or four (the
-    parent wrote ``bf16[8192,84,84,4]`` twice a minibatch, a conversion and
-    a relayout), and a minibatch's frames are moved twice, as bytes: the
-    gather, and the one transposition that puts the batch in the lanes,
-    where the compiler wants it for every convolution of the trunk.  Nothing
-    chooses at run time, so this is the mechanism's witness."""
+    builds it (``tools/step_fusions.py``; ~35 s alone).  The trajectory
+    holds packed frames as word tiles, four bytes a word, a frame 64 rows of
+    128 words, written a rollout step at a time by ``tile_columns`` where
+    they lie (the kernel's result is the buffer: no copy of the trajectory
+    anywhere in the step, nor a pass to clear it); nothing
+    frame-sized is ever written in two bytes or four a value (PR 55's
+    parent wrote ``bf16[8192,84,84,4]`` twice a minibatch), and a
+    minibatch's frames are moved ONCE, as bytes: the kernel ``gather_rows``
+    writes them batch-minor, and both of ``Conv_0``'s fusions read that
+    through a bitcast (until PR 58 a gather and a transposition,
+    ``fusion`` + ``copy u8[8192,242,128]``).  Nothing chooses at run time,
+    so this is the mechanism's witness."""
     import math
     import re
 
@@ -460,14 +464,23 @@ def test_anakin_ppo_step_keeps_its_frames_bytes_for_v5e(one_chip):
                         sorted(one_chip.device_set, key=lambda d: d.id)
                         ).as_text()
     assert "bf16[8192,84,84,4]" not in text
-    assert "u8[64,2048,242,128]" in text       # the trajectory, packed
-    assert "u8[64,2048,84,84,4]" not in text   # ... and not raw beside it
-    moved = []
+    assert "u32[131072,64,128]" in text        # the trajectory, word tiles
+    assert not re.search(r"= u32\[(131072,64|8388608),128\]\S* (copy|fusion|"
+                         r"broadcast|dynamic-update-slice)\(", text)
+    assert "u8[64,2048,242,128]" not in text   # ... and no bytes beside it
+    assert "u8[64,2048,84,84,4]" not in text   # ... nor raw frames
+    moved, kernels = [], []
     for o in entry_operations(text):
+        if o["op"] == "custom-call" and "tpu_custom_call" in o["key"]:
+            kernels.append((o["times"], o["shapes"][0]))
         if o["times"] != 32 or o["op"] in ("get-tuple-element", "bitcast",
                                            "parameter", "tuple", "while"):
             continue  # 2 epochs x 16 minibatches: the inner loop's body
         kind, dims = re.fullmatch(r"(\w+)\[([\d,]*)\]", o["shapes"][0]).groups()
         if math.prod(int(d) for d in dims.split(",") if d) >= 8192 * 22 * 22 * 64:
             moved.append((o["op"], kind))
-    assert moved == [("fusion", "u8"), ("copy", "u8")]
+    assert moved == [("custom-call", "u8")]
+    # the buffer, a rollout step's tile_columns, a minibatch's gather_rows
+    assert sorted(kernels) == [(1, "u32[131072,64,128]"),
+                               (32, "u8[30976,8192]"),
+                               (64, "u32[8388608,128]")]

@@ -179,6 +179,103 @@ def test_anakin_ppo_keeps_its_trajectory_packed():
 
     _module, init, step, total = make_anakin_ppo(config)
     assert total == 16
+    assert step.attrs == {"frame_gather": "xla"}  # no TPU here
     text = str(jax.make_jaxpr(step)(jax.eval_shape(init, 3)))
     assert "u8[4,4,242,128]" in text      # [T, N, 22 * 22 * 64 / 128, 128]
     assert "u8[4,4,84,84,4]" not in text  # and no raw trajectory beside it
+
+
+def _tiny_anakin(env, seed=3):
+    from ray_tpu.rllib import PPOConfig
+
+    return (PPOConfig().environment(env)
+            .anakin(num_envs=8, unroll_length=4)
+            .training(num_sgd_iter=2, sgd_minibatch_size=16)
+            .debugging(seed=seed))
+
+
+@pytest.mark.timeout(300)
+def test_anakin_ppo_gathers_frames_by_row_dma_to_the_same_losses(monkeypatch):
+    """Two iterations on a small Breakout84 with the minibatch's frames
+    through ``ops.gather_rows`` (interpreted here; what a TPU backend
+    chooses) against ``v[idx]`` on the same seed: the same samples, the
+    same products, so the same losses; the trajectory held as word tiles,
+    the minibatch batch-last, and the first call's span says which way."""
+    from ray_tpu import observability
+    from ray_tpu.ops import gather_rows as rows_op
+    from ray_tpu.rllib.algorithms import ppo
+
+    plain = _tiny_anakin("Breakout-Atari84-v0").build()
+    assert plain._train_step.attrs == {"frame_gather": "xla"}
+    want = [plain.train() for _ in range(2)]
+
+    monkeypatch.setattr(rows_op, "backend", lambda: "tpu")
+    assert ppo._frames_by_dma(jax.ShapeDtypeStruct((1, 22, 22, 64),
+                                                   jnp.uint8))
+    # ... by shape: a board under a tile of words, rows off the lanes, floats
+    assert not ppo._frames_by_dma(jax.ShapeDtypeStruct((1, 4, 4, 64),
+                                                       jnp.uint8))
+    assert not ppo._frames_by_dma(jax.ShapeDtypeStruct((1, 10, 10, 4),
+                                                       jnp.uint8))
+    assert not ppo._frames_by_dma(jax.ShapeDtypeStruct((1, 4), jnp.float32))
+    monkeypatch.undo()
+
+    # the shape rule as on a TPU, the kernels interpreted as on a CPU
+    monkeypatch.setattr(ppo, "_frames_by_dma", lambda seen: True)
+    config = _tiny_anakin("Breakout-Atari84-v0")
+    by_dma = config.build()
+    assert by_dma._train_step.attrs == {"frame_gather": "rows_dma"}
+    got = [by_dma.train() for _ in range(2)]
+    for g, w in zip(got, want):
+        for k in ("total_loss", "policy_loss", "vf_loss", "entropy"):
+            assert g[k] == pytest.approx(w[k], rel=1e-5, abs=1e-7), k
+    spans = [s for s in observability.session_spans()
+             if s["name"] == "train.compile"
+             and s.get("args", {}).get("program") == "anakin_ppo"]
+    assert [s["args"]["frame_gather"] for s in spans[-2:]] == ["xla",
+                                                               "rows_dma"]
+
+    _module, init, step, _total = ppo.make_anakin_ppo(config)
+    text = str(jax.make_jaxpr(step)(jax.eval_shape(init, 3)))
+    assert "u32[32,64,128]" in text        # [T * N, 8 tiles of words]
+    assert "u8[4,8,242,128]" not in text   # and no byte trajectory beside
+    assert "u8[22,22,64,16]" in text       # a minibatch, the batch last
+    assert "name=gather_rows" in text and "name=tile_columns" in text
+
+
+@pytest.mark.timeout(300)
+def test_anakin_ppo_row_dma_runs_under_the_data_mesh(monkeypatch):
+    """``num_devices=2``: inside ``shard_map`` the kernels see a device's
+    own envs and its own half of a minibatch; the same losses as ``v[idx]``
+    there."""
+    from ray_tpu.rllib.algorithms import ppo
+
+    def one_iteration():
+        config = _tiny_anakin("Breakout-Atari84-v0").resources(num_devices=2)
+        algo = config.build()
+        return algo._train_step.attrs["frame_gather"], algo.train()
+
+    way, want = one_iteration()
+    assert way == "xla"
+    monkeypatch.setattr(ppo, "_frames_by_dma", lambda seen: True)
+    way, got = one_iteration()
+    assert way == "rows_dma"
+    for k in ("total_loss", "policy_loss", "vf_loss", "entropy"):
+        assert got[k] == pytest.approx(want[k], rel=1e-5, abs=1e-7), k
+
+
+@pytest.mark.timeout(240)
+def test_anakin_ppo_on_a_vector_env_keeps_the_plain_gather(monkeypatch):
+    """CartPole's four floats are no run of lane tiles: ``v[idx]`` whatever
+    the backend."""
+    from ray_tpu.ops import gather_rows as rows_op
+    from ray_tpu.rllib.algorithms.ppo import make_anakin_ppo
+
+    monkeypatch.setattr(rows_op, "backend", lambda: "tpu")
+    config = _tiny_anakin("CartPole-v1")
+    _module, init, step, _total = make_anakin_ppo(config)
+    assert step.attrs == {"frame_gather": "xla"}
+    text = str(jax.make_jaxpr(step)(jax.eval_shape(init, 3)))
+    assert "gather_rows" not in text and "tile_columns" not in text
+    algo = config.build()
+    assert np.isfinite(algo.train()["total_loss"])

@@ -15,7 +15,12 @@ A line a group: how many a step, the compiler's cost model for all of them
 (``estimated_cycles`` at 1.5 GHz: an estimate and never a measurement; 0-35%
 above what traced runs read on most operations, several times above on a
 few, and on the PPO cell's byte copies four times off either way, a
-transposition too high and a gather too low: PERF.md section 5),
+transposition too high and a gather too low: PERF.md section 5; a Pallas
+kernel, ``tpu_custom_call``, has no estimate at all, so since PR 58 the PPO
+cell's frame path, ``gather_rows`` a minibatch and ``tile_columns`` a
+rollout step, shows only under ``--shape 30976,8192`` / ``--shape
+131072,128``, and the anakin step is built as on a TPU backend, which is
+what chooses those kernels),
 whether a matmul (``convolution``) is fused inside, and the ``op_name`` of
 the first.  ``--shape`` lists instead, in schedule order, every operation
 with that shape among its results.  Nothing runs, so this gives no time.
@@ -84,6 +89,7 @@ def _compile_anakin_step(config, devices):
     from jax.sharding import Mesh
 
     from benchmark.drivers import rl_anakin
+    from ray_tpu.ops import gather_rows
     from ray_tpu.rllib.algorithms.algorithm_config import AlgorithmConfig
     from ray_tpu.rllib.algorithms.ppo import make_anakin_ppo
     from ray_tpu.rllib.utils import mesh as mesh_util
@@ -91,11 +97,13 @@ def _compile_anakin_step(config, devices):
     def described_mesh(n):
         return Mesh(np.array(devices[:n]), (mesh_util.DATA_AXIS,))
 
+    # jax.default_backend() is the CPU here: name the path the chip takes.
     with mock.patch.object(AlgorithmConfig, "build", lambda self: self), \
-            mock.patch.object(mesh_util, "data_mesh", described_mesh):
+            mock.patch.object(mesh_util, "data_mesh", described_mesh), \
+            mock.patch.object(gather_rows, "backend", lambda: "tpu"):
         algo_config = rl_anakin.build_algo(config, len(devices), 0)
         _module, init, step, _total = make_anakin_ppo(algo_config)
-    return step.lower(jax.eval_shape(init, 0)).compile()
+        return step.lower(jax.eval_shape(init, 0)).compile()
 
 
 def _compile_train_step(config, traffic, devices):
