@@ -190,9 +190,10 @@ def main():
 
     # Tracing plane: direct-path tasks reply to their caller, bypassing
     # the head — a periodic flusher ships their spans on the node-stats
-    # cadence so they still assemble (execute_task also flushes at task
-    # start/end; this catches spans between tasks and long-running ones).
-    # An empty ring costs it one length check a period.
+    # cadence so they still assemble (a task's end flushes when
+    # obs.flush_due() says so; this catches spans between tasks and
+    # long-running ones).  An empty ring costs it one length check a
+    # period.
     from ray_tpu import observability as obs
 
     def span_flusher():
@@ -388,6 +389,7 @@ def main():
                 flush_done_buf()
 
     try:
+        obs.flush(transport)  # what the ring still holds leaves with us
         conn.close()
     except Exception:
         pass

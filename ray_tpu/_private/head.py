@@ -131,6 +131,7 @@ class Head:
         from ray_tpu import observability as _obs
 
         _obs.set_session_store(self.trace_store)
+        self._worker_readers: set = set()  # _conn_loop threads of workers
         self._event_log: deque = deque(maxlen=512)
         # ---- multi-host plane ----
         # Host identity: object resolutions are host-aware — same host means
@@ -676,6 +677,7 @@ class Head:
                     self.gcs.touch_node(agent_node)
                 if mtype == "register":
                     worker_id = WorkerID(msg["worker_id"])
+                    self._worker_readers.add(threading.current_thread())
                     self._on_register(worker_id, NodeID(msg["node_id"]), conn,
                                       msg.get("direct_addr"))
                 elif mtype == "register_node":
@@ -790,6 +792,7 @@ class Head:
                 if self._driver_conns.get(driver_wid) is conn:
                     self.on_driver_disconnected(driver_wid)
             elif worker_id is not None:
+                self._worker_readers.discard(threading.current_thread())
                 if self._conns.get(worker_id) is conn:
                     self.on_conn_closed(worker_id)
 
@@ -1019,10 +1022,10 @@ class Head:
         return path
 
     def req_span_batch(self, payload, reply, caller):
-        """Span flush from a worker/driver: ingest into the TraceStore."""
-        spans = payload.get("spans") or []
-        if spans:
-            self.trace_store.ingest(spans)
+        """Span flush from a worker/driver: ingest into the TraceStore,
+        with what the sender's ring lost since its last batch."""
+        self.trace_store.ingest(payload.get("spans") or [],
+                                dropped=int(payload.get("dropped") or 0))
         reply(True)
 
     def req_flight_record(self, payload, reply, caller):
@@ -2981,6 +2984,13 @@ class Head:
             for srv in self._local_xfer.values():
                 srv.shutdown()
             self._local_xfer.clear()
+        # The workers are gone and their sockets at their end: what they
+        # sent as they left (a last span batch) is read before this
+        # returns, so session_spans() after shutdown() has it.
+        deadline = time.monotonic() + 1.0
+        for t in list(self._worker_readers):
+            if t is not threading.current_thread():
+                t.join(max(0.0, deadline - time.monotonic()))
         for listener in (self._listener, self._tcp_listener):
             try:
                 listener.close()

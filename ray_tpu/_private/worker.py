@@ -2208,9 +2208,10 @@ class CoreWorker:
             if spec.task_type != TaskType.ACTOR_CREATION:
                 self.namespace, self.default_runtime_env = saved_job_defaults
             self.ctx.task_id = None
-            if self.mode == "worker":
-                # Goes by what the ring holds: spans recorded because a
-                # profile ran leave the worker too.
+            if self.mode == "worker" and _obs().flush_due():
+                # On a cadence, not once a task: a replica under a
+                # profile answers a thousand calls a second, and a batch
+                # a call is sent from the interpreter it measures.
                 _obs().flush(self.transport)
             if tracing_on:
                 _obs().set_context(saved_trace_ctx)

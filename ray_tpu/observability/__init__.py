@@ -25,11 +25,14 @@ OpenTelemetry-style context propagation.  Four pieces live here:
    :class:`SpanRing` collects every completed span; off, :func:`span`
    is one shared no-op.
 3. **Flush path** — ``flush(transport)`` drains the ring into a
-   ``span_batch`` one-way request to the head; workers flush at task
-   start/end and on the node-stats cadence, node agents relay their
-   ring inside ``node_stats`` frames, the head drains its own ring
-   in-process.  The head keeps batches in a byte-budgeted TraceStore,
-   which :func:`session_spans` still reads after ``ray_tpu.shutdown()``.
+   ``span_batch`` one-way request to the head.  A worker sends one on a
+   cadence (:func:`flush_due`: a task's end asks, and sends when the
+   ring is half full, holds a lifecycle span, or has waited
+   ``FLUSH_PERIOD_S``), on the node-stats period, before a task under
+   the flag, and as it leaves; node agents relay their ring inside
+   ``node_stats`` frames, the head drains its own ring in-process.  The
+   head keeps batches in a byte-budgeted TraceStore, which
+   :func:`session_spans` still reads after ``ray_tpu.shutdown()``.
 4. **Flight recorder** — the same rings double as the crash black box:
    see :mod:`ray_tpu.observability.flight_recorder`.
 
@@ -41,6 +44,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import random
 import sys
 import threading
 import time
@@ -87,8 +91,25 @@ def on() -> bool:
     return enabled() or _profile_annotation() is not None
 
 
+# Span and trace ids come from a generator of this process's own, seeded
+# once from the system's: ``os.urandom`` lets go of the interpreter around
+# its system call, and a thread that does so at every span it opens waits
+# for the interpreter again behind whoever else wanted it (in a serve
+# replica, the threads answering a thousand calls a second): the recorder
+# then slows the thread it measures.
+_ids = random.Random()  # seeds itself from os.urandom
+
+
+def _reseed_ids() -> None:
+    _ids.seed()
+
+
+if hasattr(os, "register_at_fork"):  # a forked child draws its own ids
+    os.register_at_fork(after_in_child=_reseed_ids)
+
+
 def new_id() -> str:
-    return os.urandom(8).hex()
+    return "%016x" % _ids.getrandbits(64)
 
 
 # ---------------------------------------------------------------------------
@@ -213,8 +234,11 @@ def ring() -> SpanRing:
 # ---------------------------------------------------------------------------
 # recording
 # ---------------------------------------------------------------------------
-def _append(name, start, end, ctx, parent_id, span_id, args) -> str:
-    """One completed span (wall-clock seconds) into the process ring."""
+def _append(name, start, end, ctx, parent_id, span_id, args,
+            lifecycle: bool = False) -> str:
+    """One completed span (wall-clock seconds) into the process ring.
+    ``lifecycle``: the next task's end sends it (:func:`flush_due`)."""
+    global _lifecycle_held
     trace_id = ctx[0] if ctx else None
     if parent_id is None and ctx is not None:
         parent_id = ctx[1]
@@ -228,6 +252,8 @@ def _append(name, start, end, ctx, parent_id, span_id, args) -> str:
         "proc": proc, "node": node, "os_pid": os.getpid(),
         "args": args,
     })
+    if lifecycle:
+        _lifecycle_held = True
     return sid
 
 
@@ -254,7 +280,7 @@ def record(name: str, start: float, end: float,
     if ctx is None and parent_id is None:
         parent_id = getattr(_tl, "open", None)
     return _append(name, start + _WALL_OFFSET, end + _WALL_OFFSET, ctx,
-                   parent_id, span_id, args)
+                   parent_id, span_id, args, _lifecycle)
 
 
 def record_instant(name: str, **args) -> Optional[str]:
@@ -288,6 +314,7 @@ class _Span:
     """An open span.  While open it is the parent of the thread's next
     span and, inside a trace, of anything submitted from the thread."""
     __slots__ = ("name", "args", "span_id", "_ctx", "_ann", "_saved", "_t0")
+    _lifecycle = False  # the class's, not a field: see _LifecycleSpan
 
     def __init__(self, name, args, ctx, ann):
         self.name, self.args, self._ctx = name, args, ctx
@@ -327,8 +354,14 @@ class _Span:
         trace_id, parent_id = self._ctx
         _append(self.name, start, start + dur * 1e-9,
                 (trace_id, parent_id) if trace_id is not None else None,
-                parent_id, self.span_id, self.args)
+                parent_id, self.span_id, self.args, self._lifecycle)
         return False
+
+
+class _LifecycleSpan(_Span):
+    """A span of a process's set-up: the next task's end sends it."""
+    __slots__ = ()
+    _lifecycle = True
 
 
 def span(name: str, _ctx: Optional[TraceContext] = None,
@@ -343,7 +376,7 @@ def span(name: str, _ctx: Optional[TraceContext] = None,
     ann = _profile_annotation()
     if ann is None and not _lifecycle and not enabled():
         return NO_SPAN
-    return _Span(name, args, _ctx, ann)
+    return (_LifecycleSpan if _lifecycle else _Span)(name, args, _ctx, ann)
 
 
 # ---------------------------------------------------------------------------
@@ -380,21 +413,73 @@ def _export_dropped(r: SpanRing) -> None:
         pass
 
 
+# When a task's end sends the ring (docs/OBSERVABILITY.md, "Buffering and
+# flush", has the reckoning): at half of the ring's places, so that twice
+# the busiest cell's spans a second still fit between two looks; and no
+# later than this after the last batch, which is also what a reader of a
+# live session waits for a span at most.
+FLUSH_RING_SHARE = 0.5
+FLUSH_PERIOD_S = 0.5
+
+_lifecycle_held = False  # the ring holds a lifecycle span not yet sent
+_last_flush = 0.0        # time.monotonic() of the last batch that left
+_dropped_sent = 0        # of the ring's dropped_total, what batches told
+_flush_lock = threading.Lock()  # one sender at a time: batches in order
+
+
+def flush_due() -> bool:
+    """Whether a task's end should send the ring now: it holds a
+    lifecycle span (set-up is read promptly, and is a few dozen spans a
+    process), it is at least half full, or the last batch left more than
+    ``FLUSH_PERIOD_S`` ago.  An empty ring costs one length check, as
+    before; a ring that a profile keeps filling leaves in batches of
+    hundreds, not once a call this process answers."""
+    r = _ring
+    if r is None:
+        return False
+    held = len(r)
+    return held > 0 and (
+        _lifecycle_held or held >= r.capacity * FLUSH_RING_SHARE
+        or time.monotonic() - _last_flush > FLUSH_PERIOD_S)
+
+
 def flush(transport) -> int:
     """Drain the ring and ship the batch to the head as a one-way
     ``span_batch`` request; returns how many spans went.  Goes by what
     the ring holds, not by a flag: spans recorded because a profile ran
-    leave the worker too."""
-    spans = drain_spans() if _ring is not None and len(_ring) else None
-    if not spans:
+    leave the worker too.  The batch says how many spans the ring pushed
+    out since the last one, so the head's count of lost spans
+    (:func:`session_spans_dropped`) has this process's too."""
+    global _lifecycle_held, _last_flush, _dropped_sent
+    if _ring is None or not len(_ring):
         return 0
-    try:
-        transport.request_oneway("span_batch", {"spans": spans})
-    except Exception:
-        # Head restarting / conn mid-replace: spans are droppable
-        # telemetry, never worth failing the caller for.
+    with _flush_lock:
+        _lifecycle_held = False  # before the drain: one set after it stays
+        spans = drain_spans()
+        if not spans:
+            return 0
+        dropped = _ring.dropped_total - _dropped_sent
+        try:
+            transport.request_oneway("span_batch",
+                                     {"spans": spans, "dropped": dropped})
+        except Exception:
+            # Head restarting / conn mid-replace: spans are droppable
+            # telemetry, never worth failing the caller for.
+            return 0
+        _dropped_sent += dropped
+        _last_flush = time.monotonic()
+        return len(spans)
+
+
+def flush_worker() -> int:
+    """Send whatever this worker's ring holds, now: an actor's tear-down
+    calls it (a serve replica's ``drain``: the kill behind it is abrupt).
+    0 where this process is no worker."""
+    from ray_tpu._private.worker import global_worker as w
+
+    if getattr(w, "mode", None) != "worker":  # None, a driver, local mode
         return 0
-    return len(spans)
+    return flush(w.transport)
 
 
 # The TraceStore of this process's newest head, put here by Head.__init__
@@ -421,9 +506,10 @@ def session_spans(name: Optional[str] = None) -> List[Dict[str, Any]]:
 
 def session_spans_dropped() -> int:
     """How many spans the session :func:`session_spans` reads from lost
-    before they could be read: what its head's store refused and what
-    this process's ring pushed out.  A reader that adds spans up (the
-    phases of set-up) has holes where this is not 0."""
+    before they could be read: what its head's store refused, what its
+    workers' rings pushed out between two batches (each batch says) and
+    what this process's ring pushed out.  A reader that adds spans up
+    (the phases of set-up) has holes where this is not 0."""
     lost = _session_store.spans_dropped if _session_store is not None else 0
     return lost + (_ring.dropped_total if _ring is not None else 0)
 

@@ -53,10 +53,14 @@ class TraceStore:
         self.spans_dropped = 0
         self.traces_evicted = 0
 
-    def ingest(self, spans: List[Dict[str, Any]]) -> None:
-        if not spans:
+    def ingest(self, spans: List[Dict[str, Any]], dropped: int = 0) -> None:
+        """``dropped``: spans the sender lost before this batch (a ring
+        that overflowed between two flushes); counted with the store's
+        own refusals."""
+        if not spans and not dropped:
             return
         with self._lock:
+            self.spans_dropped += dropped
             for span in spans:
                 tid = span.get("trace_id") or UNTRACED
                 tr = self._traces.get(tid)

@@ -52,6 +52,9 @@ class _Replica:
             except Exception:
                 pass
         batching.close_instance_batchers(self._callable)
+        # The kill that follows is abrupt: what the ring holds (the last
+        # iterations of a profiled engine) leaves now or never.
+        obs.flush_worker()
         return True
 
     def reconfigure(self, user_config):

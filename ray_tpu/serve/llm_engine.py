@@ -131,6 +131,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import itertools
 import logging
 import math
 import queue
@@ -786,6 +787,16 @@ class LLMEngine:
         self._work_s = 0.0
         self._step_stamps: collections.deque = collections.deque(
             maxlen=1024)
+        # Handle calls this process answered (note_reply_call): the load
+        # the loop thread shares its interpreter with.  next() hands each
+        # call its number without a lock; the store after it may land
+        # behind another thread's, so a reader can see the count a call
+        # or two short for the length of a call, and nothing is lost.
+        self._reply_seq = itertools.count(1)
+        self._reply_calls = 0
+        self._reply_calls_seen = 0  # at the last iteration's close
+        # slot-state arrays sent to the device again, and their bytes
+        self._uploads = self._upload_bytes = 0
         self._metrics = None
         self._metrics_flush = 0.0
         self._stage = None
@@ -972,6 +983,11 @@ class LLMEngine:
         if req.error is not None:
             raise req.error
 
+    def note_reply_call(self):
+        """One handle call that this process answers (``LLMServer``'s
+        ``next_chunk``, ``submit_stream``, ``request_stats``)."""
+        self._reply_calls = next(self._reply_seq)
+
     def request_stats(self, rid: int) -> Dict[str, Any]:
         """Per-request accounting (speculative acceptance metrics)."""
         req = self._requests[rid]
@@ -1004,6 +1020,9 @@ class LLMEngine:
             "drained_steps": s.get("drained_steps", 0),
             "late_eos_rows": s.get("late_eos_rows", 0),
             "tokens_generated": s.get("tokens", 0),
+            # handle calls answered (next_chunk, submit_stream,
+            # request_stats): over tokens_generated, calls a token
+            "reply_calls": self._reply_calls,
             "avg_batch_occupancy": (self._occupancy_sum / steps
                                     if steps else 0.0),
             "pages_in_use": pool["in_use"],
@@ -1502,11 +1521,18 @@ class LLMEngine:
         host does between two device programs: ``engine.idle`` while
         there is nothing to do, then ``engine.iteration`` around
         ``engine.swap``, ``engine.admit`` (with one ``engine.prefill``
-        per local prefill), ``engine.decode.dispatch``,
+        per local prefill), ``engine.grow``, ``engine.decode.prepare``,
+        ``engine.decode.dispatch`` (its parts ``engine.decode.stage``,
+        ``.call`` and ``.readback``), ``engine.decode.settle``,
         ``engine.decode.fetch``, ``engine.emit`` and
         ``engine.metrics_flush``.  In the plain loop the dispatch is
         step n+1's and the fetch and emit are step n's
-        (``_decode_once``)."""
+        (``_decode_once``).  As it closes, the iteration's span is told
+        the thread's own CPU time over it (``cpu_ms``: the rest of its
+        length the thread waited, for the device in
+        ``engine.decode.fetch`` and for the interpreter anywhere) and the
+        handle calls this process answered since the last one closed
+        (``reply_calls``)."""
         with self._cond:
             if self._nothing_to_do():
                 with obs.span("engine.idle"):
@@ -1518,7 +1544,9 @@ class LLMEngine:
             if self._closed:
                 return
         with obs.span("engine.iteration", active=len(self._slot_req),
-                      pending=len(self._pending)):
+                      pending=len(self._pending)) as it:
+            recording = it is not obs.NO_SPAN
+            cpu0 = time.thread_time() if recording else 0.0
             t_work0 = time.perf_counter()
             try:
                 # token boundary: between decode steps
@@ -1534,7 +1562,8 @@ class LLMEngine:
                         self._poll_prefill()
                         self._admit()
                         sp.set(admitted=self._stats["admitted"] - before)
-                self._grow()
+                with obs.span("engine.grow") as sp:
+                    sp.set(pages=self._grow())
                 if self._spec:
                     if self._active.any():
                         self._decode_once_spec()
@@ -1546,6 +1575,11 @@ class LLMEngine:
                 return
             self._work_s += time.perf_counter() - t_work0
             self._flush_metrics()
+            calls = self._reply_calls
+            if recording:
+                it.set(cpu_ms=(time.thread_time() - cpu0) * 1e3,
+                       reply_calls=calls - self._reply_calls_seen)
+            self._reply_calls_seen = calls
 
     # ------------------------------------------------------------------
     # hot weight swap (loop thread only)
@@ -2042,8 +2076,10 @@ class LLMEngine:
         The horizon is one token past ``_lengths`` (which the plain loop
         advances as it dispatches, so this looks one step further than
         the tokens the host has seen), or ``spec_tokens`` positions in
-        spec mode (the verify step scatters the whole window)."""
+        spec mode (the verify step scatters the whole window).  Returns
+        the pages it gave out."""
         horizon = self.spec_tokens if self._spec else 1
+        given = 0
         for slot in range(self.max_slots):
             while self._active[slot] and self._budget[slot] > 0:
                 pos = int(self._lengths[slot])
@@ -2055,6 +2091,7 @@ class LLMEngine:
                 if got is not None:
                     self._table[slot, len(self._slot_pages[slot])] = got[0]
                     self._slot_pages[slot].append(got[0])
+                    given += 1
                     continue
                 if self._inflight is not None:
                     # A dry pool: be in step with the device before any
@@ -2072,6 +2109,7 @@ class LLMEngine:
                         f"preempted"))
                     break
                 self._preempt(victim)
+        return given
 
     def _pick_victim(self, exclude: int) -> Optional[int]:
         best, best_seq = None, -1
@@ -2122,9 +2160,8 @@ class LLMEngine:
     def _drain(self):
         """Be in step with the device: read and emit the step in flight,
         if any.  Before a weight swap, and before a dry pool preempts."""
-        done, self._inflight = self._inflight, None
-        if done is not None:
-            self._collect(done)
+        if self._inflight is not None:
+            self._collect()
 
     def _on_device(self, name: str, host: np.ndarray):
         """The device's copy of an array of slot state.  It stays there
@@ -2135,65 +2172,96 @@ class LLMEngine:
             value = host.copy()  # the host's array changes in place
             held = self._resident[name] = (self._jax.device_put(value),
                                            value)
+            self._uploads += 1
+            self._upload_bytes += value.nbytes
         return held[0]
 
     def _dispatch_step(self) -> Optional[_Step]:
         """Launch one decode step for the slots that still need a token;
-        None when no slot does."""
+        None when no slot does.  Four stretches of the loop thread, each
+        a span: ``engine.decode.prepare`` (which rows, what they ask
+        for), ``engine.decode.dispatch`` (``.stage``: the slot state the
+        device does not hold yet; ``.call``: the program's enqueue;
+        ``.readback``: the copies to the host set off) and
+        ``engine.decode.settle`` (the pools and the state rebound, the
+        donated ones let go; the host's mirrors; the step's list of
+        rows)."""
         rows = self._active & (self._budget > 0)
-        if not rows.any():
+        if not rows.any():  # no step, so no span: a profile keeps them all
             return None
-        in_flight = int(self._inflight is not None)
-        self._stats["lookahead_steps" if in_flight else "drained_steps"] += 1
-        sampling_rows = self._count_sampling_rows()
-        dev = self._on_device
-        # kv_tokens: the cached rows this step's attention reads, which is
-        # what the benchmark's paged_attn_roofline counts the bytes of.
-        # state_slots: the slots whose recurrent state the step advances.
-        # index_rows: the cached rows the step's indexers score, every
-        # selecting layer's (a model with learned sparse attention).
-        stateful, moved = (), {}
-        kv_tokens = int(self._lengths[rows].sum())
-        if self._state is not None:
-            stateful = (self._state,)
-            moved = {"state_slots": int(rows.sum())}
-            self._stats["state_slots_moved"] += moved["state_slots"]
-        if self._sparse:
-            moved["index_rows"] = kv_tokens * self.num_layers
-            self._stats["dsa_rows_scored"] += moved["index_rows"]
+        with obs.span("engine.decode.prepare"):
+            in_flight = int(self._inflight is not None)
+            self._stats[
+                "lookahead_steps" if in_flight else "drained_steps"] += 1
+            sampling_rows = self._count_sampling_rows()
+            # kv_tokens: the cached rows this step's attention reads, which
+            # is what the benchmark's paged_attn_roofline counts the bytes
+            # of.  state_slots: the slots whose recurrent state the step
+            # advances.  index_rows: the cached rows the step's indexers
+            # score, every selecting layer's (a model with learned sparse
+            # attention).
+            stateful, moved = (), {}
+            kv_tokens = int(self._lengths[rows].sum())
+            if self._state is not None:
+                stateful = (self._state,)
+                moved = {"state_slots": int(rows.sum())}
+                self._stats["state_slots_moved"] += moved["state_slots"]
+            if self._sparse:
+                moved["index_rows"] = kv_tokens * self.num_layers
+                self._stats["dsa_rows_scored"] += moved["index_rows"]
         with obs.span("engine.decode.dispatch", kv_tokens=kv_tokens,
                       sampling_rows=sampling_rows, in_flight=in_flight,
                       **moved):
-            (self._k_pages, self._v_pages, nxt, lps, lengths,
-             *touched) = self._decode(
-                self._params, self._k_pages, self._v_pages,
-                dev("table", self._table), dev("lengths", self._lengths),
-                dev("last_tok", self._last_tok), dev("active", rows),
-                dev("temps", self._temps), dev("top_ps", self._top_ps),
-                dev("seeds", self._seeds), self._prev_tok,
-                dev("fresh", self._fresh), *stateful)
-            if stateful:
-                self._state = touched.pop()
+            with obs.span("engine.decode.stage") as sp:
+                dev = self._on_device
+                sent, sent_bytes = self._uploads, self._upload_bytes
+                staged = (
+                    dev("table", self._table), dev("lengths", self._lengths),
+                    dev("last_tok", self._last_tok), dev("active", rows),
+                    dev("temps", self._temps), dev("top_ps", self._top_ps),
+                    dev("seeds", self._seeds), self._prev_tok,
+                    dev("fresh", self._fresh))
+                sp.set(uploads=self._uploads - sent,
+                       upload_bytes=self._upload_bytes - sent_bytes)
+            with obs.span("engine.decode.call"):
+                outs = self._decode(self._params, self._k_pages,
+                                    self._v_pages, *staged, *stateful)
+            k_pages, v_pages, nxt, lps, lengths, *touched = outs
+            state = touched.pop() if stateful else None
             rows_read = touched.pop() if self._sparse else None
             # fetched only for a row whose request records them
             recording = self.record_experts
             selected = touched.pop() if recording and self._sparse else None
             chosen = touched.pop() if recording else None
-            for out in (nxt, lps, rows_read, *touched):
-                if out is not None:
+            fetched = [out for out in (nxt, lps, rows_read, *touched)
+                       if out is not None]
+            with obs.span("engine.decode.readback", arrays=len(fetched)):
+                for out in fetched:
                     out.copy_to_host_async()
-        self._lengths[rows] += 1  # as the program does: that K/V lands
-        self._resident["lengths"] = (lengths, self._lengths.copy())
-        self._budget[rows] -= 1
-        self._fresh[:] = False
-        self._prev_tok = nxt
-        return _Step(nxt, lps, touched[0] if touched else None,
-                     [(s, self._slot_req[s])
-                      for s in np.flatnonzero(rows).tolist()], chosen,
-                     rows_read, selected)
+        with obs.span("engine.decode.settle"):
+            # The donated pools, and the donated state with this frame's
+            # locals, die inside the span: letting go of a device array
+            # takes the loop thread ~0.2 ms in a busy replica, and that
+            # time has a name here.
+            self._k_pages, self._v_pages = k_pages, v_pages
+            if stateful:
+                self._state = state
+            del outs, staged, stateful, k_pages, v_pages, state
+            self._lengths[rows] += 1  # as the program does: that K/V lands
+            self._resident["lengths"] = (lengths, self._lengths.copy())
+            self._budget[rows] -= 1
+            self._fresh[:] = False
+            self._prev_tok = nxt
+            return _Step(nxt, lps, touched[0] if touched else None,
+                         [(s, self._slot_req[s])
+                          for s in np.flatnonzero(rows).tolist()], chosen,
+                         rows_read, selected)
 
-    def _collect(self, step: _Step):
-        """Wait for a dispatched step's results and emit them."""
+    def _collect(self):
+        """Wait for the results of the step in flight and emit them.  The
+        step is this frame's alone, so that its device arrays die where
+        the last ``engine.decode.settle`` says."""
+        step, self._inflight = self._inflight, None
         n_rows = len(step.rows)
         with obs.span("engine.decode.fetch") as sp:  # the host waits here
             nxt = np.asarray(step.tokens)
@@ -2216,14 +2284,15 @@ class LLMEngine:
                 read = int(np.asarray(step.rows_read))
                 sp.set(kv_rows_read=read)
                 self._stats["dsa_rows_read"] += read
-        self._stats["steps"] += 1
-        self._occupancy_sum += n_rows / self.max_slots
-        emitted = 0
-        chosen = selected = None
-        if any(req.record_experts for _, req in step.rows):
-            chosen = np.asarray(step.chosen)  # [expert layers, slots, k]
-            if step.selected is not None:  # [layers, slots, index_topk]
-                selected = np.asarray(step.selected)
+        with obs.span("engine.decode.settle"):
+            self._stats["steps"] += 1
+            self._occupancy_sum += n_rows / self.max_slots
+            emitted = 0
+            chosen = selected = None
+            if any(req.record_experts for _, req in step.rows):
+                chosen = np.asarray(step.chosen)  # [expert layers, slots, k]
+                if step.selected is not None:  # [layers, slots, index_topk]
+                    selected = np.asarray(step.selected)
         with obs.span("engine.emit") as sp:
             for slot, req in step.rows:
                 if self._slot_req.get(slot) is not req:
@@ -2241,8 +2310,10 @@ class LLMEngine:
                 self._append_token(slot, req, int(nxt[slot]),
                                    float(lps[slot]))
             sp.set(tokens=emitted)
-        self._stats["tokens"] += emitted
-        self._step_stamps.append(time.monotonic())
+        with obs.span("engine.decode.settle"):
+            self._stats["tokens"] += emitted
+            self._step_stamps.append(time.monotonic())
+            del step, nxt, lps, chosen, selected  # the step's arrays die here
 
     def _decode_once_spec(self):
         """Draft k-1 proposals per slot, verify the [slots, k] window in
@@ -2695,6 +2766,7 @@ class LLMServer:
                       sampling: Optional[SamplingParams] = None) -> int:
         import ray_tpu
 
+        self.engine.note_reply_call()
         if isinstance(prompt, ray_tpu.ObjectRef):
             prompt = ray_tpu.get(prompt)
         return self.engine.submit(prompt, max_new_tokens, eos_id,
@@ -2702,6 +2774,7 @@ class LLMServer:
 
     def next_chunk(self, rid: int, timeout: float = 60.0):
         """Next streamed token chunk, or None when the request retired."""
+        self.engine.note_reply_call()
         req = self.engine._requests[rid]
         try:
             chunk = req.chunks.get(timeout=timeout)
@@ -2736,6 +2809,7 @@ class LLMServer:
         return self.engine.stats()
 
     def request_stats(self, rid: int) -> dict:
+        self.engine.note_reply_call()
         return self.engine.request_stats(rid)
 
     def autoscale_metric(self) -> float:

@@ -78,6 +78,196 @@ def test_trace_store_budgets():
     assert all("duration" in r and "procs" in r for r in rows)
 
 
+# ---------------------------------------------------------------------------
+# The flush cadence (ISSUE 59): a task's end sends the ring when it is due,
+# not whenever it holds something
+# ---------------------------------------------------------------------------
+class _Sink:
+    """A transport that keeps what it is asked to send."""
+
+    def __init__(self):
+        self.sent = []
+
+    def request_oneway(self, op, payload):
+        self.sent.append((op, payload))
+
+
+@pytest.fixture
+def own_ring(monkeypatch):
+    """A small ring of this test's own in the recorder's place, its last
+    batch just gone: nothing is due until the test makes it so."""
+    ring = obs.SpanRing(capacity=16)
+    monkeypatch.setattr(obs, "_ring", ring)
+    monkeypatch.setattr(obs, "_lifecycle_held", False)
+    monkeypatch.setattr(obs, "_dropped_sent", 0)
+    monkeypatch.setattr(obs, "_dropped_exported", 0)
+    monkeypatch.setattr(obs, "_last_flush", time.monotonic())
+    monkeypatch.setattr(obs, "FLUSH_PERIOD_S", 3600.0)
+    return ring
+
+
+def _plain(n=1):
+    for i in range(n):
+        obs._append("plain", float(i), float(i) + 0.5, None, None, None, {})
+
+
+def test_an_empty_or_young_ring_is_not_due(own_ring):
+    assert not obs.flush_due()  # empty: one length check, as before
+    _plain(7)  # under half of 16, no lifecycle span, the last batch young
+    assert not obs.flush_due()
+    sink = _Sink()
+    assert obs.flush(sink) == 7  # flush itself goes by what the ring holds
+    assert [op for op, _ in sink.sent] == ["span_batch"]
+    assert obs.flush(sink) == 0 and len(sink.sent) == 1
+
+
+@pytest.mark.parametrize("why", ["lifecycle_span", "lifecycle_record",
+                                 "half_full", "age"])
+def test_what_makes_a_tasks_end_send(own_ring, monkeypatch, why):
+    _plain(2)
+    assert not obs.flush_due()
+    if why == "lifecycle_span":
+        with obs.span("set.up", _lifecycle=True):
+            assert not obs.flush_due()  # open: nothing to send yet
+    elif why == "lifecycle_record":
+        obs.record("jax.compile", 0.0, 1.0, _lifecycle=True)
+    elif why == "half_full":
+        _plain(5)
+        assert not obs.flush_due()  # 7 of 16
+        _plain(1)
+    else:
+        monkeypatch.setattr(obs, "_last_flush", time.monotonic() - 7200.0)
+    assert obs.flush_due()
+    sink = _Sink()
+    sent = obs.flush(sink)
+    assert sent == len(sink.sent[0][1]["spans"]) >= 2
+    assert not obs.flush_due() and len(own_ring) == 0
+    _plain(1)  # the batch that left made the ring young again
+    assert not obs.flush_due()
+
+
+def test_a_batch_says_what_the_ring_lost_since_the_last(own_ring):
+    _plain(20)  # 16 places: four pushed out
+    sink = _Sink()
+    assert obs.flush(sink) == 16
+    _plain(3)
+    obs.flush(sink)
+    _plain(40)  # 24 more
+    obs.flush(sink)
+    assert [p["dropped"] for _, p in sink.sent] == [4, 0, 24]
+    store = TraceStore()
+    for _, payload in sink.sent:
+        store.ingest(payload["spans"], dropped=payload["dropped"])
+    assert store.spans_dropped == 28 and store.spans_ingested == 35
+    store.ingest([], dropped=2)  # nothing left to send but the count
+    assert store.spans_dropped == 30
+
+
+def test_a_batch_that_could_not_leave_tells_its_losses_later(own_ring):
+    class Down:
+        def request_oneway(self, op, payload):
+            raise OSError("head restarting")
+
+    _plain(20)
+    assert obs.flush(Down()) == 0  # the spans are gone, the count is kept
+    _plain(1)
+    sink = _Sink()
+    obs.flush(sink)
+    assert sink.sent[0][1]["dropped"] == 4
+
+
+@pytest.fixture
+def counted_batches(monkeypatch, shutdown_only):
+    """A session whose workers send spans only as a task's end decides
+    (their periodic flusher is put out of reach), and the ``span_batch``
+    requests its head takes."""
+    from ray_tpu._private.head import Head
+
+    monkeypatch.setenv("RAY_TPU_NODE_STATS_PERIOD_S", "600")
+    batches = []
+    handler = Head.req_span_batch
+
+    def counted(self, payload, reply, caller):
+        batches.append(len(payload.get("spans") or []))
+        return handler(self, payload, reply, caller)
+
+    monkeypatch.setattr(Head, "req_span_batch", counted)
+    ray_tpu.init(num_cpus=1)
+    return batches
+
+
+@ray_tpu.remote
+class _Recorder:
+    """Records one span a call in its worker, the flag off around it."""
+
+    def note(self, name, n=1, lifecycle=False, period=None):
+        from ray_tpu import observability as o
+
+        if period is not None:
+            o.FLUSH_PERIOD_S = period
+        if lifecycle:
+            o.record(name, 0.0, 1.0, _lifecycle=True)
+            return len(o.ring())
+        tracing.enable_tracing()
+        try:
+            for i in range(n):
+                with o.span(name, i=i):
+                    pass
+        finally:
+            tracing.disable_tracing()
+        return len(o.ring())
+
+
+def test_500_task_ends_send_a_handful_of_batches_and_lose_nothing(
+        counted_batches):
+    rec = _Recorder.remote()
+    # the worker's first batch is due by age; after it the period is out
+    # of reach, so only the ring's filling or a lifecycle span sends
+    ray_tpu.get(rec.note.remote("first", period=3600.0))
+    wait_for_condition(lambda: obs.session_spans("first"))
+    before = len(counted_batches)
+    held = ray_tpu.get([rec.note.remote("tick") for _ in range(500)])
+    assert held[-1] >= 500  # 500 task ends, each with a ring that held some
+    assert len(counted_batches) - before <= 5
+    ray_tpu.shutdown()  # the worker's exit sends what its ring holds
+    assert len(obs.session_spans("tick")) == 500
+    assert len(counted_batches) - before <= 6
+
+
+def test_a_lifecycle_span_leaves_at_its_tasks_end(counted_batches):
+    rec = _Recorder.remote()
+    ray_tpu.get(rec.note.remote("first", period=3600.0))
+    wait_for_condition(lambda: obs.session_spans("first"))
+    assert ray_tpu.get(rec.note.remote("plain")) >= 1  # stays in the ring
+    ray_tpu.get(rec.note.remote("set.up", lifecycle=True))
+    # in the head's store while the session lives, and what waited with it
+    wait_for_condition(lambda: obs.session_spans("set.up"), timeout=30)
+    assert len(obs.session_spans("plain")) == 1
+    ray_tpu.shutdown()
+    assert len(obs.session_spans("set.up")) == 1
+
+
+@pytest.fixture
+def rings_of_16(monkeypatch):
+    """Set before the session starts: its workers read it as they do.
+    This process's ring is made first, at the size every other test has."""
+    obs.ring()
+    monkeypatch.setenv("RAY_TPU_TRACING_BUFFER_SIZE", "16")
+
+
+def test_a_workers_overflowed_ring_shows_in_the_sessions_count(
+        rings_of_16, counted_batches):
+    """16 places (the least a ring has) and 40 spans in one task: the
+    batch at its end tells the head of the 24 that were pushed out."""
+    before = obs.session_spans_dropped()
+    rec = _Recorder.remote()
+    assert ray_tpu.get(rec.note.remote("burst", n=40)) == 16
+    wait_for_condition(
+        lambda: obs.session_spans_dropped() - before == 24, timeout=30)
+    assert len(obs.session_spans("burst")) == 16
+
+
+
 def test_flight_bundle_roundtrip(tmp_path):
     """write_bundle/read_bundle round-trip, bundle-count pruning."""
     spans = [{"trace_id": "t1", "name": "x", "start": 1.0, "end": 2.0,

@@ -87,9 +87,32 @@ def traced_engine(gpt2, tmp_path_factory):
                 eng._params, eng._k_pages, eng._v_pages, eng._table[0],
                 np.zeros((8,), np.int32), np.int32(5), np.float32(0),
                 np.float32(1), np.int32(0)).as_text(debug_info=True)}
+        profiled, observed = obs.drain_spans(), list(observed)
+        # the same loop under the flag, its one request through the
+        # server's streaming calls, which the engine counts
+        from ray_tpu.serve.llm_engine import LLMServer
+        from ray_tpu.util import tracing
+
+        server = LLMServer.__new__(LLMServer)
+        server.engine = eng
+        calls0 = eng.stats()["reply_calls"]
+        tracing.enable_tracing()
+        try:
+            rid = server.submit_stream(prompts[0], 6)
+            calls = 1
+            while server.next_chunk(rid) is not None:
+                calls += 1
+            server.request_stats(rid)
+            calls += 2  # the chunk that said it was over, and the stats
+            time.sleep(0.3)  # the last iteration closes under the flag
+        finally:
+            tracing.disable_tracing()
+        flagged = {"spans": obs.drain_spans(), "calls": calls,
+                   "counted": eng.stats()["reply_calls"] - calls0}
     finally:
         eng.close()
-    return {"spans": obs.drain_spans(), "rids": rids, "off": off,
+        obs.drain_spans()  # the idle span that the flag opened
+    return {"spans": profiled, "flagged": flagged, "rids": rids, "off": off,
             "setup": setup,
             "counts": {k: after[k] - before[k] for k in (
                 "steps", "admitted", "lookahead_steps", "drained_steps")},
@@ -165,6 +188,104 @@ def test_counts_equal_the_engines_own(traced_engine):
                for s in named(spans, "engine.admit")) == counts["admitted"]
     assert sum(s["args"]["tokens"] for s in named(spans, "engine.emit")) \
         == 3 * 5  # six tokens a request, the first from its prefill
+
+
+# the step's account (ISSUE 59) ---------------------------------------------
+DISPATCH_PARTS = ("engine.decode.stage", "engine.decode.call",
+                  "engine.decode.readback")
+BOOK = ("engine.grow", "engine.decode.prepare", "engine.decode.settle")
+
+
+def recorded(traced_engine, how):
+    return (traced_engine["spans"] if how == "profile"
+            else traced_engine["flagged"]["spans"])
+
+
+@pytest.mark.parametrize("how", ["profile", "flag"])
+def test_a_decode_iteration_yields_the_account(traced_engine, how):
+    """Under a profile and under the flag alike: a dispatch is made of
+    stage, call and readback, one after the other; grow, prepare and
+    settle are the iteration's own children."""
+    spans = recorded(traced_engine, how)
+    by_id = {s["span_id"]: s for s in spans}
+    dispatches = named(spans, "engine.decode.dispatch")
+    assert dispatches
+    for d in dispatches:
+        parts = sorted((s for s in spans if s["parent_id"] == d["span_id"]),
+                       key=lambda s: s["start"])
+        assert tuple(p["name"] for p in parts) == DISPATCH_PARTS
+        edges = [d["start"]] + [t for p in parts
+                                for t in (p["start"], p["end"])] + [d["end"]]
+        assert edges == sorted(edges)  # disjoint, in order, inside
+        assert by_id[d["parent_id"]]["name"] == "engine.iteration"
+    for name in BOOK:
+        found = named(spans, name)
+        assert found, name
+        for s in found:
+            it = by_id[s["parent_id"]]
+            assert it["name"] == "engine.iteration"
+            assert it["start"] <= s["start"] <= s["end"] <= it["end"]
+    # a step settles three times: as it is dispatched (the donated
+    # arrays die there), as it is read, and as it is let go after the emit
+    assert len(named(spans, "engine.decode.prepare")) == len(dispatches)
+    assert len(named(spans, "engine.decode.settle")) == 3 * len(dispatches)
+    assert len(named(spans, "engine.grow")) == len(
+        named(spans, "engine.iteration"))
+
+
+@pytest.mark.parametrize("how", ["profile", "flag"])
+def test_the_accounts_arguments_are_there_and_of_their_type(
+        traced_engine, how):
+    spans = recorded(traced_engine, how)
+    for s in named(spans, "engine.decode.stage"):
+        a = s["args"]
+        assert type(a["uploads"]) is int and type(a["upload_bytes"]) is int
+        assert (a["uploads"] == 0) == (a["upload_bytes"] == 0)
+        assert 0 <= a["uploads"] <= 8
+    for s in named(spans, "engine.decode.readback"):
+        assert s["args"] == {"arrays": 2}  # tokens and their log-probs
+    grown = [s["args"]["pages"] for s in named(spans, "engine.grow")]
+    assert all(type(n) is int and n >= 0 for n in grown)
+    for s in named(spans, "engine.iteration"):
+        a = s["args"]
+        assert type(a["cpu_ms"]) is float and a["cpu_ms"] >= 0.0
+        assert type(a["reply_calls"]) is int and a["reply_calls"] >= 0
+        assert {"active", "pending"} <= set(a)
+
+
+def test_a_slots_first_step_sends_its_state_and_a_later_one_does_not(
+        traced_engine):
+    """``uploads`` follows ``_on_device``: the step after an admission
+    sends the arrays the admission changed, a step between two sends
+    none but what every step changes."""
+    stages = sorted(named(traced_engine["spans"], "engine.decode.stage"),
+                    key=lambda s: s["start"])
+    sent = [s["args"]["uploads"] for s in stages]
+    assert max(sent) >= 3 and sent[0] == max(sent)  # table, tokens, rows...
+    assert min(sent) < max(sent)
+    # a page is 8 tokens: prompts of 5, 11 and 19 with 6 new tokens each
+    # cross one page boundary between them while decoding
+    assert sum(s["args"]["pages"] for s in named(
+        traced_engine["spans"], "engine.grow")) >= 1
+
+
+def test_reply_calls_count_the_servers_calls(traced_engine):
+    flagged = traced_engine["flagged"]
+    assert flagged["counted"] == flagged["calls"] >= 4
+    told = sum(s["args"]["reply_calls"]
+               for s in named(flagged["spans"], "engine.iteration"))
+    # an iteration is told the calls since the one before it closed:
+    # all of them but those after the last iteration
+    assert 1 <= told <= flagged["calls"]
+
+
+def test_off_the_accounts_spans_are_the_shared_noop(traced_engine):
+    """Flag off, no profile: over 100 iterations the ring did not grow,
+    and every new name is the one no-op."""
+    off = traced_engine["off"]
+    assert off["steps"] >= 100 and off["ring"] == 0
+    for name in DISPATCH_PARTS + BOOK:
+        assert obs.span(name, pages=0) is obs.NO_SPAN
 
 
 def test_the_loop_runs_a_step_ahead_and_the_span_says_so(traced_engine):
