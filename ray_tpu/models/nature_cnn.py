@@ -16,8 +16,15 @@ the program names; packed frames may therefore come with the batch LAST,
 ``[H', W', 16 C, B]``, which is what ``ops.gather_rows`` writes when it
 picks a minibatch out of a sample-major trajectory in one pass, and the
 first layer then reads them as they lie, with no gather-then-transpose in
-front (PERF.md, PR 58).  The parameter tree is the unpacked kernel's:
-``Conv_0/kernel`` stays ``[8, 8, C, 32]`` with ``nn.Conv``'s initialiser.
+front (PERF.md, PR 58).  ``pack_frames`` is the plain definition of the
+packed form and what every caller with raw frames gets; where frames are
+KEPT on a TPU (anakin PPO's rollout) they are packed by the kernel
+``ops.gather_rows.fold_tiles`` instead (``pack_frames_tiled``: uint8 frames
+of four channels, one pass from the environment's bytes to the
+trajectory's word tiles and to the batch-last form the trunk reads, the
+same bytes in the same order; PERF.md, PR 60).  The parameter tree is the
+unpacked kernel's: ``Conv_0/kernel`` stays ``[8, 8, C, 32]`` with
+``nn.Conv``'s initialiser.
 """
 from __future__ import annotations
 
@@ -27,6 +34,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import gather_rows as rows_op
+
 _KERNEL, _STRIDE = 8, 4
 _FOLD = _KERNEL // _STRIDE  # the packed kernel's side
 
@@ -35,6 +44,29 @@ def _pads(height: int, width: int):
     """What ``SAME`` puts around a frame for the first layer's window."""
     return jax.lax.padtype_to_pads(
         (height, width), (_KERNEL, _KERNEL), (_STRIDE, _STRIDE), "SAME")
+
+
+def folds_tiled(frame_shape: Tuple[int, ...], dtype=jnp.uint8) -> bool:
+    """Whether ``pack_frames_tiled`` takes raw frames ``[H, W, C]`` of
+    ``dtype`` (``ops.gather_rows.folds_frames``: uint8, four channels, a
+    row of whole words)."""
+    return rows_op.folds_frames(
+        jax.ShapeDtypeStruct((1, *frame_shape), dtype),
+        _pads(*frame_shape[:2]))
+
+
+def pack_frames_tiled(x: jax.Array, into=None, at=0):
+    """``pack_frames(x)`` of raw frames ``[B, H, W, C]`` in the two forms a
+    caller that keeps them holds, by ONE kernel that reads the raw bytes
+    once (``ops.gather_rows.fold_tiles``; ``folds_tiled`` says which frames
+    it takes): ``(tiles, packed)`` with ``tiles`` the packed frames as
+    ``ops.gather_rows.row_tiles`` has them (written over items ``at ..`` of
+    the buffer ``into`` where one is given, in place) and ``packed``
+    ``[H', W', 16 C, B]``, the batch last, which the first layer reads as
+    it lies."""
+    tiles, cols = rows_op.fold_tiles(x, _pads(*x.shape[1:3]), into=into,
+                                     at=at)
+    return tiles, cols.reshape(*packed_shape(x.shape[1:]), -1)
 
 
 def packed_shape(frame_shape: Tuple[int, ...]) -> Tuple[int, int, int]:

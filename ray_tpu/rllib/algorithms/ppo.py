@@ -237,6 +237,9 @@ def make_anakin_ppo(config: AlgorithmConfig):
     # which way a minibatch of them is gathered: settled here, by shape.
     seen_as = jax.eval_shape(module.pack_obs, spec.example_obs())
     by_dma = _frames_by_dma(seen_as)
+    # ... and whether a rollout step's frames go from the environment to the
+    # trajectory through one kernel, the fold done on the way.
+    by_fold = by_dma and spec.packs_tiled
     frame, frame_size = seen_as.shape[1:], math.prod(seen_as.shape[1:])
 
     loss_fn = functools.partial(
@@ -250,14 +253,19 @@ def make_anakin_ppo(config: AlgorithmConfig):
         rng, k_act, k_step = jax.random.split(rng, 3)
         # Frames are packed here, once, and the trajectory holds that form:
         # SGD gathers and reads it as it is (models/nature_cnn.py).
-        seen = module.pack_obs(obs)
+        if by_fold:
+            # One pass over the env's bytes: into the trajectory where it
+            # lies, a frame a run of word tiles, and batch-minor for the
+            # trunk, which is how the chip holds a convolution's frames.
+            frames, seen = module.pack_obs_tiled(obs, into=frames,
+                                                 at=t * obs.shape[0])
+        else:
+            seen = module.pack_obs(obs)
+            if by_dma:
+                frames = rows_op.tile_columns(
+                    seen.reshape(seen.shape[0], -1).T, into=frames,
+                    at=t * seen.shape[0])
         action, logp, value = module.forward_exploration(params, seen, k_act)
-        if by_dma:
-            # Into the trajectory where it lies, a frame a run of word
-            # tiles; batch-minor is how the chip holds what the trunk read.
-            frames = rows_op.tile_columns(
-                seen.reshape(seen.shape[0], -1).T, into=frames,
-                at=t * seen.shape[0])
         env_states, next_obs, reward, done, _ = vector_step(
             env, env_states, action, k_step)
         ep_ret = ep_ret + reward
@@ -343,9 +351,11 @@ def make_anakin_ppo(config: AlgorithmConfig):
     else:
         step = jax.jit(train_step)
     # The first call inside a lifecycle span that says which way the frames
-    # of a minibatch go: the run's record of a choice made by shape.
+    # of a minibatch go and what packs a rollout step's: the run's record of
+    # choices made by shape.
     step = FirstCallSpan(step, "train.compile", "anakin_ppo",
-                         frame_gather="rows_dma" if by_dma else "xla")
+                         frame_gather="rows_dma" if by_dma else "xla",
+                         frame_pack="fold_tiles" if by_fold else "xla")
     return module, init_fn, step, batch_total
 
 

@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.mlp import MLP
-from ray_tpu.models.nature_cnn import (MinAtarCNN, NatureCNN, pack_frames,
+from ray_tpu.models.nature_cnn import (MinAtarCNN, NatureCNN, folds_tiled,
+                                       pack_frames, pack_frames_tiled,
                                        packed_shape)
 
 
@@ -36,6 +37,13 @@ class RLModuleSpec:
         if not self.conv or min(self.obs_shape[:2]) < 32:
             return None
         return packed_shape(self.obs_shape)
+
+    @property
+    def packs_tiled(self) -> bool:
+        """Whether ``pack_obs_tiled`` has a kernel for this spec's frames:
+        packed frames that fold as words (``nature_cnn.folds_tiled``)."""
+        return self.packed_obs_shape is not None \
+            and folds_tiled(self.obs_shape)
 
     def example_obs(self, batch: int = 1) -> np.ndarray:
         """A zero observation batch matching this spec's trunk input —
@@ -66,8 +74,9 @@ class DiscreteActorCritic(nn.Module):
     Frames of 32 pixels or more may come raw (``spec.obs_shape``), packed
     (``spec.packed_obs_shape``, what ``pack_obs`` returns) or packed with
     the batch last (``[*spec.packed_obs_shape, B]``, what a minibatch
-    gathered by ``ops.gather_rows`` is): the static shape tells which, and
-    raw frames are packed first, so all run the same convolution."""
+    gathered by ``ops.gather_rows`` is, and ``pack_obs_tiled``'s second
+    result): the static shape tells which, and raw frames are packed first,
+    so all run the same convolution."""
 
     spec: RLModuleSpec
 
@@ -80,6 +89,14 @@ class DiscreteActorCritic(nn.Module):
         if self.spec.packed_obs_shape is None:
             return obs
         return jax.lax.optimization_barrier(pack_frames(obs))
+
+    def pack_obs_tiled(self, obs, into=None, at=0):
+        """``pack_obs(obs)`` for a caller that keeps its observations as
+        word tiles (``ops.gather_rows``), where ``spec.packs_tiled``:
+        ``(tiles, seen)`` from one kernel, ``seen`` with the batch last,
+        which ``__call__`` reads as ``pack_obs``'s form
+        (``nature_cnn.pack_frames_tiled``)."""
+        return pack_frames_tiled(obs, into=into, at=at)
 
     @nn.compact
     def __call__(self, obs) -> Tuple[jax.Array, jax.Array]:

@@ -7,6 +7,7 @@ and costs no chip time.  Nothing runs, so this gives no result and no time.
 The topology is described inside a fixture, never at import: only the
 worker that is handed this file loads the TPU's library, and every worker
 collects the same tests (on-chip-measurement guide, section 2)."""
+import math
 import re
 
 import jax
@@ -445,19 +446,21 @@ def test_anakin_ppo_step_keeps_its_frames_bytes_for_v5e(one_chip):
     x 64 steps, minibatches of 8,192), built as the benchmark's driver
     builds it (``tools/step_fusions.py``; ~35 s alone).  The trajectory
     holds packed frames as word tiles, four bytes a word, a frame 64 rows of
-    128 words, written a rollout step at a time by ``tile_columns`` where
-    they lie (the kernel's result is the buffer: no copy of the trajectory
-    anywhere in the step, nor a pass to clear it); nothing
-    frame-sized is ever written in two bytes or four a value (PR 55's
-    parent wrote ``bf16[8192,84,84,4]`` twice a minibatch), and a
+    128 words, written a rollout step at a time where they lie (the
+    kernel's result is the buffer: no copy of the trajectory anywhere in
+    the step, nor a pass to clear it) by ``fold_tiles``, which takes the
+    env's raw frames AS THE LOOP CARRIES THEM (channel-major, the batch in
+    the lanes: a bitcast, not a copy) and pads and folds on the way, so a
+    rollout step moves its frames once: until PR 60 ``pack_frames`` in four
+    passes of the compiler's (``copy u8[2048,84,336]``, ``pad
+    u8[2048,88,352]``, two more copies) and ``tile_columns`` behind them.
+    Nothing frame-sized is ever written in two bytes or four a value (PR
+    55's parent wrote ``bf16[8192,84,84,4]`` twice a minibatch), and a
     minibatch's frames are moved ONCE, as bytes: the kernel ``gather_rows``
     writes them batch-minor, and both of ``Conv_0``'s fusions read that
     through a bitcast (until PR 58 a gather and a transposition,
     ``fusion`` + ``copy u8[8192,242,128]``).  Nothing chooses at run time,
     so this is the mechanism's witness."""
-    import math
-    import re
-
     from tools.step_fusions import compile_step, entry_operations
 
     text = compile_step("ppo_atari84_anakin",
@@ -469,18 +472,71 @@ def test_anakin_ppo_step_keeps_its_frames_bytes_for_v5e(one_chip):
                          r"broadcast|dynamic-update-slice)\(", text)
     assert "u8[64,2048,242,128]" not in text   # ... and no bytes beside it
     assert "u8[64,2048,84,84,4]" not in text   # ... nor raw frames
-    moved, kernels = [], []
+
+    def size(shape):
+        kind, dims = re.fullmatch(r"(\w+)\[([\d,]*)\]", shape).groups()
+        return kind, math.prod(int(d) for d in dims.split(",") if d)
+
+    moved, stepped, kernels = [], [], []
     for o in entry_operations(text):
         if o["op"] == "custom-call" and "tpu_custom_call" in o["key"]:
-            kernels.append((o["times"], o["shapes"][0]))
-        if o["times"] != 32 or o["op"] in ("get-tuple-element", "bitcast",
-                                           "parameter", "tuple", "while"):
-            continue  # 2 epochs x 16 minibatches: the inner loop's body
-        kind, dims = re.fullmatch(r"(\w+)\[([\d,]*)\]", o["shapes"][0]).groups()
-        if math.prod(int(d) for d in dims.split(",") if d) >= 8192 * 22 * 22 * 64:
+            kernels.append((o["times"], o["name"].rsplit(".", 1)[0],
+                            *o["shapes"]))
+        if o["op"] in ("get-tuple-element", "bitcast", "parameter", "tuple",
+                       "while"):
+            continue
+        kind, values = size(o["shapes"][0])
+        # 2 epochs x 16 minibatches: the inner loop's body
+        if o["times"] == 32 and values >= 8192 * 22 * 22 * 64:
             moved.append((o["op"], kind))
+        # 64 rollout steps: whatever writes 2,048 frames of bytes
+        if o["times"] == 64 and kind == "u8" and values >= 2048 * 84 * 84 * 4:
+            stepped.append(o["key"])
     assert moved == [("custom-call", "u8")]
-    # the buffer, a rollout step's tile_columns, a minibatch's gather_rows
-    assert sorted(kernels) == [(1, "u32[131072,64,128]"),
-                               (32, "u8[30976,8192]"),
-                               (64, "u32[8388608,128]")]
+    # the env shifts its frame stack, and one kernel takes it from there
+    assert stepped == ["broadcast_select_fusion u8[2048,84,84,4]"]
+    # the buffer, a rollout step's fold_tiles, a minibatch's gather_rows
+    assert sorted(kernels) == [
+        (1, "empty_tiles", "u32[131072,64,128]"),
+        (32, "gather_rows", "u8[30976,8192]"),
+        (64, "fold_tiles", "u32[8388608,128]", "u8[30976,2048]")]
+    # ... which reads the loop's own frames: no relayout in front of it
+    call = next(line for line in text.splitlines()
+                if " custom-call(" in line and "fold_tiles" in line)
+    frames = re.search(r"custom-call\(%[\w.\-]+, %([\w.\-]+),", call)[1]
+    made = next(line for line in text.splitlines()
+                if line.lstrip().startswith(f"%{frames} = "))
+    assert " bitcast(" in made and "u8[4,84,84,2048]" in made
+
+
+@pytest.mark.timeout(120)
+@pytest.mark.parametrize("n,frame,vmem_mb", [
+    (2048, (84, 84), 25),     # the PPO cell's rollout step
+    (256, (210, 160), 105),   # a whole Atari screen: most of a core's VMEM
+], ids=["84x84", "210x160"])
+def test_fold_tiles_compiles_for_v5e(one_chip, n, frame, vmem_mb):
+    """``ops.gather_rows.fold_tiles`` at real sizes, in place: Mosaic takes
+    a row of 84 bytes (not whole 32-row tiles) as words and the fold's
+    strided stores of 21 rows, and the block fits the VMEM asked for."""
+    from ray_tpu.models.nature_cnn import _pads
+    from ray_tpu.ops import gather_rows as rows_op
+
+    pads = tuple(map(tuple, _pads(*frame)))
+    x = jax.ShapeDtypeStruct((n, *frame, 4), jnp.uint8, sharding=one_chip)
+    assert rows_op.folds_frames(x, pads)
+    fold = rows_op._Fold.of((*frame, 4), pads)
+    assert fold.vmem <= vmem_mb << 20
+    into = jax.ShapeDtypeStruct(
+        (2 * n, rows_op._tile_rows(fold.words), 128), jnp.uint32,
+        sharding=one_chip)
+    at = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda x, into, at: rows_op._fold_tiles(x, into, at, pads=pads,
+                                                interpret=False),
+        donate_argnums=(1,)).lower(x, into, at).compile()
+    call = next(line for line in compiled.as_text().splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert "fold_tiles" in call and "output_to_operand_aliasing" in call
+    # the buffer is the result: nothing of its size beside it
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < n * (math.prod(frame) * 4 + fold.words * 4) * 1.3

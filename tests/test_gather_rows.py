@@ -1,14 +1,16 @@
 """``ops/gather_rows.py``: selected rows of a sample-major buffer handed
-back batch-minor (``rows[idx].T``), and the same pass the other way
-(``tile_columns``), both in interpret mode against plain ``jax.numpy``; and
-``NatureCNN`` on what the kernel hands over, packed frames with the batch
-last, against the same frames batch-first."""
+back batch-minor (``rows[idx].T``), the same pass the other way
+(``tile_columns``), and that pass with the four-by-four fold of raw frames
+taken in (``fold_tiles``), all in interpret mode against plain
+``jax.numpy``; and ``NatureCNN`` on what the kernels hand over, packed
+frames with the batch last, against the same frames batch-first."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.nature_cnn import NatureCNN, pack_frames
+from ray_tpu.models.nature_cnn import (NatureCNN, _pads, folds_tiled,
+                                       pack_frames, pack_frames_tiled)
 from ray_tpu.ops import gather_rows as rows_op
 from ray_tpu.rllib.core.rl_module import RLModuleSpec
 
@@ -74,6 +76,79 @@ def test_tile_columns_into_a_buffer_writes_its_items_and_no_other(
     assert empty.shape == buffer.shape and empty.dtype == buffer.dtype
     with pytest.raises(ValueError, match="row_tiles"):
         rows_op.tile_columns(jnp.asarray(rows.T), into=buffer[:, :4], at=0)
+
+
+def _raw(n, shape, seed=0):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.integers(0, 256, (n, *shape, 4), dtype=np.uint8))
+
+
+@pytest.mark.parametrize("shape,n,total,at", [
+    ((84, 84), 128, 512, 0),      # whole blocks: in place, at several places
+    ((84, 84), 128, 512, 128),
+    ((84, 84), 128, 512, 384),
+    ((84, 84), 130, 390, 130),    # a block and two: tiles, then a copy
+    ((84, 84), 5, 20, 7),
+    ((210, 160), 3, 9, 3),        # three rows on top, not two: pads otherwise
+    ((36, 40), 2, 4, 2),          # one group of cells, first and last at once
+], ids=["frames-at0", "frames-at128", "frames-at384", "a-block-and-two",
+        "five", "210x160", "36x40"])
+def test_fold_tiles_is_tile_columns_of_the_packed_frames(shape, n, total, at):
+    """Byte for byte what ``tile_columns`` makes of ``pack_frames``' result,
+    and the packed frames themselves with the batch last; every item of the
+    buffer outside ``[at, at + n)`` as it was."""
+    frames, pads = _raw(n, shape, seed=7), _pads(*shape)
+    packed = pack_frames(frames)
+    cols = packed.reshape(n, -1).T
+    held = np.random.default_rng(8).integers(
+        0, 256, (total, cols.shape[0])).astype(np.uint8)
+    buffer = rows_op.row_tiles(jnp.asarray(held))
+    assert rows_op.folds_frames(frames, pads) and folds_tiled((*shape, 4))
+    tiles, seen = rows_op.fold_tiles(frames, pads, into=buffer, at=at)
+    want = np.asarray(rows_op.tile_columns(cols, into=buffer, at=at))
+    assert tiles.shape == buffer.shape and tiles.dtype == buffer.dtype
+    assert np.array_equal(np.asarray(tiles), want)
+    outside = np.r_[0:at, at + n:total]
+    assert np.array_equal(np.asarray(tiles)[outside],
+                          np.asarray(buffer)[outside])
+    assert seen.shape == cols.shape and seen.dtype == jnp.uint8
+    assert np.array_equal(np.asarray(seen), np.asarray(cols))
+    # ... with no buffer: the tiles alone
+    alone, seen = rows_op.fold_tiles(frames, pads)
+    assert np.array_equal(np.asarray(alone), want[at:at + n])
+    assert np.array_equal(np.asarray(seen), np.asarray(cols))
+    # ... and as the trunk's module hands it over
+    tiles, last = pack_frames_tiled(frames, into=buffer, at=at)
+    assert np.array_equal(np.asarray(tiles), want)
+    assert np.array_equal(np.asarray(last),
+                          np.moveaxis(np.asarray(packed), 0, -1))
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((2, 96, 96, 3), np.uint8),     # three channels are no word
+    ((2, 84, 84, 4), np.float32),   # nor are floats bytes
+    ((2, 84, 86, 4), np.uint8),     # a row of 86 columns: half a word over
+    ((2, 32, 32, 4), np.uint8),     # folded, 5,184 bytes: no whole lane rows
+    ((84, 84, 4), np.uint8),        # no batch
+], ids=["three-channels", "float32", "86-columns", "32x32", "one-frame"])
+def test_frames_that_do_not_fold_as_words_are_refused(shape, dtype):
+    x = jax.ShapeDtypeStruct(shape, dtype)
+    pads = _pads(*shape[-3:-1])
+    assert not rows_op.folds_frames(x, pads)
+    with pytest.raises(ValueError, match="folds_frames"):
+        rows_op.fold_tiles(jnp.zeros(shape, dtype), pads)
+    if len(shape) == 4:
+        assert not folds_tiled(shape[1:], dtype)
+
+
+def test_fold_tiles_refuses_a_buffer_of_another_form():
+    frames, pads = _raw(2, (84, 84)), _pads(84, 84)
+    buffer = rows_op.row_tiles(jnp.zeros((4, 30976), jnp.uint8))
+    with pytest.raises(ValueError, match="row_tiles"):
+        rows_op.fold_tiles(frames, pads, into=buffer[:, :8], at=0)
+    with pytest.raises(ValueError, match="row_tiles"):
+        rows_op.fold_tiles(frames, pads,
+                           into=buffer.astype(jnp.int32), at=0)
 
 
 def test_row_tiles_keeps_a_rows_bytes_in_their_order():
@@ -158,3 +233,30 @@ def test_the_module_tells_batch_last_frames_by_their_shape():
         assert float(jnp.max(jnp.abs(first - end))) < 1e-5
     with pytest.raises(ValueError, match="B last"):
         module.apply(params, last[:, :20])
+
+
+def test_the_module_packs_raw_frames_into_tiles_and_reads_what_it_packed():
+    """``pack_obs_tiled``: the trajectory's tiles and the batch-last frames
+    from one kernel; the trunk reads the second as it reads raw frames, and
+    a trunk that packs nothing has no such pass."""
+    spec = RLModuleSpec(obs_shape=(84, 84, 4), num_actions=4, conv=True)
+    assert spec.packs_tiled
+    module = spec.build()
+    frames = _raw(5, (84, 84), seed=9)
+    actions = jnp.asarray([0, 3, 1, 2, 0])
+    params = module.init(jax.random.PRNGKey(2), frames)
+    tiles, last = module.pack_obs_tiled(frames)
+    assert last.shape == (*spec.packed_obs_shape, 5)
+    assert np.array_equal(
+        np.asarray(tiles),
+        np.asarray(rows_op.row_tiles(module.pack_obs(frames))))
+    forward = jax.jit(module.forward_train)
+    for raw, end in zip(forward(params, frames, actions),
+                        forward(params, last, actions)):
+        assert float(jnp.max(jnp.abs(raw - end))) < 1e-5
+    for other in (RLModuleSpec(obs_shape=(10, 10, 4), num_actions=3,
+                               conv=True),       # a board: nothing packed
+                  RLModuleSpec(obs_shape=(96, 96, 3), num_actions=3,
+                               conv=True),       # three channels: no words
+                  RLModuleSpec(obs_dim=4, num_actions=2)):
+        assert not other.packs_tiled

@@ -46,6 +46,7 @@ def traced_engine(gpt2, tmp_path_factory):
     from ray_tpu.serve.llm_engine import LLMEngine
 
     model, params, cfg = gpt2
+    obs.drain_spans()  # lifecycle spans of whatever file this worker ran last
     eng = LLMEngine(model, params, max_slots=4, page_size=8, max_ctx=64)
     rng = np.random.default_rng(0)
     prompts = [list(map(int, rng.integers(0, cfg.vocab_size, size=n)))

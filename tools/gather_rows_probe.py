@@ -17,9 +17,21 @@ agree; then a line a form and block size with ``ms`` a call (the mean of
 alone, of gather + ``Conv_0`` forward + weight gradient, and of a step's
 ``tile_columns``; then, from a profile of a few calls, the device's
 operations by name.  **A ``copy`` of 8,192 frames between the kernel and
-either ``Conv_0`` fusion means nothing was gained** (ISSUE 58).  Times come
-from a chip only: on the CPU the kernels are interpreted (``--tiny``) and the
-lines say so.
+either ``Conv_0`` fusion means nothing was gained** (ISSUE 58).
+
+Then a rollout step's side (``"form": "fold_tiles"``): raw frames
+``u8[2048, 84, 84, 4]`` carried through a loop as the environment's frame
+stack carries them (shifted a channel a step, so the compiler lays them out
+as it does there), into the trajectory's slab and batch-minor for the
+trunk, by ``fold_tiles`` (one kernel) and by ``pack_frames`` +
+``tile_columns`` (the compiler's four passes and a kernel): whether the two
+slabs are equal byte for byte, ``ms`` a step of the whole loop, and from a
+profile the kernel's own ``ms`` a step, the ``kernel_gb_s`` of the bytes a
+step needs moved (raw frames read once, both results written once) and the
+device's operations of a step by name.  **A ``copy``, a
+``pad`` or a ``transpose`` of 2,048 frames beside the kernel means a
+relayout stayed in front of it** (ISSUE 60).  Times come from a chip only:
+on the CPU the kernels are interpreted (``--tiny``) and the lines say so.
 """
 import argparse
 import functools
@@ -35,7 +47,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from ray_tpu.models.nature_cnn import PackedConv  # noqa: E402
+from ray_tpu.models.nature_cnn import (PackedConv, pack_frames,  # noqa: E402
+                                       pack_frames_tiled)
 from ray_tpu.ops import gather_rows as rows_op  # noqa: E402
 
 FRAME = (22, 22, 64)  # a packed 84x84x4 frame
@@ -69,6 +82,68 @@ def _timed(fn, *args, calls):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / calls * 1e3
+
+
+def _rollout(fold: bool, steps: int, envs: int):
+    """``steps`` rollout steps' frame path alone, as one program: the frame
+    stack shifted a channel (the env's part), then packed into the slab and
+    batch-minor, the second summed so that it is read."""
+    def step(t, carry):
+        obs, tiles, seen_sum = carry
+        obs = jnp.concatenate([obs[..., 1:], obs[..., :1] + 1], axis=-1)
+        if fold:
+            tiles, seen = pack_frames_tiled(obs, into=tiles, at=t * envs)
+            seen = seen.reshape(-1, envs)
+        else:
+            seen = jax.lax.optimization_barrier(pack_frames(obs))
+            seen = seen.reshape(envs, -1).T
+            tiles = rows_op.tile_columns(seen, into=tiles, at=t * envs)
+        return obs, tiles, seen_sum + jnp.sum(seen[::128], dtype=jnp.int32)
+
+    @jax.jit
+    def run(obs):
+        tiles = rows_op.empty_tiles(steps * envs, WIDTH, jnp.uint8)
+        return jax.lax.fori_loop(0, steps, step,
+                                 (obs, tiles, jnp.int32(0)))[1:]
+
+    return run
+
+
+def fold_case(args, on_chip: bool) -> bool:
+    """The rollout step's side: ``fold_tiles`` against ``pack_frames`` +
+    ``tile_columns``."""
+    steps, envs = (2, 8) if args.tiny else (16, 2048)
+    obs = jax.random.bits(jax.random.PRNGKey(args.seed + 3),
+                          (envs, 84, 84, 4), jnp.uint8)
+    # raw frames read (84 bytes a row), the slab and the batch-minor bytes
+    moved = envs * (84 * 84 * 4 + 64 * 128 * 4 + WIDTH)
+    calls = 1 if args.tiny else max(args.calls // 4, 2)
+
+    def line(name, run, want=None):
+        tiles, seen = jax.block_until_ready(run(obs))
+        out = {"form": name,
+               "ms_a_step": _timed(run, obs, calls=calls) / steps}
+        if want is not None:
+            out["slabs_equal"] = bool(jnp.array_equal(tiles, want[0])) \
+                and int(seen) == int(want[1])
+        if on_chip:  # the kernel's own time, and what stands beside it
+            from benchmark import common
+
+            window = common.TracedWindow("fold_tiles_probe")
+            jax.block_until_ready(run(obs))
+            ops = sorted(window.close().get("op_s", {}).items(),
+                         key=lambda kv: -kv[1])
+            kernel = sum(v for k, v in ops if k.startswith("tpu_custom_call"))
+            out["kernel_ms_a_step"] = kernel / steps * 1e3
+            if want is not None:
+                out["kernel_gb_s"] = moved * steps / kernel / 1e9
+            out["ops_ms_a_step"] = {k: round(v / steps * 1e3, 4)
+                                    for k, v in ops[:10]}
+        print(json.dumps(out))
+        return tiles, seen, out.get("slabs_equal", True)
+
+    *want, _ = line("pack_frames+tile_columns", _rollout(False, steps, envs))
+    return line("fold_tiles", _rollout(True, steps, envs), want)[2]
 
 
 def main() -> int:
@@ -161,6 +236,7 @@ def main() -> int:
         print(json.dumps({"ops_ms_a_call": {
             k: round(v / 4 * 1e3, 3) for k, v in
             sorted(ops.items(), key=lambda kv: -kv[1])[:12]}}))
+    ok = fold_case(args, on_chip) and ok
     print(json.dumps({"ok": ok}))
     return 0 if ok else 1
 

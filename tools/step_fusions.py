@@ -16,11 +16,11 @@ A line a group: how many a step, the compiler's cost model for all of them
 above what traced runs read on most operations, several times above on a
 few, and on the PPO cell's byte copies four times off either way, a
 transposition too high and a gather too low: PERF.md section 5; a Pallas
-kernel, ``tpu_custom_call``, has no estimate at all, so since PR 58 the PPO
-cell's frame path, ``gather_rows`` a minibatch and ``tile_columns`` a
-rollout step, shows only under ``--shape 30976,8192`` / ``--shape
-131072,128``, and the anakin step is built as on a TPU backend, which is
-what chooses those kernels),
+kernel, ``tpu_custom_call``, has no estimate at all, so the PPO cell's frame
+path, ``gather_rows`` a minibatch and ``fold_tiles`` a rollout step, is
+listed by name after the groups, each kernel with the loop body it stands
+in, and the anakin step is built as on a TPU backend, which is what chooses
+those kernels),
 whether a matmul (``convolution``) is fused inside, and the ``op_name`` of
 the first.  ``--shape`` lists instead, in schedule order, every operation
 with that shape among its results.  Nothing runs, so this gives no time.
@@ -236,6 +236,11 @@ def report(text: str, min_ms: float, shape: str | None) -> None:
         print(f"  {sum(o['times'] for o in members):5d} {total:7.2f}  "
               f"{inside:3d}/{len(members):<3d} {key}: "
               f"{members[0]['op_name']}")
+    print("kernels (Pallas: no estimate), as often as their loops turn:")
+    for o in ops:
+        if o["op"] == "custom-call" and "tpu_custom_call" in o["key"]:
+            print(f"  {o['times']:5d}  {o['name'].rsplit('.', 1)[0]} -> "
+                  f"{', '.join(o['shapes'])}: {o['op_name']}")
     print("collectives, by their place in the schedule (0 first, 1 last):")
     for o in ops:
         if o["op"].startswith(COLLECTIVES):
