@@ -1,13 +1,14 @@
-"""Model zoo (flax): seven LM families, ResNets, MLP, NatureCNN.
+"""Model zoo (flax): eight LM families, ResNets, MLP, NatureCNN.
 
 The LM families, each a file and a ``model_kind`` of
 ``serve/llm_engine.py::build_model``: GPT-2 (``gpt2.py``), the Llama decoder
 with its layer options, OLMoE's among them (``llama.py``), Falcon-H1
 (``falcon_h1.py``), Nemotron-H (``nemotron_h.py``), Ling-linear
-(``ling_linear.py``), GLM-DSA (``glm_dsa.py``) and Latent-MoE
-(``latent_moe.py``: Sarvam-105B's shape).  The first four are imported
-here; the last three are imported by ``build_model`` alone, so that no
-other kind's set-up pays for them.
+(``ling_linear.py``), GLM-DSA (``glm_dsa.py``), Latent-MoE
+(``latent_moe.py``: Sarvam-105B's shape) and the EVA decoder
+(``eva_decoder.py``: EvaByte's shape, EVA attention in every layer).  The
+first four are imported here; the last four are imported by ``build_model``
+alone, so that no other kind's set-up pays for them.
 
 The reference's model layer is RLlib's ModelCatalog + torch/tf ModelV2
 (rllib/models/catalog.py, rllib/models/torch/*) plus whatever user code

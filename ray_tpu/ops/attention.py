@@ -1209,3 +1209,44 @@ def flash_attention_qkv(qkv, num_heads: int, causal: bool = True,
         ops = tuple(jnp.split(qkv, 3, axis=-1))
     return _flash(ops, num_heads, causal, sm_scale, block_q, block_k,
                   interpret)
+
+
+def mha_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array,
+                      causal: bool = True, sm_scale: Optional[float] = None,
+                      use_flash: Optional[bool] = None
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """``mha_attention`` and every query row's log-sum-exp of its scaled
+    scores, ``[B, H, Lq]`` float32: for a caller that goes on to merge the
+    result with another softmax's part over further keys
+    (``ops/eva.py::eva_prefill_attention``).  The same dispatch; the flash
+    path is the forward kernel with the residual the backward reads (forward
+    only: this function has no gradient of its own)."""
+    b, lq, h, d = q.shape
+    lk = k.shape[1]
+    if use_flash is None:
+        use_flash = _flash_by_default(b, lq, lk, h, q.dtype, causal)
+    if not use_flash:
+        scale = sm_scale if sm_scale is not None else d ** -0.5
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((lq, lk), bool), k=lk - lq),
+                          s, NEG_INF)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        p = jnp.exp(s - lse[..., None]).astype(v.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v), lse
+    block_q, block_k = _blocks(lq, lk, d, causal, None, None)
+    ops = (q.reshape(b, lq, h * d), k.reshape(b, lk, h * d),
+           v.reshape(b, lk, h * d))
+    out, (_, _, lse) = _flash_forward(ops, h, causal, sm_scale, block_q,
+                                      block_k, False, with_lse=True)
+    # lse as ``_pack_rows`` lays it: [B, blocks, 8, Lq], a column block's
+    # heads each on sublanes of their own; with head-major operands (no
+    # column blocks) [B * H, 1, 8, Lq], a head on all eight
+    heads, _ = _form(lq, lk, h, d, q.dtype, causal, sm_scale, block_q,
+                     block_k, False)
+    if heads:
+        lse = lse.reshape(b, h // heads, heads, _LSE_SUBLANES // heads, lq)
+        lse = lse[:, :, :, 0]
+    else:
+        lse = lse[:, 0, 0]
+    return out.reshape(b, lq, h, d), lse.reshape(b, h, lq)

@@ -25,6 +25,20 @@ engine writes them once, after the last layer, so that the pool is only
 read here and updated in place there): ``paged_attention`` folds their
 causal block in with the recurrence ``ops/attention.py`` already has.
 On a CPU backend the same kernel runs interpreted.
+
+At 32 KV heads of 128 (``models/eva_decoder.py``, the widest pool so far) a
+page is 16 rows x 4,096 columns, 128 KB a pool; ``PAGES_PER_STEP`` 8 stands
+there: a step's K and V buffers, held twice, are 4 MiB of VMEM, under the
+compiler's default of scoped memory (no limit of its own is asked for:
+``tests/test_flash_compile_tpu.py``), and a step copies 2 MB, long enough
+for the next step's copies to hide behind.  The block-diagonal queries are
+32 rows x 4,096 columns: the MXU multiplies 32 times what the heads need,
+32 FLOP for every byte of K and V, still under the v5e's ridge (~240), and
+the kernel reads 80-86% of its memory roofline there (PERF.md section 5,
+PR 61).  The table row the kernel follows need not be the engine's own: a
+model whose cached rows are not its tokens hands it a row composed on the
+device (``ops/eva.py::EvaCacheMap.read_table``: summary pages, then a ring
+of window pages) with the count of live rows in it.
 """
 from __future__ import annotations
 

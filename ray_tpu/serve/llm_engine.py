@@ -122,6 +122,26 @@ Load-bearing ideas:
    nothing else (the prefix cache, a draft model, remote prefill, the
    tail prefill) is refused for such a model at construction.
 
+10. **Rows that are not tokens.**  For every other model a slot's position
+   is also its count of cached rows and the place of its next write.  A
+   model whose layers keep an exact window of rows and one pooled row for
+   every chunk before it (EVA attention: ``models/eva_decoder.py``) says
+   what a slot holds instead, and where (``cache_map``:
+   ``ops/eva.py::EvaCacheMap``): a row of the page table is ``[summary
+   columns | ring columns]``; at position n a step reads ``128 * (n //
+   2048) + n % 2048`` rows (the published window and chunk) through
+   ``ops/paged_attention.py`` as it is, over a table row composed on the
+   device (the summary pages of the windows before, then the ring); writes
+   its new row into the RING, which every window overwrites from its first
+   column; and, when its token closes a chunk, pools that page into one
+   summary row.  ``_grow`` hands out a summary page every 256 positions
+   and ring pages through the first window only: a slot at 32,768
+   positions owns 256 pages, not 2,048.  All of it is arithmetic on n, so
+   ``lengths`` stays the position, on the device, a step ahead of the
+   host.  A prefill of such a model hands over what the cache keeps (the
+   open window's rows, every whole chunk's summary) and not its every row.
+   What hands a request over as pages of K/V is refused, as in idea 9.
+
 Request/response payloads ride the object plane zero-copy: see
 ``generate_many`` (client: ``put_many`` prompts → replica:
 ``get_many`` → decode → ``put_many`` outputs → client: ``get_many``).
@@ -220,6 +240,70 @@ def _latent_attend(model):
     the paged kernel; None for every other model, whose pools and programs
     stay what they were."""
     return getattr(model, "latent_paged_attend", None)
+
+
+def _cache_map(model, page_size: int, max_ctx: int):
+    """What a slot holds, and where, for a model whose cached rows are not
+    its tokens (``cache_map``: ``models/eva_decoder.py``); None for every
+    other model, whose programs and bookkeeping stay what they were."""
+    declared = getattr(model, "cache_map", None)
+    return None if declared is None else declared(page_size, max_ctx)
+
+
+def _write_mapped(cmap, model, params, k_pages, v_pages, table, lengths,
+                  active, new_kvs):
+    """The scatter of a decode step whose cache follows a map
+    (``ops/eva.py::EvaCacheMap``): every live slot's new row into its
+    ring, and, for the slots whose token closes a chunk, that chunk's
+    summary row, which the model makes of the ring page and the new row
+    (``model.close_chunks``).  A free lane's rows, and the summary of a slot
+    that closes nothing, go to the scratch page."""
+    import jax
+    import jax.numpy as jnp
+
+    slots = jnp.arange(table.shape[0])
+    ring = jnp.where(active, table[slots, cmap.ring_column(lengths)], 0)
+    closes = active & cmap.closes_chunk(lengths)
+    summary = jnp.where(closes, table[slots, cmap.summary_column(lengths)], 0)
+    page_idx = jnp.concatenate([ring, summary])
+    off = jnp.concatenate([cmap.offset(lengths),
+                           cmap.summary_offset(lengths)])
+
+    def as_stored(rows):  # a layer's [slots, 1, H, D] -> [L, slots, H * D]
+        rows = jnp.stack(rows)
+        return rows.reshape(rows.shape[:2] + (-1,)).astype(k_pages.dtype)
+
+    new = [as_stored([nk[i] for nk in new_kvs]) for i in (0, 1)]
+    with jax.named_scope("close_chunks"):
+        pooled = model.close_chunks(params, k_pages, v_pages,
+                                    jnp.where(closes, ring, 0), *new)
+    return tuple(_write_rows(pages, jnp.concatenate([rows, both], axis=1),
+                             page_idx, off)
+                 for pages, rows, both in zip((k_pages, v_pages), new,
+                                              pooled))
+
+
+def _write_kept(cmap, k_pages, v_pages, row, p, kept):
+    """The scatter of a prefill whose model hands over what the cache keeps
+    (a layer: ring k, ring v, summary k, summary v, a batch of one): the
+    open window's ``p % window`` rows into the ring and the summaries of the
+    ``p // chunk`` whole chunks into the summary pages; whatever else the
+    bucket computed (its padding, the chunks that hold some) goes to the
+    scratch page."""
+    import jax.numpy as jnp
+
+    r = jnp.arange(kept[0][0].shape[1])       # offsets into the open window
+    at = jnp.arange(kept[0][2].shape[1]) * cmap.chunk  # a chunk's first row
+    page_idx = jnp.concatenate([
+        jnp.where(r < cmap.window_rows(p), row[cmap.ring_column(r)], 0),
+        jnp.where(at < p // cmap.chunk * cmap.chunk,
+                  row[cmap.summary_column(at)], 0)])
+    off = jnp.concatenate([cmap.offset(r), cmap.summary_offset(at)])
+    return tuple(
+        _write_rows(pages, jnp.stack([
+            jnp.concatenate([layer[i][0], layer[i + 2][0]])
+            for layer in kept]), page_idx, off)
+        for i, pages in enumerate((k_pages, v_pages)))
 
 
 def _write_new(pages, rows, page_idx, off):
@@ -541,6 +625,11 @@ class LLMEngine:
             raise ValueError(
                 f"max_ctx {self.max_ctx} (page-rounded) exceeds the model's "
                 f"max_position_embeddings {c.max_position_embeddings}")
+        # A model whose cached rows are not its tokens says how many pages
+        # a slot owns at ``max_ctx`` (idea 10 of the module's docstring).
+        self._cmap = _cache_map(model, self.page_size, self.max_ctx)
+        if self._cmap is not None:
+            self.pages_per_slot = self._cmap.pages_per_slot
         # Default pool: full provisioning (+1 scratch) — every slot can
         # reach max_ctx, preemption never fires.  Size it down to share
         # the pool across more slots than worst-case memory allows.
@@ -604,6 +693,12 @@ class LLMEngine:
                 f"{handed_over[0]}= cannot serve a model whose cache is one "
                 "pool of latent rows: a cached prefix, a draft's window and "
                 "a remote prefill all hand over pages of K AND V")
+        if self._cmap is not None and handed_over:
+            raise ValueError(
+                f"{handed_over[0]}= cannot serve a model whose cached rows "
+                "are not its tokens: a cached prefix, a draft's window and a "
+                "remote prefill all hand over one page of K/V for every "
+                "page of positions, not a ring and summaries")
         self._sparse = _sparse_attend(model) is not None
         # a prefill that is told its bucket's real rows and takes the head
         # at the last of them alone
@@ -1106,6 +1201,24 @@ class LLMEngine:
             out["dsa_selected_share"] = (
                 out["dsa_rows_read"] / out["dsa_rows_scored"]
                 if out["dsa_rows_scored"] else 0.0)
+        if self._cmap is not None:
+            # A cache whose rows are not its tokens: what a slot owns and
+            # holds at ``max_ctx`` positions, a token's bytes as stored
+            # there, and over the decode steps so far the positions the
+            # live slots held, the rows they read and the second over the
+            # first (1.0 would be a cache of one row a token).
+            m = self._cmap
+            out["kv_pages_per_slot"] = m.pages_per_slot
+            out["kv_rows_per_slot"] = m.rows_per_slot
+            out["kv_positions_per_slot"] = self.max_ctx
+            out["kv_bytes_per_token"] = (out["kv_bytes_per_token"]
+                                         * m.rows_per_slot // self.max_ctx)
+            for key in ("cache_ctx_tokens", "cache_rows_read",
+                        "cache_chunks_closed", "cache_windows_closed"):
+                out[key] = s.get(key, 0)
+            out["cache_rows_share"] = (
+                out["cache_rows_read"] / out["cache_ctx_tokens"]
+                if out["cache_ctx_tokens"] else 0.0)
         if self._prefix is not None:
             out["prefix_cache"] = self._prefix.stats()
         cache_size = getattr(self._decode, "_cache_size", None)
@@ -1177,6 +1290,7 @@ class LLMEngine:
         routes = _routes(model)
         sparse = _sparse_attend(model)
         own_hook = sparse or _latent_attend(model)
+        cmap = self._cmap  # the target's: a draft is refused beside a map
         held = getattr(model.config, "experts_held", None)
         if held is not None:  # a share: (first expert, how many)
             held = (model.config.expert_offset, held)
@@ -1198,9 +1312,13 @@ class LLMEngine:
                     # clamped: the newest wp pages.
                     last_page = jnp.maximum(lengths - 1, 0) // ps
                     first = jnp.maximum(last_page - (window_pages - 1), 0)
+                # where the cached rows are not the tokens: the row of
+                # pages to follow and the live rows of it, from the position
+                read, cached = (table, lengths) if cmap is None else \
+                    cmap.read_table(table, lengths)
                 out = model.apply(
                     {"params": params}, tokens[:, None], lengths[:, None],
-                    _paged_attend(L, k_pages, v_pages, table, lengths,
+                    _paged_attend(L, k_pages, v_pages, read, cached,
                                   active, first, own_hook),
                     mutable=(["moe", "dsa"] if record_experts and sparse
                              else ["moe"]) if routes else False, **carried)
@@ -1212,14 +1330,19 @@ class LLMEngine:
                     logits[:, -1], lengths + 1, temps, top_ps, seeds)
             with scope("scatter"):
                 # a layer's rows: [slots, 1, Hkv, D]
-                slot_ix = jnp.arange(table.shape[0])
-                page_col = jnp.minimum(lengths // ps, pp - 1)
-                page_idx = jnp.where(active, table[slot_ix, page_col], 0)
-                off = lengths % ps
-                k_pages = _write_new(k_pages, [nk[0] for nk in new_kvs],
-                                     page_idx, off)
-                v_pages = _write_new(v_pages, [nk[1] for nk in new_kvs],
-                                     page_idx, off)
+                if cmap is not None:
+                    k_pages, v_pages = _write_mapped(
+                        cmap, model, params, k_pages, v_pages, table,
+                        lengths, active, new_kvs)
+                else:
+                    slot_ix = jnp.arange(table.shape[0])
+                    page_col = jnp.minimum(lengths // ps, pp - 1)
+                    page_idx = jnp.where(active, table[slot_ix, page_col], 0)
+                    off = lengths % ps
+                    k_pages = _write_new(k_pages, [nk[0] for nk in new_kvs],
+                                         page_idx, off)
+                    v_pages = _write_new(v_pages, [nk[1] for nk in new_kvs],
+                                         page_idx, off)
             out = (k_pages, v_pages, next_tok, next_logp,
                    lengths + active.astype(lengths.dtype))
             if routes:
@@ -1323,6 +1446,7 @@ class LLMEngine:
 
         record = self.record_experts
         ragged = self._ragged
+        cmap = self._cmap
 
         def prefill(params, k_pages, v_pages, row, tokens, p, temp, top_p,
                     seed, slot=None, state=None):
@@ -1365,15 +1489,19 @@ class LLMEngine:
                     jnp.reshape(seed, (1,)))
                 next_tok, next_logp = toks[0], logps[0]
             with jax.named_scope("scatter"):
-                t = jnp.arange(bucket)
-                page_idx = jnp.where(t < p, row[t // ps], 0)
-                off = t % ps
-                # a layer's rows: [bucket, Hkv, D]
-                k_pages = _write_new(k_pages, [nk[0][0] for nk in new_kvs],
-                                     page_idx, off)
-                v_pages = _write_new(
-                    v_pages, [None if nk[1] is None else nk[1][0]
-                              for nk in new_kvs], page_idx, off)
+                if cmap is not None:  # what the model says the cache keeps
+                    k_pages, v_pages = _write_kept(cmap, k_pages, v_pages,
+                                                   row, p, new_kvs)
+                else:
+                    t = jnp.arange(bucket)
+                    page_idx = jnp.where(t < p, row[t // ps], 0)
+                    off = t % ps
+                    # a layer's rows: [bucket, Hkv, D]
+                    k_pages = _write_new(
+                        k_pages, [nk[0][0] for nk in new_kvs], page_idx, off)
+                    v_pages = _write_new(
+                        v_pages, [None if nk[1] is None else nk[1][0]
+                                  for nk in new_kvs], page_idx, off)
             out = (k_pages, v_pages, next_tok, next_logp)
             if state is not None:
                 out += (state,)
@@ -1404,6 +1532,10 @@ class LLMEngine:
             raise ValueError(
                 "a tail prefill cannot serve a model with learned sparse "
                 "attention: it attends to every row of the cached prefix")
+        if self._cmap is not None:
+            raise ValueError(
+                "a tail prefill cannot serve a model whose cached rows are "
+                "not its tokens: it reads the prefix as one row a position")
         key = ("tail", bucket)
         fn = self._prefills.get(key)
         if fn is not None:
@@ -1690,7 +1822,8 @@ class LLMEngine:
                 req = self._pending[0]
                 ctx = req.context()
                 p = len(ctx)
-                need = math.ceil(p / self.page_size)
+                cols = self._admission_columns(p)
+                need = len(cols)
                 if need + 1 > self.pool.capacity:
                     # Can never fit, even with the whole pool to itself —
                     # waiting would busy-spin forever.
@@ -1731,7 +1864,7 @@ class LLMEngine:
             self._left_queue(req)
             self._slot_pages[slot] = pages
             row = np.zeros((self.pages_per_slot,), np.int32)
-            row[:need] = pages
+            row[cols] = pages
             self._table[slot] = row
             # Longest cached prefix: adopt its pages, prefill the tail.
             cached = self._lookup_prefix(ctx)
@@ -1741,6 +1874,31 @@ class LLMEngine:
                 self._stats["prefill_tokens_saved"] += start
             nxt, lp = self._local_prefill(slot, req, ctx, start)
             self._finish_admission(slot, req, p, nxt, lp, mid_batch)
+
+    def _admission_columns(self, p: int) -> List[int]:
+        """The columns of a slot's table row that hold a page once a
+        context of ``p`` rows is cached: the first ``ceil(p / page_size)``,
+        or what the model's map says (summary pages, then ring pages)."""
+        if self._cmap is None:
+            return list(range(math.ceil(p / self.page_size)))
+        summaries, ring = self._cmap.owned(p - 1)
+        first = self._cmap.summary_pages
+        return list(range(summaries)) + list(range(first, first + ring))
+
+    def _column_missing(self, slot: int, last: int) -> Optional[int]:
+        """The column of the slot's table row that wants a page before
+        position ``last`` can be written; None when it owns them all.  A
+        slot's pages grow a position at a time, so under a map only the
+        last column of either part can be missing (page 0 is the scratch
+        page: no slot owns it)."""
+        if self._cmap is None:
+            owned = len(self._slot_pages[slot])
+            return owned if last // self.page_size >= owned else None
+        summaries, ring = self._cmap.owned(last)
+        for col in (summaries - 1, self._cmap.summary_pages + ring - 1):
+            if not self._table[slot, col]:
+                return col
+        return None
 
     def _left_queue(self, req: _Request):
         """``_admit`` has just taken ``req`` off ``_pending``: the end of
@@ -1845,6 +2003,13 @@ class LLMEngine:
         if self._sparse:
             scanned["selecting_rows"] = max(
                 0, p - self._model.config.index_topk)
+        if self._cmap is not None:
+            # what the prefill hands the cache: the windows its real rows
+            # span, the open window's rows and the whole chunks' summaries
+            m = self._cmap
+            scanned.update(windows=-(-p // m.window),
+                           window_rows=int(m.window_rows(p)),
+                           summary_rows=p // m.chunk)
         with obs.span("engine.prefill", request_id=req.id, bucket=bucket,
                       prompt_tokens=p, cached_tokens=start, **scanned):
             toks = np.zeros((bucket,), np.int32)
@@ -2083,13 +2248,13 @@ class LLMEngine:
         for slot in range(self.max_slots):
             while self._active[slot] and self._budget[slot] > 0:
                 pos = int(self._lengths[slot])
-                page_needed = min(pos + horizon - 1,
-                                  self.max_ctx - 1) // self.page_size
-                if page_needed < len(self._slot_pages[slot]):
+                last = min(pos + horizon - 1, self.max_ctx - 1)
+                col = self._column_missing(slot, last)
+                if col is None:
                     break
                 got = self.pool.alloc(1)
                 if got is not None:
-                    self._table[slot, len(self._slot_pages[slot])] = got[0]
+                    self._table[slot, col] = got[0]
                     self._slot_pages[slot].append(got[0])
                     given += 1
                     continue
@@ -2103,7 +2268,8 @@ class LLMEngine:
                 if victim is None:
                     req = self._slot_req[slot]
                     self._retire(slot, req, error=KVPoolExhaustedError(
-                        f"request {req.id} needs page {page_needed + 1} "
+                        f"request {req.id} needs page "
+                        f"{len(self._slot_pages[slot]) + 1} "
                         f"but the pool ({self.pool.capacity} pages) is "
                         f"exhausted and no other request can be "
                         f"preempted"))
@@ -2209,6 +2375,20 @@ class LLMEngine:
             if self._sparse:
                 moved["index_rows"] = kv_tokens * self.num_layers
                 self._stats["dsa_rows_scored"] += moved["index_rows"]
+            if self._cmap is not None:
+                # the rows read are not the positions held (ctx_tokens):
+                # summaries of the windows before and the open window's rows
+                m, at = self._cmap, self._lengths[rows]
+                moved.update(
+                    ctx_tokens=kv_tokens,
+                    summary_rows=int(m.summary_rows(at).sum()),
+                    window_rows=int(m.window_rows(at).sum()),
+                    chunks_closed=int(m.closes_chunk(at).sum()),
+                    windows_closed=int(m.closes_window(at).sum()))
+                kv_tokens = moved["summary_rows"] + moved["window_rows"]
+                for key in ("ctx_tokens", "chunks_closed", "windows_closed"):
+                    self._stats["cache_" + key] += moved[key]
+                self._stats["cache_rows_read"] += kv_tokens
         with obs.span("engine.decode.dispatch", kv_tokens=kv_tokens,
                       sampling_rows=sampling_rows, in_flight=in_flight,
                       **moved):
@@ -2631,6 +2811,13 @@ def _build_model(model_kind: str, config_kw: Optional[dict], seed: int):
         model = LatentMoE(LatentMoEConfig.tiny(**config_kw)
                           if config_kw.pop("tiny", True)
                           else LatentMoEConfig(**config_kw))
+    elif model_kind == "eva_decoder":
+        # imported here and nowhere else, as ``ling_linear``
+        from ray_tpu.models.eva_decoder import EvaDecoder, EvaDecoderConfig
+
+        model = EvaDecoder(EvaDecoderConfig.tiny(**config_kw)
+                           if config_kw.pop("tiny", True)
+                           else EvaDecoderConfig(**config_kw))
     else:
         raise ValueError(f"unknown model_kind {model_kind!r}")
     ids = jnp.zeros((1, 8), jnp.int32)
@@ -2702,11 +2889,14 @@ class LLMServer:
     (latent attention over the whole cache in every layer under a
     YaRN-scaled rope; the cache is ONE pool of latent rows, read by the
     latent form of the paged kernel; a leading dense layer; a sigmoid
-    router's experts with the same share).  For ``"falcon_h1"``,
+    router's experts with the same share) or ``"eva_decoder"`` (EVA
+    attention in every layer: an exact window of rows beside one pooled row
+    for every chunk before it; a slot's pages are a ring of window pages
+    and summary pages, by the model's ``cache_map``).  For ``"falcon_h1"``,
     ``"nemotron_h"`` and ``"ling_linear"`` the engine holds per-slot
-    recurrent state, and for them, ``"glm_dsa"`` and ``"latent_moe"`` it
-    refuses the four options above (a cached prefix, a draft, a prefix
-    directory, remote prefill).
+    recurrent state, and for them, ``"glm_dsa"``, ``"latent_moe"`` and
+    ``"eva_decoder"`` it refuses the four options above (a cached prefix, a
+    draft, a prefix directory, remote prefill).
     """
 
     def __init__(self, model_kind: str = "gpt2",

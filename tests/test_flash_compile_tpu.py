@@ -280,6 +280,56 @@ def test_latent_paged_attention_compiles_for_v5e_one_row_a_token(
 
 
 @pytest.mark.timeout(180)
+def test_eva_decode_kernels_compile_for_v5e_at_32_kv_heads_of_128(
+        one_chip, monkeypatch):
+    """What a decode step of ``models/eva_decoder.py`` asks of the chip at
+    the published widths, 16 slots of 256 pages: the paged kernel over pages
+    4,096 columns wide (32 KV heads of 128: its two step buffers are 4 MiB,
+    under the compiler's default of scoped VMEM, so it asks for no limit of
+    its own; its first result is ``f32[16,32,4096]``, which the benchmark's
+    ``eva_paged_attn_roofline`` tells it by), and ``eva_page_gather``, which
+    hands the step the pages of the chunks its tokens close and writes
+    nothing of the pool's size (indexing the pool made the compiler lay the
+    whole of it out anew: 4 GiB of scratch)."""
+    from ray_tpu.ops import paged_attention as pa
+    from ray_tpu.ops.eva import gather_pages
+    from tools.step_fusions import entry_operations
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    slots, pages = 16, 256
+    assert pa.pool_width(32, 128) == 4096
+    pool = shape(8, slots * pages + 1, 16, 4096)
+    text = jax.jit(lambda q, k, v, kp, vp, table, lengths: pa.paged_attention(
+        q, k, v, kp, vp, 5, table, lengths)).lower(
+        shape(slots, 1, 32, 128), shape(slots, 1, 32, 128),
+        shape(slots, 1, 32, 128), pool, pool,
+        shape(slots, pages, dtype=jnp.int32),
+        shape(slots, dtype=jnp.int32)).compile().as_text()
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert "paged_attn" in call
+    assert call.split(" = ")[1].startswith("(f32[16,32,4096]")
+    found = re.search(
+        r'"scoped_memory_configs":\[\{[^\]]*?"size":"(\d+)"', call)
+    assert not found or int(found.group(1)) <= 16 * 2 ** 20
+
+    compiled = jax.jit(gather_pages).lower(
+        pool, shape(slots, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    assert "eva_page_gather" in call
+    assert call.split(" = ")[1].startswith("bf16[8,16,16,4096]")
+    big = [o["name"] for o in entry_operations(text)
+           if any(s.startswith("bf16[8,4097") for s in o["shapes"])
+           and o["op"] not in ("parameter", "bitcast")]
+    assert not big, big
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.timeout(180)
 def test_flash_forward_compiles_for_v5e_at_16k_rows_of_192(one_chip):
     """A block of 16 heads of 192 over 16,384 rows, as the latent-attention
     prefill of the 16,384 bucket calls the forward kernel: K and V whole
